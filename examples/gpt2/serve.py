@@ -277,6 +277,23 @@ def main(argv):
         ),
         sharding=sharding,
     )
+    # Say where the weights and the cache actually landed: a workdir
+    # layout that did not fit this host serves replicated on one device
+    # (above), and that must be visible, not inferred.
+    from tensorflow_examples_tpu.telemetry.memory import tree_bytes
+
+    held = lambda tree: len(
+        set().union(*(x.devices() for x in jax.tree.leaves(tree)))
+    )
+    per_dev = lambda tree: tree_bytes(tree, per_device=True) / 2**20
+    kv = (engine.pool.k, engine.pool.v)
+    print(
+        f"placement: params on {held(engine.params)} "
+        f"({per_dev(engine.params):.0f} MiB each) and KV pool on "
+        f"{held(kv)} ({per_dev(kv):.0f} MiB each) of "
+        f"{jax.device_count()} device(s)",
+        file=sys.stderr,
+    )
     t0 = time.perf_counter()
     engine.warmup()
     print(

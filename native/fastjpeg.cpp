@@ -2,7 +2,7 @@
 //
 // Round-4 verdict: the ResNet-50 input-fed bench is host-bound and the
 // decode stage still ran in the tf.data graph while only normalize ran
-// in native/fastdata.cpp (VERDICT r4 weak #2). This library makes the
+// in native/fastdata.cpp. This library makes the
 // whole per-image path ONE C++ stage on the existing thread-pool
 // pattern: libjpeg(-turbo) decode (with DCT scaled decoding — 1/2, 1/4,
 // 1/8 — whenever the crop region stays >= the output size, which cuts

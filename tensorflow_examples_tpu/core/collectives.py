@@ -34,44 +34,18 @@ def shard_map(
     axis_names: set | None = None,
     check_vma: bool = True,
 ):
-    """Version-portable ``shard_map`` (the framework's single spelling).
-    ``check_vma`` defaults to True to match ``jax.shard_map`` — callers
-    that need it off (every Pallas-opaque site today) say so.
-
-    Newer jax exposes ``jax.shard_map`` (manual axes named via
-    ``axis_names``, replication checking via ``check_vma``); on older
-    builds the same program spells ``jax.experimental.shard_map``
-    (manual-set complement via ``auto``, checking via ``check_rep``).
-    Every shard_map in the framework routes through here so the
-    collectives layer — not each caller — owns the translation, and a
-    jax upgrade/downgrade is one-file work.
+    """``jax.shard_map`` with the framework's defaults (its single
+    spelling): ``mesh``/``axis_names`` passed only when given, so the
+    meshless form resolves its manual axes from the enclosing
+    shard_map. ``check_vma`` defaults to True to match ``jax.shard_map``
+    — callers that need it off (every Pallas-opaque site today) say so.
     """
-    if hasattr(jax, "shard_map"):
-        kw: dict = {"check_vma": check_vma}
-        if mesh is not None:
-            kw["mesh"] = mesh
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        return jax.shard_map(
-            f, in_specs=in_specs, out_specs=out_specs, **kw
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    if mesh is None:
-        # The meshless form (manual axes resolved from the enclosing
-        # shard_map context) has no pre-jax.shard_map equivalent.
-        raise NotImplementedError(
-            "context-mesh shard_map (mesh=None) requires a jax build "
-            "with jax.shard_map"
-        )
-    kw = {"check_rep": check_vma}
+    kw: dict = {"check_vma": check_vma}
+    if mesh is not None:
+        kw["mesh"] = mesh
     if axis_names is not None:
-        # jax.shard_map names the MANUAL axes; the experimental API
-        # names the complement ("auto" axes).
-        kw["auto"] = frozenset(mesh.axis_names) - set(axis_names)
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw
-    )
+        kw["axis_names"] = set(axis_names)
+    return jax.shard_map(f, in_specs=in_specs, out_specs=out_specs, **kw)
 
 
 def psum(x: Any, axis: AxisName) -> Any:

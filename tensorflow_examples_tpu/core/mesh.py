@@ -31,7 +31,7 @@ import math
 from typing import Sequence
 
 import jax
-import numpy as np
+from jax.experimental import mesh_utils
 from jax.sharding import Mesh
 
 
@@ -126,21 +126,17 @@ def create_mesh(
 ) -> Mesh:
     """Build the framework-standard 5-axis mesh.
 
-    ``jax.experimental.mesh_utils`` is used when available so the mesh
-    layout follows the physical ICI topology (keeps the fastest-varying
-    logical axis on the torus); on CPU / single chip it degenerates to a
-    simple reshape.
+    ``mesh_utils.create_device_mesh`` lays the mesh out along the
+    physical ICI topology (keeps the fastest-varying logical axis on the
+    torus); on CPU / single chip it is a plain reshape. A shape it
+    cannot place on the real chips is an error — never a quiet,
+    topology-blind reshape.
     """
     if devices is None:
         devices = jax.devices()
     config = config or MeshConfig()
     shape = config.resolve(len(devices))
-    try:
-        from jax.experimental import mesh_utils
-
-        device_array = mesh_utils.create_device_mesh(shape, devices=list(devices))
-    except Exception:
-        device_array = np.asarray(list(devices)).reshape(shape)
+    device_array = mesh_utils.create_device_mesh(shape, devices=list(devices))
     return Mesh(device_array, axis_names=AxisNames.ALL)
 
 

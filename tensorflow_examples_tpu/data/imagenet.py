@@ -317,7 +317,7 @@ def tfrecord_iter(
 
 def _native_decode_enabled() -> bool:
     """The one C++ stage (libfastjpeg: decode + crop + resize + flip +
-    normalize, VERDICT r4 weak #2) is used whenever it built; set
+    normalize) is used whenever it built; set
     ``TFE_TPU_NATIVE_DECODE=0`` to force the tf.data decode path."""
     if os.environ.get("TFE_TPU_NATIVE_DECODE", "1") == "0":
         return False

@@ -40,6 +40,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tensorflow_examples_tpu.core.device import pallas_interpret
+
 NEG_INF = -1e30
 
 
@@ -487,7 +489,7 @@ def _make_flash_bias(causal, block_q, block_kv, interpret, heads):
     return flash
 
 
-_DEFAULT_BLOCK = 256  # fastest measured end-to-end at GPT-2 shapes (v5e)
+_DEFAULT_BLOCK = 256  # one guess; the on-chip sweep is ROADMAP queue 1 item 9
 
 
 def _fit_block(target: int, seq: int) -> int:
@@ -518,12 +520,13 @@ def _fit_block(target: int, seq: int) -> int:
 
 @functools.lru_cache(maxsize=1)
 def _tuned_block_table() -> dict:
-    """Measured per-sequence block defaults from the on-chip sweep
-    (tools/flash_tune.py → docs/tpu_sweeps/flash_block_table.json,
-    committed with its evidence record). Maps str(seq) →
+    """Per-sequence block defaults from an on-chip sweep
+    (tools/flash_tune.py → tools/flash_table_from_sweep.py →
+    docs/tpu_sweeps/flash_block_table.json). Maps str(seq) →
     {"block_q": B, "block_kv": B} from the fwd+bwd-optimal cell —
-    training is the default consumer. Missing file (fresh checkout, no
-    sweep banked yet) → empty table → the 256 fallback."""
+    training is the default consumer. No sweep has been run on a v5e,
+    so the file does not exist and the table is empty (the 256
+    fallback); a file that exists but cannot be read is an error."""
     import json
     import os
 
@@ -536,7 +539,7 @@ def _tuned_block_table() -> dict:
     try:
         with open(path) as f:
             return json.load(f).get("by_seq", {})
-    except Exception:
+    except FileNotFoundError:
         return {}
 
 
@@ -560,7 +563,7 @@ def _resolve_block(block: int | None, seq: int, which: str = "block_q") -> int:
 
 def _prepare(q, k, v, causal, sm_scale, block_q, block_kv, interpret):
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret("flash_attention")
     b, h, seq_q, head_dim = q.shape
     seq_kv = k.shape[2]
     block_q = _resolve_block(block_q, seq_q, "block_q")
@@ -591,11 +594,11 @@ def flash_attention(
 ) -> jax.Array:
     """Blockwise attention, differentiable; q/k/v: [batch, heads, seq, dim].
 
-    Runs the Pallas TPU kernel on TPU; on other backends runs the same
-    kernel in interpret mode (tests) unless ``interpret=False``.
-    block_q/block_kv None = auto: 256-targeted (measured ~1.3% faster
-    end-to-end than 128 on GPT-2 124M, b8 s1024, single v5e chip,
-    within-run comparison), fitted down to a hardware-legal divisor of
+    Runs the Pallas kernel compiled by Mosaic on ``tpu`` and in
+    interpret mode on ``cpu`` (the tests); any other platform needs an
+    explicit ``interpret=`` (``core/device.pallas_interpret``).
+    block_q/block_kv None = auto: 256-targeted (not measured on a v5e —
+    PERF.md), fitted down to a hardware-legal divisor of
     the sequence; explicit sizes are enforced exactly.
 
     ``key_bias``: optional [batch, seq_kv] additive score bias (f32),

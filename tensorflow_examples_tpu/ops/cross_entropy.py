@@ -25,6 +25,7 @@ from jax import lax
 
 from tensorflow_examples_tpu.core import collectives as coll
 from tensorflow_examples_tpu.core.collectives import shard_map as _shard_map
+from tensorflow_examples_tpu.core.device import pallas_interpret
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -169,10 +170,10 @@ def cross_entropy_per_example(
     Blocks clamp to the actual (n, vocab) for small shapes."""
     if fused is None:
         fused = True
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     if not fused:
         return cross_entropy_reference(logits, labels)
+    if interpret is None:
+        interpret = pallas_interpret("fused_cross_entropy")
     n, vocab = logits.shape
     block_n = min(block_n, n)
     block_v = min(block_v, vocab)

@@ -41,6 +41,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tensorflow_examples_tpu.core.device import pallas_interpret
 from tensorflow_examples_tpu.ops.attention import NEG_INF, _fit_block
 
 
@@ -132,8 +133,7 @@ def _make_decode(q_len, block_q, block_kv, interpret, kv_blocks):
     The public entry compiles a power-of-two LADDER of these (see
     ``flash_decode_attention``) and lax.switches on the populated block
     count, so per-step grid-sequencer work is bounded by ~2× the
-    populated context rather than by ``max_len`` (VERDICT r3 item 4:
-    the clamp already suppressed DMA + MXU for unpopulated blocks, but
+    populated context rather than by ``max_len`` (the clamp already suppressed DMA + MXU for unpopulated blocks, but
     a 32k-slot cache still sequenced cdiv(32k, block) programs per
     single-token step). The kernel body is bucket-agnostic — finalize
     keys off ``pl.num_programs`` and the index clamp covers buckets
@@ -208,7 +208,7 @@ def flash_decode_attention(
     contract as the caller's cache update.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret("flash_decode_attention")
     b, h, q_len, head_dim = q.shape
     max_len = k_cache.shape[2]
     if sm_scale is None:
@@ -231,7 +231,7 @@ def flash_decode_attention(
     # cdiv(max_len, block_kv). Each bucket is its own compiled kernel;
     # the populated block count picks the smallest sufficient bucket,
     # so a short-context step through a huge cache sequences O(context)
-    # programs, not O(max_len) (VERDICT r3 item 4).
+    # programs, not O(max_len).
     total = pl.cdiv(max_len, block_kv)
     counts = []
     c = 1

@@ -31,18 +31,34 @@ prefill case):
   at 1 byte/element from HBM and widened to f32 only in VMEM — the
   whole point of int8 KV on a bandwidth-bound step.
 
-Grid is (slot, head, kv-block) with the familiar online-softmax scratch
-carry (``ops/attention.py``). Unpopulated trailing blocks are clamped
-to the last populated index in the index map — a repeated index is a
-no-op for the Pallas pipeline, so **no HBM traffic is issued for blocks
-past a slot's length** — and ``pl.when`` skips their compute.
+Grid is (slot, kv-block); one step handles EVERY head of one physical
+block, with the familiar online-softmax scratch carry
+(``ops/attention.py``) kept per head. Heads ride as a full block
+dimension because Mosaic constrains the last two dims of a block to
+(8k, 128k) or the full array dim: a per-head ``(1, 1, head_dim)`` block
+over ``[S, H, D]`` (or ``(1, 1, block_size)`` over the ``[NB, H, BS]``
+scales) cannot lower, while ``(1, H, D)`` / ``(1, H, BS)`` /
+``(1, H, BS, D)`` can — and the grid is H times shorter for it. Inside
+a step the heads are a static unrolled loop of the same 2-D
+``[1, D]·[BS, D]ᵀ`` products ``ops/decode.py`` runs. Unpopulated
+trailing blocks are clamped to the last populated index in the index
+map — a repeated index is a no-op for the Pallas pipeline, so **no HBM
+traffic is issued for blocks past a slot's length** — and ``pl.when``
+skips their compute.
+
+int8 scales are per (block, head, row), so they commute with the dot:
+``q·(k·s) == (q·k)·s``. The kernel scales the ``[1, BS]`` score and
+probability rows instead of the ``[BS, D]`` tiles — same math, D times
+fewer multiplies, and no lane→sublane relayout of the scale row.
 
 The XLA gather path (``kv_cache.varlen_decode_attention`` with
 ``block_tables=``) stays in-tree as the reference oracle:
 tests/test_kernels.py pins this kernel against it element-wise in
-interpret mode (tier-1, CPU) across slot-length/block-table edge cases,
-and the engine keeps it selectable (``ServeConfig.attention="xla"``).
-No backward: decode is inference-only.
+interpret mode (tier-1, CPU) across slot-length/block-table edge cases
+and cross-lowers it for the TPU, tests_tpu/ runs it compiled on the
+chip, and the engine keeps the oracle selectable
+(``ServeConfig.attention="xla"``). No backward: decode is
+inference-only.
 """
 
 from __future__ import annotations
@@ -55,18 +71,19 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tensorflow_examples_tpu.core.device import pallas_interpret
 from tensorflow_examples_tpu.ops.attention import NEG_INF
 
 
 def _paged_decode_kernel(
     len_ref, tbl_ref, q_ref, k_ref, v_ref, *rest, sm_scale, block_size,
-    quantized,
+    num_heads, quantized,
 ):
     if quantized:
         ksc_ref, vsc_ref, o_ref, m_s, l_s, acc_s = rest
     else:
         o_ref, m_s, l_s, acc_s = rest
-    s, j = pl.program_id(0), pl.program_id(2)
+    s, j = pl.program_id(0), pl.program_id(1)
     length = len_ref[s]
     col0 = j * block_size
 
@@ -80,38 +97,43 @@ def _paged_decode_kernel(
     # fetch was already clamped to the last populated block in the
     # index map (no DMA), and this guard skips their MXU work.
     def _attend():
-        q = q_ref[0].astype(jnp.float32) * sm_scale        # [1, D]
-        k = k_ref[0, 0].astype(jnp.float32)                # [BS, D]
-        v = v_ref[0, 0].astype(jnp.float32)
-        if quantized:
-            k = k * ksc_ref[0, 0].astype(jnp.float32)[:, None]
-            v = v * vsc_ref[0, 0].astype(jnp.float32)[:, None]
-        scores = lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [1, BS]
-        col = col0 + lax.broadcasted_iota(
+        live = col0 + lax.broadcasted_iota(
             jnp.int32, (1, block_size), 1
-        )
-        scores = jnp.where(col < length, scores, NEG_INF)
-        m = m_s[...]
-        m_new = jnp.maximum(m, jnp.max(scores, axis=1, keepdims=True))
-        p = jnp.exp(scores - m_new)
-        alpha = jnp.exp(m - m_new)
-        m_s[...] = m_new
-        l_s[...] = l_s[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_s[...] = acc_s[...] * alpha + jnp.dot(
-            p, v, preferred_element_type=jnp.float32
-        )
+        ) < length
+        for h in range(num_heads):
+            row = pl.ds(h, 1)
+            q = q_ref[0, row, :] * sm_scale                # [1, D] f32
+            k = k_ref[0, h].astype(jnp.float32)            # [BS, D]
+            v = v_ref[0, h].astype(jnp.float32)
+            scores = lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [1, BS]
+            if quantized:
+                scores = scores * ksc_ref[0, row, :]
+            scores = jnp.where(live, scores, NEG_INF)
+            m = m_s[row, :]
+            m_new = jnp.maximum(m, jnp.max(scores, axis=1, keepdims=True))
+            p = jnp.exp(scores - m_new)
+            alpha = jnp.exp(m - m_new)
+            m_s[row, :] = m_new
+            l_s[row, :] = l_s[row, :] * alpha + jnp.sum(
+                p, axis=1, keepdims=True
+            )
+            if quantized:
+                p = p * vsc_ref[0, row, :]
+            acc_s[row, :] = acc_s[row, :] * alpha + jnp.dot(
+                p, v, preferred_element_type=jnp.float32
+            )
 
     pl.when(col0 < length)(_attend)
 
-    @pl.when(j == pl.num_programs(2) - 1)
+    @pl.when(j == pl.num_programs(1) - 1)
     def _finalize():
         # An empty slot (length 0, every block skipped) divides by the
         # epsilon and writes ~0 — discarded garbage, never NaN.
         l = jnp.maximum(l_s[...], 1e-30)
-        o_ref[0] = (acc_s[...] / l).astype(o_ref.dtype)
+        o_ref[0] = acc_s[...] / l
 
 
 @functools.lru_cache(maxsize=None)
@@ -121,37 +143,32 @@ def _make_paged_decode(num_slots, num_heads, nb, block_size, head_dim,
     geometry, quantization, interpret) — the engine's KV bucket ladder
     keys the table width, mirroring the dense decode rungs."""
 
-    def kv_index(s, h, j, len_ref, tbl_ref):
+    def kv_index(s, j, len_ref, tbl_ref):
         # Clamp unpopulated blocks to the last populated one: the
         # pipeline sees an unchanged physical index and skips the copy.
         last = jnp.maximum((len_ref[s] - 1) // block_size, 0)
-        return (tbl_ref[s, jnp.minimum(j, last)], h, 0, 0)
+        return (tbl_ref[s, jnp.minimum(j, last)], 0, 0, 0)
 
-    def sc_index(s, h, j, len_ref, tbl_ref):
-        last = jnp.maximum((len_ref[s] - 1) // block_size, 0)
-        return (tbl_ref[s, jnp.minimum(j, last)], h, 0)
+    def sc_index(s, j, len_ref, tbl_ref):
+        return kv_index(s, j, len_ref, tbl_ref)[:3]
 
-    in_specs = [
-        pl.BlockSpec((1, 1, head_dim), lambda s, h, j, ln, tb: (s, h, 0)),
-        pl.BlockSpec((1, 1, block_size, head_dim), kv_index),
-        pl.BlockSpec((1, 1, block_size, head_dim), kv_index),
-    ]
+    qo_spec = pl.BlockSpec(
+        (1, num_heads, head_dim), lambda s, j, ln, tb: (s, 0, 0)
+    )
+    kv_spec = pl.BlockSpec((1, num_heads, block_size, head_dim), kv_index)
+    in_specs = [qo_spec, kv_spec, kv_spec]
     if quantized:
-        in_specs += [
-            pl.BlockSpec((1, 1, block_size), sc_index),
-            pl.BlockSpec((1, 1, block_size), sc_index),
-        ]
+        sc_spec = pl.BlockSpec((1, num_heads, block_size), sc_index)
+        in_specs += [sc_spec, sc_spec]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(num_slots, num_heads, nb),
+        grid=(num_slots, nb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, 1, head_dim), lambda s, h, j, ln, tb: (s, h, 0)
-        ),
+        out_specs=qo_spec,
         scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, head_dim), jnp.float32),
+            pltpu.VMEM((num_heads, 1), jnp.float32),
+            pltpu.VMEM((num_heads, 1), jnp.float32),
+            pltpu.VMEM((num_heads, head_dim), jnp.float32),
         ],
     )
 
@@ -160,14 +177,20 @@ def _make_paged_decode(num_slots, num_heads, nb, block_size, head_dim,
             _paged_decode_kernel,
             sm_scale=sm_scale,
             block_size=block_size,
+            num_heads=num_heads,
             quantized=quantized,
         )
-        return pl.pallas_call(
+        # q and the output cross the kernel boundary in f32 whatever
+        # the serving dtype: [S, H, D] is tiny, and f32 rows slice at
+        # any static sublane offset where packed bf16 rows do not.
+        out = pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+            out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
             interpret=interpret,
-        )(lengths, tables, q, k_blocks, v_blocks, *scales)
+        )(lengths, tables, q.astype(jnp.float32), k_blocks, v_blocks,
+          *scales)
+        return out.astype(q.dtype)
 
     return call
 
@@ -188,7 +211,7 @@ def paged_decode_attention(
     table; see the module docstring for the full contract. Returns
     [S, H, D] in ``q.dtype``."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret("paged_decode_attention")
     num_slots, num_heads, head_dim = q.shape
     _, _, block_size, _ = k_blocks.shape
     nb = block_tables.shape[1]

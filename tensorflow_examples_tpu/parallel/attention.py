@@ -198,7 +198,7 @@ def mesh_attention(
         local = functools.partial(
             dot_product_attention, causal=causal, sm_scale=sm_scale
         )
-    # Causal context-parallel padding (VERDICT r3 item 7 — the zigzag
+    # Causal context-parallel padding (the zigzag
     # odd-shard corner): pad the GLOBAL sequence so every shard is even
     # (zigzag always eligible, perfectly balanced) and every half-chunk
     # kernel-tileable. Tail pads sit at the causal future of every real

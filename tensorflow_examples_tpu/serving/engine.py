@@ -591,7 +591,7 @@ def _sample_row(key, logits, temp, top_k):
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     scaled = logits / jnp.where(temp > 0, temp, 1.0)
     kth = jax.lax.dynamic_index_in_dim(
-        jnp.sort(scaled),
+        jnp.sort(scaled, stable=False),
         jnp.maximum(scaled.shape[0] - top_k, 0),
         keepdims=False,
     )
@@ -752,7 +752,14 @@ class InferenceEngine:
         self.mesh = None
         self.param_sharding_digest = None
         if sharding is None:
-            params = jax.tree.map(jnp.asarray, params)
+            # COMMITTED to device 0, like the KV pool (_kv_sharding):
+            # jit's cache key includes which arguments are committed,
+            # so a ladder warmed with a fresh (uncommitted) pool and
+            # then served with the committed arrays its own steps
+            # returned recompiles the first rung on the first request —
+            # on any host with more than one device, and invisibly to
+            # the signature sentinel.
+            params = jax.device_put(params, jax.devices()[0])
         else:
             # No asarray pre-pass: shard_params device_puts the host
             # tree straight into the mesh layout — a model that only
@@ -1007,10 +1014,10 @@ class InferenceEngine:
         that keeps per-slot attention local to the head shard the qkv
         projection already produced. A head count the model axis
         doesn't divide replicates instead (placement is an
-        optimization, never a shape contract). None without a config
-        (single-device placement, the pre-ISSUE-7 behavior)."""
+        optimization, never a shape contract). Without a config: device
+        0, explicitly — the same committed placement as the params."""
         if self.mesh is None:
-            return None
+            return jax.sharding.SingleDeviceSharding(jax.devices()[0])
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from tensorflow_examples_tpu.core.mesh import AxisNames

@@ -64,6 +64,11 @@ class ProcessReplica:
     ``cmd`` is the spawn command (string, ``shlex``-split; a ``{port}``
     placeholder receives ``port``). The process is expected to serve
     the PR 5 frontend surface on ``http://127.0.0.1:{port}``.
+
+    No device assignment happens here: the child takes whatever its
+    own command line and environment give it. A chip belongs to one
+    process, so several TPU replicas on one host do not work this way
+    today (docs/serving.md; ROADMAP queue 2 item 4).
     """
 
     def __init__(self, cmd: str, *, port: int,
@@ -83,6 +88,11 @@ class ProcessReplica:
 
     def alive(self) -> bool:
         return self._proc is not None and self._proc.poll() is None
+
+    @property
+    def exit_code(self) -> int | None:
+        """The child's exit code; None while it runs (or never ran)."""
+        return None if self._proc is None else self._proc.poll()
 
     def terminate(self) -> None:
         """SIGTERM (the replica's own drain path), escalate to SIGKILL

@@ -16,10 +16,10 @@ runs):
   update survived into the final params, vs. work burned by bad-step
   skips and rollback replays (fed by the PR 1 guard counters).
 
-Peak FLOPs come from a device-kind table (bf16 peak per chip); unknown
-kinds (CPU test runs, new TPU generations) fall back to a deliberately
-round 1 TFLOP/s so the MFU *pipeline* stays exercised end-to-end — the
-reported value is then explicitly labeled by ``peak_is_estimate``.
+Peak FLOPs come from a device-kind table (bf16 peak per chip) or the
+``--telemetry_peak_tflops`` override. A kind that is not in the table —
+the CPU, a new TPU generation — has NO peak and therefore no MFU:
+``mfu`` is None on its lines, never a figure against an invented peak.
 """
 
 from __future__ import annotations
@@ -27,7 +27,9 @@ from __future__ import annotations
 from typing import Mapping
 
 # bf16 peak FLOPs/sec per chip by PJRT device_kind substring (first
-# match wins — order matters for "v5"/"v5 lite").
+# match wins — order matters for "v5"/"v5 lite"). Source: Google Cloud
+# TPU documentation, per-generation system architecture pages ("TPU
+# v5e": 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s per chip).
 PEAK_FLOPS_BY_DEVICE_KIND: tuple[tuple[str, float], ...] = (
     ("v6e", 918e12),
     ("v5 lite", 197e12),  # v5e reports "TPU v5 lite"
@@ -40,18 +42,15 @@ PEAK_FLOPS_BY_DEVICE_KIND: tuple[tuple[str, float], ...] = (
     ("v2", 45e12),
 )
 
-# Unknown device kind (CPU CI, future chips): keep the MFU pipeline
-# alive with an explicit, obviously-synthetic 1 TFLOP/s peak.
-DEFAULT_PEAK_FLOPS = 1e12
 
-
-def peak_flops_per_device(device_kind: str = "") -> tuple[float, bool]:
-    """(peak bf16 FLOPs/sec for one device, known?) for a PJRT kind."""
+def peak_flops_per_device(device_kind: str = "") -> float | None:
+    """Peak bf16 FLOPs/sec of one device of this PJRT kind; None for a
+    kind the table does not list."""
     kind = (device_kind or "").lower()
     for sub, peak in PEAK_FLOPS_BY_DEVICE_KIND:
         if sub in kind:
-            return peak, True
-    return DEFAULT_PEAK_FLOPS, False
+            return peak
+    return None
 
 
 def train_step_flops(
@@ -86,7 +85,7 @@ def mfu_fields(
     """The MFU block for a derived section: the analytic 6ND figure
     plus — when an in-loop profiler window measured one
     (telemetry/profiling.py) — the observed device duty cycle alongside
-    it (VERDICT r4 weak #5: never report the analytic number as if it
+    it (never report the analytic number as if it
     were a measurement). The two are deliberately separate keys: duty
     cycle is "fraction of wall time the device was busy", an upper
     bound on where MFU can go, not an MFU itself."""

@@ -5,8 +5,8 @@ or dtype that drifts mid-run (ragged final batch, a resumed run with a
 different bundle size, a config knob that changes an aval) makes XLA
 retrace + recompile the step — seconds to minutes of dead time that
 shows up nowhere except a step-time spike. The repo's own history
-(BASELINE.md round-4 sub-floor readings, diagnosed only by the
-out-of-band ``tools/hlo_fingerprint.py``) is the motivating incident.
+(round-4 sub-floor bench readings, diagnosed only by the out-of-band
+``tools/hlo_fingerprint.py``) is the motivating incident.
 
 ``CompilationSentinel`` wraps each jitted step function the trainer
 builds (train step, bundled train step per K, eval step) and tracks the

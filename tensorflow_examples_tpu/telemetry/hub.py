@@ -67,7 +67,6 @@ class Telemetry:
         tracer=None,
         flops_per_step: float = 0.0,
         peak_flops_total: float = 0.0,
-        peak_is_estimate: bool = True,
         tokens_per_example: int = 1,
         trace_file: str | None = None,
         flush_every: int = 1,
@@ -86,7 +85,6 @@ class Telemetry:
         )
         self.flops_per_step = float(flops_per_step)
         self.peak_flops_total = float(peak_flops_total)
-        self.peak_is_estimate = bool(peak_is_estimate)
         self.tokens_per_example = max(int(tokens_per_example), 1)
         self.trace_file = trace_file
         self.flush_every = max(int(flush_every), 1)
@@ -142,9 +140,6 @@ class Telemetry:
             self.registry.gauge("telemetry/peak_flops_total").set(
                 self.peak_flops_total
             )
-            self.registry.gauge("telemetry/peak_is_estimate").set(
-                1.0 if self.peak_is_estimate else 0.0
-            )
 
     @classmethod
     def from_config(cls, cfg, *, n_params: int = 0) -> "Telemetry":
@@ -163,12 +158,11 @@ class Telemetry:
             n_params, cfg.global_batch_size, tokens
         )
         peak_tflops = float(getattr(cfg, "telemetry_peak_tflops", 0.0) or 0.0)
-        if peak_tflops > 0:
-            peak, known = peak_tflops * 1e12, True
-        else:
-            peak, known = accounting.peak_flops_per_device(
-                getattr(jax.devices()[0], "device_kind", "")
-            )
+        # No peak (a device kind the table does not list, and no
+        # override) means no MFU: accounting.mfu returns None for 0.
+        peak = peak_tflops * 1e12 or accounting.peak_flops_per_device(
+            jax.devices()[0].device_kind
+        ) or 0.0
         trace_file = (
             sinks_mod.trace_path(cfg.workdir)
             if cfg.workdir
@@ -183,7 +177,6 @@ class Telemetry:
             sinks,
             flops_per_step=flops,
             peak_flops_total=peak * jax.device_count(),
-            peak_is_estimate=not known,
             tokens_per_example=tokens,
             trace_file=trace_file,
             flush_every=getattr(cfg, "telemetry_flush_every", 1),

@@ -23,7 +23,7 @@ profiler. Before this module the loop had one hardcoded one-shot window
 * When the TF profiler plugin can convert the captured xplane (the
   tools/profile_trace.py protocol), the observed **device duty cycle**
   is extracted and published as ``profile/device_duty_cycle`` — the
-  measured companion to the analytic 6ND MFU (VERDICT r4 weak #5).
+  measured companion to the analytic 6ND MFU.
   Conversion is best-effort: missing plugin/backends degrade to None.
 """
 

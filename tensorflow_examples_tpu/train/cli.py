@@ -20,7 +20,7 @@ from __future__ import annotations
 
 from absl import app, logging
 
-from tensorflow_examples_tpu.core import distributed
+from tensorflow_examples_tpu.core import device, distributed
 from tensorflow_examples_tpu.core.mesh import create_mesh
 from tensorflow_examples_tpu.data.memory import eval_batches, train_iterator
 from tensorflow_examples_tpu.train.checkpoint import CheckpointManager
@@ -42,7 +42,11 @@ def _setup(workload, default_cfg):
     install_crash_handlers(cfg.workdir)
     # Flaky-input-store policy for every file reader (data/sources.py).
     configure_io_retry(cfg.io_retries, cfg.io_backoff_secs)
+    device.enable_compile_cache()
     distributed.initialize()
+    # After initialize(): this is the first call that touches the
+    # backend. Logs the one line that names the device.
+    device.require_device(cfg.device)
     return cfg
 
 

@@ -9,7 +9,7 @@ handling here is three layers:
    that package is importable (TPU-side stack traces on Cloud TPU VMs).
 2. **Hang watchdog** (``Watchdog``): a daemon thread the training loop
    pings every step. If no progress for ``timeout_s`` (device hang, stuck
-   collective, wedged host↔TPU tunnel), it dumps every Python thread's
+   collective, lost host↔TPU link), it dumps every Python thread's
    stack — turning a silent hang into a diagnosable event. The loop also
    marks which *phase* it is in (``enter("input_fetch")`` /
    ``enter("device_step")``), so the dump says whether the host input

@@ -1,7 +1,7 @@
 """steps_per_launch (bundled train steps): K steps per device launch
 via lax.scan — the TPU-native equivalent of the reference lineage's
 Keras ``steps_per_execution`` (SURVEY.md §3(1) hot loop; the dispatch-
-bound regime diagnosed in BASELINE.md round-4 is the motivation).
+bound regime of the small workloads is the motivation).
 
 Parity contract under test: K scanned steps == K separate launches —
 same RNG stream (keyed off state.step), same optimizer sequence

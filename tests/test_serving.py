@@ -823,11 +823,15 @@ def _tiny_params(cfg):
 def paged_engine():
     """One warmed PAGED engine (fp32, block 8) for the module — same
     smoke model and ladder floors as ``warm_engine``, so every paged
-    claim is measured against the exact dense baseline."""
+    claim is measured against the exact dense baseline. The params
+    arrive COMMITTED to a device, as a checkpoint restore hands them to
+    serve.py."""
+    import jax
+
     cfg = tiny_cfg()
     engine = InferenceEngine(
         cfg,
-        _tiny_params(cfg),
+        jax.device_put(_tiny_params(cfg), jax.devices()[0]),
         cfg=ServeConfig(
             max_slots=4,
             prefill_bucket_floor=16,
@@ -1042,6 +1046,12 @@ class TestPagedGolden:
             assert res.truncated is None
         assert eng.sentinel.compile_counts() == compiles_before
         assert eng.post_warmup_recompiles() == 0
+        # ...and by jit's own count, which sees what the signature
+        # sentinel cannot: committed params with an uncommitted fresh
+        # pool used to compile the first-warmed rung a second time on
+        # any host with several devices (this harness has 8).
+        for fns in (eng._prefill_fns, eng._decode_fns, eng._extend_fns):
+            assert [fn._cache_size() for fn in fns.values()] == [1] * len(fns)
         assert eng.pool.active_slots == 0
         assert eng.pool.used_bytes() == 0  # every block returned
 

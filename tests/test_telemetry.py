@@ -214,12 +214,17 @@ class TestAccounting:
         assert accounting.mfu(100e9, 10.0, 0.0) is None
 
     def test_peak_table(self):
-        peak, known = accounting.peak_flops_per_device("TPU v4")
-        assert known and peak == 275e12
-        peak, known = accounting.peak_flops_per_device("TPU v5 lite")
-        assert known and peak == 197e12
-        peak, known = accounting.peak_flops_per_device("cpu")
-        assert not known and peak == accounting.DEFAULT_PEAK_FLOPS
+        assert accounting.peak_flops_per_device("TPU v4") == 275e12
+        assert accounting.peak_flops_per_device("TPU v5 lite") == 197e12
+        # Not in the table: no peak, hence no MFU — never a default.
+        assert accounting.peak_flops_per_device("cpu") is None
+        from tensorflow_examples_tpu.telemetry import Telemetry
+
+        tel = Telemetry.from_config(
+            tiny_cfg(telemetry_sinks="", telemetry_trace=False), n_params=10
+        )
+        assert tel.peak_flops_total == 0.0
+        assert accounting.mfu(tel.flops_per_step, 1.0, 0.0) is None
 
     def test_goodput(self):
         assert accounting.goodput({}) is None  # nothing stepped yet
@@ -464,7 +469,9 @@ def smoke_run(tmp_path_factory):
     registry_mod.reset_default_registry()
     spans_mod.reset_default_tracer()
     wd = str(tmp_path_factory.mktemp("telemetry_smoke"))
-    cfg = tiny_cfg(workdir=wd, eval_every=6)
+    # The CPU is not in the peaks table: the MFU these tests read needs
+    # an explicit peak.
+    cfg = tiny_cfg(workdir=wd, eval_every=6, telemetry_peak_tflops=1.0)
     ds = _data()
     trainer = Trainer(mnist.make_task(cfg), cfg)
     metrics = trainer.fit(

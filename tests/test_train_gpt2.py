@@ -77,7 +77,7 @@ def test_tp_matches_dp_step():
 def test_fsdp_matches_dp_step():
     """Training under fsdp=4 (ZeRO-3-style param sharding + all-gather on
     use) must match pure DP numerically, and params must actually land
-    sharded on the fsdp axis (VERDICT r1: declared but never trained)."""
+    sharded on the fsdp axis (declared but never trained)."""
     cfg = tiny_config(train_steps=3)
     mesh_dp = create_mesh(MeshConfig(data=8))
     mesh_fsdp = create_mesh(MeshConfig(data=2, fsdp=4))
@@ -101,7 +101,7 @@ def test_eval_and_fused_ce(mesh8):
 
 def test_grad_accumulation_parity(mesh8):
     """accum=2 over half-batches must equal one update over the combined
-    batch (VERDICT r1: the old test asserted only finiteness). Schedule
+    batch (the old test asserted only finiteness). Schedule
     horizons are micro-step counts rescaled by accum (optimizers._updates),
     so (steps=6, warmup=2, accum=2) and (steps=3, warmup=1) tick the same
     1-warmup/3-decay schedule."""

@@ -100,7 +100,7 @@ def test_tfrecord_pipeline(tmp_path):
 
 
 def test_tfrecord_exact_resume(tmp_path):
-    """VERDICT r2 item 5: exact resume on the STREAMING path. A resumed
+    """Exact resume on the STREAMING path. A resumed
     iterator (start_step=4) must replay the uninterrupted run's batches
     5… bit-exactly — shuffles, epoch boundaries, and random crop/flip
     augmentations all reproduced on TFRecord data."""

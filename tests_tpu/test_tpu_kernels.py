@@ -1,9 +1,9 @@
-"""Compiled-kernel numerics on the live TPU (SURVEY.md §4).
+"""Compiled-kernel numerics on the TPU (SURVEY.md §4).
 
 The CPU suite proves the Pallas kernels in interpret mode; this module
 proves the SAME kernels compiled by Mosaic on the real chip, at real
-workload shapes, against the XLA reference implementations. Skipped
-entirely off-TPU (the cpu-pinned suite under ``tests/`` owns that path).
+workload shapes, against the XLA reference implementations. With no
+TPU the session fails in ``conftest.py`` before anything here runs.
 
 Tolerances: inputs are bf16 (the production precision policy), softmax /
 logsumexp accumulate in f32 in both the kernel and the reference, so
@@ -15,17 +15,12 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-pytestmark = pytest.mark.skipif(
-    jax.default_backend() != "tpu",
-    reason="compiled-kernel parity needs the TPU backend",
-)
-
-from tensorflow_examples_tpu.ops.attention import (  # noqa: E402
+from tensorflow_examples_tpu.ops.attention import (
     attention_reference,
     flash_attention,
     flash_attention_with_lse,
 )
-from tensorflow_examples_tpu.ops.cross_entropy import (  # noqa: E402
+from tensorflow_examples_tpu.ops.cross_entropy import (
     cross_entropy_per_example,
     cross_entropy_reference,
 )

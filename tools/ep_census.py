@@ -1,7 +1,7 @@
 #!/usr/bin/env python
-"""Itemized collective census for the MoE EP train step (VERDICT r3
-item 3: the 105 residual all-reduces in the dp2×model4 compiled step
-must be *attributed*, not just counted).
+"""Itemized collective census for the MoE EP train step: the residual
+all-reduces in the dp2×model4 compiled step must be *attributed*, not
+just counted.
 
 Compiles the same MoE GPT-2 train step as bench.py's census probe on an
 8-virtual-device dp2×model4 CPU mesh, then walks the optimized HLO and
@@ -94,7 +94,7 @@ def census(hlo: str, n_devices: int, model: int):
     # Definition sites only (the %name = shape opcode(...) form) — a
     # plain substring count also hits operand REFERENCES like
     # %all-reduce.12 and overcounts ~2-3x (the round-2/3 census did
-    # exactly that; BASELINE.md round-4 note). Shape is non-greedy so
+    # exactly that). Shape is non-greedy so
     # tuple-shaped collectives (lax.all_to_all lowers to one) match,
     # and the async -start halves count once (-done is skipped).
     for m in re.finditer(

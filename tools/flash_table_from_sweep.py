@@ -11,9 +11,8 @@ using each shape's ``best_fwdbwd`` cell (training is the default
 consumer; the fwd-only optimum is recorded alongside for reference).
 ops/attention.py loads the table at kernel-build time. The kernel
 source hash (tools/kernel_source_hash.py) covers the table file, so
-swapping it automatically stales banked selftest evidence and the
-harvest re-proves compiled parity on the next live window (the sweep
-itself also ran every cell compiled on-chip).
+swapping it changes the hash a recorded tests_tpu/ result was about
+(the sweep itself also ran every cell compiled on-chip).
 """
 
 import json

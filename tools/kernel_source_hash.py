@@ -1,12 +1,11 @@
 """Content hash of the kernel sources the compiled selftest proves.
 
-A banked ``tests_tpu/`` selftest record is evidence about the kernel
-code AS IT WAS when the nodes ran on the chip. Reusing it after an
-``ops/`` edit would silently satisfy the on-chip-parity requirement
-with stale evidence (ADVICE r4). This module defines the one hash both
-sides use: the harvest embeds it in the banked record, and bench.py's
-``run_selftest(allow_banked=True)`` refuses a record whose hash does
-not match the working tree.
+A ``tests_tpu/`` result is evidence about the kernel code AS IT WAS
+when the tests ran on the chip; quoting it after an ``ops/`` edit would
+pass stale evidence off as current. This hash identifies the sources a
+recorded result was about. (Its consumer, bench.py's banked-selftest
+reuse, is gone; removing this tool with its tests is ROADMAP queue 3
+item 4.)
 
 Scope: every ``.py`` under ``tests_tpu/`` (the parity assertions),
 ``tensorflow_examples_tpu/ops/`` (the kernels they compile), and
@@ -30,9 +29,7 @@ def kernel_source_hash(repo_root: "str | None" = None) -> str:
     h = hashlib.sha256()
     # The flash block table is kernel configuration living outside the
     # package (docs/): swapping it changes every compiled flash kernel,
-    # so it must stale banked selftest evidence exactly like a source
-    # edit (flash_table_from_sweep.py used to delegate that to the
-    # operator).
+    # so it changes the hash exactly like a source edit.
     table = os.path.join(
         root, "docs", "tpu_sweeps", "flash_block_table.json"
     )

@@ -1,10 +1,9 @@
 #!/usr/bin/env python
 """Extract the last parseable JSON line from a log file.
 
-Shared by the TPU watcher scripts (tools/tpu_harvest.sh,
-tools/diag_watch.sh): bench/diag children print their record as one
-JSON line on stdout, but the watchers capture stdout+stderr merged, so
-the record must be fished out of surrounding log noise — and
+bench.py and the diag tools print their record as one JSON line on
+stdout, but a caller that captures stdout+stderr merged must fish the
+record out of surrounding log noise — and
 always-emit children may print a truncated snapshot BEFORE the full
 record, so the LAST parseable line is the authoritative one.
 

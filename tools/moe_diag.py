@@ -29,7 +29,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import bench  # noqa: E402
 from tools.diag_common import (  # noqa: E402
-    enable_compile_cache, make_emit, parse_budget, start_watchdog,
+    make_emit, parse_budget, start_watchdog,
 )
 
 OUT: dict = {"diag": "moe_components"}
@@ -194,12 +194,11 @@ def _full_step(impl: str, steps: int = 10) -> dict:
 def main() -> int:
     budget = parse_budget(sys.argv[1:], default=600)
     deadline = time.monotonic() + budget - 30
+    bench._require_tpu()  # exits non-zero off the TPU
+    bench.BACKEND = "tpu"
     watchdog = start_watchdog(budget, _emit)
     try:
-        bench.BACKEND = bench._resolve_backend()
         OUT["backend"] = bench.BACKEND
-        if bench.BACKEND == "tpu":
-            enable_compile_cache()
         OUT["probe_tflops"] = round(bench._probe_quick(), 2)
         OUT["launch_us"] = round(bench._probe_launch_us(), 2)
         _component_benches(deadline)
@@ -215,7 +214,7 @@ def main() -> int:
         OUT["error"] = f"{type(e).__name__}: {e}"
     watchdog.cancel()
     _emit()
-    return 0
+    return 1 if "error" in OUT else 0
 
 
 if __name__ == "__main__":

@@ -252,15 +252,28 @@ def main(argv=None) -> int:
         for rep in spawned:
             deadline = time.monotonic() + args.spawn_warm_timeout
             while time.monotonic() < deadline:
+                # ANY dead sibling ends start-up now, not after this
+                # replica's warm-up: on a TPU host the second process
+                # to reach for the chip dies within seconds.
+                dead = [r for r in spawned if not r.alive()]
+                if dead:
+                    raise SystemExit(
+                        "spawned replica(s) exited before /health went "
+                        "green: "
+                        + ", ".join(
+                            f"{r.url} (exit {r.exit_code})" for r in dead
+                        )
+                        + ". A chip belongs to one process: --spawn "
+                        "replicas get no device assignment, so on a TPU "
+                        "host every one after the first fails at "
+                        "start-up. Run several replicas in one process "
+                        "(tools/serve_bench.py --router) or spawn "
+                        "--device=cpu replicas (docs/serving.md)."
+                    )
                 status, body = _get_json(rep.url + "/health", 2.0)
                 if status == 200 and body.get("ok"):
                     print(f"replica {rep.url} green", file=sys.stderr)
                     break
-                if not rep.alive():
-                    raise SystemExit(
-                        f"spawned replica {rep.url} exited before its "
-                        "/health ever went green"
-                    )
                 time.sleep(0.5)
             else:
                 raise SystemExit(

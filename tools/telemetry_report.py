@@ -172,9 +172,6 @@ def summarize(lines: list[dict], trace: dict | None) -> dict:
         "step_time_p50": last["derived"].get("step_time_p50"),
         "step_time_p95": last["derived"].get("step_time_p95"),
         "mfu": derived.get("mfu"),
-        "mfu_peak_is_estimate": bool(
-            gauges.get("telemetry/peak_is_estimate", 1.0)
-        ),
         # Whole-run goodput from the cross-session counter totals (a
         # single line's goodput only covers its own process session).
         "goodput": accounting.goodput(counters),
@@ -468,9 +465,9 @@ def render(record: dict, skipped: int) -> str:
         )
         + ")"
         + (
-            " (peak FLOPs GUESSED — unknown device kind; set "
-            "--telemetry_peak_tflops for a real estimate)"
-            if record["mfu_peak_is_estimate"]
+            " (no peak FLOPs for this device kind; set "
+            "--telemetry_peak_tflops)"
+            if mfu is None
             else ""
         )
     )

@@ -134,15 +134,20 @@ def _target_name(node: ast.AST) -> tuple[str, int] | None:
     """Resolve a jit() first argument to (bare name, n bound leading
     args): ``f`` -> (f, 0); ``self._impl`` -> (_impl, 0);
     ``partial(self._impl, b)`` / ``functools.partial(f, a, b)`` ->
-    (name, len(bound))."""
+    (name, len(bound)). Sees through a call that hands its first
+    argument back decorated (the engine's ``_named(partial(...), tag)``,
+    which only sets ``__name__``)."""
     if isinstance(node, ast.Name):
         return node.id, 0
     if isinstance(node, ast.Attribute):
         return node.attr, 0
-    if isinstance(node, ast.Call) and _is_partial(node.func) and node.args:
+    if isinstance(node, ast.Call) and node.args:
         inner = _target_name(node.args[0])
-        if inner is not None:
+        if inner is None:
+            return None
+        if _is_partial(node.func):
             return inner[0], inner[1] + len(node.args) - 1
+        return inner
     return None
 
 

@@ -523,20 +523,26 @@ class ContinuousBatcher:
         reg = self.registry
         decode_steps = 0
         while not self._stop.is_set():
-            # Brownout tick (ISSUE 13): one controller evaluation per
-            # loop iteration — queue depth + KV occupancy here, the
-            # controller's own recent-TTFT window inside. Cheap host
-            # math; the ladder's hysteresis does the rate limiting.
-            self._overload.update(
-                queue_depth=self.queue_depth(),
-                kv_occupancy=float(self.engine.pool.occupancy),
-            )
-            # Interactive preempts batch for decode slots (ISSUE 13):
-            # free slots for waiting interactive requests BEFORE this
-            # iteration's admission, so the preempted batch slots are
-            # immediately reusable.
-            self._preempt_for_interactive()
-            staged = self._gather()
+            # One busy iteration on the profiler's clock (ISSUE 25):
+            # serve_admission -> [serve_prefill ...] -> serve_decode_step
+            # {engine_decode_build/upload/dispatch/fetch} -> serve_commit,
+            # with nothing between the spans but this loop's own tests.
+            with span("serve_admission"):
+                # Brownout tick (ISSUE 13): one controller evaluation
+                # per loop iteration — queue depth + KV occupancy here,
+                # the controller's own recent-TTFT window inside. Cheap
+                # host math; the ladder's hysteresis does the rate
+                # limiting.
+                self._overload.update(
+                    queue_depth=self.queue_depth(),
+                    kv_occupancy=float(self.engine.pool.occupancy),
+                )
+                # Interactive preempts batch for decode slots (ISSUE
+                # 13): free slots for waiting interactive requests
+                # BEFORE this iteration's admission, so the preempted
+                # batch slots are immediately reusable.
+                self._preempt_for_interactive()
+                staged = self._gather()
             if staged:
                 self._wd("serve_prefill")
                 for item in staged:
@@ -601,43 +607,54 @@ class ContinuousBatcher:
                 self._fail_active(e)
                 continue
             dt = time.perf_counter() - t0
-            decode_steps += 1
-            if self._watchdog is not None:
-                self._watchdog.ping(decode_steps)
-            tpot = reg.histogram("serving/tpot")
-            reg.histogram("serving/decode_step").record(dt)
-            for slot, toks in out.items():
-                item = self._active[slot]
-                cls_tpot = reg.histogram(
-                    f"serving/tpot_{item.req.slo}"
-                )
-                item.spec_drafted += drafts_by_slot.get(slot, 0)
-                item.spec_accepted += len(toks) - 1
-                per_tok = dt / len(toks)
-                committed: list[int] = []
-                for token in toks:
-                    item.tokens.append(token)
-                    item.last_token = token
-                    committed.append(token)
-                    tpot.record(per_tok)
-                    cls_tpot.record(per_tok)
-                    if item.req.eos_id is not None \
-                            and token == item.req.eos_id:
-                        # Tokens past eos in the same verify window are
-                        # discarded — identical to the non-speculative
-                        # stream, which stops here.
-                        break
-                if self._draft is not None:
-                    if drafts_by_slot:  # a verify step, not a fallback
-                        # The ENGINE-committed count (pre-eos-discard),
-                        # so the histogram and the spec_* counters
-                        # measure the same thing.
-                        reg.histogram(
-                            "serving/accepted_per_step"
-                        ).record(float(len(toks)))
-                    self._draft.extend(slot, committed)
-                self._maybe_finish(item)
-            reg.gauge("serving/active_requests").set(len(self._active))
+            with span("serve_commit"):
+                decode_steps += 1
+                if self._watchdog is not None:
+                    self._watchdog.ping(decode_steps)
+                self._commit(out, drafts_by_slot, dt)
+
+    def _commit(self, out: dict, drafts_by_slot: dict[int, int],
+                dt: float) -> None:
+        """Book one decode step's tokens (``out``: {slot: committed
+        token list}) against their requests: append, record the
+        per-token latencies, finish what is done. ``dt`` is the step's
+        wall time on the host, ``drafts_by_slot`` non-empty on a verify
+        step. Loop-thread only, under ``span/serve_commit``."""
+        reg = self.registry
+        tpot = reg.histogram("serving/tpot")
+        reg.histogram("serving/decode_step").record(dt)
+        for slot, toks in out.items():
+            item = self._active[slot]
+            cls_tpot = reg.histogram(
+                f"serving/tpot_{item.req.slo}"
+            )
+            item.spec_drafted += drafts_by_slot.get(slot, 0)
+            item.spec_accepted += len(toks) - 1
+            per_tok = dt / len(toks)
+            committed: list[int] = []
+            for token in toks:
+                item.tokens.append(token)
+                item.last_token = token
+                committed.append(token)
+                tpot.record(per_tok)
+                cls_tpot.record(per_tok)
+                if item.req.eos_id is not None \
+                        and token == item.req.eos_id:
+                    # Tokens past eos in the same verify window are
+                    # discarded — identical to the non-speculative
+                    # stream, which stops here.
+                    break
+            if self._draft is not None:
+                if drafts_by_slot:  # a verify step, not a fallback
+                    # The ENGINE-committed count (pre-eos-discard),
+                    # so the histogram and the spec_* counters
+                    # measure the same thing.
+                    reg.histogram(
+                        "serving/accepted_per_step"
+                    ).record(float(len(toks)))
+                self._draft.extend(slot, committed)
+            self._maybe_finish(item)
+        reg.gauge("serving/active_requests").set(len(self._active))
 
     def _decode_step(self, drafts_by_slot: dict[int, int]):
         """One device step over the active set; returns {slot:

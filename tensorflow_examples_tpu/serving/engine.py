@@ -673,6 +673,19 @@ class ChunkedPrefill:
         self.top_k = top_k
 
 
+def _named(step, tag: str):
+    """Give ``step`` — a ``functools.partial`` of an ``*_impl`` step
+    function over its rung — the step function's name and the rung as
+    its ``__name__`` (``paged_decode_impl_K512``). JAX names the
+    compiled program after it in the lowering, the compile events and
+    the profiler's trace; a nameless partial is ``jit__unknown``
+    everywhere, and no decode execution can be told from a prefill.
+    The sentinel's names (``serve_decode_K512``) are the operator's and
+    stay."""
+    step.__name__ = f"{step.func.__name__.lstrip('_')}_{tag}{step.args[0]}"
+    return step
+
+
 class InferenceEngine:
     """Loads params once, owns the KV pool, runs the compiled steps.
 
@@ -932,7 +945,8 @@ class InferenceEngine:
             self._prefill_fns = {
                 lb: self.sentinel.wrap(
                     jax.jit(
-                        functools.partial(self._paged_prefill_impl, lb),
+                        _named(functools.partial(
+                            self._paged_prefill_impl, lb), "L"),
                         donate_argnums=(1,),
                     ),
                     f"serve_prefill_L{lb}",
@@ -942,7 +956,8 @@ class InferenceEngine:
             self._decode_fns = {
                 kb: self.sentinel.wrap(
                     jax.jit(
-                        functools.partial(self._paged_decode_impl, kb),
+                        _named(functools.partial(
+                            self._paged_decode_impl, kb), "K"),
                         donate_argnums=(1,),
                     ),
                     f"serve_decode_K{kb}",
@@ -956,7 +971,8 @@ class InferenceEngine:
             self._extend_fns = {
                 tb: self.sentinel.wrap(
                     jax.jit(
-                        functools.partial(self._extend_impl, tb),
+                        _named(functools.partial(
+                            self._extend_impl, tb), "T"),
                         donate_argnums=(1,),
                     ),
                     f"serve_extend_T{tb}",
@@ -966,7 +982,8 @@ class InferenceEngine:
             self._verify_fns = {
                 kb: self.sentinel.wrap(
                     jax.jit(
-                        functools.partial(self._paged_verify_impl, kb),
+                        _named(functools.partial(
+                            self._paged_verify_impl, kb), "K"),
                         donate_argnums=(1,),
                     ),
                     f"serve_verify_K{kb}",
@@ -977,7 +994,8 @@ class InferenceEngine:
             self._prefill_fns = {
                 lb: self.sentinel.wrap(
                     jax.jit(
-                        functools.partial(self._prefill_impl, lb),
+                        _named(functools.partial(
+                            self._prefill_impl, lb), "L"),
                         donate_argnums=(1, 2),
                     ),
                     f"serve_prefill_L{lb}",
@@ -987,7 +1005,8 @@ class InferenceEngine:
             self._decode_fns = {
                 kb: self.sentinel.wrap(
                     jax.jit(
-                        functools.partial(self._decode_impl, kb),
+                        _named(functools.partial(
+                            self._decode_impl, kb), "K"),
                         donate_argnums=(1, 2),
                     ),
                     f"serve_decode_K{kb}",
@@ -998,7 +1017,8 @@ class InferenceEngine:
             self._verify_fns = {
                 kb: self.sentinel.wrap(
                     jax.jit(
-                        functools.partial(self._verify_impl, kb),
+                        _named(functools.partial(
+                            self._verify_impl, kb), "K"),
                         donate_argnums=(1, 2),
                     ),
                     f"serve_verify_K{kb}",
@@ -1293,7 +1313,13 @@ class InferenceEngine:
         (``span/engine_{kind}_dispatch``, ISSUE 18): the compiled call
         returns un-synced device arrays, so the span measures DISPATCH
         wall only — tracing adds no device sync and no new compiled
-        programs (the zero-recompile sentinel stays golden-pinned)."""
+        programs (the zero-recompile sentinel stays golden-pinned).
+        Its callers bracket the rest of a step the same way (ISSUE 25):
+        ``engine_{kind}_build`` (numpy inputs, block tables),
+        ``engine_{kind}_upload`` (the ``jnp.asarray`` host->device
+        copies) and ``engine_{kind}_fetch`` (the one ``np.asarray`` that
+        waits for the device), so the serve thread has no unnamed
+        stretch between a step's first line and its tokens in hand."""
         try:
             with host_span(f"engine_{kind}_dispatch"):
                 return fn(*args)
@@ -1341,19 +1367,29 @@ class InferenceEngine:
                 top_k=top_k,
             )
         else:
-            bucket = kv_mod.pick_bucket(self.prefill_ladder, n)
-            tokens = np.zeros((1, bucket), np.int32)
-            tokens[0, :n] = prompt
+            with host_span("engine_prefill_build"):
+                bucket = kv_mod.pick_bucket(self.prefill_ladder, n)
+                tokens = np.zeros((1, bucket), np.int32)
+                tokens[0, :n] = prompt
+            with host_span("engine_prefill_upload"):
+                args = (
+                    jnp.int32(slot), jnp.asarray(tokens), jnp.int32(n),
+                    request_key(seed, n), jnp.float32(temperature),
+                    jnp.int32(top_k),
+                )
             (self.pool.k, self.pool.v, tok, last) = self._run_compiled(
                 "prefill", self._prefill_fns[bucket],
-                self.params, self.pool.k, self.pool.v,
-                jnp.int32(slot), jnp.asarray(tokens), jnp.int32(n),
-                request_key(seed, n), jnp.float32(temperature),
-                jnp.int32(top_k),
+                self.params, self.pool.k, self.pool.v, *args,
             )
         self.pool.lengths[slot] = n
         self.registry.counter("serving/prefill_tokens").inc(n)
-        return int(tok), np.asarray(last)
+        return self._fetch_prefill(tok, last)
+
+    def _fetch_prefill(self, tok, last):
+        """The prefill's device->host sync: first token and last-row
+        logits, under ``span/engine_prefill_fetch``."""
+        with host_span("engine_prefill_fetch"):
+            return int(tok), np.asarray(last)
 
     def _paged_prefill(self, slot, prompt, *, seed, temperature, top_k):
         n = len(prompt)
@@ -1362,39 +1398,46 @@ class InferenceEngine:
         # it: the pool's prefix cache and the engine's extend ladder
         # are both keyed off cfg.prefix_cache, so claim_prompt_blocks
         # returns ctx=0 exactly when there is no rung to run a tail on.
-        ctx, _ = self.pool.claim_prompt_blocks(slot, prompt)
-        total_blocks = -(-n // bs)
-        key = request_key(seed, n)
-        ftemp, ftk = jnp.float32(temperature), jnp.int32(top_k)
+        with host_span("engine_prefill_build"):
+            ctx, _ = self.pool.claim_prompt_blocks(slot, prompt)
+            total_blocks = -(-n // bs)
+            if ctx == 0:
+                bucket = kv_mod.pick_bucket(self.prefill_ladder, n)
+                ids = np.zeros((bucket // bs,), np.int32)
+                ids[:total_blocks] = self.pool.block_tables[
+                    slot, :total_blocks
+                ]
+                tokens = np.zeros((1, bucket), np.int32)
+                tokens[0, :n] = prompt
+                host = (ids, tokens, np.int32(n))
+            else:
+                tail = n - ctx
+                bucket = kv_mod.pick_bucket(self.prefill_ladder, tail)
+                tail_blocks = total_blocks - ctx // bs
+                tail_ids = np.zeros((bucket // bs,), np.int32)
+                tail_ids[:tail_blocks] = self.pool.block_tables[
+                    slot, ctx // bs:total_blocks
+                ]
+                tokens = np.zeros((1, bucket), np.int32)
+                tokens[0, :tail] = prompt[ctx:]
+                host = (
+                    self.pool.block_tables[slot], tail_ids, tokens,
+                    np.int32(ctx), np.int32(tail),
+                )
+        with host_span("engine_prefill_upload"):
+            args = (
+                *map(jnp.asarray, host), request_key(seed, n),
+                jnp.float32(temperature), jnp.int32(top_k),
+            )
         if ctx == 0:
-            bucket = kv_mod.pick_bucket(self.prefill_ladder, n)
-            ids = np.zeros((bucket // bs,), np.int32)
-            ids[:total_blocks] = self.pool.block_tables[
-                slot, :total_blocks
-            ]
-            tokens = np.zeros((1, bucket), np.int32)
-            tokens[0, :n] = prompt
             kv, tok, last = self._run_compiled(
                 "prefill", self._prefill_fns[bucket],
-                self.params, self.pool.kv_state(), jnp.asarray(ids),
-                jnp.asarray(tokens), jnp.int32(n), key, ftemp, ftk,
+                self.params, self.pool.kv_state(), *args,
             )
         else:
-            tail = n - ctx
-            tb = kv_mod.pick_bucket(self.prefill_ladder, tail)
-            tail_blocks = total_blocks - ctx // bs
-            tail_ids = np.zeros((tb // bs,), np.int32)
-            tail_ids[:tail_blocks] = self.pool.block_tables[
-                slot, ctx // bs:total_blocks
-            ]
-            tokens = np.zeros((1, tb), np.int32)
-            tokens[0, :tail] = prompt[ctx:]
             kv, tok, last = self._run_compiled(
-                "prefill", self._extend_fns[tb],
-                self.params, self.pool.kv_state(),
-                jnp.asarray(self.pool.block_tables[slot]),
-                jnp.asarray(tail_ids), jnp.asarray(tokens),
-                jnp.int32(ctx), jnp.int32(tail), key, ftemp, ftk,
+                "prefill", self._extend_fns[bucket],
+                self.params, self.pool.kv_state(), *args,
             )
             self.registry.counter(
                 "serving/prefix_reused_tokens"
@@ -1454,25 +1497,29 @@ class InferenceEngine:
         (test-pinned)."""
         bs = self.cfg.kv_block_size
         slot, prompt = state.slot, state.prompt
-        start, end = state.spans[state.idx]
-        tail = end - start
-        tb = kv_mod.pick_bucket(self.prefill_ladder, tail)
-        first_block = start // bs
-        last_block = -(-end // bs)
-        tail_ids = np.zeros((tb // bs,), np.int32)
-        tail_ids[:last_block - first_block] = self.pool.block_tables[
-            slot, first_block:last_block
-        ]
-        tokens = np.zeros((1, tb), np.int32)
-        tokens[0, :tail] = prompt[start:end]
+        with host_span("engine_prefill_build"):
+            start, end = state.spans[state.idx]
+            tail = end - start
+            tb = kv_mod.pick_bucket(self.prefill_ladder, tail)
+            first_block = start // bs
+            last_block = -(-end // bs)
+            tail_ids = np.zeros((tb // bs,), np.int32)
+            tail_ids[:last_block - first_block] = self.pool.block_tables[
+                slot, first_block:last_block
+            ]
+            tokens = np.zeros((1, tb), np.int32)
+            tokens[0, :tail] = prompt[start:end]
+        with host_span("engine_prefill_upload"):
+            args = (
+                jnp.asarray(self.pool.block_tables[slot]),
+                jnp.asarray(tail_ids), jnp.asarray(tokens),
+                jnp.int32(start), jnp.int32(tail),
+                request_key(state.seed, end),
+                jnp.float32(state.temperature), jnp.int32(state.top_k),
+            )
         kv, tok, last = self._run_compiled(
             "prefill", self._extend_fns[tb],
-            self.params, self.pool.kv_state(),
-            jnp.asarray(self.pool.block_tables[slot]),
-            jnp.asarray(tail_ids), jnp.asarray(tokens),
-            jnp.int32(start), jnp.int32(tail),
-            request_key(state.seed, end),
-            jnp.float32(state.temperature), jnp.int32(state.top_k),
+            self.params, self.pool.kv_state(), *args,
         )
         self.pool.set_kv_state(kv)
         state.idx += 1
@@ -1483,7 +1530,7 @@ class InferenceEngine:
         self.pool.lengths[slot] = n
         self.pool.insert_prefix(slot, prompt)
         self.registry.counter("serving/prefill_tokens").inc(n)
-        return True, int(tok), np.asarray(last)
+        return (True, *self._fetch_prefill(tok, last))
 
     # ----------------------------------- KV page handoff (ISSUE 12 (c))
 
@@ -1684,66 +1731,70 @@ class InferenceEngine:
             # all BEFORE any device call, so no donated state is lost
             # to an injected fault.
             feng.decode_step(self.replica_id, [e[0] for e in entries])
-        s = self.cfg.max_slots
-        tokens = np.zeros((s,), np.int32)
-        positions = np.zeros((s,), np.int32)
-        temps = np.zeros((s,), np.float32)
-        top_ks = np.zeros((s,), np.int32)
-        seeds = np.zeros((s,), np.int32)
-        slots = []
-        for slot, token, seed, temp, tk in entries:
-            pos = int(self.pool.lengths[slot])
-            tokens[slot] = token
-            positions[slot] = pos
-            temps[slot] = temp
-            top_ks[slot] = tk
-            seeds[slot] = seed
-            slots.append(slot)
         bucket = kv_mod.pick_bucket(
-            self.kv_ladder, int(positions.max(initial=0)) + 1
+            self.kv_ladder,
+            max(int(self.pool.lengths[e[0]]) for e in entries) + 1,
         )
-        if self.paged:
-            from tensorflow_examples_tpu.serving import paged_kv
+        with host_span("engine_decode_build", K=bucket):
+            s = self.cfg.max_slots
+            tokens = np.zeros((s,), np.int32)
+            positions = np.zeros((s,), np.int32)
+            temps = np.zeros((s,), np.float32)
+            top_ks = np.zeros((s,), np.int32)
+            seeds = np.zeros((s,), np.int32)
+            slots = []
+            for slot, token, seed, temp, tk in entries:
+                pos = int(self.pool.lengths[slot])
+                tokens[slot] = token
+                positions[slot] = pos
+                temps[slot] = temp
+                top_ks[slot] = tk
+                seeds[slot] = seed
+                slots.append(slot)
+            tables = ()  # the dense pool has none
+            if self.paged:
+                from tensorflow_examples_tpu.serving import paged_kv
 
-            # Grow block tables BEFORE the device step: an exhaustion
-            # here has consumed nothing (no donation yet), so only the
-            # requests that could not grow fail — the engine keeps
-            # serving the rest (the batcher handles the partition).
-            exhausted = []
-            for slot in slots:
-                try:
-                    self.pool.ensure_position(
-                        slot, int(positions[slot])
+                # Grow block tables BEFORE the device step: an
+                # exhaustion here has consumed nothing (no donation
+                # yet), so only the requests that could not grow fail —
+                # the engine keeps serving the rest (the batcher
+                # handles the partition).
+                exhausted = []
+                for slot in slots:
+                    try:
+                        self.pool.ensure_position(
+                            slot, int(positions[slot])
+                        )
+                    except paged_kv.BlockExhausted:
+                        exhausted.append(slot)
+                if exhausted:
+                    raise paged_kv.BlockExhausted(
+                        "KV block pool exhausted mid-decode for slot(s) "
+                        f"{exhausted}; pool is serving at capacity",
+                        slots=tuple(exhausted),
                     )
-                except paged_kv.BlockExhausted:
-                    exhausted.append(slot)
-            if exhausted:
-                raise paged_kv.BlockExhausted(
-                    "KV block pool exhausted mid-decode for slot(s) "
-                    f"{exhausted}; pool is serving at capacity",
-                    slots=tuple(exhausted),
-                )
-            bs = self.cfg.kv_block_size
-            tables = np.ascontiguousarray(
-                self.pool.block_tables[:, :bucket // bs]
-            )
+                bs = self.cfg.kv_block_size
+                tables = (np.ascontiguousarray(
+                    self.pool.block_tables[:, :bucket // bs]
+                ),)
+        with host_span("engine_decode_upload"):
+            args = tuple(map(jnp.asarray, (
+                tokens, positions, *tables, seeds, temps, top_ks,
+            )))
+        if self.paged:
             kv, out = self._run_compiled(
                 "decode", self._decode_fns[bucket],
-                self.params, self.pool.kv_state(),
-                jnp.asarray(tokens), jnp.asarray(positions),
-                jnp.asarray(tables), jnp.asarray(seeds),
-                jnp.asarray(temps), jnp.asarray(top_ks),
+                self.params, self.pool.kv_state(), *args,
             )
             self.pool.set_kv_state(kv)
         else:
             self.pool.k, self.pool.v, out = self._run_compiled(
                 "decode", self._decode_fns[bucket],
-                self.params, self.pool.k, self.pool.v,
-                jnp.asarray(tokens), jnp.asarray(positions),
-                jnp.asarray(seeds), jnp.asarray(temps),
-                jnp.asarray(top_ks),
+                self.params, self.pool.k, self.pool.v, *args,
             )
-        out = np.asarray(out)
+        with host_span("engine_decode_fetch"):
+            out = np.asarray(out)
         for slot in slots:
             self.pool.lengths[slot] += 1
         self.registry.counter("serving/decode_steps").inc()
@@ -1784,84 +1835,89 @@ class InferenceEngine:
             # counts speculative steps exactly like plain ones, BEFORE
             # any device call (no donated state lost to a fault).
             feng.decode_step(self.replica_id, [e[0] for e in entries])
-        s = self.cfg.max_slots
         t_n = self.cfg.spec_decode_k + 1
         max_len = self.model_cfg.max_len
-        tokens = np.zeros((s, t_n), np.int32)
-        positions = np.zeros((s,), np.int32)
-        temps = np.zeros((s,), np.float32)
-        top_ks = np.zeros((s,), np.int32)
-        seeds = np.zeros((s,), np.int32)
-        slots: list[int] = []
-        drafts_by_slot: dict[int, list[int]] = {}
-        limits: dict[int, int] = {}
-        for slot, token, drafts, seed, temp, tk in entries:
-            pos = int(self.pool.lengths[slot])
-            drafts = [int(d) for d in drafts][: self.cfg.spec_decode_k]
-            tokens[slot, 0] = token
-            tokens[slot, 1:1 + len(drafts)] = drafts
-            positions[slot] = pos
-            temps[slot] = temp
-            top_ks[slot] = tk
-            seeds[slot] = seed
-            slots.append(slot)
-            drafts_by_slot[slot] = drafts
-            # Committed rows must have landed in the cache: the dense
-            # extent caps them at max_len (rows past it were dropped).
-            limits[slot] = max_len - pos
         bucket = kv_mod.pick_bucket(
             self.kv_ladder,
-            min(int(positions.max(initial=0)) + t_n, max_len),
+            min(max(int(self.pool.lengths[e[0]]) for e in entries) + t_n,
+                max_len),
         )
-        if self.paged:
-            from tensorflow_examples_tpu.serving import paged_kv
+        with host_span("engine_verify_build", K=bucket):
+            s = self.cfg.max_slots
+            tokens = np.zeros((s, t_n), np.int32)
+            positions = np.zeros((s,), np.int32)
+            temps = np.zeros((s,), np.float32)
+            top_ks = np.zeros((s,), np.int32)
+            seeds = np.zeros((s,), np.int32)
+            slots: list[int] = []
+            drafts_by_slot: dict[int, list[int]] = {}
+            limits: dict[int, int] = {}
+            for slot, token, drafts, seed, temp, tk in entries:
+                pos = int(self.pool.lengths[slot])
+                drafts = [int(d) for d in drafts][: self.cfg.spec_decode_k]
+                tokens[slot, 0] = token
+                tokens[slot, 1:1 + len(drafts)] = drafts
+                positions[slot] = pos
+                temps[slot] = temp
+                top_ks[slot] = tk
+                seeds[slot] = seed
+                slots.append(slot)
+                drafts_by_slot[slot] = drafts
+                # Committed rows must have landed in the cache: the
+                # dense extent caps them at max_len (rows past it were
+                # dropped).
+                limits[slot] = max_len - pos
+            tables = ()  # the dense pool has none
+            if self.paged:
+                from tensorflow_examples_tpu.serving import paged_kv
 
-            exhausted = []
-            for slot in slots:
-                pos = int(positions[slot])
-                try:
-                    self.pool.ensure_position(
-                        slot, min(pos + t_n - 1, max_len - 1)
-                    )
-                except paged_kv.BlockExhausted:
-                    # Shrink the spec window before shedding anything:
-                    # the NON-speculative requirement is one row.
+                exhausted = []
+                for slot in slots:
+                    pos = int(positions[slot])
                     try:
-                        self.pool.ensure_position(slot, pos)
+                        self.pool.ensure_position(
+                            slot, min(pos + t_n - 1, max_len - 1)
+                        )
                     except paged_kv.BlockExhausted:
-                        exhausted.append(slot)
-                        continue
-                limits[slot] = min(
-                    limits[slot],
-                    self.pool.covered_positions(slot) - pos,
-                )
-            if exhausted:
-                raise paged_kv.BlockExhausted(
-                    "KV block pool exhausted mid-decode for slot(s) "
-                    f"{exhausted}; pool is serving at capacity",
-                    slots=tuple(exhausted),
-                )
-            bs = self.cfg.kv_block_size
-            tables = np.ascontiguousarray(
-                self.pool.block_tables[:, :bucket // bs]
-            )
+                        # Shrink the spec window before shedding
+                        # anything: the NON-speculative requirement is
+                        # one row.
+                        try:
+                            self.pool.ensure_position(slot, pos)
+                        except paged_kv.BlockExhausted:
+                            exhausted.append(slot)
+                            continue
+                    limits[slot] = min(
+                        limits[slot],
+                        self.pool.covered_positions(slot) - pos,
+                    )
+                if exhausted:
+                    raise paged_kv.BlockExhausted(
+                        "KV block pool exhausted mid-decode for slot(s) "
+                        f"{exhausted}; pool is serving at capacity",
+                        slots=tuple(exhausted),
+                    )
+                bs = self.cfg.kv_block_size
+                tables = (np.ascontiguousarray(
+                    self.pool.block_tables[:, :bucket // bs]
+                ),)
+        with host_span("engine_verify_upload"):
+            args = tuple(map(jnp.asarray, (
+                tokens, positions, *tables, seeds, temps, top_ks,
+            )))
+        if self.paged:
             kv, out = self._run_compiled(
                 "verify", self._verify_fns[bucket],
-                self.params, self.pool.kv_state(),
-                jnp.asarray(tokens), jnp.asarray(positions),
-                jnp.asarray(tables), jnp.asarray(seeds),
-                jnp.asarray(temps), jnp.asarray(top_ks),
+                self.params, self.pool.kv_state(), *args,
             )
             self.pool.set_kv_state(kv)
         else:
             self.pool.k, self.pool.v, out = self._run_compiled(
                 "verify", self._verify_fns[bucket],
-                self.params, self.pool.k, self.pool.v,
-                jnp.asarray(tokens), jnp.asarray(positions),
-                jnp.asarray(seeds), jnp.asarray(temps),
-                jnp.asarray(top_ks),
+                self.params, self.pool.k, self.pool.v, *args,
             )
-        out = np.asarray(out)
+        with host_span("engine_verify_fetch"):
+            out = np.asarray(out)
         committed: dict[int, list[int]] = {}
         total = drafted = accepted = 0
         for slot in slots:

@@ -14,8 +14,7 @@ Layer map:
                     hang dumps.
 * ``sinks``       — JSONL (crash-safe append), clu/TensorBoard (explicit
                     null-writer fallback), console.
-* ``accounting``  — examples/sec, 6ND model-FLOPs MFU (+ observed duty
-                    cycle), goodput math.
+* ``accounting``  — examples/sec, 6ND model-FLOPs MFU, goodput math.
 * ``schema``      — the self-describing JSONL line schema + validator
                     (v2: memory / compile_warning / profile fields).
 * ``compilation`` — recompilation sentinel around the jitted step fns:

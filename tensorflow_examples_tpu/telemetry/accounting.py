@@ -76,31 +76,6 @@ def mfu(
     return flops_per_step * steps_per_sec / peak_flops_total
 
 
-def mfu_fields(
-    flops_per_step: float,
-    steps_per_sec: float | None,
-    peak_flops_total: float,
-    duty_cycle: float | None = None,
-) -> dict:
-    """The MFU block for a derived section: the analytic 6ND figure
-    plus — when an in-loop profiler window measured one
-    (telemetry/profiling.py) — the observed device duty cycle alongside
-    it (never report the analytic number as if it
-    were a measurement). The two are deliberately separate keys: duty
-    cycle is "fraction of wall time the device was busy", an upper
-    bound on where MFU can go, not an MFU itself."""
-    out: dict[str, float | None] = {
-        "mfu": (
-            mfu(flops_per_step, steps_per_sec, peak_flops_total)
-            if steps_per_sec is not None
-            else None
-        )
-    }
-    if duty_cycle is not None:
-        out["device_duty_cycle"] = float(duty_cycle)
-    return out
-
-
 def goodput(counters: Mapping[str, int]) -> float | None:
     """Productive fraction of stepped work.
 

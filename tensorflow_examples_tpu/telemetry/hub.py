@@ -117,11 +117,6 @@ class Telemetry:
         # ({"mesh_shape", "param_sharding_digest", "zero1"}); rides the
         # kind="final" line so a run record names the layout it ran on.
         self.sharding_info: dict | None = None
-        # Observed duty cycle is PER FIT (set by this fit's profiler
-        # window, never read from the process-global gauge: a later fit
-        # in the same process must not inherit an earlier fit's
-        # measurement as its own).
-        self.observed_duty_cycle: float | None = None
         self._emergency = False  # watchdog-fatal: cached-only sampling
         self._windows_since_flush = 0
         self._last_step = 0  # most recent log_window step (fatal marker)
@@ -247,17 +242,17 @@ class Telemetry:
             "step_time_p50": step_summary["p50"],
             "step_time_p95": step_summary["p95"],
             "goodput": accounting.goodput(counters),
+            # Analytic 6ND MFU: an end-to-end utilisation, not a
+            # measurement of the device (the benchmark reduces traces).
+            "mfu": (
+                accounting.mfu(
+                    self.flops_per_step, steps_per_sec,
+                    self.peak_flops_total,
+                )
+                if steps_per_sec is not None
+                else None
+            ),
         }
-        # Analytic 6ND MFU + the observed device duty cycle when THIS
-        # fit's profiler window measured one (telemetry/profiling.py).
-        derived.update(
-            accounting.mfu_fields(
-                self.flops_per_step,
-                steps_per_sec,
-                self.peak_flops_total,
-                duty_cycle=self.observed_duty_cycle,
-            )
-        )
         return derived
 
     def log_window(

@@ -20,11 +20,12 @@ profiler. Before this module the loop had one hardcoded one-shot window
   ``profile/wall_secs``) and are cross-linked from the run's final
   JSONL line as the ``"profile"`` object (dir, start, steps, wall) —
   so the record of *where the trace lives* survives with the run.
-* When the TF profiler plugin can convert the captured xplane (the
-  tools/profile_trace.py protocol), the observed **device duty cycle**
-  is extracted and published as ``profile/device_duty_cycle`` — the
-  measured companion to the analytic 6ND MFU.
-  Conversion is best-effort: missing plugin/backends degrade to None.
+
+Reducing the captured ``.xplane.pb`` to numbers (device busy and idle
+time, program and kernel times, the host's spans over the idle gaps) is
+the benchmark's: ``benchmark/trace_reduce.py`` and
+``benchmark/host_spans.py`` read it with ``jax.profiler.ProfileData``
+alone.
 """
 
 from __future__ import annotations
@@ -34,60 +35,6 @@ import os
 import time
 
 log = logging.getLogger(__name__)
-
-
-def try_device_duty_cycle(
-    trace_dir: str, force: bool = False
-) -> float | None:
-    """Extract the device duty cycle (fraction of traced wall time the
-    device was busy) from a captured xplane, via the TF profiler plugin
-    when available. Returns None when anything is missing — the
-    conversion stack is optional by design.
-
-    The conversion imports TensorFlow (tens of seconds, hundreds of MB)
-    — far too heavy to pay implicitly inside a training loop or the CI
-    suite — so it only runs when ``force=True`` (tools/profile_trace.py,
-    the measurement protocol) or ``PROFILE_DUTY_CYCLE=1`` is set (an
-    operator opting a production run in)."""
-    if not force and os.environ.get("PROFILE_DUTY_CYCLE", "") in ("", "0"):
-        return None
-    import glob
-
-    xplanes = glob.glob(
-        os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True
-    )
-    if not xplanes:
-        return None
-    try:
-        # Stale-proto guard shared with tools/profile_trace.py.
-        os.environ.setdefault(
-            "PROTOCOL_BUFFERS_PYTHON_IMPLEMENTATION", "python"
-        )
-        from tensorboard_plugin_profile.protobuf import overview_page_pb2
-        from tensorflow.python.profiler.internal import (
-            _pywrap_profiler_plugin as pp,
-        )
-
-        data, ok = pp.xspace_to_tools_data(list(xplanes), "overview_page", {})
-        if not ok:
-            return None
-        page = overview_page_pb2.OverviewPage()
-        page.ParseFromString(data)
-        fields = {
-            f.name: v
-            for f, v in page.analysis.ListFields()
-            if isinstance(v, (int, float))
-        }
-        for name, v in fields.items():
-            if "duty_cycle" in name:
-                return float(v) / 100.0 if v > 1.0 else float(v)
-        idle = fields.get("device_idle_time_percent")
-        if idle is not None:
-            return max(0.0, min(1.0, 1.0 - float(idle) / 100.0))
-    except Exception as e:  # noqa: BLE001 - optional measurement path
-        log.debug("duty-cycle extraction unavailable: %s: %s",
-                  type(e).__name__, e)
-    return None
 
 
 class ProfilerWindow:
@@ -194,19 +141,12 @@ class ProfilerWindow:
             "num_steps": steps,
             "wall_secs": round(wall, 6),
         }
-        duty = try_device_duty_cycle(self.out_dir)
         if self._telemetry is not None:
             reg = self._telemetry.registry
             reg.gauge("profile/steps").set(steps)
             reg.gauge("profile/wall_secs").set(wall)
-            if duty is not None:
-                reg.gauge("profile/device_duty_cycle").set(duty)
-                # Per-fit handoff: derived blocks read THIS fit's
-                # measurement, never the (process-global) gauge.
-                self._telemetry.observed_duty_cycle = duty
             self._telemetry.note_profile(self.info)
         log.info(
-            "profiler window closed: %d step(s) in %.3fs -> %s%s",
+            "profiler window closed: %d step(s) in %.3fs -> %s",
             steps, wall, self.out_dir,
-            f" (device duty cycle {duty:.1%})" if duty is not None else "",
         )

@@ -122,6 +122,22 @@ class TimeHistogram:
             self.max = max(self.max, s)
             self._samples.append(s)
 
+    def samples_since(self, mark: int) -> list[float]:
+        """The samples recorded since ``count`` read ``mark``, oldest
+        first — a measurement window reads ``count`` when it opens and
+        this when it closes. What the bounded sample window has already
+        dropped is not returned (size the histogram for the window:
+        ``max_samples``)."""
+        with self._lock:
+            fresh = self.count - int(mark)
+            if fresh < 0:
+                raise ValueError(
+                    f"mark {mark} is ahead of count {self.count} "
+                    f"of histogram {self.name!r}"
+                )
+            kept = list(self._samples)
+        return kept[len(kept) - min(fresh, len(kept)):]
+
     def percentile(self, q: float) -> float | None:
         """Nearest-rank percentile (q in [0, 100]) over the sample window."""
         with self._lock:

@@ -7,10 +7,16 @@ completed span becomes
 
 * a **trace event** in a bounded in-memory buffer, exported as
   Chrome-trace/Perfetto JSON (``chrome://tracing`` / ui.perfetto.dev
-  "complete" events, phase ``"X"``) by ``Telemetry.close()``; and
+  "complete" events, phase ``"X"``) by ``Telemetry.close()``;
 * a **duration sample** in the registry time-histogram
   ``span/<name>`` — which is where the run report's per-phase time
-  breakdown and the step-time percentiles come from.
+  breakdown and the step-time percentiles come from; and
+* while a ``jax.profiler`` session is open, an event ``span/<name>``
+  (with the span's ``args``) on the calling thread's line of the
+  profiler's own trace — the same clock as the device's events, so a
+  reduction can lay the host's spans over the device's idle gaps
+  (``benchmark/host_spans.py``). With no session open the annotation
+  costs a flag read.
 
 The open-span bookkeeping is keyed by thread id and readable from OTHER
 threads: the watchdog's hang dump (utils/diagnostics.py) calls
@@ -25,6 +31,7 @@ question "where did the *host* loop's wall time go".
 from __future__ import annotations
 
 import contextlib
+import functools
 import json
 import threading
 import time
@@ -35,6 +42,16 @@ from typing import Callable
 # (startup + steady state onset, the diagnostically interesting part)
 # and counts the rest as dropped.
 MAX_EVENTS = 100_000
+
+
+@functools.cache
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, imported at the first span: the
+    telemetry package itself imports without JAX (the report tools read
+    run records with it)."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation
 
 
 class Tracer:
@@ -63,37 +80,43 @@ class Tracer:
     @contextlib.contextmanager
     def span(self, name: str, **args):
         tid = threading.get_ident()
-        t0 = self._now_ns()
-        with self._lock:
-            self._open.setdefault(tid, []).append(name)
-        try:
-            yield
-        finally:
-            t1 = self._now_ns()
+        label = f"span/{name}"
+        # The annotation brackets the bookkeeping below too, so back-to-back
+        # spans leave no hole between them on the profiler's timeline.
+        with _trace_annotation()(label, **args):
+            t0 = self._now_ns()
             with self._lock:
-                stack = self._open.get(tid)
-                if stack and stack[-1] == name:
-                    stack.pop()
-                if len(self._events) < self._max_events:
-                    ev = {
-                        "name": name,
-                        "ph": "X",
-                        "ts": (t0 - self._epoch_ns) / 1e3,  # µs
-                        "dur": (t1 - t0) / 1e3,
-                        "pid": 0,
-                        "tid": tid,
-                    }
-                    if args:
-                        ev["args"] = args
-                    self._events.append(ev)
-                else:
-                    self.dropped += 1
-            reg = self._registry
-            if reg is None:
-                from tensorflow_examples_tpu.telemetry import registry as _reg
+                self._open.setdefault(tid, []).append(name)
+            try:
+                yield
+            finally:
+                t1 = self._now_ns()
+                with self._lock:
+                    stack = self._open.get(tid)
+                    if stack and stack[-1] == name:
+                        stack.pop()
+                    if len(self._events) < self._max_events:
+                        ev = {
+                            "name": name,
+                            "ph": "X",
+                            "ts": (t0 - self._epoch_ns) / 1e3,  # µs
+                            "dur": (t1 - t0) / 1e3,
+                            "pid": 0,
+                            "tid": tid,
+                        }
+                        if args:
+                            ev["args"] = args
+                        self._events.append(ev)
+                    else:
+                        self.dropped += 1
+                reg = self._registry
+                if reg is None:
+                    from tensorflow_examples_tpu.telemetry import (
+                        registry as _reg,
+                    )
 
-                reg = _reg.default_registry()
-            reg.histogram(f"span/{name}").record((t1 - t0) / 1e9)
+                    reg = _reg.default_registry()
+                reg.histogram(label).record((t1 - t0) / 1e9)
 
     # ------------------------------------------------------------ inspect
 

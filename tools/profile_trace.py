@@ -38,11 +38,6 @@ import sys
 # stale vs the image's C++ protobuf): pure-python parsing is slower but
 # always compatible.
 os.environ.setdefault("PROTOCOL_BUFFERS_PYTHON_IMPLEMENTATION", "python")
-# NB: PROFILE_DUTY_CYCLE stays unset here — this tool's _convert()
-# already runs the (heavy) overview_page conversion on the same
-# xplanes for banking; duplicating it inside the in-loop window would
-# convert every trace twice.
-
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import bench  # noqa: E402
@@ -113,9 +108,6 @@ def _trace_gpt2(steps: int = 10, warmup: int = 5) -> dict:
         ),
         "tokens_per_sec_during_trace": round(tokens / dt, 1) if dt else None,
     }
-    duty = gauges.get("profile/device_duty_cycle")
-    if duty is not None:
-        out["device_duty_cycle_inloop"] = round(float(duty), 4)
     return out
 
 

@@ -22,10 +22,9 @@ span timeline), and prints:
 * per-phase host time from the trace (where the loop's wall time went)
 * device-side facts when the run recorded them (schema v2, ISSUE 3):
   peak live-memory watermark + the params/opt/other init breakdown,
-  compile count + post-warmup recompile warnings, the in-loop profiler
-  window cross-link, and the observed device duty cycle next to the
-  analytic MFU. v1 runs simply omit these lines — absent fields degrade
-  gracefully.
+  compile count + post-warmup recompile warnings, and the in-loop
+  profiler window cross-link. v1 runs simply omit these lines — absent
+  fields degrade gracefully.
 * fleet facts (schema v3, ISSUE 4): when the run dir holds per-host
   telemetry shards (``telemetry.host{k}.jsonl``), they are merged into
   a per-host table and the slowest host is flagged; the last
@@ -225,10 +224,6 @@ def summarize(lines: list[dict], trace: dict | None) -> dict:
     record["fleet_straggler_windows"] = sum(
         1 for l in fleet_lines if l["fleet"].get("straggler")
     )
-    # From derived ONLY: the hub publishes it per fit, while the gauge
-    # is process-global and would attribute an earlier fit's
-    # measurement to this record.
-    record["device_duty_cycle"] = derived.get("device_duty_cycle")
     if trace is not None:
         phases: dict[str, dict] = {}
         for ev in trace.get("traceEvents", []):
@@ -452,18 +447,10 @@ def render(record: dict, skipped: int) -> str:
         + _fmt(p95 * 1e3 if p95 is not None else None, "ms")
     )
     mfu = record["mfu"]
-    duty = record.get("device_duty_cycle")
     out.append(
         "mfu estimate: "
         + (_fmt(mfu * 100, "%", nd=4) if mfu is not None else "n/a")
-        + " (6ND analytic"
-        + (
-            f"; observed device duty cycle {_fmt(duty * 100, '%', nd=1)} "
-            "from the profiler window"
-            if duty is not None
-            else ""
-        )
-        + ")"
+        + " (6ND analytic)"
         + (
             " (no peak FLOPs for this device kind; set "
             "--telemetry_peak_tflops)"

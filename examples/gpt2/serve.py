@@ -286,7 +286,7 @@ def main(argv):
         set().union(*(x.devices() for x in jax.tree.leaves(tree)))
     )
     per_dev = lambda tree: tree_bytes(tree, per_device=True) / 2**20
-    kv = (engine.pool.k, engine.pool.v)
+    kv = (engine.pool.k, engine.pool.v)  # per-layer tuples when paged
     print(
         f"placement: params on {held(engine.params)} "
         f"({per_dev(engine.params):.0f} MiB each) and KV pool on "

@@ -17,15 +17,16 @@ prefill case):
 
 * ``q`` [S, H, D] — one new query per slot, its own K/V already
   written through the block table.
-* ``k_blocks`` / ``v_blocks`` [NB, H, BS, D] — ONE layer's physical
-  block pools (``serving/paged_kv.PagedKVPool`` layout).
+* ``k_blocks`` / ``v_blocks`` [NB, BS, H*D] — ONE layer's physical
+  block pools (``serving/paged_kv.PagedKVPool`` layout: a token's row
+  is its H*D values).
 * ``lengths`` [S] int32 — populated lengths INCLUDING the new token;
   slot s attends columns ``< lengths[s]``, nothing else.
 * ``block_tables`` [S, nb] int32 — logical->physical block map for the
   active KV bucket (``nb = bucket // BS``); entries past a slot's
   allocation point at the null block, whose rows the length mask never
   admits.
-* ``k_scale`` / ``v_scale`` [NB, H, BS] f32 (optional) — the int8
+* ``k_scale`` / ``v_scale`` [NB, BS, H] f32 (optional) — the int8
   pools' blockwise per-row scales (``core/precision``): passing them
   selects the **dequant-in-kernel** path, so a quantized cache is read
   at 1 byte/element from HBM and widened to f32 only in VMEM — the
@@ -33,23 +34,20 @@ prefill case):
 
 Grid is (slot, kv-block); one step handles EVERY head of one physical
 block, with the familiar online-softmax scratch carry
-(``ops/attention.py``) kept per head. Heads ride as a full block
-dimension because Mosaic constrains the last two dims of a block to
-(8k, 128k) or the full array dim: a per-head ``(1, 1, head_dim)`` block
-over ``[S, H, D]`` (or ``(1, 1, block_size)`` over the ``[NB, H, BS]``
-scales) cannot lower, while ``(1, H, D)`` / ``(1, H, BS)`` /
-``(1, H, BS, D)`` can — and the grid is H times shorter for it. Inside
-a step the heads are a static unrolled loop of the same 2-D
-``[1, D]·[BS, D]ᵀ`` products ``ops/decode.py`` runs. Unpopulated
-trailing blocks are clamped to the last populated index in the index
-map — a repeated index is a no-op for the Pallas pipeline, so **no HBM
-traffic is issued for blocks past a slot's length** — and ``pl.when``
-skips their compute.
+(``ops/attention.py``) kept per head. A step's K/V block is the pool's
+own ``(1, BS, H*D)`` — Mosaic constrains the last two dims of a block
+to (8k, 128k) or the full array dim, and these are the full dims, as
+are ``(1, H, D)`` of q and ``(1, BS, H)`` of the scales. The block is
+widened to f32 once and the heads are a static unrolled loop over its
+``D``-wide lane slices, each the same 2-D ``[1, D]·[BS, D]ᵀ`` product
+``ops/decode.py`` runs. Unpopulated trailing blocks are clamped to the
+last populated index in the index map — a repeated index is a no-op
+for the Pallas pipeline, so **no HBM traffic is issued for blocks past
+a slot's length** — and ``pl.when`` skips their compute.
 
-int8 scales are per (block, head, row), so they commute with the dot:
-``q·(k·s) == (q·k)·s``. The kernel scales the ``[1, BS]`` score and
-probability rows instead of the ``[BS, D]`` tiles — same math, D times
-fewer multiplies, and no lane→sublane relayout of the scale row.
+int8 scales are per (block, row, head): a ``[BS, 1]`` column of the
+scale block multiplies the head's ``[BS, D]`` tile along lanes —
+``core/precision.dequantize_rows`` in VMEM.
 
 The XLA gather path (``kv_cache.varlen_decode_attention`` with
 ``block_tables=``) stays in-tree as the reference oracle:
@@ -100,17 +98,23 @@ def _paged_decode_kernel(
         live = col0 + lax.broadcasted_iota(
             jnp.int32, (1, block_size), 1
         ) < length
+        head_dim = q_ref.shape[-1]
+        kb = k_ref[0].astype(jnp.float32)                  # [BS, H*D]
+        vb = v_ref[0].astype(jnp.float32)
+        if quantized:
+            ksc, vsc = ksc_ref[0], vsc_ref[0]              # [BS, H]
         for h in range(num_heads):
             row = pl.ds(h, 1)
+            lanes = slice(h * head_dim, (h + 1) * head_dim)
             q = q_ref[0, row, :] * sm_scale                # [1, D] f32
-            k = k_ref[0, h].astype(jnp.float32)            # [BS, D]
-            v = v_ref[0, h].astype(jnp.float32)
+            k, v = kb[:, lanes], vb[:, lanes]              # [BS, D]
+            if quantized:
+                k = k * ksc[:, h:h + 1]
+                v = v * vsc[:, h:h + 1]
             scores = lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )  # [1, BS]
-            if quantized:
-                scores = scores * ksc_ref[0, row, :]
             scores = jnp.where(live, scores, NEG_INF)
             m = m_s[row, :]
             m_new = jnp.maximum(m, jnp.max(scores, axis=1, keepdims=True))
@@ -120,8 +124,6 @@ def _paged_decode_kernel(
             l_s[row, :] = l_s[row, :] * alpha + jnp.sum(
                 p, axis=1, keepdims=True
             )
-            if quantized:
-                p = p * vsc_ref[0, row, :]
             acc_s[row, :] = acc_s[row, :] * alpha + jnp.dot(
                 p, v, preferred_element_type=jnp.float32
             )
@@ -147,18 +149,17 @@ def _make_paged_decode(num_slots, num_heads, nb, block_size, head_dim,
         # Clamp unpopulated blocks to the last populated one: the
         # pipeline sees an unchanged physical index and skips the copy.
         last = jnp.maximum((len_ref[s] - 1) // block_size, 0)
-        return (tbl_ref[s, jnp.minimum(j, last)], 0, 0, 0)
-
-    def sc_index(s, j, len_ref, tbl_ref):
-        return kv_index(s, j, len_ref, tbl_ref)[:3]
+        return (tbl_ref[s, jnp.minimum(j, last)], 0, 0)
 
     qo_spec = pl.BlockSpec(
         (1, num_heads, head_dim), lambda s, j, ln, tb: (s, 0, 0)
     )
-    kv_spec = pl.BlockSpec((1, num_heads, block_size, head_dim), kv_index)
+    kv_spec = pl.BlockSpec(
+        (1, block_size, num_heads * head_dim), kv_index
+    )
     in_specs = [qo_spec, kv_spec, kv_spec]
     if quantized:
-        sc_spec = pl.BlockSpec((1, num_heads, block_size), sc_index)
+        sc_spec = pl.BlockSpec((1, block_size, num_heads), kv_index)
         in_specs += [sc_spec, sc_spec]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -213,7 +214,7 @@ def paged_decode_attention(
     if interpret is None:
         interpret = pallas_interpret("paged_decode_attention")
     num_slots, num_heads, head_dim = q.shape
-    _, _, block_size, _ = k_blocks.shape
+    block_size = k_blocks.shape[1]
     nb = block_tables.shape[1]
     if (k_scale is None) != (v_scale is None):
         raise ValueError("pass both k_scale and v_scale, or neither")
@@ -245,31 +246,13 @@ def paged_decode_reference(
     sm_scale: float | None = None,
 ) -> jax.Array:
     """The XLA gather-path oracle the kernel is pinned against: exactly
-    what the engine runs under ``attention="xla"`` — dequantize (int8)
-    or gather (fp) by table, then ``varlen_decode_attention``."""
+    what the engine runs under ``attention="xla"`` — gather (and, int8,
+    dequantize) by table, then the masked attention."""
     from tensorflow_examples_tpu.serving.kv_cache import (
         varlen_decode_attention,
     )
 
-    if k_scale is not None:
-        from tensorflow_examples_tpu.core.precision import (
-            dequantize_int8_rows,
-        )
-
-        s, nb = block_tables.shape
-        _, h, bs, d = k_blocks.shape
-
-        def gather(blocks, scales):
-            g = dequantize_int8_rows(
-                blocks[block_tables], scales[block_tables], q.dtype
-            )
-            return g.transpose(0, 2, 1, 3, 4).reshape(s, h, nb * bs, d)
-
-        return varlen_decode_attention(
-            q, gather(k_blocks, k_scale), gather(v_blocks, v_scale),
-            lengths, sm_scale=sm_scale,
-        )
     return varlen_decode_attention(
         q, k_blocks, v_blocks, lengths, sm_scale=sm_scale,
-        block_tables=block_tables,
+        block_tables=block_tables, k_scale=k_scale, v_scale=v_scale,
     )

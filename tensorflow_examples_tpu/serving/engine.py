@@ -324,86 +324,64 @@ def _verify_forward(cfg: TransformerConfig, params, k_cache, v_cache,
 # ---------------------------------------------------------- paged forward
 #
 # The paged mirrors of the dense cache ops (ISSUE 8): same math, but
-# K/V land in [L, NB, H, BS, D] block pools addressed through per-slot
-# block tables instead of a per-slot max_len extent. ``kv`` is the
-# pool's device-state tuple — (k, v) or, under int8, (k, v, k_scale,
-# v_scale) with per-row scales stored blockwise
-# (core/precision.quantize_int8_rows).
+# K/V land in per-layer [NB, BS, H*D] block pools addressed through
+# per-slot block tables instead of a per-slot max_len extent. ``kv`` is
+# the pool's device state — (k, v) or, quantized, (k, v, k_scale,
+# v_scale), each a tuple of one array per layer, with per-row scales
+# stored blockwise ([NB, BS, H]; core/precision.quantize_rows). No
+# program reads or writes more of a layer's array than the blocks its
+# tables name: writes are in-place scatters of token rows, reads gather
+# blocks and re-view only what they gathered.
+
+
+def _paged_write_rows(kv, layer, index, k, v):
+    """Write K/V ``[..., H, hd]`` into layer ``layer``'s arrays at
+    ``index`` (a tuple indexing their leading axes, block then row):
+    values flattened to the pool's ``H*hd`` rows, quantized with their
+    per-row scales when the pool is (the store dtype, int8 or fp8,
+    rides on the pool arrays themselves — one write path serves both).
+    A decode or verify step writes ``(write_blocks, offsets)``, one row
+    per slot (and draft); parked slots write into the null block (their
+    table entry is 0) — discarded by masking."""
+    from tensorflow_examples_tpu.core.precision import quantize_rows
+
+    def rows(x):
+        return x.reshape(*x.shape[:-2], -1)
+
+    if len(kv) == 4:
+        (qk, sk), (qv, sv) = (
+            quantize_rows(x, kv[0][layer].dtype) for x in (k, v)
+        )
+        new = (rows(qk), rows(qv), sk, sv)
+    else:
+        new = (rows(k), rows(v))
+    return tuple(
+        (*arrs[:layer],
+         arrs[layer].at[index].set(x.astype(arrs[layer].dtype)),
+         *arrs[layer + 1:])
+        for arrs, x in zip(kv, new)
+    )
 
 
 def _paged_write_prompt(kv, ks, vs, block_ids, *, block_size):
-    """Scatter a prefill's freshly computed K/V ([L, bucket, H, hd])
-    into the blocks named by ``block_ids`` [bucket // BS] (pad entries
-    point at the null block; their garbage is never read)."""
-    from tensorflow_examples_tpu.core.precision import quantize_rows
-
-    num_layers, bucket, h, hd = ks.shape
-    nb = bucket // block_size
-
-    def to_blocks(x):  # [L, bucket, H, hd] -> [L, nb, H, BS, hd]
-        return x.reshape(
-            num_layers, nb, block_size, h, hd
-        ).transpose(0, 1, 3, 2, 4)
-
-    kb, vb = to_blocks(ks), to_blocks(vs)
-    if len(kv) == 4:
-        # Quantized pool: the store dtype (int8 or fp8) rides on the
-        # pool arrays themselves — one write path serves both.
-        k, v, ksc, vsc = kv
-        qk, sk = quantize_rows(kb, k.dtype)
-        qv, sv = quantize_rows(vb, v.dtype)
-        return (
-            k.at[:, block_ids].set(qk),
-            v.at[:, block_ids].set(qv),
-            ksc.at[:, block_ids].set(sk),
-            vsc.at[:, block_ids].set(sv),
+    """Scatter a prefill's freshly computed K/V (per layer ``[bucket,
+    H, hd]``) into the blocks named by ``block_ids`` [bucket // BS]
+    (pad entries point at the null block; their garbage is never
+    read). ``[bucket, H, hd] -> [nb, BS, H, hd]`` is a pure reshape."""
+    for layer, (k, v) in enumerate(zip(ks, vs)):
+        k, v = (
+            x.reshape(-1, block_size, *x.shape[1:]) for x in (k, v)
         )
-    k, v = kv
-    return (
-        k.at[:, block_ids].set(kb.astype(k.dtype)),
-        v.at[:, block_ids].set(vb.astype(v.dtype)),
-    )
+        kv = _paged_write_rows(kv, layer, (block_ids,), k, v)
+    return kv
 
 
-def _paged_write_rows(kv, layer, write_blocks, offsets, k, v):
-    """One decode step's per-slot rows ([S, H, hd]) into block
-    ``write_blocks[s]`` at row ``offsets[s]``. Parked slots write into
-    the null block (their table entry is 0) — discarded by masking."""
-    from tensorflow_examples_tpu.core.precision import quantize_rows
-
+def _layer_scales(kv, layer) -> dict:
+    """A quantized pool's per-row scales of one layer, as the keyword
+    arguments every paged attention takes ({} for an fp pool)."""
     if len(kv) == 4:
-        kk, vv, ksc, vsc = kv
-        qk, sk = quantize_rows(k, kk.dtype)
-        qv, sv = quantize_rows(v, vv.dtype)
-        return (
-            kk.at[layer, write_blocks, :, offsets, :].set(qk),
-            vv.at[layer, write_blocks, :, offsets, :].set(qv),
-            ksc.at[layer, write_blocks, :, offsets].set(sk),
-            vsc.at[layer, write_blocks, :, offsets].set(sv),
-        )
-    kk, vv = kv
-    return (
-        kk.at[layer, write_blocks, :, offsets, :].set(k.astype(kk.dtype)),
-        vv.at[layer, write_blocks, :, offsets, :].set(v.astype(vv.dtype)),
-    )
-
-
-def _paged_gather_dequant(kv, layer, tables, dtype):
-    """int8 path: gather blocks + blockwise scales by table, dequantize
-    to ``dtype`` -> (k, v) [S, H, nb*BS, D] (the fp paths instead hand
-    ``varlen_decode_attention`` the raw pool via ``block_tables=``)."""
-    from tensorflow_examples_tpu.core.precision import dequantize_int8_rows
-
-    k, v, ksc, vsc = kv
-    s, nb = tables.shape
-    _, _, h, bs, d = k.shape
-
-    def gather(blocks, scales):
-        g = dequantize_int8_rows(blocks[layer][tables],
-                                 scales[layer][tables], dtype)
-        return g.transpose(0, 2, 1, 3, 4).reshape(s, h, nb * bs, d)
-
-    return gather(k, ksc), gather(v, vsc)
+        return dict(k_scale=kv[2][layer], v_scale=kv[3][layer])
+    return {}
 
 
 def _paged_decode_forward(cfg: TransformerConfig, params, kv, tokens,
@@ -423,34 +401,20 @@ def _paged_decode_forward(cfg: TransformerConfig, params, kv, tokens,
         tables, (positions // block_size)[:, None], axis=1
     )[:, 0]
     offsets = positions % block_size
-    fused = attention == "paged_flash"
-    if fused:
+    attend = kv_mod.varlen_decode_attention
+    if attention == "paged_flash":
         from tensorflow_examples_tpu.ops.paged_decode import (
-            paged_decode_attention,
+            paged_decode_attention as attend,
         )
     for layer in range(cfg.num_layers):
         p = params[f"h_{layer}"]
         y = _layer_norm(x, p["ln_1"])
         q, k, v = _qkv(y, p["attn"])  # [S, H, hd]
-        kv = _paged_write_rows(kv, layer, write_blocks, offsets, k, v)
-        if len(kv) == 4:
-            if fused:
-                att = paged_decode_attention(
-                    q, kv[0][layer], kv[1][layer], lengths, tables,
-                    k_scale=kv[2][layer], v_scale=kv[3][layer],
-                )
-            else:
-                kk, vv = _paged_gather_dequant(kv, layer, tables, q.dtype)
-                att = kv_mod.varlen_decode_attention(q, kk, vv, lengths)
-        elif fused:
-            att = paged_decode_attention(
-                q, kv[0][layer], kv[1][layer], lengths, tables
-            )
-        else:
-            att = kv_mod.varlen_decode_attention(
-                q, kv[0][layer], kv[1][layer], lengths,
-                block_tables=tables,
-            )
+        kv = _paged_write_rows(kv, layer, (write_blocks, offsets), k, v)
+        att = attend(
+            q, kv[0][layer], kv[1][layer], lengths, block_tables=tables,
+            **_layer_scales(kv, layer),
+        )
         x = x + _attn_out(att, p["attn"])
         x = x + _block_mlp(_layer_norm(x, p["ln_2"]), p)
     x = _layer_norm(x, params["ln_f"])
@@ -483,15 +447,11 @@ def _paged_verify_forward(cfg: TransformerConfig, params, kv, tokens,
         p = params[f"h_{layer}"]
         y = _layer_norm(x, p["ln_1"])
         q, k, v = _qkv(y, p["attn"])  # [S, T, H, hd]
-        kv = _paged_write_rows(kv, layer, write_blocks, offsets, k, v)
-        if len(kv) == 4:
-            kk, vv = _paged_gather_dequant(kv, layer, tables, q.dtype)
-            att = kv_mod.varlen_verify_attention(q, kk, vv, positions)
-        else:
-            att = kv_mod.varlen_verify_attention(
-                q, kv[0][layer], kv[1][layer], positions,
-                block_tables=tables,
-            )
+        kv = _paged_write_rows(kv, layer, (write_blocks, offsets), k, v)
+        att = kv_mod.varlen_verify_attention(
+            q, kv[0][layer], kv[1][layer], positions, block_tables=tables,
+            **_layer_scales(kv, layer),
+        )
         x = x + _attn_out(att, p["attn"])
         x = x + _block_mlp(_layer_norm(x, p["ln_2"]), p)
     x = _layer_norm(x, params["ln_f"])
@@ -511,8 +471,6 @@ def _extend_forward(cfg: TransformerConfig, params, kv, ctx_table,
     scores/softmax, probabilities cast to the value dtype, f32
     accumulation) so hits stay token-identical at fp32 (test-pinned).
     """
-    from tensorflow_examples_tpu.core.precision import dequantize_int8_rows
-
     wte = params["wte"]["embedding"]
     tb = tokens.shape[1]
     sm_scale = cfg.head_dim ** -0.5
@@ -522,9 +480,7 @@ def _extend_forward(cfg: TransformerConfig, params, kv, ctx_table,
     x = _rows(wte, tokens) + _rows(
         params["wpe"]["embedding"], jnp.minimum(positions, cfg.max_len - 1)
     )[None]
-    quantized = len(kv) == 4
-    nb = ctx_table.shape[0]
-    ctx_cols = nb * block_size
+    ctx_cols = ctx_table.shape[0] * block_size
     colc = jax.lax.broadcasted_iota(jnp.int32, (1, 1, tb, ctx_cols), 3)
     rowt = jax.lax.broadcasted_iota(jnp.int32, (1, 1, tb, tb), 2)
     colt = jax.lax.broadcasted_iota(jnp.int32, (1, 1, tb, tb), 3)
@@ -535,27 +491,20 @@ def _extend_forward(cfg: TransformerConfig, params, kv, ctx_table,
         q, k, v = _qkv(y, p["attn"])  # [1, tb, H, hd]
         ks.append(k[0])
         vs.append(v[0])
-        if quantized:
-            kc = dequantize_int8_rows(
-                kv[0][layer][ctx_table], kv[2][layer][ctx_table], q.dtype
+        # The cached context as [ctx_cols, H, hd], from this layer's
+        # blocks of the table alone.
+        kc, vc = (
+            x.astype(q.dtype) for x in kv_mod.gather_layer_kv(
+                kv[0][layer], kv[1][layer], ctx_table, cfg.num_heads,
+                q.dtype, **_layer_scales(kv, layer),
             )
-            vc = dequantize_int8_rows(
-                kv[1][layer][ctx_table], kv[3][layer][ctx_table], q.dtype
-            )
-        else:
-            kc = kv[0][layer][ctx_table].astype(q.dtype)
-            vc = kv[1][layer][ctx_table].astype(q.dtype)
-        # [nb, H, BS, hd] -> [H, nb*BS, hd]
-        kc = kc.transpose(1, 0, 2, 3).reshape(-1, ctx_cols, cfg.head_dim)
-        vc = vc.transpose(1, 0, 2, 3).reshape(-1, ctx_cols, cfg.head_dim)
-        qh = q.transpose(0, 2, 1, 3)  # [1, H, tb, hd]
+        )
         s_ctx = jnp.einsum(
-            "bhtd,hkd->bhtk", qh, kc, preferred_element_type=jnp.float32
+            "bthd,khd->bhtk", q, kc, preferred_element_type=jnp.float32
         ) * sm_scale
         s_ctx = jnp.where(colc < ctx_len, s_ctx, NEG_INF)
-        kh = k.transpose(0, 2, 1, 3)
         s_tail = jnp.einsum(
-            "bhtd,bhkd->bhtk", qh, kh, preferred_element_type=jnp.float32
+            "bthd,bkhd->bhtk", q, k, preferred_element_type=jnp.float32
         ) * sm_scale
         s_tail = jnp.where(rowt >= colt, s_tail, NEG_INF)
         prob = jax.nn.softmax(
@@ -563,20 +512,23 @@ def _extend_forward(cfg: TransformerConfig, params, kv, ctx_table,
         )
         p_ctx, p_tail = prob[..., :ctx_cols], prob[..., ctx_cols:]
         out = jnp.einsum(
-            "bhtk,hkd->bhtd", p_ctx.astype(vc.dtype), vc,
+            "bhtk,khd->bthd", p_ctx.astype(vc.dtype), vc,
             preferred_element_type=jnp.float32,
         ) + jnp.einsum(
-            "bhtk,bhkd->bhtd", p_tail.astype(v.dtype),
-            v.transpose(0, 2, 1, 3), preferred_element_type=jnp.float32,
+            "bhtk,bkhd->bthd", p_tail.astype(v.dtype), v,
+            preferred_element_type=jnp.float32,
         )
-        att = out.astype(q.dtype).transpose(0, 2, 1, 3)
+        att = out.astype(q.dtype)
         x = x + _attn_out(att, p["attn"])
         x = x + _block_mlp(_layer_norm(x, p["ln_2"]), p)
     x = _layer_norm(x, params["ln_f"])
-    kv = _paged_write_prompt(
-        kv, jnp.stack(ks), jnp.stack(vs), tail_ids, block_size=block_size
-    )
+    kv = _paged_write_prompt(kv, ks, vs, tail_ids, block_size=block_size)
     return kv, jnp.dot(x, _w(wte).T)
+
+
+# The pool's device state in order (``PagedKVPool.kv_state``), by the
+# names its arrays have in a KV page payload.
+_PAGE_NAMES = ("k", "v", "k_scale", "v_scale")
 
 
 # -------------------------------------------------------------- sampling
@@ -1029,8 +981,10 @@ class InferenceEngine:
         self._ref_fwd = None
 
     def _kv_sharding(self):
-        """KV-pool NamedSharding from the ShardingConfig: heads (dim 2
-        of [L, S, H, max_len, D]) shard over ``model`` — the layout
+        """KV-pool NamedSharding from the ShardingConfig: heads shard
+        over ``model`` — dim 2 of the dense [L, S, H, max_len, D], and
+        the last dim of a paged layer's [NB, BS, H*D] (and of its
+        [NB, BS, H] scales), whose shards hold whole heads — the layout
         that keeps per-slot attention local to the head shard the qkv
         projection already produced. A head count the model axis
         doesn't divide replicates instead (placement is an
@@ -1048,6 +1002,8 @@ class InferenceEngine:
             if m > 1 and self.model_cfg.num_heads % m == 0
             else None
         )
+        if self.paged:
+            return NamedSharding(self.mesh, P(None, None, heads))
         return NamedSharding(self.mesh, P(None, None, heads, None, None))
 
     # ----------------------------------------------------- compiled fns
@@ -1568,14 +1524,22 @@ class InferenceEngine:
         idx = jnp.asarray(
             [int(b) for b in self.pool.block_tables[slot, skip:nb]]
         )
-        state = self.pool.kv_state()
+        # The wire keeps its [L, pages, H, BS, D] order (scales [L,
+        # pages, H, BS]); the few pages moved are re-ordered on the
+        # host copy.
+        def to_wire(name, layers):
+            # [L, pages, BS, H*D] (scales: [L, pages, BS, H]).
+            arr = np.stack(jax.device_get([a[idx] for a in layers]))
+            if not name.endswith("_scale"):
+                arr = arr.reshape(
+                    *arr.shape[:-1], self.model_cfg.num_heads, -1
+                )
+            return np.ascontiguousarray(np.moveaxis(arr, 3, 2))
+
         arrays = {
-            "k": np.asarray(state[0][:, idx]),
-            "v": np.asarray(state[1][:, idx]),
+            name: to_wire(name, layers)
+            for name, layers in zip(_PAGE_NAMES, self.pool.kv_state())
         }
-        if self.pool.quantized:
-            arrays["k_scale"] = np.asarray(state[2][:, idx])
-            arrays["v_scale"] = np.asarray(state[3][:, idx])
         meta = dict(
             block_size=bs,
             num_layers=self.model_cfg.num_layers,
@@ -1664,15 +1628,14 @@ class InferenceEngine:
                     f"pages array {name!r} has shape "
                     f"{tuple(arr.shape)}, expected {want_shape}"
                 )
-        state = list(self.pool.kv_state())
-        names = ("k", "v", "k_scale", "v_scale")[: len(state)]
-        for i, name in enumerate(names):
+        state = self.pool.kv_state()
+        for name, layers in zip(_PAGE_NAMES, state):
             # The payload arrays carry the DONOR's cache dtype; a
             # same-width mismatch (f16 pages into a bf16 pool) would
             # value-cast every KV entry — exactly the silently-wrong
             # cache the wire format promises cannot happen. kv_bits
             # catches width; this catches kind.
-            want = jnp.dtype(state[i].dtype)
+            want = jnp.dtype(layers[0].dtype)
             got = jnp.dtype(arrays[name].dtype)
             if got != want:
                 raise ValueError(
@@ -1699,11 +1662,17 @@ class InferenceEngine:
         if fresh:
             col = nb - len(fresh) - start  # payload column of fresh[0]
             idx = jnp.asarray(fresh)
-            for i, name in enumerate(names):
-                state[i] = state[i].at[:, idx].set(
-                    jnp.asarray(arrays[name][:, col:])
-                )
-            self.pool.set_kv_state(tuple(state))
+            new = []
+            for name, layers in zip(_PAGE_NAMES, state):
+                # Wire order [L, pages, H, BS(, D)] -> the pool's rows
+                # [L, pages, BS, H(*D)], on the host copy.
+                pages = np.moveaxis(arrays[name][:, col:], 2, 3)
+                pages = pages.reshape(*pages.shape[:3], -1)
+                new.append(tuple(
+                    a.at[idx].set(jnp.asarray(pages[layer]))
+                    for layer, a in enumerate(layers)
+                ))
+            self.pool.set_kv_state(tuple(new))
         self.pool.lengths[slot] = n
         self.pool.insert_prefix(slot, prompt)
         self.registry.counter("serving/kv_pages_imported").inc(

@@ -67,21 +67,41 @@ def pick_bucket(ladder: list[int], needed: int) -> int:
     )
 
 
-def gather_block_kv(blocks: jax.Array, block_tables: jax.Array) -> jax.Array:
-    """Gather per-slot contiguous cache views out of a paged block pool.
+def gather_block_kv(blocks: jax.Array, block_tables: jax.Array,
+                    num_heads: int, *, scales: jax.Array | None = None,
+                    dtype=None) -> jax.Array:
+    """Gather contiguous cache views out of ONE layer's paged block pool.
 
-    blocks: [NB, H, BS, D] — one layer's block pool (NB physical
-    blocks of BS token rows each). block_tables: [S, nb] int32 — each
-    slot's logical-block -> physical-block map for the active KV
-    bucket (nb = bucket // BS; entries past a slot's allocation point
-    at the reserved null block 0, whose rows length-masking never
-    lets through). Returns [S, H, nb*BS, D] — exactly the dense-pool
-    slice :func:`varlen_decode_attention` consumes.
+    blocks: [NB, BS, H*D] — NB physical blocks of BS token rows, a row
+    being the token's H*D values (``paged_kv.PagedKVPool``). block_tables:
+    [..., nb] int32 — logical-block -> physical-block maps for the active
+    KV bucket (nb = bucket // BS; entries past an allocation point at
+    the reserved null block 0, whose rows length-masking never lets
+    through). Returns [..., nb*BS, H, D]: only the gathered blocks are
+    ever re-viewed, never the pool.
+
+    ``scales`` ([NB, BS, H], the int8/fp8 pools' per-row scales) selects
+    the dequantizing gather, to ``dtype``.
     """
-    s, nb = block_tables.shape
-    _, h, bs, d = blocks.shape
-    g = blocks[block_tables]             # [S, nb, H, BS, D]
-    return g.transpose(0, 2, 1, 3, 4).reshape(s, h, nb * bs, d)
+    bs = blocks.shape[1]
+    view = (*block_tables.shape[:-1], block_tables.shape[-1] * bs, num_heads)
+    g = blocks[block_tables].reshape(*view, -1)
+    if scales is not None:
+        from tensorflow_examples_tpu.core.precision import dequantize_rows
+
+        g = dequantize_rows(g, scales[block_tables].reshape(view), dtype)
+    return g
+
+
+def gather_layer_kv(k_blocks, v_blocks, block_tables, num_heads, dtype,
+                    *, k_scale=None, v_scale=None):
+    """:func:`gather_block_kv` of one layer's K and V pools (and their
+    scales, when the pool is quantized) by the same tables."""
+    return tuple(
+        gather_block_kv(blocks, block_tables, num_heads, scales=scales,
+                        dtype=dtype)
+        for blocks, scales in ((k_blocks, k_scale), (v_blocks, v_scale))
+    )
 
 
 def varlen_decode_attention(
@@ -92,6 +112,8 @@ def varlen_decode_attention(
     *,
     sm_scale: float | None = None,
     block_tables: jax.Array | None = None,
+    k_scale: jax.Array | None = None,
+    v_scale: jax.Array | None = None,
 ) -> jax.Array:
     """Single-token attention over per-slot populated cache prefixes.
 
@@ -102,29 +124,34 @@ def varlen_decode_attention(
     lengths: [S] int32 populated lengths INCLUDING the new token.
 
     With ``block_tables`` ([S, nb] int32, ISSUE 8), k_cache/v_cache
-    are instead a paged block pool ([NB, H, BS, D]) and each slot's
-    view is gathered by its block table first
-    (:func:`gather_block_kv`) — the paged mirror of the dense slice,
-    same masking contract downstream.
+    are instead one layer's paged block pool ([NB, BS, H*D], with
+    ``k_scale``/``v_scale`` [NB, BS, H] when it is quantized) and each
+    slot's view is gathered by its block table first
+    (:func:`gather_block_kv`, [S, Kb, H, D]) — the paged mirror of the
+    dense slice, same masking contract downstream.
 
     Returns [S, H, D]. Numerics mirror
     ``ops/decode.decode_attention_reference`` (f32 scores/softmax,
     output cast back to q.dtype) with the scalar length promoted to a
     vector — slot s sees columns < lengths[s], nothing else.
     """
+    kv = "shkd"
     if block_tables is not None:
-        k_cache = gather_block_kv(k_cache, block_tables)
-        v_cache = gather_block_kv(v_cache, block_tables)
+        k_cache, v_cache = gather_layer_kv(
+            k_cache, v_cache, block_tables, q.shape[-2], q.dtype,
+            k_scale=k_scale, v_scale=v_scale,
+        )
+        kv = "skhd"
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     s = jnp.einsum(
-        "shd,shkd->shk", q, k_cache, preferred_element_type=jnp.float32
+        f"shd,{kv}->shk", q, k_cache, preferred_element_type=jnp.float32
     ) * sm_scale
     col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
     s = jnp.where(col < lengths[:, None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(v_cache.dtype)
     return jnp.einsum(
-        "shk,shkd->shd", p, v_cache, preferred_element_type=jnp.float32
+        f"shk,{kv}->shd", p, v_cache, preferred_element_type=jnp.float32
     ).astype(q.dtype)
 
 
@@ -136,6 +163,8 @@ def varlen_verify_attention(
     *,
     sm_scale: float | None = None,
     block_tables: jax.Array | None = None,
+    k_scale: jax.Array | None = None,
+    v_scale: jax.Array | None = None,
 ) -> jax.Array:
     """Multi-token generalization of :func:`varlen_decode_attention`
     for the speculative ``verify_k`` step (ISSUE 11).
@@ -149,22 +178,26 @@ def varlen_verify_attention(
     length vector (T=1 reduces to exactly
     ``varlen_decode_attention(..., lengths=positions + 1)``).
 
-    k_cache / v_cache: [S, H, Kb, D] bucket-sliced caches, or the
-    paged block pool ([NB, H, BS, D]) when ``block_tables`` is given —
-    same gather contract as the decode path. Returns [S, T, H, D];
-    numerics mirror the decode path (f32 scores/softmax, probabilities
-    cast to the value dtype, f32 accumulation) so a verify step's
-    sampled tokens match what T single-token steps would have drawn —
-    the property every token-identical golden with speculation on
-    rests on.
+    k_cache / v_cache: [S, H, Kb, D] bucket-sliced caches, or one
+    layer's paged block pool ([NB, BS, H*D], scales [NB, BS, H]) when
+    ``block_tables`` is given — same gather contract as the decode
+    path. Returns [S, T, H, D]; numerics mirror the decode path (f32
+    scores/softmax, probabilities cast to the value dtype, f32
+    accumulation) so a verify step's sampled tokens match what T
+    single-token steps would have drawn — the property every
+    token-identical golden with speculation on rests on.
     """
+    kv = "shkd"
     if block_tables is not None:
-        k_cache = gather_block_kv(k_cache, block_tables)
-        v_cache = gather_block_kv(v_cache, block_tables)
+        k_cache, v_cache = gather_layer_kv(
+            k_cache, v_cache, block_tables, q.shape[-2], q.dtype,
+            k_scale=k_scale, v_scale=v_scale,
+        )
+        kv = "skhd"
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     s = jnp.einsum(
-        "sthd,shkd->shtk", q, k_cache,
+        f"sthd,{kv}->shtk", q, k_cache,
         preferred_element_type=jnp.float32,
     ) * sm_scale
     col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 3)
@@ -172,11 +205,10 @@ def varlen_verify_attention(
     limit = positions[:, None, None, None] + row
     s = jnp.where(col <= limit, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(v_cache.dtype)
-    out = jnp.einsum(
-        "shtk,shkd->shtd", p, v_cache,
+    return jnp.einsum(
+        f"shtk,{kv}->sthd", p, v_cache,
         preferred_element_type=jnp.float32,
     ).astype(q.dtype)
-    return out.transpose(0, 2, 1, 3)
 
 
 class KVCachePool:

@@ -6,8 +6,9 @@ the slot is claimed: a 12-token request holds as much cache as a
 workload. This module replaces the storage layer behind the same
 interface the engine/batcher already speak:
 
-* **Paged blocks** — the device arrays are ``[L, NB, H, BS, D]`` pools
-  of ``NB`` physical blocks of ``BS`` (power-of-two) token rows each.
+* **Paged blocks** — the device arrays are, per layer, ``[NB, BS, H*D]``
+  pools of ``NB`` physical blocks of ``BS`` (power-of-two) token rows
+  each, a row being the token's ``H*D`` values (lane-dense).
   A slot holds a *block table* (logical block index -> physical block
   id); capacity scales with the tokens a request has actually used,
   so a mixed short/long request set commits a fraction of the dense
@@ -38,7 +39,7 @@ interface the engine/batcher already speak:
   at positions ``>= prompt_len > c``, i.e. in its own private blocks;
   a shared block is never written again while published.
 * **int8 KV** (``kv_dtype="int8"``) — blocks store int8 with per-row
-  f32 scales kept blockwise (``[L, NB, H, BS]``,
+  f32 scales kept blockwise (``[NB, BS, H]`` per layer,
   ``core/precision.quantize_int8_rows``): rows append one decode step
   at a time without requantizing the block. fp32/bf16 paged serving
   stays token-identical to the dense reference; int8 is a measured
@@ -208,8 +209,14 @@ class PagedKVPool:
     # ------------------------------------------------------ device state
 
     def _alloc_arrays(self) -> None:
-        shape = (self.num_layers, self.num_blocks, self.num_heads,
-                 self.block_size, self.head_dim)
+        # One array per layer, a token's row its H*D values: the minor
+        # dimension is lane-dense (768 = 6 x 128 for GPT-2), so the
+        # TPU's default layout is the one the row scatters and block
+        # gathers want. A trailing [..., BS, D=64] made XLA put NB
+        # minor-most and convert the WHOLE pool on the way in and out
+        # of every program (PERF.md, PR 26).
+        shape = (self.num_blocks, self.block_size,
+                 self.num_heads * self.head_dim)
         if self.kv_dtype == "fp8":
             from tensorflow_examples_tpu.core import precision
 
@@ -219,17 +226,27 @@ class PagedKVPool:
         else:
             store = self.dtype
         kw = {} if self._sharding is None else {"device": self._sharding}
-        self.k = jnp.zeros(shape, store, **kw)
-        self.v = jnp.zeros(shape, store, **kw)
+
+        def per_layer(make, shape, dtype):
+            return tuple(
+                make(shape, dtype, **kw) for _ in range(self.num_layers)
+            )
+
+        self.k = per_layer(jnp.zeros, shape, store)
+        self.v = per_layer(jnp.zeros, shape, store)
         if self.quantized:
-            self.k_scale = jnp.ones(shape[:-1], jnp.float32, **kw)
-            self.v_scale = jnp.ones(shape[:-1], jnp.float32, **kw)
+            sshape = shape[:-1] + (self.num_heads,)
+            self.k_scale = per_layer(jnp.ones, sshape, jnp.float32)
+            self.v_scale = per_layer(jnp.ones, sshape, jnp.float32)
         else:
             self.k_scale = self.v_scale = None
 
     def kv_state(self) -> tuple:
-        """The device-array tuple the engine's compiled steps donate
-        and return (``set_kv_state`` reassigns from the outputs)."""
+        """The device state the engine's compiled steps donate and
+        return (``set_kv_state`` reassigns from the outputs): ``(k, v)``
+        or, quantized, ``(k, v, k_scale, v_scale)``, each a tuple of
+        ``num_layers`` arrays — ``[NB, BS, H*D]`` payloads, ``[NB, BS,
+        H]`` scales."""
         if self.quantized:
             return (self.k, self.v, self.k_scale, self.v_scale)
         return (self.k, self.v)

@@ -278,12 +278,10 @@ class TestPagedDecodeKernel:
 
     def _pool(self, seed=0, dtype=jnp.float32):
         rng = np.random.default_rng(seed)
-        k = jnp.asarray(
-            rng.standard_normal((self.NB, self.H, self.BS, self.D)), dtype
-        )
-        v = jnp.asarray(
-            rng.standard_normal((self.NB, self.H, self.BS, self.D)), dtype
-        )
+        # The paged pool's per-layer layout: [NB, BS, H*D].
+        shape = (self.NB, self.BS, self.H * self.D)
+        k = jnp.asarray(rng.standard_normal(shape), dtype)
+        v = jnp.asarray(rng.standard_normal(shape), dtype)
         return k, v
 
     def _case(self, lengths, tables, seed=0):
@@ -377,8 +375,11 @@ class TestPagedDecodeKernel:
             rng.standard_normal((s, self.H, self.D)), jnp.float32
         )
         k, v = self._pool(3)
-        qk, ks = quantize_int8_rows(k)
-        qv, vs = quantize_int8_rows(v)
+        # Per (block, row, head) scales [NB, BS, H], as the pool keeps.
+        heads = lambda x: x.reshape(self.NB, self.BS, self.H, self.D)
+        qk, ks = quantize_int8_rows(heads(k))
+        qv, vs = quantize_int8_rows(heads(v))
+        qk, qv = qk.reshape(k.shape), qv.reshape(v.shape)
         lengths = jnp.asarray([5, 16, 27], jnp.int32)
         tables = jnp.asarray(
             [[1, 0, 0, 0], [2, 3, 0, 0], [4, 5, 6, 7]], jnp.int32
@@ -426,7 +427,7 @@ class TestPagedDecodeKernel:
                 jnp.zeros((1, self.H, self.D)), k, v,
                 jnp.ones((1,), jnp.int32),
                 jnp.zeros((1, 2), jnp.int32),
-                k_scale=jnp.ones((self.NB, self.H, self.BS)),
+                k_scale=jnp.ones((self.NB, self.BS, self.H)),
             )
 
 
@@ -622,8 +623,8 @@ _S = jax.ShapeDtypeStruct
 def _paged_args(kv_dtype, q_dtype=jnp.float32):
     return (
         _S((8, 12, 64), q_dtype),
-        _S((512, 12, 16, 64), kv_dtype),
-        _S((512, 12, 16, 64), kv_dtype),
+        _S((512, 16, 768), kv_dtype),
+        _S((512, 16, 768), kv_dtype),
         _S((8,), jnp.int32),
         _S((8, 64), jnp.int32),
     )
@@ -646,7 +647,7 @@ def _lowering_cases():
     ce = functools.partial(cross_entropy_per_example, interpret=False)
     decode = functools.partial(flash_decode_attention, interpret=False)
     paged = functools.partial(paged_decode_attention, interpret=False)
-    scales = (_S((512, 12, 16), f32),) * 2
+    scales = (_S((512, 16, 12), f32),) * 2
     return {
         "flash_fwd": (flash, qkv),
         "flash_grad": (jax.grad(total(flash), argnums=(0, 1, 2)), qkv),

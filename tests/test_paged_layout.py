@@ -1,0 +1,281 @@
+"""The paged KV pool's device layout, judged by the TPU's compiler
+(ISSUE 26).
+
+The pool is one ``[NB, BS, H*D]`` array per layer so that no paged
+program converts, copies or slices a pool-sized array: with a minor
+dimension of ``D = 64`` (half a 128-lane tile) XLA:TPU moved ``NB``
+minor-most and every program re-ordered the WHOLE pool on its way in
+and out — 78% of a decode step on the chip (PERF.md, PR 23/25).
+
+This compiles the engine's own largest decode, prefill and extend
+programs for a DESCRIBED v5e — the TPU compiler is installed in the
+sandbox; no chip is attached or used, as ``benchmark/sizing.py`` does
+for the cells — on a small model whose pool dwarfs everything else,
+and reads the optimised HLO: bytes and ops by the compiler, never a
+time. It is the "did the layout engage" guard, and catches the next
+accidental relayout.
+"""
+
+import os
+import re
+import sys
+
+import jax
+import numpy as np
+import pytest
+
+from tensorflow_examples_tpu.models import transformer
+from tensorflow_examples_tpu.serving.engine import InferenceEngine, ServeConfig
+
+pytestmark = pytest.mark.serving
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# head_dim 64, H*D = 128 lanes, and a pool (2 x 2 layers x 4.2 M
+# elements) far larger than the weights (0.5 M) or any gathered view
+# (4 slots x 128 tokens x 128).
+MODEL = dict(vocab_size=512, max_len=128, num_layers=2, num_heads=2,
+             d_model=128, dropout=0.0, attention="xla")
+SERVE = dict(max_slots=4, prefill_bucket_floor=16, kv_bucket_floor=32,
+             kv_block_size=16, kv_blocks=2048)
+
+# Results that move no bytes, whatever their size.
+FREE_OPS = {"parameter", "get-tuple-element", "tuple", "bitcast"}
+_INSTR = re.compile(
+    r"^\s*(?:ROOT )?%?(?P<name>[\w.\-]+) = (?P<dtype>\w+)\[(?P<dims>[\d,]*)\]"
+    r"(?:\{[^}]*\})? (?P<op>[\w\-]+)\((?P<rest>.*)$"
+)
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?(?P<name>[\w.\-]+) \(.*\{\s*$")
+
+
+@pytest.fixture(scope="module")
+def described_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        from jax.experimental import topologies
+
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no libtpu here, or it is held elsewhere
+        pytest.skip(f"a v5e cannot be described here: {e}")
+    # A program compiled for a described chip is written to the
+    # persistent cache and cannot be read back without one.
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield jax.sharding.SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", cache_was)
+
+
+def _engine(kv_dtype):
+    mcfg = transformer.TransformerConfig(**MODEL)
+    params = transformer.Transformer(mcfg).init(
+        {"params": jax.random.PRNGKey(0)}, np.zeros((1, 8), np.int32)
+    )["params"]
+    return InferenceEngine(
+        mcfg, params, cfg=ServeConfig(kv_dtype=kv_dtype, **SERVE)
+    )
+
+
+def _pool_sized_results(hlo: str, sizes: set[int]) -> list[str]:
+    """Instructions of the optimised HLO whose result has a pool's or a
+    layer's element count and is neither free nor an in-place scatter
+    (a ``scatter``, or a fusion whose computation holds one)."""
+    bodies: dict[str, list[str]] = {}
+    current = None
+    for line in hlo.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            current = bodies.setdefault(head["name"], [])
+        elif current is not None:
+            current.append(line)
+    scatters = {
+        name for name, body in bodies.items()
+        if any(" scatter(" in line for line in body)
+    }
+    found = []
+    for line in hlo.splitlines():
+        m = _INSTR.match(line)
+        if not m or m["op"] in FREE_OPS or m["op"] == "scatter":
+            continue
+        count = int(np.prod([int(d) for d in m["dims"].split(",") if d]))
+        if count not in sizes:
+            continue
+        called = re.search(r"calls=%?([\w.\-]+)", m["rest"])
+        if m["op"] == "fusion" and called and called[1] in scatters:
+            continue
+        found.append(f"{m['op']} {m['dtype']}[{m['dims']}] {m['name']}")
+    return found
+
+
+@pytest.mark.parametrize("kv_dtype", ["", "int8"], ids=["f32", "int8"])
+@pytest.mark.parametrize("family", ["decode", "prefill", "extend"])
+def test_no_program_touches_the_whole_pool(described_chip, family, kv_dtype):
+    sys.path.insert(0, REPO)
+    try:
+        from benchmark import sizing
+    finally:
+        sys.path.remove(REPO)
+
+    engine = _engine(kv_dtype)
+    state = engine.pool.kv_state()
+    layer = state[0][0]
+    assert layer.shape == (SERVE["kv_blocks"], SERVE["kv_block_size"],
+                           MODEL["d_model"])
+    pool_bytes = sum(a.nbytes for a in jax.tree.leaves(state))
+    fn, args = sizing.engine_programs(engine, described_chip)[family]
+    compiled = fn.lower(*args).compile()
+
+    sizes = {layer.size, layer.size * MODEL["num_layers"]}
+    assert _pool_sized_results(compiled.as_text(), sizes) == []
+    mem = compiled.memory_analysis()
+    # Donated and updated in place: the whole pool is aliased ...
+    assert mem.alias_size_in_bytes >= pool_bytes
+    # ... and nothing pool-sized is left among the temporaries (the
+    # [L, NB, H, BS, D] pool's programs held 2.5 times the pool).
+    assert mem.temp_size_in_bytes < pool_bytes / 4, (
+        mem.temp_size_in_bytes, pool_bytes)
+
+
+# ------------------------------------------------- tokens on the new layout
+#
+# The same mathematics on the re-laid pool: every paged path at a
+# lane-dense geometry (head_dim 64, H*D = 128, blocks of 16) against
+# the engine's plain cacheless reference.
+
+GOLDEN_MODEL = dict(MODEL, vocab_size=211, max_len=64)
+GOLDEN_SERVE = dict(max_slots=4, prefill_bucket_floor=16,
+                    kv_bucket_floor=32, kv_block_size=16)
+
+
+def _golden_engine(**serve_kw):
+    """Unwarmed: only the rungs a test drives are compiled."""
+    from tensorflow_examples_tpu.telemetry.registry import MetricsRegistry
+
+    mcfg = transformer.TransformerConfig(**GOLDEN_MODEL)
+    params = transformer.Transformer(mcfg).init(
+        {"params": jax.random.PRNGKey(3)}, np.zeros((1, 8), np.int32)
+    )["params"]
+    return InferenceEngine(
+        mcfg, params, cfg=ServeConfig(**GOLDEN_SERVE, **serve_kw),
+        registry=MetricsRegistry(),
+    )
+
+
+def _prompt(seed, n):
+    rng = np.random.default_rng(seed)
+    return [int(t) for t in rng.integers(0, GOLDEN_MODEL["vocab_size"], n)]
+
+
+def _decode_stream(eng, slot, first, n, seed):
+    out = [int(first)]
+    for _ in range(n - 1):
+        out.append(eng.decode([(slot, out[-1], seed, 0.0, 0)])[slot])
+    return out
+
+
+def _assert_tracks(eng, got, ref):
+    """fp pools: token-identical. Quantized pools: the bounded
+    divergence their goldens pin (first token exact — prefill attends
+    fresh unquantized K/V — and >= 75% of the stream agreeing)."""
+    if not eng.pool.quantized:
+        assert got == ref
+        return
+    assert got[0] == ref[0], "first token must be exact"
+    agree = sum(a == b for a, b in zip(got, ref))
+    assert agree >= 0.75 * len(ref), (got, ref)
+
+
+@pytest.mark.timeout(300)
+@pytest.mark.parametrize("kv_dtype,attention", [
+    ("", "xla"), ("", "paged_flash"), ("int8", "xla"),
+    ("int8", "paged_flash"), ("fp8", "xla"),
+])
+def test_decode_and_extend_track_the_reference(kv_dtype, attention):
+    """Prefill -> decode across a block boundary, then a second prompt
+    that HITS the first one's published blocks (the extend rung over
+    gathered context), both slots decoding in one batch."""
+    from tensorflow_examples_tpu.core import precision
+
+    if kv_dtype == "fp8" and not precision.fp8_supported():
+        pytest.skip("no float8_e4m3fn on this build")
+    eng = _golden_engine(kv_dtype=kv_dtype, attention=attention)
+    head = _prompt(1, 32)                      # two full blocks
+    a, b = head + _prompt(2, 5), head + _prompt(3, 9)
+    n = 12
+    slot_a = eng.pool.alloc()
+    tok_a, _ = eng.prefill(slot_a, a, seed=4)
+    hits = eng.pool.prefix_hits
+    slot_b = eng.pool.alloc()
+    tok_b, _ = eng.prefill(slot_b, b, seed=5)
+    assert eng.pool.prefix_hits == hits + 1    # b ran the extend rung
+    got_a, got_b = [int(tok_a)], [int(tok_b)]
+    for _ in range(n - 1):
+        out = eng.decode([(slot_a, got_a[-1], 4, 0.0, 0),
+                          (slot_b, got_b[-1], 5, 0.0, 0)])
+        got_a.append(out[slot_a])
+        got_b.append(out[slot_b])
+    eng.pool.free(slot_a)
+    eng.pool.free(slot_b)
+    _assert_tracks(eng, got_a, eng.reference_generate(a, max_new=n, seed=4))
+    _assert_tracks(eng, got_b, eng.reference_generate(b, max_new=n, seed=5))
+
+
+@pytest.mark.timeout(300)
+def test_verify_commits_the_reference_tokens():
+    """The speculative verify rung: right drafts are all committed,
+    wrong ones rejected at the first disagreement, and the committed
+    stream is the reference's either way (the window crosses a block
+    boundary at row 16)."""
+    eng = _golden_engine(spec_decode_k=3)
+    prompt = _prompt(7, 13)
+    ref = eng.reference_generate(prompt, max_new=16, seed=6, temperature=0.9)
+    assert len(set(ref)) > 4                   # a stream worth drafting
+    slot = eng.pool.alloc()
+    tok, _ = eng.prefill(slot, prompt, seed=6, temperature=0.9)
+    got = [int(tok)]
+    accepted = []
+    for spoil in (False, True, False, True):
+        drafts = list(ref[len(got):len(got) + 3])
+        if spoil:
+            drafts[1] = (drafts[1] + 1) % GOLDEN_MODEL["vocab_size"]
+        new = eng.verify([(slot, got[-1], drafts, 6, 0.9, 0)])[slot]
+        accepted.append(len(new) - 1)
+        got += new
+    eng.pool.free(slot)
+    assert accepted == [3, 1, 3, 1]
+    assert got == ref[:len(got)]
+
+
+@pytest.mark.timeout(300)
+@pytest.mark.parametrize("kv_dtype", ["", "int8"], ids=["f32", "int8"])
+def test_exported_pages_continue_identically(kv_dtype):
+    """export -> wire -> import into ANOTHER engine: the wire keeps its
+    [L, pages, H, BS, D] order, so the importer's continuation is the
+    donor's own (and, unquantized, the reference's)."""
+    import json
+
+    donor, importer = (_golden_engine(kv_dtype=kv_dtype) for _ in range(2))
+    prompt = _prompt(11, 37)
+    n = 8
+    d_slot = donor.pool.alloc()
+    first, _ = donor.prefill(d_slot, prompt, seed=9)
+    pages = json.loads(json.dumps(donor.export_kv_pages(d_slot, prompt)))
+    mcfg = donor.model_cfg
+    from tensorflow_examples_tpu.serving import scheduler
+
+    _, arrays = scheduler.decode_pages(pages)
+    assert arrays["k"].shape == (
+        mcfg.num_layers, 3, mcfg.num_heads, 16, mcfg.head_dim)
+    if kv_dtype:
+        assert arrays["k_scale"].shape == arrays["k"].shape[:-1]
+    i_slot = importer.pool.alloc()
+    importer.import_kv_pages(i_slot, pages, prompt)
+    donor_stream = _decode_stream(donor, d_slot, first, n, 9)
+    importer_stream = _decode_stream(importer, i_slot, first, n, 9)
+    donor.pool.free(d_slot)
+    importer.pool.free(i_slot)
+    assert importer_stream == donor_stream
+    _assert_tracks(
+        donor, donor_stream, donor.reference_generate(prompt, max_new=n, seed=9)
+    )

@@ -1081,7 +1081,11 @@ class TestPagedGolden:
                 if list(key[1]) == prefix[:8] or list(key[1]) == prefix[8:]
             ]
             assert len(shared) == 2
-            k_before = np.asarray(eng.pool.k[:, shared]).copy()
+            # Per-layer [NB, BS, H*D] arrays: the shared blocks' rows.
+            read = lambda: np.stack(
+                [np.asarray(layer)[shared] for layer in eng.pool.k]
+            )
+            k_before = read()
             res_b = batcher.submit(b_req).result(timeout=60)
         finally:
             batcher.close(drain=True)
@@ -1093,7 +1097,7 @@ class TestPagedGolden:
             b_req.prompt, max_new=4, seed=22, temperature=0.9
         )
         np.testing.assert_array_equal(
-            np.asarray(eng.pool.k[:, shared]), k_before,
+            read(), k_before,
             err_msg="a shared prefix block was written (COW violated)",
         )
         assert eng.post_warmup_recompiles() == 0
@@ -1295,7 +1299,7 @@ class TestFp8KV:
         )
         eng.warmup()
         assert eng.pool.kv_bits == 8
-        assert eng.pool.k.dtype == precision.fp8_dtype()
+        assert all(a.dtype == precision.fp8_dtype() for a in eng.pool.k)
         rng = np.random.default_rng(5)
         for i in range(3):
             prompt = [int(t) for t in rng.integers(0, 211, 5 + i * 6)]

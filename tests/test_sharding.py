@@ -570,19 +570,48 @@ class TestShardedServing:
         )
         return InferenceEngine(mcfg, params, cfg=serve, sharding=sc)
 
-    def test_sharded_params_and_pool(self):
-        eng = self._engine(gpt2_sharding({"data": 1, "model": 2}))
+    # Every placement test runs on both pools: the dense [L, S, H,
+    # max_len, D] array (heads on dim 2) and the paged pool's per-layer
+    # [NB, BS, H*D] arrays (whole heads on the last dim).
+    POOLS = pytest.mark.parametrize(
+        "serve_kw", [{}, {"kv_block_size": 8}], ids=["dense", "paged"]
+    )
+
+    @POOLS
+    def test_sharded_params_and_pool(self, serve_kw):
+        import jax
+
+        eng = self._engine(
+            gpt2_sharding({"data": 1, "model": 2}), **serve_kw
+        )
         qkv = eng.params["h_0"]["attn"]["qkv"]["kernel"]
         assert "model" in str(qkv.sharding.spec)  # NOT replicated
         assert len({s.device for s in qkv.addressable_shards}) == 2
-        assert "model" in str(eng.pool.k.sharding.spec)
+
+        def specs():
+            arrays = jax.tree.leaves((eng.pool.k, eng.pool.v))
+            assert len(arrays) == (
+                2 * eng.model_cfg.num_layers if eng.paged else 2
+            )
+            return [a.sharding.spec for a in arrays]
+
+        old_specs = specs()
+        assert all("model" in str(sp) for sp in old_specs)
+        if eng.paged:
+            mcfg = eng.model_cfg
+            assert all(
+                tuple(sp) == (None, None, "model") for sp in old_specs
+            )
+            assert eng.pool.k[0].addressable_shards[0].data.shape[-1] == (
+                mcfg.num_heads // 2 * mcfg.head_dim
+            )
         assert eng.param_sharding_digest is not None
         # reallocate() preserves the pool placement.
-        old_spec = eng.pool.k.sharding.spec
         eng.pool.reallocate()
-        assert eng.pool.k.sharding.spec == old_spec
+        assert specs() == old_specs
 
-    def test_batched_token_identity_and_zero_recompiles(self):
+    @POOLS
+    def test_batched_token_identity_and_zero_recompiles(self, serve_kw):
         """Acceptance: serving from sharded (non-replicated) params
         keeps batched output token-identical to the unbatched reference
         and zero post-warmup recompiles — through the continuous
@@ -592,7 +621,9 @@ class TestShardedServing:
             Request,
         )
 
-        eng = self._engine(gpt2_sharding({"data": 1, "model": 2}))
+        eng = self._engine(
+            gpt2_sharding({"data": 1, "model": 2}), **serve_kw
+        )
         eng.warmup()
         assert eng.warmed
         reqs = [
@@ -620,11 +651,14 @@ class TestShardedServing:
             assert tokens == ref, (r.prompt, tokens, ref)
         assert eng.post_warmup_recompiles() == 0
 
-    def test_sharded_matches_replicated_engine(self):
+    @POOLS
+    def test_sharded_matches_replicated_engine(self, serve_kw):
         """Placement must not change tokens: the sharded engine's
         greedy output equals the replicated engine's."""
-        a = self._engine(gpt2_sharding({"data": 1, "model": 2}))
-        b = self._engine(None)
+        a = self._engine(
+            gpt2_sharding({"data": 1, "model": 2}), **serve_kw
+        )
+        b = self._engine(None, **serve_kw)
         for eng in (a, b):
             eng.warmup()
 

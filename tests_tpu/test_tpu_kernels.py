@@ -155,11 +155,12 @@ def test_paged_decode_compiled_parity():
     s, h, d, bs, nb_pool = 8, 12, 64, 16, 65
     rng = np.random.default_rng(0)
     q = jax.random.normal(jax.random.PRNGKey(0), (s, h, d), jnp.float32)
+    # The paged pool's per-layer layout: [NB, BS, H*D].
     kb = jax.random.normal(
-        jax.random.PRNGKey(1), (nb_pool, h, bs, d), jnp.float32
+        jax.random.PRNGKey(1), (nb_pool, bs, h * d), jnp.float32
     )
     vb = jax.random.normal(
-        jax.random.PRNGKey(2), (nb_pool, h, bs, d), jnp.float32
+        jax.random.PRNGKey(2), (nb_pool, bs, h * d), jnp.float32
     )
     nb = 8  # bucket = 128 rows
     perm = rng.permutation(np.arange(1, nb_pool))
@@ -174,8 +175,10 @@ def test_paged_decode_compiled_parity():
     )
     ref = paged_decode_reference(q, kb, vb, lengths, tables)
     assert _max_abs(out, ref) < 2e-2
-    qk, ks = quantize_int8_rows(kb)
-    qv, vs = quantize_int8_rows(vb)
+    heads = lambda x: x.reshape(nb_pool, bs, h, d)
+    qk, ks = quantize_int8_rows(heads(kb))  # scales [NB, BS, H]
+    qv, vs = quantize_int8_rows(heads(vb))
+    qk, qv = qk.reshape(kb.shape), qv.reshape(vb.shape)
     out8 = paged_decode_attention(
         q, qk, qv, lengths, tables, k_scale=ks, v_scale=vs,
         interpret=False,

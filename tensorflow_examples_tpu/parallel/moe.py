@@ -33,6 +33,12 @@ assignments). (The round-1 formulation built a dense one-hot
 ``[n, E, C]`` dispatch tensor and einsummed against it: O(n·E·C)
 memory — fine for toy shapes, dead at real n·E.)
 
+``moe_ffn_held`` is the grouped formulation for a chip that holds
+SOME of the experts (the serving engine's expert layer,
+``serving/blocks.py``): the router at its full width with a sigmoid
+score, SwiGLU experts, and only the pairs routed to the held experts
+computed — this chip's part of the routed sum.
+
 ``moe_ffn`` is pure (params in, tokens out) so it slots into flax
 modules (models/transformer.py MoeMlp) and composes with remat/scan.
 """
@@ -59,16 +65,28 @@ def _router(
     top_k: int,
     rng: jax.Array | None,
     jitter: float,
+    select: str = "softmax",
+    precision=None,
 ):
     """Top-k router, shared by every dispatch formulation. Returns
-    (gates, experts, mean_onehot0 [E], mean_probs [E])."""
+    (gates, experts, mean_onehot0 [E], mean_probs [E]). ``select`` is
+    the score every expert gets before the top-k: ``softmax`` over the
+    experts (Switch/GShard) or an independent ``sigmoid`` each."""
     e = gate_w.shape[-1]
-    logits = tokens.astype(jnp.float32) @ gate_w.astype(jnp.float32)
+    logits = jnp.dot(
+        tokens.astype(jnp.float32), gate_w.astype(jnp.float32),
+        precision=precision,
+    )
     if rng is not None and jitter > 0:
         logits += jax.random.uniform(
             rng, logits.shape, jnp.float32, -jitter, jitter
         )
-    probs = jax.nn.softmax(logits, axis=-1)  # [n, E]
+    if select not in ("softmax", "sigmoid"):
+        raise ValueError(f"router select={select!r} not in ('softmax', 'sigmoid')")
+    probs = (
+        jax.nn.sigmoid(logits) if select == "sigmoid"
+        else jax.nn.softmax(logits, axis=-1)
+    )  # [n, E]
 
     # Sequential top-k: argmax, mask, repeat (k is tiny and static).
     masked = probs
@@ -165,8 +183,24 @@ def _combine(yout, flat_slots, keeps, gates, n):
 # and tests_tpu/ re-proves the compiled numerics under it either way.
 GMM_TILE_CAP: int = 512
 
+# Wide weights take tiles wider than the cap. [rows, 4096] x [16, 4096,
+# 4096] bf16 on a v5e (PR 28; 27 tilings each, 10 calls timed): a
+# prefill chunk's product, 4096 rows of which 512 are in the 16 groups,
+# took 1.98 ms under the cap's (512, 512, 512) and 1.00 ms under (256,
+# 1024, 2048); a decode step's, 256 rows of which 32 in 13 groups, 1.15
+# ms under (256, 512, 512) and 0.76 ms under (128, 1024, 2048); reading
+# the hit experts' 537 MB once is 0.66 ms. Wider n tiles won every time
+# (fewer passes over the rows), tk mattered little, and tn = tk = 2048
+# did not fit VMEM: the rule is the widest [tk, tn] weight tile of at
+# most GMM_WEIGHT_TILE_BYTES (double-buffered, half of the 16 MiB of
+# scoped VMEM), n first. Narrower weights (k < 1024 or n < 2048, the
+# training shapes) keep the cap, which no chip run has swept yet.
+GMM_WIDE_TN: int = 2048
+GMM_WEIGHT_TILE_BYTES: int = 4 << 20
 
-def _gmm_tiling(m: int, k: int, n: int) -> "tuple[int, int, int]":
+
+def _gmm_tiling(m: int, k: int, n: int,
+                itemsize: int = 2) -> "tuple[int, int, int]":
     """Largest tiles <= GMM_TILE_CAP the shape admits: tm must DIVIDE m
     (make_group_metadata raises otherwise). tk prefers the largest
     lane-aligned (multiple-of-128) tile in [cap/2, cap] that DIVIDES
@@ -177,7 +211,21 @@ def _gmm_tiling(m: int, k: int, n: int) -> "tuple[int, int, int]":
     k=640/896 (no large divisor) on one near-cap masked pass instead
     of many tiny exact ones — grid-step overhead is the whole reason
     these tiles are big. n is masked internally so its tile is only
-    capped to the dim."""
+    capped to the dim.
+
+    Weights that ``GMM_WIDE_TN`` divides (and whose k a tile twice the
+    cap divides) take the wide tiles instead: tn = GMM_WIDE_TN, tk the
+    largest power of two that keeps a weight tile of ``itemsize`` bytes
+    an element within GMM_WEIGHT_TILE_BYTES, and row tiles of 256 — 128
+    where there are no more than 256 rows, so that a few rows in many
+    groups do not pad every group to 256."""
+    tk_wide = GMM_WEIGHT_TILE_BYTES // (GMM_WIDE_TN * itemsize)
+    if n % GMM_WIDE_TN == 0 and tk_wide >= 2 * GMM_TILE_CAP \
+            and k % tk_wide == 0:
+        tm = 256 if m > 256 else 128
+        while m % tm:
+            tm //= 2
+        return tm, tk_wide, GMM_WIDE_TN
     tm = GMM_TILE_CAP
     while m % tm:
         tm //= 2
@@ -225,7 +273,8 @@ def _grouped_matmul(lhs, rhs, sizes):
             # Positional, as jax 0.9.0's gmm(lhs, rhs, group_sizes,
             # preferred_element_type, tiling, ...) declares them.
             return megablox.gmm(
-                lhs, rhs, sizes, lhs.dtype, _gmm_tiling(m, k, n)
+                lhs, rhs, sizes, lhs.dtype,
+                _gmm_tiling(m, k, n, rhs.dtype.itemsize),
             )
         _warn_ragged_dot_on_tpu(m, k, n)
     return lax.ragged_dot(lhs, rhs, sizes)
@@ -340,6 +389,32 @@ def _capacity_slots_sorted(tokens, experts, top_k, e, capacity):
     return xin, pair_slot, pair_keep, slot_pair, slot_valid, kept
 
 
+def _grouped_dispatch(tokens, gates, experts, groups, ffn):
+    """The sorted dispatch and combine of the grouped formulation,
+    around any expert FFN: argsort the (token, rank) pairs by group,
+    ``ffn(sorted rows [n*k, d], their group ids, group sizes) -> [n*k,
+    d]``, gate, inverse-permute, sum over ranks. Returns ``([n, d]
+    f32, sizes [groups] int32)``.
+
+    Both permutation hops ride _permute_rows so fwd AND bwd are
+    gathers (argsort hands us the inverse for free); the token
+    replication is a jnp.repeat, whose transpose is a contiguous
+    [n, k] reduce — the whole fwd+bwd dispatch path is scatter-free."""
+    n, d = tokens.shape
+    top_k = len(experts)
+    eid, order, inv, sizes = _pair_sort(experts, groups)
+    sizes = sizes.astype(jnp.int32)
+    gat = jnp.stack(gates, axis=1).reshape(-1)            # [n·k] f32
+    srt_tok = _permute_rows(
+        jnp.repeat(tokens, top_k, axis=0), order, inv
+    )                                                     # [n·k, d]
+    srt_eid = jnp.take(eid, order, axis=0)
+    y = ffn(srt_tok, srt_eid, sizes)
+    yw = y.astype(jnp.float32) * _permute_rows(gat, order, inv)[:, None]
+    restored = _permute_rows(yw, inv, order)              # pair order
+    return jnp.sum(restored.reshape(n, top_k, d), axis=1), sizes
+
+
 def _moe_ffn_grouped(
     gate_w, w_in, b_in, w_out, b_out, x, *, top_k, rng, jitter
 ):
@@ -373,32 +448,82 @@ def _moe_ffn_grouped(
     )
     aux = e * jnp.sum(moh0 * mpr)
 
-    # Both permutation hops ride _permute_rows so fwd AND bwd are
-    # gathers (argsort hands us the inverse for free); the token
-    # replication is a jnp.repeat, whose transpose is a contiguous
-    # [n, k] reduce — the whole fwd+bwd dispatch path is scatter-free.
-    eid, order, inv, sizes = _pair_sort(experts, e)
-    sizes = sizes.astype(jnp.int32)
-    gat = jnp.stack(gates, axis=1).reshape(-1)            # [n·k] f32
-    srt_tok = _permute_rows(
-        jnp.repeat(tokens, top_k, axis=0), order, inv
-    )                                                     # [n·k, d]
-    srt_eid = jnp.take(eid, order, axis=0)
+    def ffn(srt_tok, srt_eid, sizes):
+        h = _grouped_matmul(srt_tok, w_in, sizes) + jnp.take(
+            b_in, srt_eid, axis=0
+        )
+        h = jax.nn.gelu(h, approximate=True)
+        return _grouped_matmul(h, w_out, sizes) + jnp.take(
+            b_out, srt_eid, axis=0
+        )
 
-    h = _grouped_matmul(srt_tok, w_in, sizes) + jnp.take(
-        b_in, srt_eid, axis=0
-    )
-    h = jax.nn.gelu(h, approximate=True)
-    y = _grouped_matmul(h, w_out, sizes) + jnp.take(b_out, srt_eid, axis=0)
-
-    yw = y.astype(jnp.float32) * _permute_rows(gat, order, inv)[:, None]
-    restored = _permute_rows(yw, inv, order)              # pair order
-    out = jnp.sum(restored.reshape(n, top_k, d), axis=1)
+    out, _ = _grouped_dispatch(tokens, gates, experts, e, ffn)
     return (
         out.reshape(b, s, d).astype(x.dtype),
         aux,
         jnp.float32(0.0),
     )
+
+
+def moe_ffn_held(
+    router_w: jax.Array,  # [d, E]: the router at its published width
+    w_gate: jax.Array,    # [n_held, d, ff]
+    w_up: jax.Array,      # [n_held, d, ff]
+    w_down: jax.Array,    # [n_held, ff, d]
+    tokens: jax.Array,    # [n, d]; the router reads them in float32
+    *,
+    held: tuple,
+    top_k: int,
+    valid: jax.Array | None = None,  # [n] bool: rows that are real tokens
+) -> tuple[jax.Array, jax.Array]:
+    """The expert layer of a chip that is TOLD WHAT IT HOLDS: ``held``
+    are the ids, among the router's ``E`` experts, of the SwiGLU experts
+    whose weights are here (``w_*[i]`` is expert ``held[i]``).
+
+    The router scores all ``E`` experts (sigmoid, top ``top_k``,
+    weights normalised over the chosen), and the chip computes the
+    grouped formulation's product for the (token, expert) pairs routed
+    to its own experts, dropless, combined with the router's weights.
+    Pairs routed to absent experts contribute nothing: the result is
+    this chip's PART of the routed sum, and that partial sum goes on
+    (the sum over every chip's ``held`` is the whole layer; nothing
+    here stands in for the other chips or their exchange).
+
+    Returns ``(part [n, d] float32, pairs [n_held] int32)``: the pairs
+    each held expert computed. Rows with ``valid`` false (padding, a
+    parked slot) are routed nowhere: not computed, not counted."""
+    e = router_w.shape[-1]
+    n_held = len(held)
+    gates, experts, _, _ = _router(
+        tokens, router_w, top_k=min(top_k, e), rng=None, jitter=0.0,
+        select="sigmoid", precision=lax.Precision.HIGHEST,
+    )
+    # Global expert id -> this chip's group; every absent expert is
+    # the one group past the held, which the product never visits.
+    table = [n_held] * e
+    for i, ex in enumerate(held):
+        table[ex] = i
+    table = jnp.asarray(table, jnp.int32)
+    local = [jnp.take(table, ej) for ej in experts]
+    if valid is not None:
+        local = [jnp.where(valid, lj, n_held) for lj in local]
+
+    def ffn(srt_tok, srt_eid, sizes):
+        mine = sizes[:n_held]
+        with jax.named_scope("moe_experts"):
+            g = _grouped_matmul(srt_tok, w_gate, mine).astype(jnp.float32)
+            u = _grouped_matmul(srt_tok, w_up, mine).astype(jnp.float32)
+            y = _grouped_matmul(
+                (jax.nn.silu(g) * u).astype(w_down.dtype), w_down, mine
+            )
+        # The rows of the absent group lie behind the held groups and
+        # no product wrote them: whatever is there is not a number.
+        return jnp.where((srt_eid < n_held)[:, None], y, 0)
+
+    part, sizes = _grouped_dispatch(
+        tokens.astype(w_gate.dtype), gates, local, n_held + 1, ffn
+    )
+    return part, sizes[:n_held]
 
 
 def moe_ffn(

@@ -142,6 +142,11 @@ class Request:
     deadline_s: float | None = None  # relative to submit time
     kind: str = "generate"       # generate | classify | prefill | resume
     classify_top_n: int = 5
+    logprobs: bool = False       # generate: also return the model's
+    #                              log-probability of each generated
+    #                              token (Result.logprobs) — the first
+    #                              from the prefill's logits, the rest
+    #                              from the decode steps' own fetch
     pages: dict | None = None        # resume: the handed-off KV pages
     first_token: int | None = None   # resume: the prefill's sampled token
     skip_tokens: int = 0             # prefill: leading prompt tokens the
@@ -169,6 +174,7 @@ class Result:
     tokens: list[int]               # generated tokens (generate)
     prompt_len: int
     top: list[dict] | None = None   # classify payload
+    logprobs: list[float] | None = None  # per token, where asked for
     truncated: str | None = None  # None | "deadline" | "max_len"
     #                               | "shutdown" | "brownout" (the
     #                               level-2 generation cap bit: tokens
@@ -195,7 +201,7 @@ class Result:
 class _InFlight:
     __slots__ = (
         "req", "future", "slot", "t_submit", "t_admit", "t_first",
-        "deadline", "tokens", "last_token", "spec_drafted",
+        "deadline", "tokens", "logprobs", "last_token", "spec_drafted",
         "spec_accepted", "max_new_eff", "spans", "t_decode0",
         "decode_seg", "decode_tok0",
     )
@@ -212,6 +218,7 @@ class _InFlight:
             if req.deadline_s is not None else None
         )
         self.tokens: list[int] = []
+        self.logprobs: list[float] = []  # filled where req.logprobs
         self.last_token: int | None = None
         # ISSUE 18 trace collection (None = untraced, zero overhead).
         # The span list SURVIVES preemption resets below — a preempted
@@ -371,6 +378,18 @@ class ContinuousBatcher:
             fut.set_exception(ValueError(
                 "disaggregated prefill/decode requires the paged KV "
                 "pool (set kv_block_size)"
+            ))
+            reg.counter("serving/rejected_total").inc()
+            return fut
+        if req.logprobs and (
+            req.kind != "generate" or self._draft is not None
+        ):
+            # A verify step commits several tokens from one fetch of
+            # tokens alone, and a resumed stream's first token was
+            # sampled elsewhere: neither has a log-probability to give.
+            fut.set_exception(ValueError(
+                "'logprobs' is served on /generate without speculative "
+                "decoding only"
             ))
             reg.counter("serving/rejected_total").inc()
             return fut
@@ -632,6 +651,8 @@ class ContinuousBatcher:
             item.spec_accepted += len(toks) - 1
             per_tok = dt / len(toks)
             committed: list[int] = []
+            if item.req.logprobs:  # a plain step: one token a slot
+                item.logprobs.append(float(self.engine.last_logprobs[slot]))
             for token in toks:
                 item.tokens.append(token)
                 item.last_token = token
@@ -848,6 +869,7 @@ class ContinuousBatcher:
         item.t_admit = None
         item.t_first = None
         item.tokens = []
+        item.logprobs = []
         item.last_token = None
         item.spec_drafted = 0
         item.spec_accepted = 0
@@ -1055,6 +1077,10 @@ class ContinuousBatcher:
             return
         item.tokens.append(first)
         item.last_token = first
+        if req.logprobs:
+            from tensorflow_examples_tpu.serving.engine import token_logprob
+
+            item.logprobs.append(token_logprob(last_logits, first))
         if item.spans is not None:
             self._start_decode_segment(item)
         if self._draft is not None:
@@ -1162,6 +1188,7 @@ class ContinuousBatcher:
             item,
             Result(
                 tokens=item.tokens,
+                logprobs=item.logprobs if item.req.logprobs else None,
                 prompt_len=len(item.req.prompt),
                 truncated=truncated,
                 spec_drafted=item.spec_drafted,

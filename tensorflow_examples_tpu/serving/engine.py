@@ -59,11 +59,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from tensorflow_examples_tpu.core import precision as precision_mod
-from tensorflow_examples_tpu.core.precision import materialize as _w
-from tensorflow_examples_tpu.core.precision import take_rows as _rows
 from tensorflow_examples_tpu.models.transformer import TransformerConfig
 from tensorflow_examples_tpu.ops.attention import NEG_INF, attention_reference
 from tensorflow_examples_tpu.serving import kv_cache as kv_mod
+from tensorflow_examples_tpu.serving.blocks import Gpt2Block, block_for
 from tensorflow_examples_tpu.telemetry import registry as registry_mod
 from tensorflow_examples_tpu.telemetry.compilation import CompilationSentinel
 from tensorflow_examples_tpu.telemetry.spans import span as host_span
@@ -164,41 +163,14 @@ class ServeConfig:
 
 # --------------------------------------------------------------- forward
 #
-# Pure functions over the Transformer param tree. f32-by-default like the
-# flax model (params dtype is the compute dtype); LayerNorm/softmax math
-# mirrors flax defaults (eps 1e-5, gelu approximate). Every matmul weight
-# is read through ``core/precision.materialize`` (``_w``) and embedding
-# tables through ``take_rows`` (``_rows``): under a PrecisionConfig the
-# leaf is a QuantizedWeight dequantized HERE, inside the jitted step —
-# XLA fuses the scale-multiply into the consuming dot, so HBM holds the
-# weights at 1 byte/element (ISSUE 15). Unquantized trees pass through
-# unchanged (the helpers are identity on plain arrays).
-
-
-def _layer_norm(x, p, eps=1e-5):
-    mean = jnp.mean(x, axis=-1, keepdims=True)
-    var = jnp.mean(jnp.square(x - mean), axis=-1, keepdims=True)
-    return (x - mean) * jax.lax.rsqrt(var + eps) * p["scale"] + p["bias"]
-
-
-def _block_mlp(x, p):
-    h = jnp.dot(x, _w(p["mlp_fc"]["kernel"])) + p["mlp_fc"]["bias"]
-    h = jax.nn.gelu(h, approximate=True)
-    return jnp.dot(h, _w(p["mlp_proj"]["kernel"])) + p["mlp_proj"]["bias"]
-
-
-def _qkv(x, p):
-    """[..., d] -> q, k, v each [..., H, hd]."""
-    y = jnp.einsum("...d,dthc->...thc", x, _w(p["qkv"]["kernel"]))
-    y = y + p["qkv"]["bias"]
-    return y[..., 0, :, :], y[..., 1, :, :], y[..., 2, :, :]
-
-
-def _attn_out(att, p):
-    """[..., H, hd] attention output -> [..., d] residual contribution."""
-    return jnp.einsum("...hc,hcd->...d", att, _w(p["proj"]["kernel"])) + p[
-        "proj"
-    ]["bias"]
+# Every forward below is ONE loop over a block interface
+# (serving/blocks.py): ``model.embed``, ``model.block(params, x, layer,
+# positions, attend, valid)`` per layer, ``model.head``. They differ only
+# in where ``attend(q, k, v)`` finds K and V — the fresh prompt, the
+# dense per-slot cache, the paged pool through a block table — and in
+# what they write. The dense-cache and verify forwards serve GPT-2 alone
+# (the engine refuses them for any other block at construction); the
+# three paged forwards serve every block.
 
 
 def _prefill_attend(q, k, v, *, impl: str):
@@ -219,33 +191,61 @@ def _prefill_attend(q, k, v, *, impl: str):
     return swap(out)
 
 
+def _run_blocks(model, params, x, positions, attend_for, valid=None):
+    """The loop every forward shares: ``attend_for(layer)`` is the
+    layer's ``attend(q, k, v)``. Returns the final hidden state and the
+    blocks' stats summed over the layers (None for a model without)."""
+    stats = None
+    for layer in range(model.num_layers):
+        x, s = model.block(
+            params, x, layer, positions, attend_for(layer), valid
+        )
+        if s is not None:
+            stats = s if stats is None else stats + s
+    return x, stats
+
+
+def _plain(model, layer) -> bool:
+    """A layer the GPT-2 attention paths serve as they are: as many
+    key/value heads as query heads, no window."""
+    return (
+        model.num_kv_heads == model.num_heads
+        and model.layer_windows[layer] is None
+    )
+
+
+def _per_kind(tables) -> tuple:
+    """A program's block-table argument by kind: the bare array of a
+    one-kind pool (GPT-2's programs keep their signature), a tuple of
+    one array per kind otherwise."""
+    return tables if isinstance(tables, tuple) else (tables,)
+
+
 def forward_full(cfg: TransformerConfig, params, tokens, *, impl="xla"):
     """Full causal forward of ``tokens`` [B, L]: logits [B, L, V] plus
-    the per-layer K/V ([2, num_layers, B, H, L, hd]) the prefill path
-    writes into the cache. Also the engine's cacheless reference path
-    (which recomputes attention over the whole prefix per emitted
-    token)."""
-    wte = params["wte"]["embedding"]
-    positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)
-    x = _rows(wte, tokens) + _rows(
-        params["wpe"]["embedding"], positions
-    )[None]
+    the per-layer K/V ([2, num_layers, B, H, L, hd]) the dense prefill
+    path writes into the cache. Also the engine's cacheless reference
+    path (which recomputes attention over the whole prefix per emitted
+    token). GPT-2 only."""
+    model = Gpt2Block(cfg)
     ks, vs = [], []
-    for layer in range(cfg.num_layers):
-        p = params[f"h_{layer}"]
-        y = _layer_norm(x, p["ln_1"])
-        q, k, v = _qkv(y, p["attn"])
+
+    def attend(q, k, v):
         ks.append(k)
         vs.append(v)
-        x = x + _attn_out(_prefill_attend(q, k, v, impl=impl), p["attn"])
-        x = x + _block_mlp(_layer_norm(x, p["ln_2"]), p)
-    x = _layer_norm(x, params["ln_f"])
-    return jnp.dot(x, _w(wte).T), jnp.stack(ks), jnp.stack(vs)
+        return _prefill_attend(q, k, v, impl=impl)
+
+    positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)
+    x, _ = _run_blocks(
+        model, params, model.embed(params, tokens, positions), positions,
+        lambda layer: attend,
+    )
+    return model.head(params, x), jnp.stack(ks), jnp.stack(vs)
 
 
 def _decode_forward(cfg: TransformerConfig, params, k_cache, v_cache,
                     tokens, positions, *, kv_bucket: int):
-    """One continuous-decode step over every slot.
+    """One continuous-decode step over every slot (dense cache, GPT-2).
 
     tokens/positions: [S] — each slot's input token and the cache row
     it occupies (= the slot's pre-step populated length). Returns the
@@ -253,85 +253,89 @@ def _decode_forward(cfg: TransformerConfig, params, k_cache, v_cache,
     decoding ride along with position 0: their write lands in a row a
     future prefill fully overwrites, and their output is discarded.
     """
-    wte = params["wte"]["embedding"]
-    x = _rows(wte, tokens) + _rows(params["wpe"]["embedding"], positions)
+    model = Gpt2Block(cfg)
     idx = jnp.arange(tokens.shape[0])
     lengths = positions + 1  # populated length including the new token
-    for layer in range(cfg.num_layers):
-        p = params[f"h_{layer}"]
-        y = _layer_norm(x, p["ln_1"])
-        q, k, v = _qkv(y, p["attn"])  # [S, H, hd]
-        k_cache = k_cache.at[layer, idx, :, positions, :].set(
-            k.astype(k_cache.dtype)
-        )
-        v_cache = v_cache.at[layer, idx, :, positions, :].set(
-            v.astype(v_cache.dtype)
-        )
-        att = kv_mod.varlen_decode_attention(
-            q,
-            jax.lax.slice_in_dim(k_cache[layer], 0, kv_bucket, axis=2),
-            jax.lax.slice_in_dim(v_cache[layer], 0, kv_bucket, axis=2),
-            lengths,
-        )
-        x = x + _attn_out(att, p["attn"])
-        x = x + _block_mlp(_layer_norm(x, p["ln_2"]), p)
-    x = _layer_norm(x, params["ln_f"])
-    return k_cache, v_cache, jnp.dot(x, _w(wte).T)
+    cache = [k_cache, v_cache]
+
+    def attend_for(layer):
+        def attend(q, k, v):  # [S, H, hd]
+            cache[0] = cache[0].at[layer, idx, :, positions, :].set(
+                k.astype(cache[0].dtype)
+            )
+            cache[1] = cache[1].at[layer, idx, :, positions, :].set(
+                v.astype(cache[1].dtype)
+            )
+            return kv_mod.varlen_decode_attention(
+                q,
+                jax.lax.slice_in_dim(cache[0][layer], 0, kv_bucket, axis=2),
+                jax.lax.slice_in_dim(cache[1][layer], 0, kv_bucket, axis=2),
+                lengths,
+            )
+        return attend
+
+    x, _ = _run_blocks(
+        model, params, model.embed(params, tokens, positions), positions,
+        attend_for,
+    )
+    return cache[0], cache[1], model.head(params, x)
 
 
 def _verify_forward(cfg: TransformerConfig, params, k_cache, v_cache,
                     tokens, positions, *, kv_bucket: int):
-    """The speculative ``verify_k`` step (ISSUE 11): score T = k+1
-    tokens per slot in ONE forward. ``tokens`` [S, T] holds each slot's
-    launch token followed by its k draft tokens; row t lands in cache
-    row ``positions[s] + t`` and attends its own populated prefix
-    (``kv_cache.varlen_verify_attention``). Returns the updated caches
-    and logits [S, T, V]. T=1 is numerically the plain decode step.
+    """The speculative ``verify_k`` step (ISSUE 11; dense cache,
+    GPT-2): score T = k+1 tokens per slot in ONE forward. ``tokens``
+    [S, T] holds each slot's launch token followed by its k draft
+    tokens; row t lands in cache row ``positions[s] + t`` and attends
+    its own populated prefix (``kv_cache.varlen_verify_attention``).
+    Returns the updated caches and logits [S, T, V]. T=1 is numerically
+    the plain decode step.
 
     Rows past ``max_len`` (a short-budget slot padded to the fixed T)
     are dropped by scatter semantics and their logits discarded —
     acceptance (host side) never commits past the rows that landed.
     """
-    wte = params["wte"]["embedding"]
+    model = Gpt2Block(cfg)
     s_n, t_n = tokens.shape
     pos_grid = positions[:, None] + jnp.arange(t_n, dtype=jnp.int32)
-    x = _rows(wte, tokens) + _rows(
-        params["wpe"]["embedding"], jnp.minimum(pos_grid, cfg.max_len - 1)
-    )
     idx = jnp.arange(s_n)
-    for layer in range(cfg.num_layers):
-        p = params[f"h_{layer}"]
-        y = _layer_norm(x, p["ln_1"])
-        q, k, v = _qkv(y, p["attn"])  # [S, T, H, hd]
-        k_cache = k_cache.at[layer, idx[:, None], :, pos_grid, :].set(
-            k.astype(k_cache.dtype)
-        )
-        v_cache = v_cache.at[layer, idx[:, None], :, pos_grid, :].set(
-            v.astype(v_cache.dtype)
-        )
-        att = kv_mod.varlen_verify_attention(
-            q,
-            jax.lax.slice_in_dim(k_cache[layer], 0, kv_bucket, axis=2),
-            jax.lax.slice_in_dim(v_cache[layer], 0, kv_bucket, axis=2),
-            positions,
-        )
-        x = x + _attn_out(att, p["attn"])
-        x = x + _block_mlp(_layer_norm(x, p["ln_2"]), p)
-    x = _layer_norm(x, params["ln_f"])
-    return k_cache, v_cache, jnp.dot(x, _w(wte).T)
+    cache = [k_cache, v_cache]
+
+    def attend_for(layer):
+        def attend(q, k, v):  # [S, T, H, hd]
+            cache[0] = cache[0].at[layer, idx[:, None], :, pos_grid, :].set(
+                k.astype(cache[0].dtype)
+            )
+            cache[1] = cache[1].at[layer, idx[:, None], :, pos_grid, :].set(
+                v.astype(cache[1].dtype)
+            )
+            return kv_mod.varlen_verify_attention(
+                q,
+                jax.lax.slice_in_dim(cache[0][layer], 0, kv_bucket, axis=2),
+                jax.lax.slice_in_dim(cache[1][layer], 0, kv_bucket, axis=2),
+                positions,
+            )
+        return attend
+
+    x = model.embed(params, tokens, jnp.minimum(pos_grid, cfg.max_len - 1))
+    x, _ = _run_blocks(model, params, x, pos_grid, attend_for)
+    return cache[0], cache[1], model.head(params, x)
 
 
 # ---------------------------------------------------------- paged forward
 #
 # The paged mirrors of the dense cache ops (ISSUE 8): same math, but
-# K/V land in per-layer [NB, BS, H*D] block pools addressed through
+# K/V land in per-layer [NB, BS, Hkv*D] block pools addressed through
 # per-slot block tables instead of a per-slot max_len extent. ``kv`` is
 # the pool's device state — (k, v) or, quantized, (k, v, k_scale,
 # v_scale), each a tuple of one array per layer, with per-row scales
 # stored blockwise ([NB, BS, H]; core/precision.quantize_rows). No
 # program reads or writes more of a layer's array than the blocks its
 # tables name: writes are in-place scatters of token rows, reads gather
-# blocks and re-view only what they gathered.
+# blocks and re-view only what they gathered. A layer's blocks are
+# those of its KIND (``layer_kind[layer]``: 0 the full kind, then one
+# per window; paged_kv.py): a pool of several kinds hands every program
+# one table per kind where a one-kind pool hands it one.
 
 
 def _paged_write_rows(kv, layer, index, k, v):
@@ -363,16 +367,18 @@ def _paged_write_rows(kv, layer, index, k, v):
     )
 
 
-def _paged_write_prompt(kv, ks, vs, block_ids, *, block_size):
+def _paged_write_prompt(kv, ks, vs, block_ids, layer_kind, *, block_size):
     """Scatter a prefill's freshly computed K/V (per layer ``[bucket,
-    H, hd]``) into the blocks named by ``block_ids`` [bucket // BS]
-    (pad entries point at the null block; their garbage is never
-    read). ``[bucket, H, hd] -> [nb, BS, H, hd]`` is a pure reshape."""
+    H, hd]``) into the blocks named by ``block_ids`` (one ``[bucket //
+    BS]`` array per kind; pad entries point at the null block; their
+    garbage is never read). ``[bucket, H, hd] -> [nb, BS, H, hd]`` is a
+    pure reshape."""
+    ids = _per_kind(block_ids)
     for layer, (k, v) in enumerate(zip(ks, vs)):
         k, v = (
             x.reshape(-1, block_size, *x.shape[1:]) for x in (k, v)
         )
-        kv = _paged_write_rows(kv, layer, (block_ids,), k, v)
+        kv = _paged_write_rows(kv, layer, (ids[layer_kind[layer]],), k, v)
     return kv
 
 
@@ -384,58 +390,115 @@ def _layer_scales(kv, layer) -> dict:
     return {}
 
 
-def _paged_decode_forward(cfg: TransformerConfig, params, kv, tokens,
-                          positions, tables, *, block_size: int,
+def _paged_prefill_forward(model, params, kv, block_ids, tokens, length,
+                           layer_kind, *, block_size: int, impl: str):
+    """A whole prompt, ``tokens`` [1, bucket] right-padded: causal
+    self-attention over the fresh K/V, which are then scattered into
+    the slot's blocks. Returns the pool state, the final hidden state
+    [1, bucket, d] and the blocks' stats."""
+    ks, vs = [], []
+
+    def attend_for(layer):
+        def attend(q, k, v):  # [1, bucket, H, hd]
+            ks.append(k[0])
+            vs.append(v[0])
+            if _plain(model, layer):
+                return _prefill_attend(q, k, v, impl=impl)
+            return kv_mod.grouped_chunk_attention(
+                q[0], k[0], v[0], window=model.layer_windows[layer]
+            )[None]
+        return attend
+
+    positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)
+    x, stats = _run_blocks(
+        model, params, model.embed(params, tokens, positions), positions,
+        attend_for, positions < length,
+    )
+    kv = _paged_write_prompt(
+        kv, ks, vs, block_ids, layer_kind, block_size=block_size
+    )
+    return kv, x, stats
+
+
+def _paged_decode_forward(model, params, kv, tokens, positions, tables,
+                          layer_kind, *, block_size: int,
                           attention: str = "xla"):
-    """The paged twin of ``_decode_forward``: writes route through the
-    block table, attention gathers by it (the
+    """One continuous-decode step over every slot of the paged pool:
+    writes route through the block table, attention gathers by it (the
     ``varlen_decode_attention`` block-table path). Under
     ``attention="paged_flash"`` the gather + masked attention fuse into
     the ``ops/paged_decode`` Pallas kernel — one launch reading K/V
     straight through the table (int8 pools dequantize in-kernel); the
-    XLA gather path stays as the selectable reference oracle."""
-    wte = params["wte"]["embedding"]
-    x = _rows(wte, tokens) + _rows(params["wpe"]["embedding"], positions)
+    XLA gather path stays as the selectable reference oracle.
+
+    ``tables``: per kind, [S, nb]. The full kind's holds each slot's
+    logical blocks from 0; a window kind's those from the oldest block
+    the slot's query reads (``kv_cache.window_base``), ``nb <= W / BS +
+    1`` whatever the context. Slots not decoding have all-null tables
+    (and position 0): their rows land in the null block, their pairs
+    are routed nowhere, their output is discarded."""
+    tabs = _per_kind(tables)
     lengths = positions + 1
-    write_blocks = jnp.take_along_axis(
-        tables, (positions // block_size)[:, None], axis=1
-    )[:, 0]
+    write_blocks = []
+    for kind, table in enumerate(tabs):
+        column = positions // block_size
+        if kind:  # a window kind's table starts at the oldest block read
+            window = model.layer_windows[layer_kind.index(kind)]
+            column -= kv_mod.window_base(
+                positions, window, block_size
+            ) // block_size
+        write_blocks.append(
+            jnp.take_along_axis(table, column[:, None], axis=1)[:, 0]
+        )
     offsets = positions % block_size
-    attend = kv_mod.varlen_decode_attention
+    plain_attend = kv_mod.varlen_decode_attention
     if attention == "paged_flash":
         from tensorflow_examples_tpu.ops.paged_decode import (
-            paged_decode_attention as attend,
+            paged_decode_attention as plain_attend,
         )
-    for layer in range(cfg.num_layers):
-        p = params[f"h_{layer}"]
-        y = _layer_norm(x, p["ln_1"])
-        q, k, v = _qkv(y, p["attn"])  # [S, H, hd]
-        kv = _paged_write_rows(kv, layer, (write_blocks, offsets), k, v)
-        att = attend(
-            q, kv[0][layer], kv[1][layer], lengths, block_tables=tables,
-            **_layer_scales(kv, layer),
-        )
-        x = x + _attn_out(att, p["attn"])
-        x = x + _block_mlp(_layer_norm(x, p["ln_2"]), p)
-    x = _layer_norm(x, params["ln_f"])
-    return kv, jnp.dot(x, _w(wte).T)
+    state = [kv]
+
+    def attend_for(layer):
+        kind = layer_kind[layer]
+
+        def attend(q, k, v):  # [S, H, hd]
+            state[0] = kv_ = _paged_write_rows(
+                state[0], layer, (write_blocks[kind], offsets), k, v
+            )
+            if _plain(model, layer):
+                return plain_attend(
+                    q, kv_[0][layer], kv_[1][layer], lengths,
+                    block_tables=tabs[kind], **_layer_scales(kv_, layer),
+                )
+            return kv_mod.grouped_decode_attention(
+                q, kv_[0][layer], kv_[1][layer], positions, tabs[kind],
+                num_kv_heads=model.num_kv_heads,
+                window=model.layer_windows[layer],
+            )
+        return attend
+
+    # A live slot always holds logical block 0 of the full kind (a model
+    # whose blocks count nothing never reads this).
+    valid = tabs[0][:, 0] != 0
+    x, stats = _run_blocks(
+        model, params, model.embed(params, tokens, positions), positions,
+        attend_for, valid,
+    )
+    return state[0], model.head(params, x), stats
 
 
 def _paged_verify_forward(cfg: TransformerConfig, params, kv, tokens,
                           positions, tables, *, block_size: int):
-    """The paged twin of ``_verify_forward``: T rows per slot scattered
-    through the block table (the spec window may cross block
+    """The paged twin of ``_verify_forward`` (GPT-2): T rows per slot
+    scattered through the block table (the spec window may cross block
     boundaries), attention over the slot's gathered view. Rows beyond a
     slot's allocated blocks — draft padding the pool could not or need
     not back — resolve to the null block, whose garbage acceptance
     never commits."""
-    wte = params["wte"]["embedding"]
+    model = Gpt2Block(cfg)
     s_n, t_n = tokens.shape
     nb = tables.shape[1]
     pos_grid = positions[:, None] + jnp.arange(t_n, dtype=jnp.int32)
-    x = _rows(wte, tokens) + _rows(
-        params["wpe"]["embedding"], jnp.minimum(pos_grid, cfg.max_len - 1)
-    )
     blk = jnp.minimum(pos_grid // block_size, nb - 1)
     write_blocks = jnp.where(
         pos_grid < nb * block_size,
@@ -443,87 +506,118 @@ def _paged_verify_forward(cfg: TransformerConfig, params, kv, tokens,
         0,
     )
     offsets = pos_grid % block_size
-    for layer in range(cfg.num_layers):
-        p = params[f"h_{layer}"]
-        y = _layer_norm(x, p["ln_1"])
-        q, k, v = _qkv(y, p["attn"])  # [S, T, H, hd]
-        kv = _paged_write_rows(kv, layer, (write_blocks, offsets), k, v)
-        att = kv_mod.varlen_verify_attention(
-            q, kv[0][layer], kv[1][layer], positions, block_tables=tables,
-            **_layer_scales(kv, layer),
-        )
-        x = x + _attn_out(att, p["attn"])
-        x = x + _block_mlp(_layer_norm(x, p["ln_2"]), p)
-    x = _layer_norm(x, params["ln_f"])
-    return kv, jnp.dot(x, _w(wte).T)
+    state = [kv]
+
+    def attend_for(layer):
+        def attend(q, k, v):  # [S, T, H, hd]
+            state[0] = kv_ = _paged_write_rows(
+                state[0], layer, (write_blocks, offsets), k, v
+            )
+            return kv_mod.varlen_verify_attention(
+                q, kv_[0][layer], kv_[1][layer], positions,
+                block_tables=tables, **_layer_scales(kv_, layer),
+            )
+        return attend
+
+    x = model.embed(params, tokens, jnp.minimum(pos_grid, cfg.max_len - 1))
+    x, _ = _run_blocks(model, params, x, pos_grid, attend_for)
+    return state[0], model.head(params, x)
 
 
-def _extend_forward(cfg: TransformerConfig, params, kv, ctx_table,
-                    tail_ids, tokens, ctx_len, *, block_size: int):
-    """Chunked prefill on top of a cached context: run only the prompt
-    TAIL (``tokens`` [1, tb], absolute positions ``ctx_len + i``), with
-    each tail row attending over (a) the cached context gathered by
-    ``ctx_table`` [max_blocks], masked to ``ctx_len`` columns, and (b)
-    the tail itself, causally. This is what makes a prefix-cache hit a
-    compute saving, not just a memory one: the shared prefix's layers
-    are never re-run. Tail K/V is scattered into ``tail_ids``
-    [tb // BS]. Numerics mirror ``varlen_decode_attention`` (f32
+def _plain_extend_attention(q, k, v, kc, vc, ctx_len, sm_scale):
+    """One extend step's attention for equal heads and no window: each
+    tail row over (a) the cached context ``kc``/``vc`` [ctx_cols, H,
+    hd], masked to ``ctx_len`` columns, and (b) the tail itself,
+    causally. Numerics mirror ``varlen_decode_attention`` (f32
     scores/softmax, probabilities cast to the value dtype, f32
-    accumulation) so hits stay token-identical at fp32 (test-pinned).
-    """
-    wte = params["wte"]["embedding"]
-    tb = tokens.shape[1]
-    sm_scale = cfg.head_dim ** -0.5
-    positions = ctx_len + jnp.arange(tb, dtype=jnp.int32)
-    # Pad rows past the true tail may index past max_len; clip — they
-    # are causally downstream of every real row and discarded.
-    x = _rows(wte, tokens) + _rows(
-        params["wpe"]["embedding"], jnp.minimum(positions, cfg.max_len - 1)
-    )[None]
-    ctx_cols = ctx_table.shape[0] * block_size
+    accumulation) so hits stay token-identical at fp32 (test-pinned)."""
+    tb, ctx_cols = q.shape[1], kc.shape[0]
     colc = jax.lax.broadcasted_iota(jnp.int32, (1, 1, tb, ctx_cols), 3)
     rowt = jax.lax.broadcasted_iota(jnp.int32, (1, 1, tb, tb), 2)
     colt = jax.lax.broadcasted_iota(jnp.int32, (1, 1, tb, tb), 3)
+    s_ctx = jnp.einsum(
+        "bthd,khd->bhtk", q, kc, preferred_element_type=jnp.float32
+    ) * sm_scale
+    s_ctx = jnp.where(colc < ctx_len, s_ctx, NEG_INF)
+    s_tail = jnp.einsum(
+        "bthd,bkhd->bhtk", q, k, preferred_element_type=jnp.float32
+    ) * sm_scale
+    s_tail = jnp.where(rowt >= colt, s_tail, NEG_INF)
+    prob = jax.nn.softmax(
+        jnp.concatenate([s_ctx, s_tail], axis=-1), axis=-1
+    )
+    p_ctx, p_tail = prob[..., :ctx_cols], prob[..., ctx_cols:]
+    out = jnp.einsum(
+        "bhtk,khd->bthd", p_ctx.astype(vc.dtype), vc,
+        preferred_element_type=jnp.float32,
+    ) + jnp.einsum(
+        "bhtk,bkhd->bthd", p_tail.astype(v.dtype), v,
+        preferred_element_type=jnp.float32,
+    )
+    return out.astype(q.dtype)
+
+
+def _extend_forward(model, params, kv, ctx_table, tail_ids, tokens,
+                    ctx_len, tail_len, layer_kind, *, block_size: int):
+    """Chunked prefill on top of a cached context: run only the prompt
+    TAIL (``tokens`` [1, tb], absolute positions ``ctx_len + i``), with
+    each tail row attending over (a) the cached context gathered by
+    ``ctx_table``, masked to ``ctx_len`` columns, and (b) the tail
+    itself, causally. This is what makes a prefix-cache hit a compute
+    saving, not just a memory one (the shared prefix's layers are never
+    re-run), and what runs one chunk of a chunked prefill. Tail K/V is
+    scattered into ``tail_ids`` [tb // BS].
+
+    ``ctx_table`` and ``tail_ids``: per kind. The full kind's context
+    table is the slot's whole table [max_blocks]; a window kind's holds
+    the logical blocks from the oldest one the chunk's first query
+    reads (``kv_cache.window_base(ctx_len, ...)``), at most ``W / BS +
+    1``."""
+    tb = tokens.shape[1]
+    sm_scale = model.head_dim ** -0.5
+    ctx_tables = _per_kind(ctx_table)
+    positions = ctx_len + jnp.arange(tb, dtype=jnp.int32)
     ks, vs = [], []
-    for layer in range(cfg.num_layers):
-        p = params[f"h_{layer}"]
-        y = _layer_norm(x, p["ln_1"])
-        q, k, v = _qkv(y, p["attn"])  # [1, tb, H, hd]
-        ks.append(k[0])
-        vs.append(v[0])
-        # The cached context as [ctx_cols, H, hd], from this layer's
-        # blocks of the table alone.
-        kc, vc = (
-            x.astype(q.dtype) for x in kv_mod.gather_layer_kv(
-                kv[0][layer], kv[1][layer], ctx_table, cfg.num_heads,
-                q.dtype, **_layer_scales(kv, layer),
+
+    def attend_for(layer):
+        kind = layer_kind[layer]
+        window = model.layer_windows[layer]
+
+        def attend(q, k, v):  # [1, tb, H, hd]
+            ks.append(k[0])
+            vs.append(v[0])
+            # The cached context as [ctx_cols, Hkv, hd], from this
+            # layer's blocks of the table alone.
+            kc, vc = (
+                x.astype(q.dtype) for x in kv_mod.gather_layer_kv(
+                    kv[0][layer], kv[1][layer], ctx_tables[kind],
+                    model.num_kv_heads, q.dtype, **_layer_scales(kv, layer),
+                )
             )
-        )
-        s_ctx = jnp.einsum(
-            "bthd,khd->bhtk", q, kc, preferred_element_type=jnp.float32
-        ) * sm_scale
-        s_ctx = jnp.where(colc < ctx_len, s_ctx, NEG_INF)
-        s_tail = jnp.einsum(
-            "bthd,bkhd->bhtk", q, k, preferred_element_type=jnp.float32
-        ) * sm_scale
-        s_tail = jnp.where(rowt >= colt, s_tail, NEG_INF)
-        prob = jax.nn.softmax(
-            jnp.concatenate([s_ctx, s_tail], axis=-1), axis=-1
-        )
-        p_ctx, p_tail = prob[..., :ctx_cols], prob[..., ctx_cols:]
-        out = jnp.einsum(
-            "bhtk,khd->bthd", p_ctx.astype(vc.dtype), vc,
-            preferred_element_type=jnp.float32,
-        ) + jnp.einsum(
-            "bhtk,bkhd->bthd", p_tail.astype(v.dtype), v,
-            preferred_element_type=jnp.float32,
-        )
-        att = out.astype(q.dtype)
-        x = x + _attn_out(att, p["attn"])
-        x = x + _block_mlp(_layer_norm(x, p["ln_2"]), p)
-    x = _layer_norm(x, params["ln_f"])
-    kv = _paged_write_prompt(kv, ks, vs, tail_ids, block_size=block_size)
-    return kv, jnp.dot(x, _w(wte).T)
+            if _plain(model, layer):
+                return _plain_extend_attention(
+                    q, k, v, kc, vc, ctx_len, sm_scale
+                )
+            return kv_mod.grouped_chunk_attention(
+                q[0], k[0], v[0], kc, vc, ctx_len=ctx_len,
+                ctx_base=kv_mod.window_base(ctx_len, window, block_size),
+                window=window,
+            )[None]
+        return attend
+
+    # Pad rows past the true tail may index past max_len; clip — they
+    # are causally downstream of every real row and discarded.
+    x = model.embed(
+        params, tokens, jnp.minimum(positions, model.max_len - 1)
+    )
+    x, stats = _run_blocks(
+        model, params, x, positions, attend_for,
+        jnp.arange(tb) < tail_len,
+    )
+    kv = _paged_write_prompt(
+        kv, ks, vs, tail_ids, layer_kind, block_size=block_size
+    )
+    return kv, x, stats
 
 
 # The pool's device state in order (``PagedKVPool.kv_state``), by the
@@ -555,6 +649,18 @@ def _sample_row(key, logits, temp, top_k):
 
 
 _sample_batch = jax.vmap(_sample_row)
+
+
+def _token_logprobs(logits, tokens):
+    """The model's own log-probability (temperature 1, no top-k: what
+    ``classify`` reports) of each row's ``tokens`` entry, float32
+    log-softmax — carried to the host as its int32 bit pattern so that
+    it rides in the step's one fetch behind the tokens."""
+    logits = logits.astype(jnp.float32)
+    chosen = jnp.take_along_axis(logits, tokens[:, None], axis=-1)[:, 0]
+    return jax.lax.bitcast_convert_type(
+        chosen - jax.nn.logsumexp(logits, axis=-1), jnp.int32
+    )
 
 
 def request_key(seed: int, position: int) -> jax.Array:
@@ -613,7 +719,7 @@ class ChunkedPrefill:
     ``engine.prefill_step`` once per decode-loop iteration."""
 
     __slots__ = ("slot", "prompt", "spans", "idx", "seed",
-                 "temperature", "top_k")
+                 "temperature", "top_k", "pending")
 
     def __init__(self, slot, prompt, spans, seed, temperature, top_k):
         self.slot = slot
@@ -623,6 +729,9 @@ class ChunkedPrefill:
         self.seed = seed
         self.temperature = temperature
         self.top_k = top_k
+        # Outputs of the chunks before the last, still on the device:
+        # read with the final chunk's (the blocks' stats ride in them).
+        self.pending = []
 
 
 def _named(step, tag: str):
@@ -636,6 +745,19 @@ def _named(step, tag: str):
     stay."""
     step.__name__ = f"{step.func.__name__.lstrip('_')}_{tag}{step.args[0]}"
     return step
+
+
+def _pack(tables: list):
+    """A program's table argument: the bare array of a one-kind pool,
+    a tuple of one array per kind otherwise (``_per_kind`` undoes it)."""
+    return tables[0] if len(tables) == 1 else tuple(tables)
+
+
+def _upload(host):
+    """One host value to the device; a list is one table per kind."""
+    if isinstance(host, list):
+        return _pack([jnp.asarray(t) for t in host])
+    return jnp.asarray(host)
 
 
 class InferenceEngine:
@@ -657,11 +779,17 @@ class InferenceEngine:
         sharding=None,
         precision=None,
     ):
-        if model_cfg.moe_experts:
+        # The block this engine runs, chosen by the config's type
+        # (serving/blocks.py): GPT-2's or Cohere2-MoE's.
+        self.model = block_for(model_cfg)
+        self.gpt2 = isinstance(self.model, Gpt2Block)
+        if self.gpt2 and model_cfg.moe_experts:
             raise NotImplementedError(
-                "serving engine currently covers dense GPT-2 models only"
+                "the GPT-2 serving block is dense: a TransformerConfig "
+                "with moe_experts is not served (the expert layer the "
+                "engine runs is Cohere2MoeConfig's)"
             )
-        if model_cfg.attention not in ("xla", "flash"):
+        if self.gpt2 and model_cfg.attention not in ("xla", "flash"):
             # ring/ulysses are training-side context-parallel impls.
             raise ValueError(
                 f"model attention={model_cfg.attention!r}; the serving "
@@ -669,6 +797,8 @@ class InferenceEngine:
             )
         self.model_cfg = model_cfg
         self.cfg = cfg or ServeConfig()
+        if not self.gpt2:
+            self._refuse_for_block(sharding, precision)
         # Fleet identity (ISSUE 10): which replica this engine is in a
         # multi-replica process (serve_bench --router / the chaos
         # harness). The serve-side fault engine keys on it; 0 for a
@@ -773,12 +903,7 @@ class InferenceEngine:
         reg.gauge("precision/quantized_params").set(
             self._precision_stats["quantized_params"]
         )
-        wte = self.params["wte"]["embedding"]
-        param_dtype = (
-            jnp.float32
-            if isinstance(wte, precision_mod.QuantizedWeight)
-            else wte.dtype
-        )
+        param_dtype = self.model.param_dtype(self.params)
         cache_dtype = (
             jnp.dtype(self.cfg.cache_dtype)
             if self.cfg.cache_dtype
@@ -850,12 +975,16 @@ class InferenceEngine:
                 PagedKVPool,
             )
 
+            # A cache row holds the KEY/VALUE heads (fewer than the
+            # query heads under grouped-query attention); kv_blocks
+            # counts the full kind's blocks, a window kind's follow
+            # from the slots, its W, the chunk and the block size.
             self.pool = PagedKVPool(
-                num_layers=model_cfg.num_layers,
+                num_layers=self.model.num_layers,
                 num_slots=self.cfg.max_slots,
-                num_heads=model_cfg.num_heads,
+                num_heads=self.model.num_kv_heads,
                 max_len=model_cfg.max_len,
-                head_dim=model_cfg.head_dim,
+                head_dim=self.model.head_dim,
                 block_size=bs,
                 num_blocks=self.cfg.kv_blocks,
                 dtype=cache_dtype,
@@ -863,8 +992,13 @@ class InferenceEngine:
                 prefix_cache=self.cfg.prefix_cache,
                 registry=self.registry,
                 sharding=self._kv_sharding(),
+                layer_windows=self.model.layer_windows,
+                window_span=self.cfg.prefill_chunk_tokens,
             )
+            self._layer_kind = self.pool.layer_kind
+            self._kinds = len(self.pool.kinds)
         else:
+            self._kinds = 1
             if self.kv_dtype:
                 raise ValueError(
                     "kv_dtype (quantized KV) requires the paged pool — "
@@ -880,8 +1014,16 @@ class InferenceEngine:
                 registry=self.registry,
                 sharding=self._kv_sharding(),
             )
+        # With chunked prefill on, no prefill or extend call is ever
+        # longer than one chunk (a longer prompt is split, prefill_open):
+        # the rungs above the chunk's are unreachable, and for a model
+        # whose pool has a window kind they must not exist — the kind's
+        # block space holds W plus ONE chunk a slot.
+        longest = model_cfg.max_len
+        if self.cfg.prefill_chunk_tokens and self._kinds > 1:
+            longest = min(longest, self.cfg.prefill_chunk_tokens)
         self.prefill_ladder = kv_mod.bucket_ladder(
-            self.cfg.prefill_bucket_floor, model_cfg.max_len
+            min(self.cfg.prefill_bucket_floor, longest), longest
         )
         self.kv_ladder = kv_mod.bucket_ladder(
             self.cfg.kv_bucket_floor, model_cfg.max_len
@@ -978,7 +1120,45 @@ class InferenceEngine:
                 for kb in self.kv_ladder
             } if self.cfg.spec_decode_k > 0 else {}
         self.warmed = False
+        # The last decode step's log-probability of each slot's token.
+        self.last_logprobs = np.zeros((cfg.max_slots,), np.float32)
         self._ref_fwd = None
+
+    def _refuse_for_block(self, sharding, precision) -> None:
+        """A block other than GPT-2's runs on the paged pool's XLA
+        path — prefill, extend (chunked prefill) and decode — and on
+        nothing else. Every other mechanism keeps serving GPT-2 as it
+        is and REFUSES this block here, by name, at construction: no
+        silent fallback."""
+        cfg, name = self.cfg, self.model.name
+        refused = [
+            ("the dense (un-paged) KV cache", cfg.kv_block_size <= 0,
+             "set kv_block_size"),
+            ("speculative verify (spec_decode_k)", cfg.spec_decode_k > 0,
+             "its verify forward is GPT-2's"),
+            ("KV page export/import (role='prefill'/'decode')",
+             cfg.role != "mixed", "a page payload has one block-id space "
+             "and equal heads"),
+            ("quantized KV (kv_dtype int8/fp8)",
+             bool(cfg.kv_dtype or (precision and precision.kv_dtype)),
+             "the grouped-query gather does not dequantize"),
+            ("weight quantization (weight_dtype / precision=)",
+             bool(cfg.weight_dtype or precision),
+             "the block reads its weights as stored"),
+            ("the fused paged_flash decode kernel (attention='paged_flash')",
+             cfg.attention == "paged_flash", "the kernel reads rows of "
+             "equal heads through one table"),
+            ("the Pallas flash prefill (attention='flash')",
+             cfg.attention == "flash", "no grouped-query or window mask"),
+            ("sharded serving (sharding=)", sharding is not None,
+             "the placement rules are GPT-2's"),
+        ]
+        for mechanism, on, why in refused:
+            if on:
+                raise NotImplementedError(
+                    f"{mechanism} does not serve the {name} block "
+                    f"({why}); it serves GPT-2 only"
+                )
 
     def _kv_sharding(self):
         """KV-pool NamedSharding from the ShardingConfig: heads shard
@@ -999,7 +1179,7 @@ class InferenceEngine:
         m = int(self.mesh.shape[AxisNames.MODEL])
         heads = (
             AxisNames.MODEL
-            if m > 1 and self.model_cfg.num_heads % m == 0
+            if m > 1 and self.model.num_kv_heads % m == 0
             else None
         )
         if self.paged:
@@ -1037,7 +1217,10 @@ class InferenceEngine:
         )
         # The sampled token lands at sequence index position + 1.
         keys = _request_key_batch(seeds, positions + 1)
-        return k_cache, v_cache, _sample_batch(keys, logits, temps, top_ks)
+        toks = _sample_batch(keys, logits, temps, top_ks)
+        return k_cache, v_cache, jnp.concatenate(
+            [toks, _token_logprobs(logits, toks)]
+        )
 
     def _verify_impl(self, bucket, params, k_cache, v_cache, tokens,
                      positions, seeds, temps, top_ks):
@@ -1055,32 +1238,42 @@ class InferenceEngine:
 
     # --------------------------------------------- compiled fns (paged)
 
+    def _with_stats(self, tokens, stats):
+        """What a step hands back for the host's ONE fetch: the sampled
+        token(s) (a decode step's with their log-probabilities'
+        bits), and behind them the blocks' int32 stats where the model
+        has any (no second transfer)."""
+        if stats is None:
+            return tokens
+        return jnp.concatenate([jnp.reshape(tokens, (-1,)), stats])
+
     def _paged_prefill_impl(self, bucket, params, kv, block_ids, tokens,
                             length, key, temp, top_k):
         """The paged twin of ``_prefill_impl``: same forward, K/V
         scattered into the slot's blocks instead of its dense extent."""
-        logits, ks, vs = forward_full(
-            self.model_cfg, params, tokens, impl=self._prefill_attn
+        del bucket  # static: encoded in tokens.shape
+        kv, x, stats = _paged_prefill_forward(
+            self.model, params, kv, block_ids, tokens, length,
+            self._layer_kind, block_size=self.cfg.kv_block_size,
+            impl=self._prefill_attn,
         )
-        kv = _paged_write_prompt(
-            kv, ks[:, 0], vs[:, 0], block_ids,
-            block_size=self.cfg.kv_block_size,
-        )
-        last = jax.lax.dynamic_index_in_dim(
-            logits[0], length - 1, keepdims=False
-        )
-        return kv, _sample_row(key, last, temp, top_k), last
+        last = self.model.last_logits(params, x[0], length - 1)
+        tok = _sample_row(key, last, temp, top_k)
+        return kv, self._with_stats(tok, stats), last
 
     def _paged_decode_impl(self, bucket, params, kv, tokens, positions,
                            tables, seeds, temps, top_ks):
         del bucket  # static: encoded in tables.shape
-        kv, logits = _paged_decode_forward(
-            self.model_cfg, params, kv, tokens, positions, tables,
-            block_size=self.cfg.kv_block_size,
+        kv, logits, stats = _paged_decode_forward(
+            self.model, params, kv, tokens, positions, tables,
+            self._layer_kind, block_size=self.cfg.kv_block_size,
             attention=self.cfg.attention,
         )
         keys = _request_key_batch(seeds, positions + 1)
-        return kv, _sample_batch(keys, logits, temps, top_ks)
+        toks = _sample_batch(keys, logits, temps, top_ks)
+        return kv, self._with_stats(
+            jnp.concatenate([toks, _token_logprobs(logits, toks)]), stats
+        )
 
     def _paged_verify_impl(self, bucket, params, kv, tokens, positions,
                            tables, seeds, temps, top_ks):
@@ -1096,18 +1289,80 @@ class InferenceEngine:
 
     def _extend_impl(self, tail_bucket, params, kv, ctx_table, tail_ids,
                      tokens, ctx_len, tail_len, key, temp, top_k):
-        """Prefix-cache hit path: prefill only the prompt tail over the
-        cached context (see ``_extend_forward``); samples the first
-        token from the tail's last true row."""
+        """Prefix-cache hit path and chunked prefill: prefill only the
+        prompt tail over the cached context (see ``_extend_forward``);
+        samples the first token from the tail's last true row."""
         del tail_bucket  # static: encoded in tokens.shape
-        kv, logits = _extend_forward(
-            self.model_cfg, params, kv, ctx_table, tail_ids, tokens,
-            ctx_len, block_size=self.cfg.kv_block_size,
+        kv, x, stats = _extend_forward(
+            self.model, params, kv, ctx_table, tail_ids, tokens,
+            ctx_len, tail_len, self._layer_kind,
+            block_size=self.cfg.kv_block_size,
         )
-        last = jax.lax.dynamic_index_in_dim(
-            logits[0], tail_len - 1, keepdims=False
-        )
-        return kv, _sample_row(key, last, temp, top_k), last
+        last = self.model.last_logits(params, x[0], tail_len - 1)
+        tok = _sample_row(key, last, temp, top_k)
+        return kv, self._with_stats(tok, stats), last
+
+    # ------------------------------------------------- tables, by kind
+
+    def _kind_blocks(self, nb_full: int) -> list[int]:
+        """Columns of each kind's table in a program whose full kind
+        gets ``nb_full``: a window kind never needs more than the
+        ``W / BS + 1`` logical blocks one query's window touches."""
+        bs = self.cfg.kv_block_size
+        return [
+            nb_full if w is None else min(nb_full, w // bs + 1)
+            for w in self.pool.kinds
+        ]
+
+    def _kind_tables(self, position, nb_full: int, slot=None,
+                     live=None) -> list:
+        """Each kind's table as the programs read it, for one ``slot``
+        ([nb]) or for all ([S, nb], ``position`` a vector): the full
+        kind's logical blocks from 0, a window kind's from the oldest
+        block a query at ``position`` reads. ``live`` (with all slots)
+        names the slots the step serves: every other row is null, so
+        that a slot which holds blocks but is not stepping — one in the
+        middle of a chunked prefill — is written into the null block
+        like an empty one, and not into its own position 0."""
+        pool, bs = self.pool, self.cfg.kv_block_size
+        rows = slice(None) if slot is None else slice(slot, slot + 1)
+        out = [np.ascontiguousarray(pool.block_tables[rows, :nb_full])]
+        for kind, (w, nb) in enumerate(
+            zip(pool.kinds, self._kind_blocks(nb_full))
+        ):
+            if kind == 0:
+                continue
+            first = np.maximum(np.asarray(position) - w + 1, 0) // bs
+            cols = np.minimum(
+                np.reshape(first, (-1, 1)) + np.arange(nb),
+                pool.max_blocks_per_slot - 1,
+            )
+            out.append(np.take_along_axis(
+                pool.window_tables(kind)[rows], cols, axis=1
+            ).astype(np.int32))
+        if live is not None:
+            parked = np.ones((out[0].shape[0],), bool)
+            parked[list(live)] = False
+            out[0] = out[0].copy()  # it may be the pool's own array
+            for table in out:
+                table[parked] = 0
+        return out if slot is None else [t[0] for t in out]
+
+    def _span_ids(self, slot: int, first_block: int, last_block: int,
+                  width: int) -> list:
+        """Per kind, the physical ids of the slot's logical blocks
+        ``[first_block, last_block)`` padded with the null block to
+        ``width``: where a prefill or a chunk scatters its K/V."""
+        out = []
+        for kind in range(self._kinds):
+            table = self.pool.block_tables if kind == 0 \
+                else self.pool.window_tables(kind)
+            ids = np.zeros((width,), np.int32)
+            ids[:last_block - first_block] = table[
+                slot, first_block:last_block
+            ]
+            out.append(ids)
+        return out
 
     # --------------------------------------------------------- lifecycle
 
@@ -1122,10 +1377,18 @@ class InferenceEngine:
         ftemp = jnp.float32(0.0)
         if self.paged:
             bs = self.cfg.kv_block_size
+
+            def ztab(*lead, kinds):
+                # All-null tables of every kind, shaped as the step
+                # functions are called: ``lead`` then the kind's blocks.
+                return _pack([
+                    jnp.zeros((*lead, nb), jnp.int32) for nb in kinds
+                ])
+
             for lb in self.prefill_ladder:
                 kv, tok, _ = self._prefill_fns[lb](
                     self.params, self.pool.kv_state(),
-                    jnp.zeros((lb // bs,), jnp.int32),
+                    ztab(kinds=[lb // bs] * self._kinds),
                     jnp.zeros((1, lb), jnp.int32), zero + 1, key, ftemp,
                     zero,
                 )
@@ -1135,7 +1398,7 @@ class InferenceEngine:
                 kv, toks = self._decode_fns[kb](
                     self.params, self.pool.kv_state(),
                     jnp.zeros((s,), jnp.int32), jnp.zeros((s,), jnp.int32),
-                    jnp.zeros((s, kb // bs), jnp.int32),
+                    ztab(s, kinds=self._kind_blocks(kb // bs)),
                     jnp.zeros((s,), jnp.int32),
                     jnp.zeros((s,), jnp.float32),
                     jnp.zeros((s,), jnp.int32),
@@ -1145,8 +1408,9 @@ class InferenceEngine:
             for tb in self._extend_fns:
                 kv, tok, _ = self._extend_fns[tb](
                     self.params, self.pool.kv_state(),
-                    jnp.zeros((self.pool.max_blocks_per_slot,), jnp.int32),
-                    jnp.zeros((tb // bs,), jnp.int32),
+                    ztab(kinds=self._kind_blocks(
+                        self.pool.max_blocks_per_slot)),
+                    ztab(kinds=[tb // bs] * self._kinds),
                     jnp.zeros((1, tb), jnp.int32), zero + bs, zero + 1,
                     key, ftemp, zero,
                 )
@@ -1341,11 +1605,20 @@ class InferenceEngine:
         self.registry.counter("serving/prefill_tokens").inc(n)
         return self._fetch_prefill(tok, last)
 
-    def _fetch_prefill(self, tok, last):
+    def _fetch_prefill(self, tok, last, pending=()):
         """The prefill's device->host sync: first token and last-row
-        logits, under ``span/engine_prefill_fetch``."""
+        logits, under ``span/engine_prefill_fetch``. Where the model's
+        blocks report stats they ride behind the token, and in the
+        ``pending`` outputs of a chunked prefill's earlier chunks
+        (finished long since: reading them waits for nothing)."""
         with host_span("engine_prefill_fetch"):
-            return int(tok), np.asarray(last)
+            tok, last = np.asarray(tok), np.asarray(last)
+            if self.model.stats_len:
+                for out in (*map(np.asarray, pending), tok):
+                    self.model.count_stats(
+                        self.registry, out[1:], decode=False
+                    )
+            return int(tok.reshape(-1)[0]), last
 
     def _paged_prefill(self, slot, prompt, *, seed, temperature, top_k):
         n = len(prompt)
@@ -1356,33 +1629,31 @@ class InferenceEngine:
         # returns ctx=0 exactly when there is no rung to run a tail on.
         with host_span("engine_prefill_build"):
             ctx, _ = self.pool.claim_prompt_blocks(slot, prompt)
+            self.pool.ensure_span(slot, ctx, n)
             total_blocks = -(-n // bs)
             if ctx == 0:
                 bucket = kv_mod.pick_bucket(self.prefill_ladder, n)
-                ids = np.zeros((bucket // bs,), np.int32)
-                ids[:total_blocks] = self.pool.block_tables[
-                    slot, :total_blocks
-                ]
+                ids = self._span_ids(slot, 0, total_blocks, bucket // bs)
                 tokens = np.zeros((1, bucket), np.int32)
                 tokens[0, :n] = prompt
                 host = (ids, tokens, np.int32(n))
             else:
                 tail = n - ctx
                 bucket = kv_mod.pick_bucket(self.prefill_ladder, tail)
-                tail_blocks = total_blocks - ctx // bs
-                tail_ids = np.zeros((bucket // bs,), np.int32)
-                tail_ids[:tail_blocks] = self.pool.block_tables[
-                    slot, ctx // bs:total_blocks
-                ]
+                tail_ids = self._span_ids(
+                    slot, ctx // bs, total_blocks, bucket // bs
+                )
                 tokens = np.zeros((1, bucket), np.int32)
                 tokens[0, :tail] = prompt[ctx:]
                 host = (
-                    self.pool.block_tables[slot], tail_ids, tokens,
-                    np.int32(ctx), np.int32(tail),
+                    self._kind_tables(
+                        ctx, self.pool.max_blocks_per_slot, slot
+                    ),
+                    tail_ids, tokens, np.int32(ctx), np.int32(tail),
                 )
         with host_span("engine_prefill_upload"):
             args = (
-                *map(jnp.asarray, host), request_key(seed, n),
+                *map(_upload, host), request_key(seed, n),
                 jnp.float32(temperature), jnp.int32(top_k),
             )
         if ctx == 0:
@@ -1457,18 +1728,20 @@ class InferenceEngine:
             start, end = state.spans[state.idx]
             tail = end - start
             tb = kv_mod.pick_bucket(self.prefill_ladder, tail)
-            first_block = start // bs
-            last_block = -(-end // bs)
-            tail_ids = np.zeros((tb // bs,), np.int32)
-            tail_ids[:last_block - first_block] = self.pool.block_tables[
-                slot, first_block:last_block
-            ]
+            # Window kinds claim this chunk's blocks and let go of what
+            # no query from ``start`` on can read.
+            self.pool.ensure_span(slot, start, end)
+            tail_ids = self._span_ids(
+                slot, start // bs, -(-end // bs), tb // bs
+            )
+            ctx_tables = self._kind_tables(
+                start, self.pool.max_blocks_per_slot, slot
+            )
             tokens = np.zeros((1, tb), np.int32)
             tokens[0, :tail] = prompt[start:end]
         with host_span("engine_prefill_upload"):
             args = (
-                jnp.asarray(self.pool.block_tables[slot]),
-                jnp.asarray(tail_ids), jnp.asarray(tokens),
+                *map(_upload, (ctx_tables, tail_ids, tokens)),
                 jnp.int32(start), jnp.int32(tail),
                 request_key(state.seed, end),
                 jnp.float32(state.temperature), jnp.int32(state.top_k),
@@ -1481,14 +1754,24 @@ class InferenceEngine:
         state.idx += 1
         self.registry.counter("serving/prefill_chunks").inc()
         if state.idx < len(state.spans):
+            if self.model.stats_len:
+                state.pending.append(tok)
             return False, None, None
         n = len(prompt)
         self.pool.lengths[slot] = n
         self.pool.insert_prefix(slot, prompt)
         self.registry.counter("serving/prefill_tokens").inc(n)
-        return (True, *self._fetch_prefill(tok, last))
+        return (True, *self._fetch_prefill(tok, last, state.pending))
 
     # ----------------------------------- KV page handoff (ISSUE 12 (c))
+
+    def _pages_are_gpt2s(self, what: str) -> None:
+        if not self.gpt2:
+            raise NotImplementedError(
+                f"KV page {what} does not serve the {self.model.name} "
+                "block (a page payload has one block-id space and equal "
+                "heads); it serves GPT-2 only"
+            )
 
     def export_kv_pages(self, slot: int, prompt: Sequence[int], *,
                         skip_tokens: int = 0) -> dict:
@@ -1506,6 +1789,7 @@ class InferenceEngine:
         full blocks they cover stay OFF the wire (``start_block``
         meta). Floored to this replica's block multiple and capped so
         at least the final (partial) block always ships."""
+        self._pages_are_gpt2s("export")
         if not self.paged:
             raise ValueError(
                 "KV page export requires the paged pool (set "
@@ -1565,6 +1849,7 @@ class InferenceEngine:
         publish the prompt into the local prefix cache so later
         shared-prefix traffic gains affinity here too. A
         ``BlockExhausted`` propagates before any write (503 upstream)."""
+        self._pages_are_gpt2s("import")
         if not self.paged:
             raise ValueError(
                 "KV page import requires the paged pool (set "
@@ -1689,7 +1974,9 @@ class InferenceEngine:
         """One continuous-decode step. ``entries`` is the active set:
         (slot, input_token, seed, temperature, top_k) per request —
         every entry's input token sits at cache row
-        ``pool.lengths[slot]``. Returns {slot: generated token}."""
+        ``pool.lengths[slot]``. Returns {slot: generated token}; the
+        model's log-probability of each is ``last_logprobs[slot]``
+        until the next step."""
         if not entries:
             return {}
         feng = faults_mod.serve_active()
@@ -1744,11 +2031,30 @@ class InferenceEngine:
                         slots=tuple(exhausted),
                     )
                 bs = self.cfg.kv_block_size
-                tables = (np.ascontiguousarray(
-                    self.pool.block_tables[:, :bucket // bs]
+                tables = (self._kind_tables(
+                    positions, bucket // bs, live=slots
                 ),)
+                if self._kinds > 1:
+                    # What the pool holds for what is resident, and
+                    # what of it this step's tables reach, sampled once
+                    # a step (kv_bytes_per_resident_token; the decode
+                    # roofline's cache bytes).
+                    reg, pool = self.registry, self.pool
+                    ctx = positions[slots].astype(np.int64) + 1
+                    reg.counter("serving/kv_sampled_bytes").inc(
+                        pool.used_bytes()
+                    )
+                    reg.counter("serving/kv_sampled_tokens").inc(
+                        int(ctx.sum())
+                    )
+                    reg.counter("serving/kv_sampled_reach_bytes").inc(sum(
+                        pool.bytes_per_block(kind) // bs * int(
+                            (ctx if w is None else np.minimum(ctx, w)).sum()
+                        )
+                        for kind, w in enumerate(pool.kinds)
+                    ))
         with host_span("engine_decode_upload"):
-            args = tuple(map(jnp.asarray, (
+            args = tuple(map(_upload, (
                 tokens, positions, *tables, seeds, temps, top_ks,
             )))
         if self.paged:
@@ -1764,6 +2070,10 @@ class InferenceEngine:
             )
         with host_span("engine_decode_fetch"):
             out = np.asarray(out)
+        # [S] tokens, [S] float32 log-probabilities as bits, the stats.
+        self.last_logprobs = out[s:2 * s].view(np.float32)
+        if self.model.stats_len:
+            self.model.count_stats(self.registry, out[2 * s:], decode=True)
         for slot in slots:
             self.pool.lengths[slot] += 1
         self.registry.counter("serving/decode_steps").inc()
@@ -1867,9 +2177,9 @@ class InferenceEngine:
                         slots=tuple(exhausted),
                     )
                 bs = self.cfg.kv_block_size
-                tables = (np.ascontiguousarray(
-                    self.pool.block_tables[:, :bucket // bs]
-                ),)
+                tables = (self._kind_tables(
+                    positions, bucket // bs, live=slots
+                )[0],)
         with host_span("engine_verify_upload"):
             args = tuple(map(jnp.asarray, (
                 tokens, positions, *tables, seeds, temps, top_ks,
@@ -1970,11 +2280,19 @@ class InferenceEngine:
         return top_logprobs(np.asarray(last), top_n)
 
 
+def _log_softmax(logits: np.ndarray) -> np.ndarray:
+    x = logits.astype(np.float64)
+    return x - (np.log(np.sum(np.exp(x - x.max()))) + x.max())
+
+
 def top_logprobs(logits: np.ndarray, top_n: int) -> list[dict]:
     """Next-token distribution head: top-n (token, logprob) pairs."""
-    x = logits.astype(np.float64)
-    logz = np.log(np.sum(np.exp(x - x.max()))) + x.max()
-    order = np.argsort(x)[::-1][:top_n]
-    return [
-        {"token": int(t), "logprob": float(x[t] - logz)} for t in order
-    ]
+    logp = _log_softmax(logits)
+    order = np.argsort(logp)[::-1][:top_n]
+    return [{"token": int(t), "logprob": float(logp[t])} for t in order]
+
+
+def token_logprob(logits: np.ndarray, token: int) -> float:
+    """The log-probability of ``token`` under ``logits`` (a prefill's
+    last row: what the first generated token was sampled from)."""
+    return float(_log_softmax(logits)[token])

@@ -9,9 +9,13 @@ Endpoints:
 
 * ``POST /generate`` — body ``{"prompt": [ids], "max_new_tokens": n,
   "temperature": t, "top_k": k, "seed": s, "eos_id": id,
-  "deadline_s": d, "slo": "interactive"|"batch"}`` (all but ``prompt``
-  optional; ``"text"`` may replace ``prompt`` when the frontend was
-  built with a tokenizer). ``slo`` is the ISSUE 13 service class:
+  "deadline_s": d, "slo": "interactive"|"batch", "logprobs": bool}``
+  (all but ``prompt`` optional; ``"text"`` may replace ``prompt`` when
+  the frontend was built with a tokenizer). ``logprobs`` adds
+  ``"logprobs": [...]`` to the reply: the model's own log-probability
+  (temperature 1, as ``/classify`` reports) of each generated token,
+  from the same device fetch as the token; refused with speculative
+  decoding on. ``slo`` is the ISSUE 13 service class:
   batch queues behind interactive and absorbs shedding/preemption
   first.
   Replies ``{"tokens": [...], "prompt_len": n, "truncated": null,
@@ -156,7 +160,7 @@ def _request_from_body(body: dict, *, kind: str, tokenizer=None) -> Request:
         raise ValueError("'prompt' must be a non-empty list of token ids")
     known = {
         "prompt", "text", "max_new_tokens", "temperature", "top_k",
-        "seed", "eos_id", "deadline_s", "top_n", "slo",
+        "seed", "eos_id", "deadline_s", "top_n", "slo", "logprobs",
         # ISSUE 16: idempotency / resume markers. The ROUTER consumes
         # these (journal dedupe, replay-and-skip) and strips them
         # before dispatch, but a replica must also tolerate them so a
@@ -190,6 +194,9 @@ def _request_from_body(body: dict, *, kind: str, tokenizer=None) -> Request:
         raise ValueError(
             "'slo' must be 'interactive' or 'batch'"
         )
+    logprobs = body.get("logprobs", False)
+    if not isinstance(logprobs, bool):
+        raise ValueError("'logprobs' must be true or false")
     pages = first_token = None
     if kind == "resume":
         pages = body.get("pages")
@@ -229,6 +236,7 @@ def _request_from_body(body: dict, *, kind: str, tokenizer=None) -> Request:
         deadline_s=number("deadline_s", None, float, 0.0),
         kind=kind,
         classify_top_n=number("top_n", 5, int, 1),
+        logprobs=logprobs,
         pages=pages,
         first_token=first_token,
         skip_tokens=(
@@ -349,6 +357,8 @@ class ServingFrontend:
             reply["pages"] = result.pages
         else:
             reply["tokens"] = result.tokens
+            if result.logprobs is not None:
+                reply["logprobs"] = result.logprobs
             if self.tokenizer is not None:
                 reply["text"] = self.tokenizer.decode(result.tokens)
         if result.spans:

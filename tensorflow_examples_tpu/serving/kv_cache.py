@@ -80,7 +80,8 @@ def gather_block_kv(blocks: jax.Array, block_tables: jax.Array,
     through). Returns [..., nb*BS, H, D]: only the gathered blocks are
     ever re-viewed, never the pool.
 
-    ``scales`` ([NB, BS, H], the int8/fp8 pools' per-row scales) selects
+    ``num_heads`` is the heads a row holds: the key/value heads of a
+    grouped-query model. ``scales`` ([NB, BS, H], the int8/fp8 pools' per-row scales) selects
     the dequantizing gather, to ``dtype``.
     """
     bs = blocks.shape[1]
@@ -209,6 +210,133 @@ def varlen_verify_attention(
         f"shtk,{kv}->sthd", p, v_cache,
         preferred_element_type=jnp.float32,
     ).astype(q.dtype)
+
+
+def _window_ok(query_pos, key_pos, window):
+    """Causal (and, with a window, ``0 <= i - j < W``) visibility of
+    key positions to query positions, broadcast together."""
+    ok = key_pos <= query_pos
+    if window is not None:
+        ok &= query_pos - key_pos < window
+    return ok
+
+
+def window_base(position, window, block_size: int):
+    """Absolute position of column 0 of a window kind's gathered view:
+    the start of the oldest logical block a query at ``position`` reads
+    (the block tables the engine uploads for a window kind begin
+    there); 0 for a full kind."""
+    if window is None:
+        return 0
+    return (jnp.maximum(position - window + 1, 0) // block_size) * block_size
+
+
+def grouped_decode_attention(
+    q: jax.Array,
+    k_blocks: jax.Array,
+    v_blocks: jax.Array,
+    positions: jax.Array,
+    block_tables: jax.Array,
+    *,
+    num_kv_heads: int,
+    window: int | None = None,
+    sm_scale: float | None = None,
+) -> jax.Array:
+    """:func:`varlen_decode_attention` over a paged layer for
+    grouped-query heads and, optionally, a window.
+
+    q: [S, H, D], slot s's query at ``positions[s]`` (its own K/V
+    already written). k_blocks / v_blocks: one layer's pool ``[NB, BS,
+    G*D]`` with ``G = num_kv_heads``; query head ``i`` reads KV head
+    ``i // (H / G)``. block_tables: [S, nb] — for a full layer the
+    slot's logical blocks from 0, for a window layer those from the
+    oldest block the query reads (:func:`window_base`), ``nb <= W / BS
+    + 1`` whatever the context. Slot s sees key positions ``j`` with
+    ``0 <= positions[s] - j`` (``< W`` under a window). Numerics as the
+    plain path: f32 scores and softmax, probabilities in the value
+    dtype, f32 accumulation. Returns [S, H, D]."""
+    s_n, h, d = q.shape
+    g = num_kv_heads
+    k, v = gather_layer_kv(k_blocks, v_blocks, block_tables, g, q.dtype)
+    if sm_scale is None:
+        sm_scale = d ** -0.5
+    scores = jnp.einsum(
+        "sgrd,skgd->sgrk", q.reshape(s_n, g, h // g, d), k,
+        preferred_element_type=jnp.float32,
+    ) * sm_scale
+    base = window_base(positions, window, k_blocks.shape[1])
+    key_pos = jnp.reshape(base, (-1, 1)) + jnp.arange(k.shape[1])[None, :]
+    ok = _window_ok(positions[:, None], key_pos, window)
+    scores = jnp.where(ok[:, None, None, :], scores, NEG_INF)
+    p = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+    return jnp.einsum(
+        "sgrk,skgd->sgrd", p, v, preferred_element_type=jnp.float32
+    ).astype(q.dtype).reshape(s_n, h, d)
+
+
+def grouped_chunk_attention(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    k_ctx: jax.Array | None = None,
+    v_ctx: jax.Array | None = None,
+    *,
+    ctx_len=0,
+    ctx_base=0,
+    window: int | None = None,
+    sm_scale: float | None = None,
+) -> jax.Array:
+    """Attention of one prompt chunk for grouped-query heads and,
+    optionally, a window: a prefill (no context) or one extend step.
+
+    q: [T, H, D], the chunk's queries at positions ``ctx_len + t``; k,
+    v: [T, G, D], the chunk's own keys and values (seen causally).
+    k_ctx, v_ctx: [C, G, D] — the cached context as gathered, column c
+    at position ``ctx_base + c``, of which only positions below
+    ``ctx_len`` are populated. One KV group at a time (``lax.map``), so
+    the scores that exist at once are ``[H / G, T, C + T]`` f32, not
+    all H heads'. Returns [T, H, D]."""
+    t_n, h, d = q.shape
+    g = k.shape[1]
+    if sm_scale is None:
+        sm_scale = d ** -0.5
+    q_pos = ctx_len + jnp.arange(t_n)
+    ok_tail = _window_ok(q_pos[:, None], q_pos[None, :], window)
+    if k_ctx is not None:
+        c_pos = ctx_base + jnp.arange(k_ctx.shape[0])
+        ok_ctx = _window_ok(q_pos[:, None], c_pos[None, :], window) & (
+            c_pos < ctx_len
+        )[None, :]
+
+    def one_group(args):
+        qg, kg, vg, kcg, vcg = args  # [R,T,D] [T,D] [T,D] [C,D] [C,D]
+        # The context's columns (where there is one) before the chunk's.
+        pieces = [(kg, vg, ok_tail)]
+        if kcg is not None:
+            pieces.insert(0, (kcg, vcg, ok_ctx))
+        prob = jax.nn.softmax(jnp.concatenate([
+            jnp.where(ok[None], jnp.einsum(
+                "rtd,kd->rtk", qg, kx, preferred_element_type=jnp.float32
+            ) * sm_scale, NEG_INF)
+            for kx, _, ok in pieces
+        ], axis=-1), axis=-1)
+        out, col = None, 0
+        for _, vx, _ in pieces:
+            part = jnp.einsum(
+                "rtk,kd->rtd",
+                prob[..., col:col + vx.shape[0]].astype(vx.dtype), vx,
+                preferred_element_type=jnp.float32,
+            )
+            out = part if out is None else out + part
+            col += vx.shape[0]
+        return out
+
+    by_group = lambda x: None if x is None else jnp.moveaxis(x, 1, 0)
+    out = jax.lax.map(one_group, (
+        jnp.moveaxis(q.reshape(t_n, g, h // g, d), (1, 2), (0, 1)),
+        by_group(k), by_group(v), by_group(k_ctx), by_group(v_ctx),
+    ))  # [G, R, T, D]
+    return jnp.moveaxis(out, 2, 0).reshape(t_n, h, d).astype(q.dtype)
 
 
 class KVCachePool:

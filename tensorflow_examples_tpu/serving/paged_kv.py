@@ -45,6 +45,23 @@ interface the engine/batcher already speak:
   stays token-identical to the dense reference; int8 is a measured
   bounded-divergence mode (tests pin both).
 
+* **Layer kinds** (ISSUE 28) — a layer is ``full`` (every token of the
+  context stays readable) or ``window(W)`` (query ``i`` reads keys
+  ``j`` with ``0 <= i - j < W``). Each kind is a block-id SPACE of its
+  own: its layers' arrays have the kind's ``NB``, and the pool keeps
+  one free list and one table per slot per kind. A window kind's table
+  is indexed by logical block like the other, but a block all of whose
+  positions have left the window goes back to the kind's free list
+  (``ensure_position`` / ``ensure_span``), so a slot never holds more
+  than ``(W + span) / BS + 1`` of them whatever its context, ``span``
+  being the most positions one step writes (the prefill chunk).
+  ``num_blocks`` counts the full kind's blocks; a window kind's ``NB``
+  follows from the slots, ``W``, ``span`` and ``BS``. A model whose
+  layers are all ``full`` (GPT-2) has one kind and nothing changes.
+  With a window kind present, prompt blocks are NOT shared across
+  requests (a hit would find released blocks): nothing is published or
+  looked up, the prefix cache stays empty.
+
 Occupancy telemetry splits what the dense pool conflated (ISSUE 8
 satellite): ``serving/kv_occupancy`` is the **used-block fraction**
 (the capacity signal the router tier load-balances on), while
@@ -80,6 +97,82 @@ class BlockExhausted(RuntimeError):
         self.slots = tuple(slots)
 
 
+class _WindowSpace:
+    """One window kind's block-id space: ``num_blocks`` physical blocks
+    (0 the null block), a free list, and one table per slot indexed by
+    LOGICAL block, ``NULL_BLOCK`` where a block was released or never
+    claimed. A slot's live blocks are the logical range ``[first[slot],
+    end[slot])``. No refcounts: a window kind's blocks are never
+    shared. The pool's lock guards every call."""
+
+    def __init__(self, window: int, num_slots: int, max_blocks: int,
+                 block_size: int, span: int):
+        if window < 1 or window % block_size:
+            raise ValueError(
+                f"window={window} must be a positive multiple of "
+                f"block_size={block_size}"
+            )
+        self.window = window
+        self.block_size = block_size
+        # Positions start-W+1 .. end-1 with end - start <= span: the
+        # most logical blocks one slot can hold live.
+        self.per_slot = min(max_blocks, (window + span) // block_size + 1)
+        self.num_blocks = num_slots * self.per_slot + 1
+        self.tables = np.full((num_slots, max_blocks), NULL_BLOCK, np.int32)
+        self.first = np.zeros((num_slots,), np.int32)
+        self.end = np.zeros((num_slots,), np.int32)
+        self.released_total = 0
+        self.reset()
+
+    def reset(self) -> None:
+        self.tables[:, :] = NULL_BLOCK
+        self.first[:] = 0
+        self.end[:] = 0
+        self.free = list(range(self.num_blocks - 1, 0, -1))
+
+    @property
+    def used(self) -> int:
+        return self.num_blocks - 1 - len(self.free)
+
+    def first_block(self, position: int) -> int:
+        """The oldest logical block a query at ``position`` reads."""
+        return max(position - self.window + 1, 0) // self.block_size
+
+    def cover(self, slot: int, start: int, end: int) -> int:
+        """Make the slot's table hold what queries at positions
+        ``[start, end)`` read and write: release every block wholly
+        older than ``start - W + 1``, claim the blocks up to the one
+        that holds ``end - 1``. Returns the number released."""
+        lo = self.first_block(start)
+        hi = (end - 1) // self.block_size + 1
+        first, last = int(self.first[slot]), int(self.end[slot])
+        released = 0
+        for i in range(first, min(lo, last)):
+            self.free.append(int(self.tables[slot, i]))
+            self.tables[slot, i] = NULL_BLOCK
+            released += 1
+        begin = max(last, lo)
+        if hi - begin > len(self.free):
+            # Cannot happen while per_slot is honoured; never silently.
+            raise BlockExhausted(
+                f"window({self.window}) block space exhausted: "
+                f"{self.num_blocks - 1} blocks all in use"
+            )
+        for i in range(begin, hi):
+            self.tables[slot, i] = self.free.pop()
+        self.first[slot] = max(first, lo)
+        self.end[slot] = max(last, hi)
+        self.released_total += released
+        return released
+
+    def release_slot(self, slot: int) -> None:
+        for i in range(int(self.first[slot]), int(self.end[slot])):
+            self.free.append(int(self.tables[slot, i]))
+        self.tables[slot, :] = NULL_BLOCK
+        self.first[slot] = 0
+        self.end[slot] = 0
+
+
 class PagedKVPool:
     """Paged drop-in for ``kv_cache.KVCachePool``: same slot interface
     (``alloc``/``free``/``reset``/``reallocate``/``lengths``/
@@ -112,7 +205,15 @@ class PagedKVPool:
         prefix_cache: bool = True,
         registry=None,
         sharding=None,
+        layer_windows=None,
+        window_span: int = 0,
     ):
+        """``num_heads`` is the heads a cache row holds (the key/value
+        heads of a grouped-query model). ``layer_windows``: one entry a
+        layer, ``None`` for a full layer or the window ``W``; omitted,
+        every layer is full. ``window_span``: the most positions one
+        step writes into a slot (the prefill chunk; 0 = ``max_len``),
+        which with ``W`` sizes a window kind's block space."""
         if num_slots < 1:
             raise ValueError(f"num_slots={num_slots} must be >= 1")
         if block_size < 1 or block_size & (block_size - 1):
@@ -162,7 +263,28 @@ class PagedKVPool:
                     "use kv_dtype='int8'"
                 )
         self.quantized = self.kv_dtype in ("int8", "fp8")
-        self.prefix_cache_enabled = bool(prefix_cache)
+        # Kinds: index 0 is the full kind (this object's own tables,
+        # free list and refcounts below), then one _WindowSpace per
+        # distinct window, ascending.
+        windows = tuple(layer_windows) if layer_windows is not None \
+            else (None,) * num_layers
+        if len(windows) != num_layers:
+            raise ValueError(
+                f"layer_windows has {len(windows)} entries for "
+                f"{num_layers} layers"
+            )
+        self.kinds = (None, *sorted({w for w in windows if w is not None}))
+        self.layer_kind = tuple(self.kinds.index(w) for w in windows)
+        # (The list never changes; each space's tables and free list
+        # are read and written under self._lock, like the full kind's.)
+        self._windows = [
+            _WindowSpace(w, num_slots, self.max_blocks_per_slot,
+                         block_size, int(window_span) or max_len)
+            for w in self.kinds[1:]
+        ]
+        # Sharing prompt blocks across requests needs every block of
+        # the prefix to be there still: not with a window kind.
+        self.prefix_cache_enabled = bool(prefix_cache) and not self._windows
         self._registry = registry
         self._sharding = sharding
         self._alloc_arrays()
@@ -215,8 +337,7 @@ class PagedKVPool:
         # gathers want. A trailing [..., BS, D=64] made XLA put NB
         # minor-most and convert the WHOLE pool on the way in and out
         # of every program (PERF.md, PR 26).
-        shape = (self.num_blocks, self.block_size,
-                 self.num_heads * self.head_dim)
+        row = self.num_heads * self.head_dim
         if self.kv_dtype == "fp8":
             from tensorflow_examples_tpu.core import precision
 
@@ -227,26 +348,39 @@ class PagedKVPool:
             store = self.dtype
         kw = {} if self._sharding is None else {"device": self._sharding}
 
-        def per_layer(make, shape, dtype):
+        def per_layer(make, minor, dtype):
+            # Each layer's array has the NB of the layer's kind.
             return tuple(
-                make(shape, dtype, **kw) for _ in range(self.num_layers)
+                make((self.kind_blocks(kind), self.block_size, minor),
+                     dtype, **kw)
+                for kind in self.layer_kind
             )
 
-        self.k = per_layer(jnp.zeros, shape, store)
-        self.v = per_layer(jnp.zeros, shape, store)
+        self.k = per_layer(jnp.zeros, row, store)
+        self.v = per_layer(jnp.zeros, row, store)
         if self.quantized:
-            sshape = shape[:-1] + (self.num_heads,)
-            self.k_scale = per_layer(jnp.ones, sshape, jnp.float32)
-            self.v_scale = per_layer(jnp.ones, sshape, jnp.float32)
+            self.k_scale = per_layer(jnp.ones, self.num_heads, jnp.float32)
+            self.v_scale = per_layer(jnp.ones, self.num_heads, jnp.float32)
         else:
             self.k_scale = self.v_scale = None
+
+    def kind_blocks(self, kind: int) -> int:
+        """Physical blocks (the null block included) of one kind."""
+        return self.num_blocks if kind == 0 \
+            else self._windows[kind - 1].num_blocks
+
+    def window_tables(self, kind: int) -> np.ndarray:
+        """A window kind's ``[num_slots, max_len // BS]`` table, by
+        logical block (read by the engine on the loop thread, like
+        ``block_tables``)."""
+        return self._windows[kind - 1].tables
 
     def kv_state(self) -> tuple:
         """The device state the engine's compiled steps donate and
         return (``set_kv_state`` reassigns from the outputs): ``(k, v)``
         or, quantized, ``(k, v, k_scale, v_scale)``, each a tuple of
         ``num_layers`` arrays — ``[NB, BS, H*D]`` payloads, ``[NB, BS,
-        H]`` scales."""
+        H]`` scales, ``NB`` that of the layer's kind."""
         if self.quantized:
             return (self.k, self.v, self.k_scale, self.v_scale)
         return (self.k, self.v)
@@ -301,6 +435,11 @@ class PagedKVPool:
         reg.gauge("serving/kv_blocks_total").set(usable)
         reg.gauge("serving/kv_tokens").set(int(self.lengths.sum()))
         reg.gauge("serving/prefix_cache_blocks").set(len(self._cache))
+        if self._windows:
+            reg.gauge("serving/kv_blocks_in_use_full").set(used)
+            reg.gauge("serving/kv_blocks_in_use_window").set(
+                sum(w.used for w in self._windows)
+            )
 
     def alloc(self) -> int | None:
         """Claim a free slot (None when every slot is taken). No blocks
@@ -324,6 +463,8 @@ class PagedKVPool:
                 self._release_block_locked(int(self.block_tables[slot, i]))
             self.block_tables[slot, :] = NULL_BLOCK
             self._slot_blocks[slot] = 0
+            for w in self._windows:
+                w.release_slot(slot)
             self.lengths[slot] = 0
             self._free_slots.append(slot)
             self._publish_locked()
@@ -343,6 +484,8 @@ class PagedKVPool:
             self._drop_cache_locked()
             self._free_blocks = list(range(self.num_blocks - 1, 0, -1))
             self._refcount[:] = 0
+            for w in self._windows:
+                w.reset()
             self.prefix_hits = 0
             self.prefix_misses = 0
             self._publish_locked()
@@ -355,11 +498,15 @@ class PagedKVPool:
     @property
     def occupancy(self) -> float:
         """Used-block fraction — what ``/health`` reports and the
-        router load-balances on. A full-slots pool of short prompts is
+        router load-balances on (the fullest kind's, where there are
+        several). A full-slots pool of short prompts is
         NOT full (that is the satellite fix: slot occupancy is
         published separately as ``serving/kv_slot_occupancy``)."""
         with self._lock:
-            return float((self._refcount > 0).sum()) / (self.num_blocks - 1)
+            return max([
+                float((self._refcount > 0).sum()) / (self.num_blocks - 1),
+                *(w.used / (w.num_blocks - 1) for w in self._windows),
+            ])
 
     def max_active_length(self) -> int:
         with self._lock:
@@ -447,6 +594,7 @@ class PagedKVPool:
         with self._lock:
             have = int(self._slot_blocks[slot])
             if need <= have:
+                self._cover_windows_locked(slot, position, position + 1)
                 return
             if need > self.max_blocks_per_slot:
                 raise ValueError(
@@ -464,6 +612,26 @@ class PagedKVPool:
                 self._refcount[bid] = 1
                 self.block_tables[slot, have + i] = bid
             self._slot_blocks[slot] = need
+            self._cover_windows_locked(slot, position, position + 1)
+            self._publish_locked()
+
+    def _cover_windows_locked(self, slot: int, start: int, end: int) -> None:
+        released = sum(w.cover(slot, start, end) for w in self._windows)
+        if released:
+            self._reg().counter(
+                "serving/kv_window_blocks_released_total"
+            ).inc(released)
+
+    def ensure_span(self, slot: int, start: int, end: int) -> None:
+        """Before a prefill or one of its chunks writes positions
+        ``[start, end)``: every window kind claims the blocks of the
+        span and releases what no query from ``start`` on can read. The
+        full kind's blocks were claimed whole at admission
+        (``claim_prompt_blocks``); a pool of one kind does nothing."""
+        if not self._windows:
+            return
+        with self._lock:
+            self._cover_windows_locked(slot, start, end)
             self._publish_locked()
 
     def covered_positions(self, slot: int) -> int:
@@ -629,22 +797,29 @@ class PagedKVPool:
 
     # -------------------------------------------------- byte accounting
 
-    def bytes_per_block(self) -> int:
+    def bytes_per_block(self, kind: int | None = None) -> int:
         """K+V device bytes one physical block commits (int8 payload +
-        its blockwise f32 row scales when quantized)."""
+        its blockwise f32 row scales when quantized) over the layers of
+        its ``kind`` — over every layer when the pool has one kind."""
         row = self.num_heads * self.head_dim
         if self.quantized:
             per = self.block_size * row * 1 + self.block_size * self.num_heads * 4
         else:
             per = self.block_size * row * jnp.dtype(self.dtype).itemsize
-        return int(2 * self.num_layers * per)
+        layers = self.num_layers if kind is None \
+            else self.layer_kind.count(kind)
+        return int(2 * layers * per)
 
     def used_bytes(self) -> int:
         """Cache bytes committed to the active request set — blocks
         actually referenced, not slots claimed. The number the tier-1
         memory-claim test compares against the dense pool's."""
         with self._lock:
-            return int((self._refcount > 0).sum()) * self.bytes_per_block()
+            return int((self._refcount > 0).sum()) * self.bytes_per_block(0) \
+                + sum(
+                    w.used * self.bytes_per_block(kind)
+                    for kind, w in enumerate(self._windows, 1)
+                )
 
     # ------------------------------------------------------------- stats
 

@@ -337,6 +337,21 @@ ALERT_STATES = ("firing", "resolved")
 INSTRUMENT_PREFIXES = ("serving/", "router/", "autoscaler/",
                        "precision/", "trace/", "alert/", "probe/")
 
+# Instruments of a model with experts and a pool of several layer kinds
+# (ISSUE 28; writers: serving/blocks.py `count_stats`, serving/engine.py
+# `decode`, serving/paged_kv.py; catalog: docs/observability.md). Counters unless
+# noted; `serving/moe_pairs_expert_<id>` is one counter per held expert.
+MOE_KV_KIND_INSTRUMENTS = (
+    "serving/moe_pairs_routed", "serving/moe_pairs_held",
+    "serving/moe_decode_pairs_held", "serving/moe_decode_experts_hit",
+    "serving/kv_window_blocks_released_total",
+    "serving/kv_sampled_bytes", "serving/kv_sampled_tokens",
+    "serving/kv_sampled_reach_bytes",
+    "serving/kv_blocks_in_use_full",    # gauge
+    "serving/kv_blocks_in_use_window",  # gauge
+)
+MOE_EXPERT_COUNTER_PREFIX = "serving/moe_pairs_expert_"
+
 # The per-host entry of a fleet line's "hosts" list: "host" is a
 # required int, and each of these is required numeric-or-null (the
 # writer side, fleet.VECTOR_KEYS, aliases FLEET_VECTOR_KEYS below — the

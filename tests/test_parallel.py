@@ -295,6 +295,29 @@ class TestExplicitEP:
         assert m % tm == 0 and tm == 128
         assert k % tk == 0 and tk <= k and tn <= n
 
+    def test_gmm_tiling_widens_for_wide_weights(self):
+        """Weights that a 2048-wide n tile divides take it, with the
+        widest k tile that keeps one weight tile within the byte
+        budget (the chip sweep of PR 28: fewer passes over the rows
+        won every time, tn = tk = 2048 did not fit VMEM), and row tiles
+        of 256, 128 for few rows. It is a rule on (m, k, n, itemsize),
+        not a table of shapes; narrower weights keep the cap."""
+        from tensorflow_examples_tpu.parallel.moe import (
+            GMM_TILE_CAP, GMM_WEIGHT_TILE_BYTES, _gmm_tiling,
+        )
+
+        assert _gmm_tiling(4096, 4096, 4096) == (256, 1024, 2048)
+        assert _gmm_tiling(256, 4096, 4096) == (128, 1024, 2048)
+        assert _gmm_tiling(512, 8192, 2048) == (256, 1024, 2048)
+        assert _gmm_tiling(384, 2048, 6144) == (128, 1024, 2048)
+        for m, k, n, item in [(4096, 4096, 4096, 2), (512, 8192, 2048, 2)]:
+            tm, tk, tn = _gmm_tiling(m, k, n, item)
+            assert tk * tn * item <= GMM_WEIGHT_TILE_BYTES and m % tm == 0
+        # float32 weights: the budget leaves no k tile wider than the cap
+        cap = GMM_TILE_CAP
+        assert _gmm_tiling(4096, 4096, 4096, 4) == (cap, cap, cap)
+        assert _gmm_tiling(4096, 4096, 3072) == (cap, cap, cap)  # 2048 does not divide n
+
     @pytest.mark.parametrize("top_k", [1, 2])
     def test_grouped_matches_scatter_impl(self, top_k):
         """The sort-based dropless ragged_dot path (the TPU hot path)

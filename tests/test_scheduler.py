@@ -453,6 +453,41 @@ class TestChunkedPrefillGolden:
         assert engine.post_warmup_recompiles() == 0
 
     @pytest.mark.timeout(300)
+    def test_decode_steps_between_chunks_leave_the_prompt_as_it_was(
+        self, chunk_engine
+    ):
+        """A slot in the middle of a chunked prefill holds blocks but
+        is not stepping: a decode step of the OTHER slots must hand it
+        a null table row, like an empty slot's. (It used to hand it its
+        own, and wrote a stand-in token's K/V over the prompt's
+        position 0: the same tokens more often than not, other
+        logits.) Logits, not tokens."""
+        engine = chunk_engine
+        engine.pool.reset()
+        rng = np.random.default_rng(3)
+        long = [int(t) for t in rng.integers(0, 50, (70,))]
+        short = [int(t) for t in rng.integers(0, 50, (5,))]
+
+        def chunked(between):
+            slot = engine.pool.alloc()
+            state, done = engine.prefill_open(slot, long), False
+            assert len(state.spans) > 2  # cold: nothing of it is cached
+            while not done:
+                between()
+                done, _, last = engine.prefill_step(state)
+            engine.pool.free(slot)
+            return last
+
+        alone = chunked(lambda: None)
+        engine.pool.reset()  # drops the prompt's published blocks too
+        other = engine.pool.alloc()
+        tok = [engine.prefill(other, short)[0]]
+        with_steps = chunked(lambda: tok.append(
+            engine.decode([(other, tok[-1], 0, 0.0, 0)])[other]
+        ))
+        engine.pool.free(other)
+        np.testing.assert_array_equal(with_steps, alone)
+
     def test_chunked_prefill_reuses_cached_prefix(self, chunk_engine):
         """A chunked admission still takes the prefix-cache hit: the
         cached context never re-chunks, only the cold tail does."""

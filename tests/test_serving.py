@@ -708,6 +708,35 @@ class TestFrontend:
         assert status == 200
         assert reply["top"] == eng.reference_classify([1, 2, 3], top_n=4)
 
+    @pytest.mark.timeout(120)
+    def test_generate_logprobs_over_http(self, live_frontend):
+        """``"logprobs": true``: one log-probability a token — the
+        first from the prefill's logits, the rest from the decode
+        steps' own fetch — each what ``/classify`` reports for that
+        token after the same prefix; the tokens as without it; on
+        ``/classify`` and as a non-boolean it is a 400."""
+        prompt = [17, 4, 99, 23]
+        url = live_frontend.url("/generate")
+        status, reply = _post(
+            url, {"prompt": prompt, "max_new_tokens": 4, "logprobs": True}
+        )
+        assert status == 200 and len(reply["logprobs"]) == 4
+        _, plain = _post(url, {"prompt": prompt, "max_new_tokens": 4})
+        assert plain["tokens"] == reply["tokens"] and "logprobs" not in plain
+        for k, (tok, lp) in enumerate(zip(reply["tokens"], reply["logprobs"])):
+            _, dist = _post(
+                live_frontend.url("/classify"),
+                {"prompt": prompt + reply["tokens"][:k], "top_n": 1000},
+            )
+            want = {e["token"]: e["logprob"] for e in dist["top"]}[tok]
+            assert abs(lp - want) < 1e-4, k
+        for path, body in (
+            ("/generate", {"prompt": prompt, "logprobs": 1}),
+            ("/classify", {"prompt": prompt, "logprobs": True}),
+        ):
+            status, err = _post(live_frontend.url(path), body)
+            assert status == 400 and "logprobs" in err["error"], body
+
     def test_bad_requests_are_400(self, live_frontend):
         url = live_frontend.url("/generate")
         for body in (

@@ -219,6 +219,21 @@ class TestSpeculativeGolden:
         assert eng.sentinel.compile_counts() == compiles_before
         assert eng.post_warmup_recompiles() == 0
 
+    def test_logprobs_are_refused_with_speculation_on(self, spec_engine):
+        """A verify step commits several tokens from one fetch of
+        tokens alone: a request that asks for log-probabilities is
+        refused at submit, by name, and nothing is queued."""
+        batcher = ContinuousBatcher(spec_engine).start()
+        try:
+            fut = batcher.submit(
+                Request(prompt=[3, 4, 5], max_new_tokens=4, logprobs=True)
+            )
+            with pytest.raises(ValueError, match="logprobs.*speculative"):
+                fut.result(timeout=30)
+            assert batcher.queue_depth() == 0
+        finally:
+            batcher.close(drain=True)
+
     @pytest.mark.timeout(300)
     def test_paged_token_identical_to_reference(self, paged_spec_engine):
         """The paged twin: same contract through block tables (the

@@ -62,6 +62,7 @@ from tensorflow_examples_tpu.core import precision as precision_mod
 from tensorflow_examples_tpu.models.transformer import TransformerConfig
 from tensorflow_examples_tpu.ops.attention import NEG_INF, attention_reference
 from tensorflow_examples_tpu.serving import kv_cache as kv_mod
+from tensorflow_examples_tpu.serving import launch_block
 from tensorflow_examples_tpu.serving.blocks import Gpt2Block, block_for
 from tensorflow_examples_tpu.telemetry import registry as registry_mod
 from tensorflow_examples_tpu.telemetry.compilation import CompilationSentinel
@@ -670,11 +671,15 @@ def request_key(seed: int, position: int) -> jax.Array:
     return jax.random.fold_in(jax.random.PRNGKey(seed), position)
 
 
-# Vmapped over per-slot (seed, position) vectors INSIDE the jitted
-# decode step — eager per-slot fold_in dispatches on the batcher loop
-# thread would sit between consecutive compiled decode steps, exactly
-# where TPOT is won or lost. Seeds are int32 (the frontend caps them at
-# 2**31 - 1) so the traced PRNGKey seeding matches the eager replay's.
+# Every program derives its keys INSIDE the trace, from the (seed,
+# position) its launch block carries — decode vmapped over the per-slot
+# vectors, a prefill, an extend or a chunk from its two scalars
+# (``InferenceEngine._operands``). An eager ``request_key`` on the
+# batcher loop thread is two tiny device programs with operands of
+# their own, sitting between consecutive compiled steps, exactly where
+# TPOT is won or lost; only the plain reference calls it eagerly. Seeds
+# are int32 (the frontend caps them at 2**31 - 1) so the traced
+# PRNGKey seeding matches the eager replay's.
 _request_key_batch = jax.vmap(request_key)
 
 
@@ -751,13 +756,6 @@ def _pack(tables: list):
     """A program's table argument: the bare array of a one-kind pool,
     a tuple of one array per kind otherwise (``_per_kind`` undoes it)."""
     return tables[0] if len(tables) == 1 else tuple(tables)
-
-
-def _upload(host):
-    """One host value to the device; a list is one table per kind."""
-    if isinstance(host, list):
-        return _pack([jnp.asarray(t) for t in host])
-    return jnp.asarray(host)
 
 
 class InferenceEngine:
@@ -1119,6 +1117,28 @@ class InferenceEngine:
                 )
                 for kb in self.kv_ladder
             } if self.cfg.spec_decode_k > 0 else {}
+        # The launch protocol: a program's per-launch operands travel as
+        # ONE packed int32 block (launch_block), laid out per (kind,
+        # rung) by ``_launch_spec`` and moved by ``_put`` — one
+        # transfer per launch, counted beside the launches themselves.
+        self._specs = {
+            (kind, rung): self._launch_spec(kind, rung)
+            for kind, fns in (
+                ("prefill", self._prefill_fns), ("decode", self._decode_fns),
+                ("extend", self._extend_fns), ("verify", self._verify_fns),
+            )
+            for rung in fns
+        }
+        # Where a block lives: the whole of it wherever params are —
+        # device 0, committed like the params and the pool, or every
+        # device of the mesh.
+        self._block_home = jax.devices()[0] if self.mesh is None else (
+            jax.sharding.NamedSharding(
+                self.mesh, jax.sharding.PartitionSpec()
+            )
+        )
+        self._transfers = reg.counter("serving/launch_transfers_total")
+        self._launches = reg.counter("serving/launches_total")
         self.warmed = False
         # The last decode step's log-probability of each slot's token.
         self.last_logprobs = np.zeros((cfg.max_slots,), np.float32)
@@ -1186,15 +1206,84 @@ class InferenceEngine:
             return NamedSharding(self.mesh, P(None, None, heads))
         return NamedSharding(self.mesh, P(None, None, heads, None, None))
 
+    # ------------------------------------------------- launch operands
+
+    def _launch_spec(self, kind: str, rung: int) -> tuple:
+        """The layout of one program's launch block (launch_block): its
+        operands in the order its step function reads them, a static
+        function of the program's kind, its rung, ``max_slots`` and the
+        pool's kinds. A table is one array per kind. The three
+        single-request programs end in (seed, position, temperature,
+        top_k): the two the sampling key is made of, in the trace."""
+        Field = launch_block.Field
+        s = self.cfg.max_slots
+        bs = self.cfg.kv_block_size
+        if kind in ("decode", "verify"):
+            wide = (s, self.cfg.spec_decode_k + 1) if kind == "verify" else (s,)
+            tables = [Field("tables", [
+                (s, nb) for nb in self._kind_blocks(rung // bs)
+            ])] if self.paged else []
+            return (
+                Field("tokens", wide), Field("positions", (s,)), *tables,
+                Field("seeds", (s,)), Field("temps", (s,), "float32"),
+                Field("top_ks", (s,)),
+            )
+        if kind == "extend":
+            where = (
+                Field("ctx_table", [(nb,) for nb in self._kind_blocks(
+                    self.pool.max_blocks_per_slot)]),
+                Field("tail_ids", [(rung // bs,)] * self._kinds),
+                Field("tokens", (1, rung)),
+                Field("ctx_len", ()), Field("tail_len", ()),
+            )
+        else:
+            where = (
+                Field("block_ids", [(rung // bs,)] * self._kinds)
+                if self.paged else Field("slot", ()),
+                Field("tokens", (1, rung)), Field("length", ()),
+            )
+        return (
+            *where, Field("seed", ()), Field("position", ()),
+            Field("temperature", (), "float32"), Field("top_k", ()),
+        )
+
+    def _operands(self, kind: str, rung: int, operands: tuple):
+        """A program's operands as its step function reads them. The
+        engine launches every program with ONE packed block: taken
+        apart here (static slices and bitcasts), and the sampling key
+        of a prefill, an extend or a chunk made here, in the trace,
+        from the block's (seed, position). The long form — every
+        operand an argument of its own, the key a ``uint32[2]`` — is
+        passed through: ``benchmark/sizing.py`` and ``sizing_kinds.py``
+        lower the programs that way (PERF.md §7)."""
+        if len(operands) > 1:
+            return operands
+        vals = [
+            _pack(v) if isinstance(v, list) else v
+            for v in launch_block.unpack(self._specs[kind, rung], operands[0])
+        ]
+        if kind in ("prefill", "extend"):
+            *where, seed, position, temp, top_k = vals
+            vals = [*where, request_key(seed, position), temp, top_k]
+        return vals
+
+    def _put(self, spec, values):
+        """A launch's operands to the device: ONE transfer."""
+        self._transfers.inc()
+        return jax.device_put(
+            launch_block.pack(spec, values), self._block_home
+        )
+
     # ----------------------------------------------------- compiled fns
 
-    def _prefill_impl(self, bucket, params, k_cache, v_cache, slot,
-                      tokens, length, key, temp, top_k):
+    def _prefill_impl(self, bucket, params, k_cache, v_cache, *operands):
         """tokens [1, bucket] (right-padded), length = true prompt len.
         Writes the slot's cache rows [0, bucket) (pad rows carry
         garbage K/V that per-slot length masking never reads), samples
         the first generated token from the logits at row length-1."""
-        del bucket  # static: encoded in tokens.shape
+        slot, tokens, length, key, temp, top_k = self._operands(
+            "prefill", bucket, operands
+        )
         logits, ks, vs = forward_full(
             self.model_cfg, params, tokens, impl=self._prefill_attn
         )
@@ -1209,8 +1298,10 @@ class InferenceEngine:
         )
         return k_cache, v_cache, _sample_row(key, last, temp, top_k), last
 
-    def _decode_impl(self, bucket, params, k_cache, v_cache, tokens,
-                     positions, seeds, temps, top_ks):
+    def _decode_impl(self, bucket, params, k_cache, v_cache, *operands):
+        tokens, positions, seeds, temps, top_ks = self._operands(
+            "decode", bucket, operands
+        )
         k_cache, v_cache, logits = _decode_forward(
             self.model_cfg, params, k_cache, v_cache, tokens, positions,
             kv_bucket=bucket,
@@ -1222,12 +1313,14 @@ class InferenceEngine:
             [toks, _token_logprobs(logits, toks)]
         )
 
-    def _verify_impl(self, bucket, params, k_cache, v_cache, tokens,
-                     positions, seeds, temps, top_ks):
+    def _verify_impl(self, bucket, params, k_cache, v_cache, *operands):
         """Speculative verify (ISSUE 11): tokens [S, T] = launch token
         + k drafts per slot, one forward, per-position sampling keys.
         Returns the caches and the sampled stream [S, T] the host's
         acceptance walks."""
+        tokens, positions, seeds, temps, top_ks = self._operands(
+            "verify", bucket, operands
+        )
         k_cache, v_cache, logits = _verify_forward(
             self.model_cfg, params, k_cache, v_cache, tokens, positions,
             kv_bucket=bucket,
@@ -1247,11 +1340,12 @@ class InferenceEngine:
             return tokens
         return jnp.concatenate([jnp.reshape(tokens, (-1,)), stats])
 
-    def _paged_prefill_impl(self, bucket, params, kv, block_ids, tokens,
-                            length, key, temp, top_k):
+    def _paged_prefill_impl(self, bucket, params, kv, *operands):
         """The paged twin of ``_prefill_impl``: same forward, K/V
         scattered into the slot's blocks instead of its dense extent."""
-        del bucket  # static: encoded in tokens.shape
+        block_ids, tokens, length, key, temp, top_k = self._operands(
+            "prefill", bucket, operands
+        )
         kv, x, stats = _paged_prefill_forward(
             self.model, params, kv, block_ids, tokens, length,
             self._layer_kind, block_size=self.cfg.kv_block_size,
@@ -1261,9 +1355,10 @@ class InferenceEngine:
         tok = _sample_row(key, last, temp, top_k)
         return kv, self._with_stats(tok, stats), last
 
-    def _paged_decode_impl(self, bucket, params, kv, tokens, positions,
-                           tables, seeds, temps, top_ks):
-        del bucket  # static: encoded in tables.shape
+    def _paged_decode_impl(self, bucket, params, kv, *operands):
+        tokens, positions, tables, seeds, temps, top_ks = self._operands(
+            "decode", bucket, operands
+        )
         kv, logits, stats = _paged_decode_forward(
             self.model, params, kv, tokens, positions, tables,
             self._layer_kind, block_size=self.cfg.kv_block_size,
@@ -1275,24 +1370,25 @@ class InferenceEngine:
             jnp.concatenate([toks, _token_logprobs(logits, toks)]), stats
         )
 
-    def _paged_verify_impl(self, bucket, params, kv, tokens, positions,
-                           tables, seeds, temps, top_ks):
+    def _paged_verify_impl(self, bucket, params, kv, *operands):
         """The paged twin of ``_verify_impl`` (same sampling contract;
         the verify attention keeps the gather path — its cost amortizes
         over T tokens)."""
-        del bucket  # static: encoded in tables.shape
+        tokens, positions, tables, seeds, temps, top_ks = self._operands(
+            "verify", bucket, operands
+        )
         kv, logits = _paged_verify_forward(
             self.model_cfg, params, kv, tokens, positions, tables,
             block_size=self.cfg.kv_block_size,
         )
         return kv, _sample_verify(seeds, positions, logits, temps, top_ks)
 
-    def _extend_impl(self, tail_bucket, params, kv, ctx_table, tail_ids,
-                     tokens, ctx_len, tail_len, key, temp, top_k):
+    def _extend_impl(self, tail_bucket, params, kv, *operands):
         """Prefix-cache hit path and chunked prefill: prefill only the
         prompt tail over the cached context (see ``_extend_forward``);
         samples the first token from the tail's last true row."""
-        del tail_bucket  # static: encoded in tokens.shape
+        (ctx_table, tail_ids, tokens, ctx_len, tail_len, key, temp,
+         top_k) = self._operands("extend", tail_bucket, operands)
         kv, x, stats = _extend_forward(
             self.model, params, kv, ctx_table, tail_ids, tokens,
             ctx_len, tail_len, self._layer_kind,
@@ -1371,91 +1467,20 @@ class InferenceEngine:
         pass). Returns per-fn compile counts; after this, any further
         compile is a sentinel-warned recompile and
         ``post_warmup_recompiles()`` counts it."""
-        s = self.cfg.max_slots
-        zero = jnp.zeros((), jnp.int32)
-        key = jax.random.PRNGKey(0)
-        ftemp = jnp.float32(0.0)
-        if self.paged:
-            bs = self.cfg.kv_block_size
-
-            def ztab(*lead, kinds):
-                # All-null tables of every kind, shaped as the step
-                # functions are called: ``lead`` then the kind's blocks.
-                return _pack([
-                    jnp.zeros((*lead, nb), jnp.int32) for nb in kinds
-                ])
-
-            for lb in self.prefill_ladder:
-                kv, tok, _ = self._prefill_fns[lb](
-                    self.params, self.pool.kv_state(),
-                    ztab(kinds=[lb // bs] * self._kinds),
-                    jnp.zeros((1, lb), jnp.int32), zero + 1, key, ftemp,
-                    zero,
-                )
-                self.pool.set_kv_state(kv)
-                tok.block_until_ready()
-            for kb in self.kv_ladder:
-                kv, toks = self._decode_fns[kb](
-                    self.params, self.pool.kv_state(),
-                    jnp.zeros((s,), jnp.int32), jnp.zeros((s,), jnp.int32),
-                    ztab(s, kinds=self._kind_blocks(kb // bs)),
-                    jnp.zeros((s,), jnp.int32),
-                    jnp.zeros((s,), jnp.float32),
-                    jnp.zeros((s,), jnp.int32),
-                )
-                self.pool.set_kv_state(kv)
-                toks.block_until_ready()
-            for tb in self._extend_fns:
-                kv, tok, _ = self._extend_fns[tb](
-                    self.params, self.pool.kv_state(),
-                    ztab(kinds=self._kind_blocks(
-                        self.pool.max_blocks_per_slot)),
-                    ztab(kinds=[tb // bs] * self._kinds),
-                    jnp.zeros((1, tb), jnp.int32), zero + bs, zero + 1,
-                    key, ftemp, zero,
-                )
-                self.pool.set_kv_state(kv)
-                tok.block_until_ready()
-            t_n = self.cfg.spec_decode_k + 1
-            for kb in self._verify_fns:
-                kv, toks = self._verify_fns[kb](
-                    self.params, self.pool.kv_state(),
-                    jnp.zeros((s, t_n), jnp.int32),
-                    jnp.zeros((s,), jnp.int32),
-                    jnp.zeros((s, kb // bs), jnp.int32),
-                    jnp.zeros((s,), jnp.int32),
-                    jnp.zeros((s,), jnp.float32),
-                    jnp.zeros((s,), jnp.int32),
-                )
-                self.pool.set_kv_state(kv)
-                toks.block_until_ready()
-        else:
-            for lb in self.prefill_ladder:
-                self.pool.k, self.pool.v, tok, _ = self._prefill_fns[lb](
-                    self.params, self.pool.k, self.pool.v, zero,
-                    jnp.zeros((1, lb), jnp.int32), zero + 1, key, ftemp,
-                    zero,
-                )
-                tok.block_until_ready()
-            for kb in self.kv_ladder:
-                self.pool.k, self.pool.v, toks = self._decode_fns[kb](
-                    self.params, self.pool.k, self.pool.v,
-                    jnp.zeros((s,), jnp.int32), jnp.zeros((s,), jnp.int32),
-                    jnp.zeros((s,), jnp.int32), jnp.zeros((s,), jnp.float32),
-                    jnp.zeros((s,), jnp.int32),
-                )
-                toks.block_until_ready()
-            t_n = self.cfg.spec_decode_k + 1
-            for kb in self._verify_fns:
-                self.pool.k, self.pool.v, toks = self._verify_fns[kb](
-                    self.params, self.pool.k, self.pool.v,
-                    jnp.zeros((s, t_n), jnp.int32),
-                    jnp.zeros((s,), jnp.int32),
-                    jnp.zeros((s,), jnp.int32),
-                    jnp.zeros((s,), jnp.float32),
-                    jnp.zeros((s,), jnp.int32),
-                )
-                toks.block_until_ready()
+        # Every program is compiled as it is served: one packed block
+        # of zeros (all-null tables: the writes land in the null block)
+        # through the launch path's own transfer.
+        bs = self.cfg.kv_block_size
+        for kind, fns, named in (
+            ("prefill", self._prefill_fns, dict(length=1)),
+            ("decode", self._decode_fns, {}),
+            ("extend", self._extend_fns, dict(ctx_len=bs, tail_len=1)),
+            ("verify", self._verify_fns, {}),
+        ):
+            for rung, fn in fns.items():
+                spec = self._specs[kind, rung]
+                block = self._put(spec, launch_block.zeros(spec, **named))
+                self._call(fn, block)[0].block_until_ready()
         self.pool.reset()
         self.warmed = True
         counts = self.sentinel.compile_counts()
@@ -1521,7 +1546,7 @@ class InferenceEngine:
 
     # ------------------------------------------------------ request ops
 
-    def _run_compiled(self, kind: str, fn, *args):
+    def _run_compiled(self, kind: str, fn, block):
         """Run one donated compiled step. On ANY runtime failure the
         donated KV buffers were consumed, so the pool is reallocated
         and :class:`EngineStepError` surfaces — the one place the
@@ -1536,19 +1561,36 @@ class InferenceEngine:
         programs (the zero-recompile sentinel stays golden-pinned).
         Its callers bracket the rest of a step the same way (ISSUE 25):
         ``engine_{kind}_build`` (numpy inputs, block tables),
-        ``engine_{kind}_upload`` (the ``jnp.asarray`` host->device
-        copies) and ``engine_{kind}_fetch`` (the one ``np.asarray`` that
-        waits for the device), so the serve thread has no unnamed
-        stretch between a step's first line and its tokens in hand."""
+        ``engine_{kind}_upload`` (``_put``: the operands packed into
+        one block and its one ``device_put``) and
+        ``engine_{kind}_fetch`` (the one ``np.asarray`` that waits for
+        the device), so the serve thread has no unnamed stretch between
+        a step's first line and its tokens in hand."""
         try:
             with host_span(f"engine_{kind}_dispatch"):
-                return fn(*args)
+                return self._call(fn, block)
         except Exception as e:
             self.pool.reallocate()
             raise EngineStepError(
                 f"compiled {kind} step failed (KV caches reallocated): "
                 f"{type(e).__name__}: {e}"
             ) from e
+
+    def _call(self, fn, block):
+        """One compiled launch, counted (``serving/launches_total``
+        beside ``serving/launch_transfers_total`` is the launch
+        protocol's engagement: 1 transfer a launch): params, the
+        pool's device state — donated — and the operand block in; the
+        state kept from what comes back, the rest returned."""
+        self._launches.inc()
+        if self.paged:
+            kv, *out = fn(self.params, self.pool.kv_state(), block)
+            self.pool.set_kv_state(kv)
+        else:
+            self.pool.k, self.pool.v, *out = fn(
+                self.params, self.pool.k, self.pool.v, block
+            )
+        return out
 
     def _prefill_fault_tick(self, slot: int) -> None:
         """Serve-side fault hook for PREFILL-role replicas (ISSUE 12):
@@ -1592,14 +1634,11 @@ class InferenceEngine:
                 tokens = np.zeros((1, bucket), np.int32)
                 tokens[0, :n] = prompt
             with host_span("engine_prefill_upload"):
-                args = (
-                    jnp.int32(slot), jnp.asarray(tokens), jnp.int32(n),
-                    request_key(seed, n), jnp.float32(temperature),
-                    jnp.int32(top_k),
-                )
-            (self.pool.k, self.pool.v, tok, last) = self._run_compiled(
-                "prefill", self._prefill_fns[bucket],
-                self.params, self.pool.k, self.pool.v, *args,
+                block = self._put(self._specs["prefill", bucket], (
+                    slot, tokens, n, seed, n, temperature, top_k,
+                ))
+            tok, last = self._run_compiled(
+                "prefill", self._prefill_fns[bucket], block
             )
         self.pool.lengths[slot] = n
         self.registry.counter("serving/prefill_tokens").inc(n)
@@ -1636,7 +1675,9 @@ class InferenceEngine:
                 ids = self._span_ids(slot, 0, total_blocks, bucket // bs)
                 tokens = np.zeros((1, bucket), np.int32)
                 tokens[0, :n] = prompt
-                host = (ids, tokens, np.int32(n))
+                kind, fns, host = "prefill", self._prefill_fns, (
+                    ids, tokens, n,
+                )
             else:
                 tail = n - ctx
                 bucket = kv_mod.pick_bucket(self.prefill_ladder, tail)
@@ -1645,31 +1686,23 @@ class InferenceEngine:
                 )
                 tokens = np.zeros((1, bucket), np.int32)
                 tokens[0, :tail] = prompt[ctx:]
-                host = (
+                kind, fns, host = "extend", self._extend_fns, (
                     self._kind_tables(
                         ctx, self.pool.max_blocks_per_slot, slot
                     ),
-                    tail_ids, tokens, np.int32(ctx), np.int32(tail),
+                    tail_ids, tokens, ctx, tail,
                 )
+        # The first token is drawn with the key of (seed, n), made in
+        # the program.
         with host_span("engine_prefill_upload"):
-            args = (
-                *map(_upload, host), request_key(seed, n),
-                jnp.float32(temperature), jnp.int32(top_k),
-            )
-        if ctx == 0:
-            kv, tok, last = self._run_compiled(
-                "prefill", self._prefill_fns[bucket],
-                self.params, self.pool.kv_state(), *args,
-            )
-        else:
-            kv, tok, last = self._run_compiled(
-                "prefill", self._extend_fns[bucket],
-                self.params, self.pool.kv_state(), *args,
-            )
+            block = self._put(self._specs[kind, bucket], (
+                *host, seed, n, temperature, top_k,
+            ))
+        tok, last = self._run_compiled("prefill", fns[bucket], block)
+        if ctx:
             self.registry.counter(
                 "serving/prefix_reused_tokens"
             ).inc(ctx)
-        self.pool.set_kv_state(kv)
         self.pool.insert_prefix(slot, prompt)
         return tok, last
 
@@ -1740,17 +1773,13 @@ class InferenceEngine:
             tokens = np.zeros((1, tb), np.int32)
             tokens[0, :tail] = prompt[start:end]
         with host_span("engine_prefill_upload"):
-            args = (
-                *map(_upload, (ctx_tables, tail_ids, tokens)),
-                jnp.int32(start), jnp.int32(tail),
-                request_key(state.seed, end),
-                jnp.float32(state.temperature), jnp.int32(state.top_k),
-            )
-        kv, tok, last = self._run_compiled(
-            "prefill", self._extend_fns[tb],
-            self.params, self.pool.kv_state(), *args,
+            block = self._put(self._specs["extend", tb], (
+                ctx_tables, tail_ids, tokens, start, tail,
+                state.seed, end, state.temperature, state.top_k,
+            ))
+        tok, last = self._run_compiled(
+            "prefill", self._extend_fns[tb], block
         )
-        self.pool.set_kv_state(kv)
         state.idx += 1
         self.registry.counter("serving/prefill_chunks").inc()
         if state.idx < len(state.spans):
@@ -2054,20 +2083,12 @@ class InferenceEngine:
                         for kind, w in enumerate(pool.kinds)
                     ))
         with host_span("engine_decode_upload"):
-            args = tuple(map(_upload, (
+            block = self._put(self._specs["decode", bucket], (
                 tokens, positions, *tables, seeds, temps, top_ks,
-            )))
-        if self.paged:
-            kv, out = self._run_compiled(
-                "decode", self._decode_fns[bucket],
-                self.params, self.pool.kv_state(), *args,
-            )
-            self.pool.set_kv_state(kv)
-        else:
-            self.pool.k, self.pool.v, out = self._run_compiled(
-                "decode", self._decode_fns[bucket],
-                self.params, self.pool.k, self.pool.v, *args,
-            )
+            ))
+        (out,) = self._run_compiled(
+            "decode", self._decode_fns[bucket], block
+        )
         with host_span("engine_decode_fetch"):
             out = np.asarray(out)
         # [S] tokens, [S] float32 log-probabilities as bits, the stats.
@@ -2179,22 +2200,14 @@ class InferenceEngine:
                 bs = self.cfg.kv_block_size
                 tables = (self._kind_tables(
                     positions, bucket // bs, live=slots
-                )[0],)
+                ),)
         with host_span("engine_verify_upload"):
-            args = tuple(map(jnp.asarray, (
+            block = self._put(self._specs["verify", bucket], (
                 tokens, positions, *tables, seeds, temps, top_ks,
-            )))
-        if self.paged:
-            kv, out = self._run_compiled(
-                "verify", self._verify_fns[bucket],
-                self.params, self.pool.kv_state(), *args,
-            )
-            self.pool.set_kv_state(kv)
-        else:
-            self.pool.k, self.pool.v, out = self._run_compiled(
-                "verify", self._verify_fns[bucket],
-                self.params, self.pool.k, self.pool.v, *args,
-            )
+            ))
+        (out,) = self._run_compiled(
+            "verify", self._verify_fns[bucket], block
+        )
         with host_span("engine_verify_fetch"):
             out = np.asarray(out)
         committed: dict[int, list[int]] = {}

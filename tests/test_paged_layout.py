@@ -25,6 +25,7 @@ import numpy as np
 import pytest
 
 from tensorflow_examples_tpu.models import transformer
+from tensorflow_examples_tpu.serving import launch_block
 from tensorflow_examples_tpu.serving.engine import InferenceEngine, ServeConfig
 
 pytestmark = pytest.mark.serving
@@ -110,7 +111,13 @@ def _pool_sized_results(hlo: str, sizes: set[int]) -> list[str]:
 
 @pytest.mark.parametrize("kv_dtype", ["", "int8"], ids=["f32", "int8"])
 @pytest.mark.parametrize("family", ["decode", "prefill", "extend"])
-def test_no_program_touches_the_whole_pool(described_chip, family, kv_dtype):
+@pytest.mark.parametrize("form", ["long", "packed"])
+def test_no_program_touches_the_whole_pool(described_chip, form, family,
+                                           kv_dtype):
+    """``long``: every operand an argument of its own, as
+    ``benchmark/sizing.py`` spells the programs out. ``packed``: params,
+    the pool and the ONE operand block — the form the engine launches,
+    so what is served is what is guarded."""
     sys.path.insert(0, REPO)
     try:
         from benchmark import sizing
@@ -124,6 +131,13 @@ def test_no_program_touches_the_whole_pool(described_chip, family, kv_dtype):
                            MODEL["d_model"])
     pool_bytes = sum(a.nbytes for a in jax.tree.leaves(state))
     fn, args = sizing.engine_programs(engine, described_chip)[family]
+    if form == "packed":
+        ladder = engine.kv_ladder if family == "decode" \
+            else engine.prefill_ladder
+        spec = engine._specs[family, ladder[-1]]
+        args = (*args[:2], jax.ShapeDtypeStruct(
+            (launch_block.size(spec),), np.int32, sharding=described_chip
+        ))
     compiled = fn.lower(*args).compile()
 
     sizes = {layer.size, layer.size * MODEL["num_layers"]}

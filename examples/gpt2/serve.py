@@ -53,19 +53,20 @@ flags.DEFINE_float("serve_watchdog_secs", 60.0,
 flags.DEFINE_float("stats_every", 10.0,
                    "seconds between serving.jsonl stats lines (0 disables)")
 flags.DEFINE_integer(
-    "kv_block_size", 0,
-    "paged KV block size (docs/serving.md; 0 = dense pool). Power of "
-    "two dividing the bucket floors and max_len; slot capacity then "
-    "scales with used tokens and shared prompt prefixes prefill once.")
+    "kv_block_size", 16,
+    "token rows per KV block (docs/serving.md). Power of two dividing "
+    "the bucket floors and max_len; a request holds the blocks its "
+    "tokens fill, and shared prompt prefixes prefill once.")
 flags.DEFINE_integer(
     "kv_blocks", 0,
-    "physical KV blocks (0 = dense-equivalent worst case); shrink to "
-    "bank the memory paging saves — exhaustion sheds load loudly (503)")
+    "physical KV blocks (0 = the worst case, max_slots x max_len / "
+    "kv_block_size); shrink to bank the memory paging saves — "
+    "exhaustion sheds load loudly (503)")
 flags.DEFINE_string(
     "kv_dtype", "",
     "KV cache storage dtype: '' (cache dtype), 'int8', or 'fp8' "
-    "(per-block scales; bounded-divergence modes — require "
-    "--kv_block_size; fp8 needs backend float8 support)")
+    "(per-row scales; bounded-divergence modes; fp8 needs backend "
+    "float8 support)")
 flags.DEFINE_string(
     "weight_dtype", "",
     "weight-only quantization (docs/serving.md quantization section): "
@@ -78,7 +79,7 @@ flags.DEFINE_string(
     "rule, scales inherit their weight's spec.")
 flags.DEFINE_boolean(
     "prefix_cache", True,
-    "reuse immutable full prompt blocks across requests (paged only)")
+    "reuse immutable full prompt blocks across requests")
 flags.DEFINE_integer(
     "spec_decode_k", 0,
     "speculative decoding draft window (docs/serving.md): verify up to "
@@ -93,7 +94,7 @@ flags.DEFINE_string(
     "decode_attention", "",
     "decode attention impl: '' (engine default), 'xla' (gather "
     "reference), 'flash' (Pallas prefill attend), or 'paged_flash' "
-    "(fused paged-decode kernel; requires --kv_block_size)")
+    "(fused paged-decode kernel)")
 flags.DEFINE_string(
     "role", "mixed",
     "fleet scheduling role (docs/serving.md scheduling section): "
@@ -106,8 +107,8 @@ flags.DEFINE_integer(
     "chunked prefill admission (docs/serving.md): split any cold "
     "prompt tail longer than this into block-aligned chunks run one "
     "per decode-loop iteration, so a long prefill interleaves with "
-    "decode steps. Requires --kv_block_size (+ prefix_cache) and must "
-    "be a multiple of it. 0 disables.")
+    "decode steps. Requires prefix_cache and must be a multiple of "
+    "--kv_block_size. 0 disables.")
 flags.DEFINE_boolean(
     "brownout", False,
     "overload brownout ladder (docs/serving.md overload section): "
@@ -286,7 +287,7 @@ def main(argv):
         set().union(*(x.devices() for x in jax.tree.leaves(tree)))
     )
     per_dev = lambda tree: tree_bytes(tree, per_device=True) / 2**20
-    kv = (engine.pool.k, engine.pool.v)  # per-layer tuples when paged
+    kv = (engine.pool.k, engine.pool.v)  # one array per layer each
     print(
         f"placement: params on {held(engine.params)} "
         f"({per_dev(engine.params):.0f} MiB each) and KV pool on "

@@ -37,13 +37,11 @@ dicts) and — repo-natively — sees through
 ``self._run_compiled(kind, fn, *args)``, the engine's one donation
 funnel, mapping ``donate_argnums`` onto ``args``. After a donating
 call, any read of the same expression (a name or dotted attribute
-chain) before it is reassigned flags. The engine's own patterns pass
-by construction (both live in ``InferenceEngine._call``): the dense
-pool's donated ``self.pool.k/v`` reassigned as targets of the very call
-statement, and the paged pool's ``self.pool.kv_state()`` — a fresh
-tuple of the per-layer ``[NB, BS, H*D]`` arrays, built in the call and
-never read again — replaced by ``self.pool.set_kv_state(kv)`` from the
-outputs.
+chain) before it is reassigned flags. The engine's own pattern passes
+by construction (``InferenceEngine._call``): the pool's
+``self.pool.kv_state()`` — a fresh tuple of the per-layer
+``[NB, BS, H*D]`` arrays, built in the call and never read again —
+replaced by ``self.pool.set_kv_state(kv)`` from the outputs.
 """
 
 from __future__ import annotations

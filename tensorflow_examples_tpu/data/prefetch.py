@@ -49,6 +49,7 @@ from typing import Iterator
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from tensorflow_examples_tpu.telemetry import registry as _telemetry_registry
 from tensorflow_examples_tpu.telemetry.spans import span as _trace_span
@@ -73,10 +74,28 @@ def put_batch(batch, sharding):
     """The one host→device placement path (used by loop and prefetch).
 
     Global-view semantics: every process passes the SAME full global
-    batch and ``device_put`` materializes each process's addressable
-    shards from it. For per-host data sources use ``put_local_batch``.
+    batch and each materializes its addressable shards from it. For
+    per-host data sources use ``put_local_batch``.
+
+    Across processes the shards are cut on the host
+    (``make_array_from_callback``): ``device_put`` of a host value onto
+    a sharding that spans processes first asserts, in a collective,
+    that every process passed the same value — a fast host would then
+    spend its input phase waiting for the slowest one's batch, and the
+    fleet's straggler attribution would see every host input-stalled.
     """
-    return jax.tree.map(lambda x: jax.device_put(jnp.asarray(x), sharding), batch)
+    if sharding.is_fully_addressable:
+        return jax.tree.map(
+            lambda x: jax.device_put(jnp.asarray(x), sharding), batch
+        )
+
+    def cut(x):
+        x = np.asarray(x)
+        return jax.make_array_from_callback(
+            x.shape, sharding, lambda index: x[index]
+        )
+
+    return jax.tree.map(cut, batch)
 
 
 def put_local_batch(batch, sharding):
@@ -87,8 +106,6 @@ def put_local_batch(batch, sharding):
     own shard of the data — and the result is one global jax.Array on
     ``sharding``. On a single process this is identical to ``put_batch``.
     """
-    import numpy as np
-
     return jax.tree.map(
         lambda x: jax.make_array_from_process_local_data(
             sharding, np.asarray(x)
@@ -107,8 +124,6 @@ def bundle_batches(it: Iterator, k: int) -> Iterator:
     would have run (the loop validates the step span divides by k, so
     a well-sized stream never hits this).
     """
-    import numpy as np
-
     while True:
         group = []
         for _ in range(k):

@@ -1,7 +1,7 @@
 """Fused Pallas paged-decode attention: block-table gather + varlen
 masked attention in ONE kernel (ISSUE 11 tentpole).
 
-The paged serving decode step (``serving/engine._paged_decode_forward``)
+The paged serving decode step (``serving/engine._forward_decode``)
 previously ran two XLA programs per layer: a gather that materializes
 each slot's contiguous cache view out of the block pool
 (``kv_cache.gather_block_kv`` — O(bucket) HBM *writes* per step for
@@ -143,7 +143,7 @@ def _make_paged_decode(num_slots, num_heads, nb, block_size, head_dim,
                        quantized, interpret):
     """One compiled variant per (slots, heads, table width, block
     geometry, quantization, interpret) — the engine's KV bucket ladder
-    keys the table width, mirroring the dense decode rungs."""
+    keys the table width."""
 
     def kv_index(s, j, len_ref, tbl_ref):
         # Clamp unpopulated blocks to the last populated one: the

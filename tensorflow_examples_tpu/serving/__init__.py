@@ -7,15 +7,15 @@ TPU-friendly static shapes.
 
 Layout (one module per concern, mirroring the training stack):
 
-* ``kv_cache.py``  — preallocated slot-granular KV cache pool with
-  per-slot length tracking and the variable-length decode attention
-  that reads it (the per-slot generalization of
-  ``ops/decode.flash_decode_attention``'s populated-prefix contract),
-  plus its gather-by-block-table path for the paged pool.
-* ``paged_kv.py``  — ISSUE 8: the block-paged pool behind the same
-  interface — free-list block allocator with loud exhaustion, prefix
-  cache reusing immutable full prompt blocks (shared system prompts
-  prefill once), optional int8 KV with per-block scales.
+* ``kv_cache.py``  — the bucket ladders, the gather of a slot's view
+  out of the pool by its block table, and the variable-length decode
+  and verify attentions that read it (the per-slot generalization of
+  ``ops/decode.flash_decode_attention``'s populated-prefix contract).
+* ``paged_kv.py``  — ISSUE 8: the KV pool, the only one — slots with
+  per-slot length tracking over block-granular storage, a free-list
+  block allocator with loud exhaustion, a prefix cache reusing
+  immutable full prompt blocks (shared system prompts prefill once),
+  optional int8/fp8 KV with per-row scales.
 * ``router.py``    — ISSUE 8/10: the fleet tier — an HTTP router over
   N engine replicas with load-aware dispatch from ``/health`` probes,
   drain-aware rollout, per-replica circuit breakers, bounded
@@ -30,9 +30,9 @@ Layout (one module per concern, mirroring the training stack):
   :class:`~.chaos.ChaosFleet` (replicas + hardened router +
   supervisor) for the chaos acceptance tier and ``serve_bench
   --chaos``.
-* ``engine.py``    — the compiled serving step: bucketed prefill +
-  fixed-shape continuous decode, warmed up ahead of traffic over the
-  padding-bucket ladder and wrapped in the PR-3 recompilation sentinel
+* ``engine.py``    — the compiled serving step: bucketed prefill and
+  extend + fixed-shape continuous decode, warmed up ahead of traffic
+  over the padding-bucket ladder and wrapped in the PR-3 recompilation sentinel
   so steady-state serving is provably zero-recompile. ISSUE 11 adds
   the speculative ``verify_k`` rungs (score k draft tokens in one
   forward, commit the longest agreeing prefix, token-identical by
@@ -76,7 +76,6 @@ from tensorflow_examples_tpu.serving.frontend import (  # noqa: F401
     ServingFrontend,
     run_until_preempted,
 )
-from tensorflow_examples_tpu.serving.kv_cache import KVCachePool  # noqa: F401
 from tensorflow_examples_tpu.serving.paged_kv import (  # noqa: F401
     BlockExhausted,
     PagedKVPool,

@@ -130,8 +130,7 @@ class Request:
     runs the prompt to completion-of-prefill and resolves with the
     first generated token plus the slot's serialized KV pages
     (``Result.pages``); ``resume`` imports ``pages``/``first_token``
-    from a prefill replica and continues the decode stream. Both
-    require the paged pool."""
+    from a prefill replica and continues the decode stream."""
 
     prompt: list[int]
     max_new_tokens: int = 16
@@ -368,17 +367,6 @@ class ContinuousBatcher:
         )
         if req.kind not in ("generate", "classify", "prefill", "resume"):
             fut.set_exception(ValueError(f"unknown kind {req.kind!r}"))
-            reg.counter("serving/rejected_total").inc()
-            return fut
-        if req.kind in ("prefill", "resume") and not getattr(
-            self.engine, "paged", False
-        ):
-            # The handoff verbs move KV as serialized pages — only the
-            # block-paged pool has a page to move.
-            fut.set_exception(ValueError(
-                "disaggregated prefill/decode requires the paged KV "
-                "pool (set kv_block_size)"
-            ))
             reg.counter("serving/rejected_total").inc()
             return fut
         if req.logprobs and (
@@ -1237,7 +1225,7 @@ class ContinuousBatcher:
         counterpart of the training window line (validated in tier-1;
         the frontend serves the latest one at ``/window`` and
         examples/gpt2/serve.py appends them to ``serving.jsonl``).
-        Paged pools (serving/paged_kv.py) add their block/prefix-cache
+        The pool (serving/paged_kv.py) adds its block/prefix-cache
         fields to the ``serving`` object — the v6 additions."""
         reg = self.registry
         counters = {
@@ -1302,9 +1290,7 @@ class ContinuousBatcher:
         serving["brownout_transitions"] = int(
             self._overload.transitions()
         )
-        paged = getattr(self.engine.pool, "paged_stats", None)
-        if callable(paged):
-            serving.update(paged())
+        serving.update(self.engine.pool.paged_stats())
         # Schema-v11 precision keys (ISSUE 15): what precision this
         # replica is actually serving at and what it costs vs f32 —
         # stamped only when the engine holds quantized weights (an

@@ -1,7 +1,7 @@
 """The blocks the serving engine can run, behind one interface.
 
 The engine's forwards (``serving/engine.py``: prefill, extend, decode,
-and GPT-2's dense and verify paths) are each ONE loop
+and GPT-2's verify) are each ONE loop
 
     x = model.embed(params, tokens, positions)
     for layer: x, stats = model.block(params, x, layer, positions, attend, valid)

@@ -10,20 +10,27 @@ engine runs comes from a FINITE, warmed-up ladder:
   bucket (``ServeConfig.prefill_bucket_floor`` up to the model's
   ``max_len``) and runs batch-1: one compiled program per rung.
   Causal masking makes the pad rows inert — the true prompt length
-  rides in as a traced scalar that only picks the logits row and the
-  cache write extent.
+  rides in as a traced scalar that only picks the logits row. The
+  prompt's K/V are scattered into the slot's blocks of the ONE KV pool
+  (``paged_kv.PagedKVPool``: per layer ``[NB, BS, H*D]`` blocks behind
+  per-slot block tables).
+* **Extend** runs only a prompt's TAIL over context already in the
+  pool — a prefix-cache hit, or one chunk of a chunked prefill — one
+  program per tail bucket of the same ladder.
 * **Decode** always runs the full ``[max_slots]`` batch — continuous
   batching means the batch composition changes every step, so the
   batch *shape* must not. Per-slot state (token, position, sampling
-  key/temperature/top-k) rides in as traced vectors; the KV cache is
-  sliced to the smallest power-of-two bucket covering the longest
+  seed/temperature/top-k) rides in as traced vectors; the block tables
+  are cut to the smallest power-of-two bucket covering the longest
   active request (``kv_bucket_floor`` ladder), so short-context steps
-  read O(bucket) cache bytes — the serving-side mirror of
+  gather O(bucket) cache bytes — the serving-side mirror of
   ``ops/decode.flash_decode_attention``'s populated-prefix ladder,
   which the prefill path reuses directly under ``attention="flash"``
   (its scalar-length contract matches prefill exactly; the per-slot
   length *vector* of continuous decode is what
   ``kv_cache.varlen_decode_attention`` generalizes).
+* **Verify** (``spec_decode_k > 0``) is decode over T = k+1 rows a
+  slot, on the decode ladder.
 
 ``warmup()`` compiles the entire ladder ahead of traffic (the
 AOT-compiled serving path: every program exists before the first
@@ -63,6 +70,7 @@ from tensorflow_examples_tpu.models.transformer import TransformerConfig
 from tensorflow_examples_tpu.ops.attention import NEG_INF, attention_reference
 from tensorflow_examples_tpu.serving import kv_cache as kv_mod
 from tensorflow_examples_tpu.serving import launch_block
+from tensorflow_examples_tpu.serving import paged_kv
 from tensorflow_examples_tpu.serving.blocks import Gpt2Block, block_for
 from tensorflow_examples_tpu.telemetry import registry as registry_mod
 from tensorflow_examples_tpu.telemetry.compilation import CompilationSentinel
@@ -81,8 +89,7 @@ class ServeConfig:
     kv_bucket_floor: int = 64
     attention: str = "xla"       # xla | flash (Pallas prefill attend) |
     #                              paged_flash (fused Pallas paged-decode
-    #                              kernel, ops/paged_decode.py; requires
-    #                              the paged pool)
+    #                              kernel, ops/paged_decode.py)
     cache_dtype: str = ""        # "" -> follow the params dtype
     # ---- weight quantization (core/precision.py registry; ISSUE 15) ----
     weight_dtype: str = ""       # "" (serve the tree as restored) |
@@ -107,11 +114,12 @@ class ServeConfig:
     #                              speculative (no second model)
     draft_ngram: int = 3         # longest n-gram the drafter matches
     # ---- paged KV (serving/paged_kv.py; ISSUE 8) ----
-    kv_block_size: int = 0       # 0 -> dense pool (legacy); else paged,
-    #                              power of two dividing both bucket
-    #                              floors and max_len
-    kv_blocks: int = 0           # physical blocks; 0 -> dense-equivalent
-    #                              worst case (slots * max_len / block)
+    kv_block_size: int = 16      # token rows per KV block: a power of
+    #                              two dividing both bucket floors and
+    #                              max_len
+    kv_blocks: int = 0           # physical blocks; 0 -> the worst case
+    #                              (slots * max_len / block: every slot
+    #                              at max_len at once)
     kv_dtype: str = ""           # "" -> cache_dtype | "int8" (per-block
     #                              scales, bounded-divergence mode)
     prefix_cache: bool = True    # reuse immutable full prompt blocks
@@ -134,9 +142,8 @@ class ServeConfig:
     #                              extend rungs, so a long prefill
     #                              interleaves with decode steps
     #                              instead of monopolizing them.
-    #                              Requires the paged pool with
-    #                              prefix_cache=True; must be a
-    #                              multiple of kv_block_size.
+    #                              Requires prefix_cache=True; must
+    #                              be a multiple of kv_block_size.
     # ---- continuous batcher (serving/batcher.py) ----
     max_batch: int = 0           # admission cap; 0 -> max_slots
     max_queue: int = 64          # bounded queue PER SLO CLASS: beyond
@@ -161,6 +168,15 @@ class ServeConfig:
     # ---- frontend ----
     request_timeout_s: float = 120.0
 
+    def __post_init__(self):
+        if self.kv_block_size <= 0:
+            raise ValueError(
+                f"kv_block_size={self.kv_block_size}: the dense "
+                "(un-paged) KV pool that 0 used to select is gone; the "
+                "engine serves from the paged pool alone (kv_block_size "
+                "is a power of two, 16 by default)"
+            )
+
 
 # --------------------------------------------------------------- forward
 #
@@ -168,10 +184,9 @@ class ServeConfig:
 # (serving/blocks.py): ``model.embed``, ``model.block(params, x, layer,
 # positions, attend, valid)`` per layer, ``model.head``. They differ only
 # in where ``attend(q, k, v)`` finds K and V — the fresh prompt, the
-# dense per-slot cache, the paged pool through a block table — and in
-# what they write. The dense-cache and verify forwards serve GPT-2 alone
-# (the engine refuses them for any other block at construction); the
-# three paged forwards serve every block.
+# pool through a block table — and in what they write. The verify
+# forward serves GPT-2 alone (the engine refuses it for any other block
+# at construction); prefill, decode and extend serve every block.
 
 
 def _prefill_attend(q, k, v, *, impl: str):
@@ -222,115 +237,26 @@ def _per_kind(tables) -> tuple:
     return tables if isinstance(tables, tuple) else (tables,)
 
 
-def forward_full(cfg: TransformerConfig, params, tokens, *, impl="xla"):
-    """Full causal forward of ``tokens`` [B, L]: logits [B, L, V] plus
-    the per-layer K/V ([2, num_layers, B, H, L, hd]) the dense prefill
-    path writes into the cache. Also the engine's cacheless reference
-    path (which recomputes attention over the whole prefix per emitted
-    token). GPT-2 only."""
+def forward_full(cfg: TransformerConfig, params, tokens):
+    """Full causal forward of ``tokens`` [B, L]: logits [B, L, V]. The
+    engine's cacheless reference path (which recomputes attention over
+    the whole prefix per emitted token). GPT-2 only."""
     model = Gpt2Block(cfg)
-    ks, vs = [], []
-
-    def attend(q, k, v):
-        ks.append(k)
-        vs.append(v)
-        return _prefill_attend(q, k, v, impl=impl)
-
     positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)
     x, _ = _run_blocks(
         model, params, model.embed(params, tokens, positions), positions,
-        lambda layer: attend,
+        lambda layer: functools.partial(_prefill_attend, impl="xla"),
     )
-    return model.head(params, x), jnp.stack(ks), jnp.stack(vs)
+    return model.head(params, x)
 
 
-def _decode_forward(cfg: TransformerConfig, params, k_cache, v_cache,
-                    tokens, positions, *, kv_bucket: int):
-    """One continuous-decode step over every slot (dense cache, GPT-2).
-
-    tokens/positions: [S] — each slot's input token and the cache row
-    it occupies (= the slot's pre-step populated length). Returns the
-    updated caches and next-token logits [S, V]. Slots not actively
-    decoding ride along with position 0: their write lands in a row a
-    future prefill fully overwrites, and their output is discarded.
-    """
-    model = Gpt2Block(cfg)
-    idx = jnp.arange(tokens.shape[0])
-    lengths = positions + 1  # populated length including the new token
-    cache = [k_cache, v_cache]
-
-    def attend_for(layer):
-        def attend(q, k, v):  # [S, H, hd]
-            cache[0] = cache[0].at[layer, idx, :, positions, :].set(
-                k.astype(cache[0].dtype)
-            )
-            cache[1] = cache[1].at[layer, idx, :, positions, :].set(
-                v.astype(cache[1].dtype)
-            )
-            return kv_mod.varlen_decode_attention(
-                q,
-                jax.lax.slice_in_dim(cache[0][layer], 0, kv_bucket, axis=2),
-                jax.lax.slice_in_dim(cache[1][layer], 0, kv_bucket, axis=2),
-                lengths,
-            )
-        return attend
-
-    x, _ = _run_blocks(
-        model, params, model.embed(params, tokens, positions), positions,
-        attend_for,
-    )
-    return cache[0], cache[1], model.head(params, x)
-
-
-def _verify_forward(cfg: TransformerConfig, params, k_cache, v_cache,
-                    tokens, positions, *, kv_bucket: int):
-    """The speculative ``verify_k`` step (ISSUE 11; dense cache,
-    GPT-2): score T = k+1 tokens per slot in ONE forward. ``tokens``
-    [S, T] holds each slot's launch token followed by its k draft
-    tokens; row t lands in cache row ``positions[s] + t`` and attends
-    its own populated prefix (``kv_cache.varlen_verify_attention``).
-    Returns the updated caches and logits [S, T, V]. T=1 is numerically
-    the plain decode step.
-
-    Rows past ``max_len`` (a short-budget slot padded to the fixed T)
-    are dropped by scatter semantics and their logits discarded —
-    acceptance (host side) never commits past the rows that landed.
-    """
-    model = Gpt2Block(cfg)
-    s_n, t_n = tokens.shape
-    pos_grid = positions[:, None] + jnp.arange(t_n, dtype=jnp.int32)
-    idx = jnp.arange(s_n)
-    cache = [k_cache, v_cache]
-
-    def attend_for(layer):
-        def attend(q, k, v):  # [S, T, H, hd]
-            cache[0] = cache[0].at[layer, idx[:, None], :, pos_grid, :].set(
-                k.astype(cache[0].dtype)
-            )
-            cache[1] = cache[1].at[layer, idx[:, None], :, pos_grid, :].set(
-                v.astype(cache[1].dtype)
-            )
-            return kv_mod.varlen_verify_attention(
-                q,
-                jax.lax.slice_in_dim(cache[0][layer], 0, kv_bucket, axis=2),
-                jax.lax.slice_in_dim(cache[1][layer], 0, kv_bucket, axis=2),
-                positions,
-            )
-        return attend
-
-    x = model.embed(params, tokens, jnp.minimum(pos_grid, cfg.max_len - 1))
-    x, _ = _run_blocks(model, params, x, pos_grid, attend_for)
-    return cache[0], cache[1], model.head(params, x)
-
-
-# ---------------------------------------------------------- paged forward
+# ------------------------------------------------------- the pool's forwards
 #
-# The paged mirrors of the dense cache ops (ISSUE 8): same math, but
 # K/V land in per-layer [NB, BS, Hkv*D] block pools addressed through
-# per-slot block tables instead of a per-slot max_len extent. ``kv`` is
-# the pool's device state — (k, v) or, quantized, (k, v, k_scale,
-# v_scale), each a tuple of one array per layer, with per-row scales
-# stored blockwise ([NB, BS, H]; core/precision.quantize_rows). No
+# per-slot block tables (ISSUE 8; paged_kv.py). ``kv`` is the pool's
+# device state — (k, v) or, quantized, (k, v, k_scale, v_scale), each
+# a tuple of one array per layer, with per-row scales stored blockwise
+# ([NB, BS, H]; core/precision.quantize_rows). No
 # program reads or writes more of a layer's array than the blocks its
 # tables name: writes are in-place scatters of token rows, reads gather
 # blocks and re-view only what they gathered. A layer's blocks are
@@ -391,8 +317,8 @@ def _layer_scales(kv, layer) -> dict:
     return {}
 
 
-def _paged_prefill_forward(model, params, kv, block_ids, tokens, length,
-                           layer_kind, *, block_size: int, impl: str):
+def _forward_prefill(model, params, kv, block_ids, tokens, length,
+                     layer_kind, *, block_size: int, impl: str):
     """A whole prompt, ``tokens`` [1, bucket] right-padded: causal
     self-attention over the fresh K/V, which are then scattered into
     the slot's blocks. Returns the pool state, the final hidden state
@@ -421,9 +347,9 @@ def _paged_prefill_forward(model, params, kv, block_ids, tokens, length,
     return kv, x, stats
 
 
-def _paged_decode_forward(model, params, kv, tokens, positions, tables,
-                          layer_kind, *, block_size: int,
-                          attention: str = "xla"):
+def _forward_decode(model, params, kv, tokens, positions, tables,
+                    layer_kind, *, block_size: int,
+                    attention: str = "xla"):
     """One continuous-decode step over every slot of the paged pool:
     writes route through the block table, attention gathers by it (the
     ``varlen_decode_attention`` block-table path). Under
@@ -488,14 +414,19 @@ def _paged_decode_forward(model, params, kv, tokens, positions, tables,
     return state[0], model.head(params, x), stats
 
 
-def _paged_verify_forward(cfg: TransformerConfig, params, kv, tokens,
-                          positions, tables, *, block_size: int):
-    """The paged twin of ``_verify_forward`` (GPT-2): T rows per slot
-    scattered through the block table (the spec window may cross block
-    boundaries), attention over the slot's gathered view. Rows beyond a
-    slot's allocated blocks — draft padding the pool could not or need
-    not back — resolve to the null block, whose garbage acceptance
-    never commits."""
+def _forward_verify(cfg: TransformerConfig, params, kv, tokens,
+                    positions, tables, *, block_size: int):
+    """The speculative ``verify_k`` step (ISSUE 11; GPT-2): score
+    T = k+1 tokens per slot in ONE forward. ``tokens`` [S, T] holds each
+    slot's launch token followed by its k draft tokens; row t lands at
+    position ``positions[s] + t``, scattered through the block table
+    (the spec window may cross block boundaries), and attends its own
+    populated prefix over the slot's gathered view
+    (``kv_cache.varlen_verify_attention``). Returns the pool state and
+    logits [S, T, V]; T=1 is numerically the plain decode step. Rows
+    beyond a slot's allocated blocks — draft padding the pool could not
+    or need not back — resolve to the null block, whose garbage
+    acceptance (host side) never commits."""
     model = Gpt2Block(cfg)
     s_n, t_n = tokens.shape
     nb = tables.shape[1]
@@ -558,7 +489,7 @@ def _plain_extend_attention(q, k, v, kc, vc, ctx_len, sm_scale):
     return out.astype(q.dtype)
 
 
-def _extend_forward(model, params, kv, ctx_table, tail_ids, tokens,
+def _forward_extend(model, params, kv, ctx_table, tail_ids, tokens,
                     ctx_len, tail_len, layer_kind, *, block_size: int):
     """Chunked prefill on top of a cached context: run only the prompt
     TAIL (``tokens`` [1, tb], absolute positions ``ctx_len + i``), with
@@ -907,12 +838,6 @@ class InferenceEngine:
             if self.cfg.cache_dtype
             else param_dtype
         )
-        self.paged = self.cfg.kv_block_size > 0
-        if self.cfg.attention == "paged_flash" and not self.paged:
-            raise ValueError(
-                "attention='paged_flash' is the fused paged-decode "
-                "kernel — it requires the paged pool (set kv_block_size)"
-            )
         if self.cfg.attention == "paged_flash" and self.kv_dtype == "fp8":
             raise ValueError(
                 "attention='paged_flash' dequantizes int8 in-kernel; "
@@ -930,11 +855,11 @@ class InferenceEngine:
                 "must be >= 0"
             )
         if self.cfg.prefill_chunk_tokens:
-            if not self.paged or not self.cfg.prefix_cache:
+            if not self.cfg.prefix_cache:
                 raise ValueError(
-                    "prefill_chunk_tokens requires the paged pool with "
-                    "prefix_cache=True (the chunk program IS the "
-                    "per-tail-bucket extend rung)"
+                    "prefill_chunk_tokens requires prefix_cache=True "
+                    "(the chunk program IS the per-tail-bucket extend "
+                    "rung)"
                 )
             if self.cfg.prefill_chunk_tokens % self.cfg.kv_block_size:
                 raise ValueError(
@@ -956,62 +881,40 @@ class InferenceEngine:
                 f"exceed prefill_bucket_floor="
                 f"{self.cfg.prefill_bucket_floor}"
             )
-        if self.paged:
-            bs = self.cfg.kv_block_size
-            for name, val in (
-                ("prefill_bucket_floor", self.cfg.prefill_bucket_floor),
-                ("kv_bucket_floor", self.cfg.kv_bucket_floor),
-                ("max_len", model_cfg.max_len),
-            ):
-                if val % bs:
-                    raise ValueError(
-                        f"kv_block_size={bs} must divide {name}={val} "
-                        "(every compiled bucket is a whole number of "
-                        "blocks)"
-                    )
-            from tensorflow_examples_tpu.serving.paged_kv import (
-                PagedKVPool,
-            )
-
-            # A cache row holds the KEY/VALUE heads (fewer than the
-            # query heads under grouped-query attention); kv_blocks
-            # counts the full kind's blocks, a window kind's follow
-            # from the slots, its W, the chunk and the block size.
-            self.pool = PagedKVPool(
-                num_layers=self.model.num_layers,
-                num_slots=self.cfg.max_slots,
-                num_heads=self.model.num_kv_heads,
-                max_len=model_cfg.max_len,
-                head_dim=self.model.head_dim,
-                block_size=bs,
-                num_blocks=self.cfg.kv_blocks,
-                dtype=cache_dtype,
-                kv_dtype=self.kv_dtype,
-                prefix_cache=self.cfg.prefix_cache,
-                registry=self.registry,
-                sharding=self._kv_sharding(),
-                layer_windows=self.model.layer_windows,
-                window_span=self.cfg.prefill_chunk_tokens,
-            )
-            self._layer_kind = self.pool.layer_kind
-            self._kinds = len(self.pool.kinds)
-        else:
-            self._kinds = 1
-            if self.kv_dtype:
+        bs = self.cfg.kv_block_size
+        for name, val in (
+            ("prefill_bucket_floor", self.cfg.prefill_bucket_floor),
+            ("kv_bucket_floor", self.cfg.kv_bucket_floor),
+            ("max_len", model_cfg.max_len),
+        ):
+            if val % bs:
                 raise ValueError(
-                    "kv_dtype (quantized KV) requires the paged pool — "
-                    "set kv_block_size"
+                    f"kv_block_size={bs} must divide {name}={val} "
+                    "(every compiled bucket is a whole number of "
+                    "blocks)"
                 )
-            self.pool = kv_mod.KVCachePool(
-                num_layers=model_cfg.num_layers,
-                num_slots=self.cfg.max_slots,
-                num_heads=model_cfg.num_heads,
-                max_len=model_cfg.max_len,
-                head_dim=model_cfg.head_dim,
-                dtype=cache_dtype,
-                registry=self.registry,
-                sharding=self._kv_sharding(),
-            )
+        # A cache row holds the KEY/VALUE heads (fewer than the query
+        # heads under grouped-query attention); kv_blocks counts the
+        # full kind's blocks, a window kind's follow from the slots, its
+        # W, the chunk and the block size.
+        self.pool = paged_kv.PagedKVPool(
+            num_layers=self.model.num_layers,
+            num_slots=self.cfg.max_slots,
+            num_heads=self.model.num_kv_heads,
+            max_len=model_cfg.max_len,
+            head_dim=self.model.head_dim,
+            block_size=bs,
+            num_blocks=self.cfg.kv_blocks,
+            dtype=cache_dtype,
+            kv_dtype=self.kv_dtype,
+            prefix_cache=self.cfg.prefix_cache,
+            registry=self.registry,
+            sharding=self._kv_sharding(),
+            layer_windows=self.model.layer_windows,
+            window_span=self.cfg.prefill_chunk_tokens,
+        )
+        self._layer_kind = self.pool.layer_kind
+        self._kinds = len(self.pool.kinds)
         # With chunked prefill on, no prefill or extend call is ever
         # longer than one chunk (a longer prompt is split, prefill_open):
         # the rungs above the chunk's are unreachable, and for a model
@@ -1026,97 +929,60 @@ class InferenceEngine:
         self.kv_ladder = kv_mod.bucket_ladder(
             self.cfg.kv_bucket_floor, model_cfg.max_len
         )
-        # The KV caches are donated (the dense steps take k/v as args
-        # 1/2 after partial binds the bucket; the paged steps take the
-        # pool's whole device-state tuple as arg 1): every step returns
-        # the updated caches and the pool unconditionally reassigns
-        # from the outputs, so XLA can alias in place instead of
-        # copying the pool per generated token. Backends without
-        # donation support just ignore the hint.
-        if self.paged:
-            self._prefill_fns = {
-                lb: self.sentinel.wrap(
-                    jax.jit(
-                        _named(functools.partial(
-                            self._paged_prefill_impl, lb), "L"),
-                        donate_argnums=(1,),
-                    ),
-                    f"serve_prefill_L{lb}",
-                )
-                for lb in self.prefill_ladder
-            }
-            self._decode_fns = {
-                kb: self.sentinel.wrap(
-                    jax.jit(
-                        _named(functools.partial(
-                            self._paged_decode_impl, kb), "K"),
-                        donate_argnums=(1,),
-                    ),
-                    f"serve_decode_K{kb}",
-                )
-                for kb in self.kv_ladder
-            }
-            # One extend program per TAIL bucket; the cached context
-            # always rides in as the slot's full block table (masked to
-            # the true context length) — |prefill ladder| programs, not
-            # a ladder product.
-            self._extend_fns = {
-                tb: self.sentinel.wrap(
-                    jax.jit(
-                        _named(functools.partial(
-                            self._extend_impl, tb), "T"),
-                        donate_argnums=(1,),
-                    ),
-                    f"serve_extend_T{tb}",
-                )
-                for tb in self.prefill_ladder
-            } if self.cfg.prefix_cache else {}
-            self._verify_fns = {
-                kb: self.sentinel.wrap(
-                    jax.jit(
-                        _named(functools.partial(
-                            self._paged_verify_impl, kb), "K"),
-                        donate_argnums=(1,),
-                    ),
-                    f"serve_verify_K{kb}",
-                )
-                for kb in self.kv_ladder
-            } if self.cfg.spec_decode_k > 0 else {}
-        else:
-            self._prefill_fns = {
-                lb: self.sentinel.wrap(
-                    jax.jit(
-                        _named(functools.partial(
-                            self._prefill_impl, lb), "L"),
-                        donate_argnums=(1, 2),
-                    ),
-                    f"serve_prefill_L{lb}",
-                )
-                for lb in self.prefill_ladder
-            }
-            self._decode_fns = {
-                kb: self.sentinel.wrap(
-                    jax.jit(
-                        _named(functools.partial(
-                            self._decode_impl, kb), "K"),
-                        donate_argnums=(1, 2),
-                    ),
-                    f"serve_decode_K{kb}",
-                )
-                for kb in self.kv_ladder
-            }
-            self._extend_fns = {}
-            self._verify_fns = {
-                kb: self.sentinel.wrap(
-                    jax.jit(
-                        _named(functools.partial(
-                            self._verify_impl, kb), "K"),
-                        donate_argnums=(1, 2),
-                    ),
-                    f"serve_verify_K{kb}",
-                )
-                for kb in self.kv_ladder
-            } if self.cfg.spec_decode_k > 0 else {}
+        # The pool's whole device-state tuple is donated (arg 1 after
+        # partial binds the rung): every step returns the updated state
+        # and the pool unconditionally reassigns from the outputs, so
+        # XLA can alias in place instead of copying the pool per
+        # generated token. Backends without donation support just
+        # ignore the hint.
+        self._prefill_fns = {
+            lb: self.sentinel.wrap(
+                jax.jit(
+                    _named(functools.partial(
+                        self._paged_prefill_impl, lb), "L"),
+                    donate_argnums=(1,),
+                ),
+                f"serve_prefill_L{lb}",
+            )
+            for lb in self.prefill_ladder
+        }
+        self._decode_fns = {
+            kb: self.sentinel.wrap(
+                jax.jit(
+                    _named(functools.partial(
+                        self._paged_decode_impl, kb), "K"),
+                    donate_argnums=(1,),
+                ),
+                f"serve_decode_K{kb}",
+            )
+            for kb in self.kv_ladder
+        }
+        # One extend program per TAIL bucket; the cached context always
+        # rides in as the slot's full block table (masked to the true
+        # context length) — |prefill ladder| programs, not a ladder
+        # product.
+        self._extend_fns = {
+            tb: self.sentinel.wrap(
+                jax.jit(
+                    _named(functools.partial(
+                        self._extend_impl, tb), "T"),
+                    donate_argnums=(1,),
+                ),
+                f"serve_extend_T{tb}",
+            )
+            for tb in self.prefill_ladder
+        } if self.cfg.prefix_cache else {}
+        self._verify_fns = {
+            kb: self.sentinel.wrap(
+                jax.jit(
+                    _named(functools.partial(
+                        self._paged_verify_impl, kb), "K"),
+                    donate_argnums=(1,),
+                ),
+                f"serve_verify_K{kb}",
+            )
+            for kb in self.kv_ladder
+        } if self.cfg.spec_decode_k > 0 else {}
         # The launch protocol: a program's per-launch operands travel as
         # ONE packed int32 block (launch_block), laid out per (kind,
         # rung) by ``_launch_spec`` and moved by ``_put`` — one
@@ -1145,15 +1011,13 @@ class InferenceEngine:
         self._ref_fwd = None
 
     def _refuse_for_block(self, sharding, precision) -> None:
-        """A block other than GPT-2's runs on the paged pool's XLA
-        path — prefill, extend (chunked prefill) and decode — and on
-        nothing else. Every other mechanism keeps serving GPT-2 as it
+        """A block other than GPT-2's runs on the pool's XLA path —
+        prefill, extend (chunked prefill) and decode — and on nothing
+        else. Every other mechanism keeps serving GPT-2 as it
         is and REFUSES this block here, by name, at construction: no
         silent fallback."""
         cfg, name = self.cfg, self.model.name
         refused = [
-            ("the dense (un-paged) KV cache", cfg.kv_block_size <= 0,
-             "set kv_block_size"),
             ("speculative verify (spec_decode_k)", cfg.spec_decode_k > 0,
              "its verify forward is GPT-2's"),
             ("KV page export/import (role='prefill'/'decode')",
@@ -1182,9 +1046,8 @@ class InferenceEngine:
 
     def _kv_sharding(self):
         """KV-pool NamedSharding from the ShardingConfig: heads shard
-        over ``model`` — dim 2 of the dense [L, S, H, max_len, D], and
-        the last dim of a paged layer's [NB, BS, H*D] (and of its
-        [NB, BS, H] scales), whose shards hold whole heads — the layout
+        over ``model`` — the last dim of a layer's [NB, BS, H*D] (and of
+        its [NB, BS, H] scales), whose shards hold whole heads — the layout
         that keeps per-slot attention local to the head shard the qkv
         projection already produced. A head count the model axis
         doesn't divide replicates instead (placement is an
@@ -1202,9 +1065,7 @@ class InferenceEngine:
             if m > 1 and self.model.num_kv_heads % m == 0
             else None
         )
-        if self.paged:
-            return NamedSharding(self.mesh, P(None, None, heads))
-        return NamedSharding(self.mesh, P(None, None, heads, None, None))
+        return NamedSharding(self.mesh, P(None, None, heads))
 
     # ------------------------------------------------- launch operands
 
@@ -1220,11 +1081,11 @@ class InferenceEngine:
         bs = self.cfg.kv_block_size
         if kind in ("decode", "verify"):
             wide = (s, self.cfg.spec_decode_k + 1) if kind == "verify" else (s,)
-            tables = [Field("tables", [
-                (s, nb) for nb in self._kind_blocks(rung // bs)
-            ])] if self.paged else []
             return (
-                Field("tokens", wide), Field("positions", (s,)), *tables,
+                Field("tokens", wide), Field("positions", (s,)),
+                Field("tables", [
+                    (s, nb) for nb in self._kind_blocks(rung // bs)
+                ]),
                 Field("seeds", (s,)), Field("temps", (s,), "float32"),
                 Field("top_ks", (s,)),
             )
@@ -1238,8 +1099,7 @@ class InferenceEngine:
             )
         else:
             where = (
-                Field("block_ids", [(rung // bs,)] * self._kinds)
-                if self.paged else Field("slot", ()),
+                Field("block_ids", [(rung // bs,)] * self._kinds),
                 Field("tokens", (1, rung)), Field("length", ()),
             )
         return (
@@ -1276,61 +1136,6 @@ class InferenceEngine:
 
     # ----------------------------------------------------- compiled fns
 
-    def _prefill_impl(self, bucket, params, k_cache, v_cache, *operands):
-        """tokens [1, bucket] (right-padded), length = true prompt len.
-        Writes the slot's cache rows [0, bucket) (pad rows carry
-        garbage K/V that per-slot length masking never reads), samples
-        the first generated token from the logits at row length-1."""
-        slot, tokens, length, key, temp, top_k = self._operands(
-            "prefill", bucket, operands
-        )
-        logits, ks, vs = forward_full(
-            self.model_cfg, params, tokens, impl=self._prefill_attn
-        )
-        # [L, 1, bucket, H, hd] -> [L, 1, H, bucket, hd] cache layout.
-        kstack = ks.transpose(0, 1, 3, 2, 4).astype(k_cache.dtype)
-        vstack = vs.transpose(0, 1, 3, 2, 4).astype(v_cache.dtype)
-        start = (0, slot.astype(jnp.int32), 0, 0, 0)
-        k_cache = jax.lax.dynamic_update_slice(k_cache, kstack, start)
-        v_cache = jax.lax.dynamic_update_slice(v_cache, vstack, start)
-        last = jax.lax.dynamic_index_in_dim(
-            logits[0], length - 1, keepdims=False
-        )
-        return k_cache, v_cache, _sample_row(key, last, temp, top_k), last
-
-    def _decode_impl(self, bucket, params, k_cache, v_cache, *operands):
-        tokens, positions, seeds, temps, top_ks = self._operands(
-            "decode", bucket, operands
-        )
-        k_cache, v_cache, logits = _decode_forward(
-            self.model_cfg, params, k_cache, v_cache, tokens, positions,
-            kv_bucket=bucket,
-        )
-        # The sampled token lands at sequence index position + 1.
-        keys = _request_key_batch(seeds, positions + 1)
-        toks = _sample_batch(keys, logits, temps, top_ks)
-        return k_cache, v_cache, jnp.concatenate(
-            [toks, _token_logprobs(logits, toks)]
-        )
-
-    def _verify_impl(self, bucket, params, k_cache, v_cache, *operands):
-        """Speculative verify (ISSUE 11): tokens [S, T] = launch token
-        + k drafts per slot, one forward, per-position sampling keys.
-        Returns the caches and the sampled stream [S, T] the host's
-        acceptance walks."""
-        tokens, positions, seeds, temps, top_ks = self._operands(
-            "verify", bucket, operands
-        )
-        k_cache, v_cache, logits = _verify_forward(
-            self.model_cfg, params, k_cache, v_cache, tokens, positions,
-            kv_bucket=bucket,
-        )
-        return k_cache, v_cache, _sample_verify(
-            seeds, positions, logits, temps, top_ks
-        )
-
-    # --------------------------------------------- compiled fns (paged)
-
     def _with_stats(self, tokens, stats):
         """What a step hands back for the host's ONE fetch: the sampled
         token(s) (a decode step's with their log-probabilities'
@@ -1341,12 +1146,15 @@ class InferenceEngine:
         return jnp.concatenate([jnp.reshape(tokens, (-1,)), stats])
 
     def _paged_prefill_impl(self, bucket, params, kv, *operands):
-        """The paged twin of ``_prefill_impl``: same forward, K/V
-        scattered into the slot's blocks instead of its dense extent."""
+        """tokens [1, bucket] (right-padded), length = true prompt len.
+        Scatters the prompt's K/V into the slot's blocks (pad rows land
+        in the null block or carry garbage that per-slot length masking
+        never reads), samples the first generated token from the logits
+        at row length-1."""
         block_ids, tokens, length, key, temp, top_k = self._operands(
             "prefill", bucket, operands
         )
-        kv, x, stats = _paged_prefill_forward(
+        kv, x, stats = _forward_prefill(
             self.model, params, kv, block_ids, tokens, length,
             self._layer_kind, block_size=self.cfg.kv_block_size,
             impl=self._prefill_attn,
@@ -1359,7 +1167,7 @@ class InferenceEngine:
         tokens, positions, tables, seeds, temps, top_ks = self._operands(
             "decode", bucket, operands
         )
-        kv, logits, stats = _paged_decode_forward(
+        kv, logits, stats = _forward_decode(
             self.model, params, kv, tokens, positions, tables,
             self._layer_kind, block_size=self.cfg.kv_block_size,
             attention=self.cfg.attention,
@@ -1371,13 +1179,15 @@ class InferenceEngine:
         )
 
     def _paged_verify_impl(self, bucket, params, kv, *operands):
-        """The paged twin of ``_verify_impl`` (same sampling contract;
-        the verify attention keeps the gather path — its cost amortizes
-        over T tokens)."""
+        """Speculative verify (ISSUE 11): tokens [S, T] = launch token
+        + k drafts per slot, one forward, per-position sampling keys.
+        Returns the pool state and the sampled stream [S, T] the host's
+        acceptance walks. (The verify attention keeps the gather path —
+        its cost amortizes over T tokens.)"""
         tokens, positions, tables, seeds, temps, top_ks = self._operands(
             "verify", bucket, operands
         )
-        kv, logits = _paged_verify_forward(
+        kv, logits = _forward_verify(
             self.model_cfg, params, kv, tokens, positions, tables,
             block_size=self.cfg.kv_block_size,
         )
@@ -1385,11 +1195,11 @@ class InferenceEngine:
 
     def _extend_impl(self, tail_bucket, params, kv, *operands):
         """Prefix-cache hit path and chunked prefill: prefill only the
-        prompt tail over the cached context (see ``_extend_forward``);
+        prompt tail over the cached context (see ``_forward_extend``);
         samples the first token from the tail's last true row."""
         (ctx_table, tail_ids, tokens, ctx_len, tail_len, key, temp,
          top_k) = self._operands("extend", tail_bucket, operands)
-        kv, x, stats = _extend_forward(
+        kv, x, stats = _forward_extend(
             self.model, params, kv, ctx_table, tail_ids, tokens,
             ctx_len, tail_len, self._layer_kind,
             block_size=self.cfg.kv_block_size,
@@ -1583,13 +1393,8 @@ class InferenceEngine:
         pool's device state — donated — and the operand block in; the
         state kept from what comes back, the rest returned."""
         self._launches.inc()
-        if self.paged:
-            kv, *out = fn(self.params, self.pool.kv_state(), block)
-            self.pool.set_kv_state(kv)
-        else:
-            self.pool.k, self.pool.v, *out = fn(
-                self.params, self.pool.k, self.pool.v, block
-            )
+        kv, *out = fn(self.params, self.pool.kv_state(), block)
+        self.pool.set_kv_state(kv)
         return out
 
     def _prefill_fault_tick(self, slot: int) -> None:
@@ -1610,7 +1415,7 @@ class InferenceEngine:
         """Run a prompt into ``slot``; returns (first generated token,
         last-position logits as numpy — the classify payload).
 
-        Paged mode allocates exactly the blocks the prompt needs
+        Allocates exactly the blocks the prompt needs
         (``paged_kv.BlockExhausted`` propagates BEFORE any device call
         — no donation happened, so only THIS request fails) and, on a
         prefix-cache hit, maps the shared blocks into the slot's table
@@ -1623,44 +1428,6 @@ class InferenceEngine:
                 f"prompt length {n} exceeds max_len {self.model_cfg.max_len}"
             )
         self._prefill_fault_tick(slot)
-        if self.paged:
-            tok, last = self._paged_prefill(
-                slot, prompt, seed=seed, temperature=temperature,
-                top_k=top_k,
-            )
-        else:
-            with host_span("engine_prefill_build"):
-                bucket = kv_mod.pick_bucket(self.prefill_ladder, n)
-                tokens = np.zeros((1, bucket), np.int32)
-                tokens[0, :n] = prompt
-            with host_span("engine_prefill_upload"):
-                block = self._put(self._specs["prefill", bucket], (
-                    slot, tokens, n, seed, n, temperature, top_k,
-                ))
-            tok, last = self._run_compiled(
-                "prefill", self._prefill_fns[bucket], block
-            )
-        self.pool.lengths[slot] = n
-        self.registry.counter("serving/prefill_tokens").inc(n)
-        return self._fetch_prefill(tok, last)
-
-    def _fetch_prefill(self, tok, last, pending=()):
-        """The prefill's device->host sync: first token and last-row
-        logits, under ``span/engine_prefill_fetch``. Where the model's
-        blocks report stats they ride behind the token, and in the
-        ``pending`` outputs of a chunked prefill's earlier chunks
-        (finished long since: reading them waits for nothing)."""
-        with host_span("engine_prefill_fetch"):
-            tok, last = np.asarray(tok), np.asarray(last)
-            if self.model.stats_len:
-                for out in (*map(np.asarray, pending), tok):
-                    self.model.count_stats(
-                        self.registry, out[1:], decode=False
-                    )
-            return int(tok.reshape(-1)[0]), last
-
-    def _paged_prefill(self, slot, prompt, *, seed, temperature, top_k):
-        n = len(prompt)
         bs = self.cfg.kv_block_size
         # A hit is only possible when the extend rungs exist to serve
         # it: the pool's prefix cache and the engine's extend ladder
@@ -1704,7 +1471,24 @@ class InferenceEngine:
                 "serving/prefix_reused_tokens"
             ).inc(ctx)
         self.pool.insert_prefix(slot, prompt)
-        return tok, last
+        self.pool.lengths[slot] = n
+        self.registry.counter("serving/prefill_tokens").inc(n)
+        return self._fetch_prefill(tok, last)
+
+    def _fetch_prefill(self, tok, last, pending=()):
+        """The prefill's device->host sync: first token and last-row
+        logits, under ``span/engine_prefill_fetch``. Where the model's
+        blocks report stats they ride behind the token, and in the
+        ``pending`` outputs of a chunked prefill's earlier chunks
+        (finished long since: reading them waits for nothing)."""
+        with host_span("engine_prefill_fetch"):
+            tok, last = np.asarray(tok), np.asarray(last)
+            if self.model.stats_len:
+                for out in (*map(np.asarray, pending), tok):
+                    self.model.count_stats(
+                        self.registry, out[1:], decode=False
+                    )
+            return int(tok.reshape(-1)[0]), last
 
     # --------------------------------- chunked prefill (ISSUE 12 (b))
 
@@ -1819,11 +1603,6 @@ class InferenceEngine:
         meta). Floored to this replica's block multiple and capped so
         at least the final (partial) block always ships."""
         self._pages_are_gpt2s("export")
-        if not self.paged:
-            raise ValueError(
-                "KV page export requires the paged pool (set "
-                "kv_block_size)"
-            )
         if skip_tokens < 0:
             raise ValueError(f"skip_tokens={skip_tokens} must be >= 0")
         from tensorflow_examples_tpu.serving import scheduler
@@ -1879,11 +1658,6 @@ class InferenceEngine:
         shared-prefix traffic gains affinity here too. A
         ``BlockExhausted`` propagates before any write (503 upstream)."""
         self._pages_are_gpt2s("import")
-        if not self.paged:
-            raise ValueError(
-                "KV page import requires the paged pool (set "
-                "kv_block_size)"
-            )
         from tensorflow_examples_tpu.serving import scheduler
 
         meta, arrays = scheduler.decode_pages(payload)
@@ -2036,55 +1810,50 @@ class InferenceEngine:
                 top_ks[slot] = tk
                 seeds[slot] = seed
                 slots.append(slot)
-            tables = ()  # the dense pool has none
-            if self.paged:
-                from tensorflow_examples_tpu.serving import paged_kv
-
-                # Grow block tables BEFORE the device step: an
-                # exhaustion here has consumed nothing (no donation
-                # yet), so only the requests that could not grow fail —
-                # the engine keeps serving the rest (the batcher
-                # handles the partition).
-                exhausted = []
-                for slot in slots:
-                    try:
-                        self.pool.ensure_position(
-                            slot, int(positions[slot])
-                        )
-                    except paged_kv.BlockExhausted:
-                        exhausted.append(slot)
-                if exhausted:
-                    raise paged_kv.BlockExhausted(
-                        "KV block pool exhausted mid-decode for slot(s) "
-                        f"{exhausted}; pool is serving at capacity",
-                        slots=tuple(exhausted),
+            # Grow block tables BEFORE the device step: an exhaustion
+            # here has consumed nothing (no donation yet), so only the
+            # requests that could not grow fail — the engine keeps
+            # serving the rest (the batcher handles the partition).
+            exhausted = []
+            for slot in slots:
+                try:
+                    self.pool.ensure_position(
+                        slot, int(positions[slot])
                     )
-                bs = self.cfg.kv_block_size
-                tables = (self._kind_tables(
-                    positions, bucket // bs, live=slots
-                ),)
-                if self._kinds > 1:
-                    # What the pool holds for what is resident, and
-                    # what of it this step's tables reach, sampled once
-                    # a step (kv_bytes_per_resident_token; the decode
-                    # roofline's cache bytes).
-                    reg, pool = self.registry, self.pool
-                    ctx = positions[slots].astype(np.int64) + 1
-                    reg.counter("serving/kv_sampled_bytes").inc(
-                        pool.used_bytes()
+                except paged_kv.BlockExhausted:
+                    exhausted.append(slot)
+            if exhausted:
+                raise paged_kv.BlockExhausted(
+                    "KV block pool exhausted mid-decode for slot(s) "
+                    f"{exhausted}; pool is serving at capacity",
+                    slots=tuple(exhausted),
+                )
+            bs = self.cfg.kv_block_size
+            tables = self._kind_tables(
+                positions, bucket // bs, live=slots
+            )
+            if self._kinds > 1:
+                # What the pool holds for what is resident, and what
+                # of it this step's tables reach, sampled once a step
+                # (kv_bytes_per_resident_token; the decode roofline's
+                # cache bytes).
+                reg, pool = self.registry, self.pool
+                ctx = positions[slots].astype(np.int64) + 1
+                reg.counter("serving/kv_sampled_bytes").inc(
+                    pool.used_bytes()
+                )
+                reg.counter("serving/kv_sampled_tokens").inc(
+                    int(ctx.sum())
+                )
+                reg.counter("serving/kv_sampled_reach_bytes").inc(sum(
+                    pool.bytes_per_block(kind) // bs * int(
+                        (ctx if w is None else np.minimum(ctx, w)).sum()
                     )
-                    reg.counter("serving/kv_sampled_tokens").inc(
-                        int(ctx.sum())
-                    )
-                    reg.counter("serving/kv_sampled_reach_bytes").inc(sum(
-                        pool.bytes_per_block(kind) // bs * int(
-                            (ctx if w is None else np.minimum(ctx, w)).sum()
-                        )
-                        for kind, w in enumerate(pool.kinds)
-                    ))
+                    for kind, w in enumerate(pool.kinds)
+                ))
         with host_span("engine_decode_upload"):
             block = self._put(self._specs["decode", bucket], (
-                tokens, positions, *tables, seeds, temps, top_ks,
+                tokens, positions, tables, seeds, temps, top_ks,
             ))
         (out,) = self._run_compiled(
             "decode", self._decode_fns[bucket], block
@@ -2163,47 +1932,37 @@ class InferenceEngine:
                 seeds[slot] = seed
                 slots.append(slot)
                 drafts_by_slot[slot] = drafts
-                # Committed rows must have landed in the cache: the
-                # dense extent caps them at max_len (rows past it were
-                # dropped).
-                limits[slot] = max_len - pos
-            tables = ()  # the dense pool has none
-            if self.paged:
-                from tensorflow_examples_tpu.serving import paged_kv
-
-                exhausted = []
-                for slot in slots:
-                    pos = int(positions[slot])
+            exhausted = []
+            for slot in slots:
+                pos = int(positions[slot])
+                try:
+                    self.pool.ensure_position(
+                        slot, min(pos + t_n - 1, max_len - 1)
+                    )
+                except paged_kv.BlockExhausted:
+                    # Shrink the spec window before shedding anything:
+                    # the NON-speculative requirement is one row.
                     try:
-                        self.pool.ensure_position(
-                            slot, min(pos + t_n - 1, max_len - 1)
-                        )
+                        self.pool.ensure_position(slot, pos)
                     except paged_kv.BlockExhausted:
-                        # Shrink the spec window before shedding
-                        # anything: the NON-speculative requirement is
-                        # one row.
-                        try:
-                            self.pool.ensure_position(slot, pos)
-                        except paged_kv.BlockExhausted:
-                            exhausted.append(slot)
-                            continue
-                    limits[slot] = min(
-                        limits[slot],
-                        self.pool.covered_positions(slot) - pos,
-                    )
-                if exhausted:
-                    raise paged_kv.BlockExhausted(
-                        "KV block pool exhausted mid-decode for slot(s) "
-                        f"{exhausted}; pool is serving at capacity",
-                        slots=tuple(exhausted),
-                    )
-                bs = self.cfg.kv_block_size
-                tables = (self._kind_tables(
-                    positions, bucket // bs, live=slots
-                ),)
+                        exhausted.append(slot)
+                        continue
+                # Committed rows must have landed in the cache: in the
+                # blocks the slot holds (at most max_len rows).
+                limits[slot] = self.pool.covered_positions(slot) - pos
+            if exhausted:
+                raise paged_kv.BlockExhausted(
+                    "KV block pool exhausted mid-decode for slot(s) "
+                    f"{exhausted}; pool is serving at capacity",
+                    slots=tuple(exhausted),
+                )
+            bs = self.cfg.kv_block_size
+            tables = self._kind_tables(
+                positions, bucket // bs, live=slots
+            )
         with host_span("engine_verify_upload"):
             block = self._put(self._specs["verify", bucket], (
-                tokens, positions, *tables, seeds, temps, top_ks,
+                tokens, positions, tables, seeds, temps, top_ks,
             ))
         (out,) = self._run_compiled(
             "verify", self._verify_fns[bucket], block
@@ -2243,9 +2002,7 @@ class InferenceEngine:
         must not count against the zero-recompile budget."""
         if self._ref_fwd is None:
             def step(params, tokens, length, key, temp, top_k):
-                logits, _, _ = forward_full(
-                    self.model_cfg, params, tokens, impl="xla"
-                )
+                logits = forward_full(self.model_cfg, params, tokens)
                 last = jax.lax.dynamic_index_in_dim(
                     logits[0], length - 1, keepdims=False
                 )

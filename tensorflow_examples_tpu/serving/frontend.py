@@ -25,7 +25,7 @@ Endpoints:
   replies the top-n next-token distribution
   ``{"top": [{"token": id, "logprob": lp}, ...]}``.
 * ``POST /prefill`` / ``POST /resume`` — the disaggregated-role
-  handoff pair (ISSUE 12, paged pool only). ``/prefill`` runs the
+  handoff pair (ISSUE 12). ``/prefill`` runs the
   prompt to completion-of-prefill and replies ``{"first_token": id,
   "pages": {...}}`` (``serving/scheduler.py`` wire format, int8 scales
   included); ``/resume`` takes the same generate body plus
@@ -395,30 +395,26 @@ class ServingFrontend:
         body["brownout_transitions"] = int(
             batcher._overload.transitions()
         )
-        paged = getattr(engine.pool, "paged_stats", None)
-        if callable(paged):
-            stats = paged()
-            body["kv_block_occupancy"] = stats["kv_block_occupancy"]
-            body["kv_slot_occupancy"] = stats["kv_slot_occupancy"]
-            body["prefix_hit_rate"] = stats["prefix_hit_rate"]
-        digest = getattr(engine.pool, "prefix_digest", None)
-        if callable(digest):
-            # The affinity summary (ISSUE 12): content chain keys of
-            # the cached prefix blocks — what the router's
-            # prefix-affinity dispatch matches prompts against.
-            d = digest()
-            body["prefix_block_size"] = engine.pool.block_size
-            body["prefix_blocks"] = d["blocks"]
-            body["prefix_chains"] = d["chains"]
-            body["prefix_digest"] = d["keys"]
-            # ISSUE 13 satellite: say when the digest is capped, so
-            # affinity misses on very large caches are diagnosable.
-            body["digest_truncated"] = bool(d.get("truncated"))
-            if d.get("bloom"):
-                # ISSUE 15 satellite: past the cap the FULL chain-key
-                # set still routes — as a bloom filter the router
-                # matches against instead of the truncated list.
-                body["prefix_bloom"] = d["bloom"]
+        stats = engine.pool.paged_stats()
+        body["kv_block_occupancy"] = stats["kv_block_occupancy"]
+        body["kv_slot_occupancy"] = stats["kv_slot_occupancy"]
+        body["prefix_hit_rate"] = stats["prefix_hit_rate"]
+        # The affinity summary (ISSUE 12): content chain keys of the
+        # cached prefix blocks — what the router's prefix-affinity
+        # dispatch matches prompts against.
+        d = engine.pool.prefix_digest()
+        body["prefix_block_size"] = engine.pool.block_size
+        body["prefix_blocks"] = d["blocks"]
+        body["prefix_chains"] = d["chains"]
+        body["prefix_digest"] = d["keys"]
+        # ISSUE 13 satellite: say when the digest is capped, so
+        # affinity misses on very large caches are diagnosable.
+        body["digest_truncated"] = bool(d.get("truncated"))
+        if d.get("bloom"):
+            # ISSUE 15 satellite: past the cap the FULL chain-key set
+            # still routes — as a bloom filter the router matches
+            # against instead of the truncated list.
+            body["prefix_bloom"] = d["bloom"]
         wd = batcher._watchdog
         if wd is not None:
             status = wd.status()

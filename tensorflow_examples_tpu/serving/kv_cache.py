@@ -1,45 +1,43 @@
-"""Slot-granular KV cache pool + variable-length decode attention.
+"""Bucket ladders, block gathers and the cache attentions.
 
 The training-side decode path (``models/transformer.py`` flax ``cache``
 collection) keys the whole batch off ONE scalar index — fine for
 sampling a fixed batch in lockstep, useless for continuous batching
-where every concurrent request sits at a different position. This
-module owns the serving-side replacement:
+where every concurrent request sits at a different position. The
+serving-side replacement is the block pool of ``paged_kv.py``; this
+module holds what the compiled steps do with it:
 
-* ``KVCachePool`` preallocates the worst-case cache ONCE —
-  ``[layers, slots, heads, max_len, head_dim]`` for K and V — and hands
-  out *slots* (one per in-flight request) with host-side alloc/free and
-  per-slot populated-length tracking. Slot state is published as
-  ``serving/kv_occupancy`` / ``serving/kv_tokens`` gauges on every
-  transition, so a scrape always sees live cache pressure.
+* ``bucket_ladder`` / ``pick_bucket`` — the power-of-two rungs every
+  compiled program comes from.
+* ``gather_block_kv`` / ``gather_layer_kv`` — a slot's contiguous view
+  out of one layer's ``[NB, BS, H*D]`` blocks, by its block table
+  (dequantizing where the pool is int8/fp8).
 * ``varlen_decode_attention`` is the per-slot generalization of
   ``ops/decode.flash_decode_attention``'s contract: each slot's query
   attends over exactly its own populated prefix (``lengths`` rides in
   as a vector, not a scalar). The bucket discipline lives in the
-  caller (``engine.py``): the cache is sliced to the smallest
+  caller (``engine.py``): the block tables are cut to the smallest
   power-of-two KV bucket covering the longest active request before
-  this runs, so a step over mostly-short requests reads O(bucket)
+  this runs, so a step over mostly-short requests gathers O(bucket)
   cache bytes, not O(max_len) — the same populated-prefix economics as
-  the flash-decode bucket ladder, expressed through XLA slicing
+  the flash-decode bucket ladder, expressed through an XLA gather
   instead of a Pallas grid (scalar-prefetch index maps cannot see a
   per-slot length vector; the single-length case — prefill — reuses
   the Pallas kernel directly, see ``engine._prefill_attend``).
+  ``varlen_verify_attention`` is its T-rows-a-slot form, and the
+  ``grouped_*`` attentions serve grouped-query and window layers.
 
-Everything here is functionally pure on the device side: the pool's
-arrays are replaced wholesale by the jitted steps that update them, so
-the engine composes with donation on backends that support it.
+Everything here is functionally pure: the pool's arrays are replaced
+wholesale by the jitted steps that update them, so the engine composes
+with donation on backends that support it.
 """
 
 from __future__ import annotations
 
-import threading
-
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from tensorflow_examples_tpu.ops.attention import NEG_INF
-from tensorflow_examples_tpu.telemetry import registry as registry_mod
 
 
 def bucket_ladder(floor: int, max_len: int) -> list[int]:
@@ -107,12 +105,12 @@ def gather_layer_kv(k_blocks, v_blocks, block_tables, num_heads, dtype,
 
 def varlen_decode_attention(
     q: jax.Array,
-    k_cache: jax.Array,
-    v_cache: jax.Array,
+    k_blocks: jax.Array,
+    v_blocks: jax.Array,
     lengths: jax.Array,
     *,
+    block_tables: jax.Array,
     sm_scale: float | None = None,
-    block_tables: jax.Array | None = None,
     k_scale: jax.Array | None = None,
     v_scale: jax.Array | None = None,
 ) -> jax.Array:
@@ -120,50 +118,44 @@ def varlen_decode_attention(
 
     q: [S, H, D] — one new query per slot, sitting at global position
     ``lengths[s] - 1`` (its own K/V already written to the cache).
-    k_cache / v_cache: [S, H, Kb, D] — the cache sliced to the active
-    KV bucket; slots' rows >= their length are garbage and masked.
+    k_blocks / v_blocks: one layer's block pool ([NB, BS, H*D], with
+    ``k_scale``/``v_scale`` [NB, BS, H] when it is quantized).
+    block_tables: [S, nb] int32, cut to the active KV bucket — each
+    slot's view is gathered by its table first
+    (:func:`gather_block_kv`, [S, Kb, H, D]); rows >= the slot's length
+    are garbage and masked.
     lengths: [S] int32 populated lengths INCLUDING the new token.
-
-    With ``block_tables`` ([S, nb] int32, ISSUE 8), k_cache/v_cache
-    are instead one layer's paged block pool ([NB, BS, H*D], with
-    ``k_scale``/``v_scale`` [NB, BS, H] when it is quantized) and each
-    slot's view is gathered by its block table first
-    (:func:`gather_block_kv`, [S, Kb, H, D]) — the paged mirror of the
-    dense slice, same masking contract downstream.
 
     Returns [S, H, D]. Numerics mirror
     ``ops/decode.decode_attention_reference`` (f32 scores/softmax,
     output cast back to q.dtype) with the scalar length promoted to a
     vector — slot s sees columns < lengths[s], nothing else.
     """
-    kv = "shkd"
-    if block_tables is not None:
-        k_cache, v_cache = gather_layer_kv(
-            k_cache, v_cache, block_tables, q.shape[-2], q.dtype,
-            k_scale=k_scale, v_scale=v_scale,
-        )
-        kv = "skhd"
+    k_cache, v_cache = gather_layer_kv(
+        k_blocks, v_blocks, block_tables, q.shape[-2], q.dtype,
+        k_scale=k_scale, v_scale=v_scale,
+    )
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     s = jnp.einsum(
-        f"shd,{kv}->shk", q, k_cache, preferred_element_type=jnp.float32
+        "shd,skhd->shk", q, k_cache, preferred_element_type=jnp.float32
     ) * sm_scale
     col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
     s = jnp.where(col < lengths[:, None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(v_cache.dtype)
     return jnp.einsum(
-        f"shk,{kv}->shd", p, v_cache, preferred_element_type=jnp.float32
+        "shk,skhd->shd", p, v_cache, preferred_element_type=jnp.float32
     ).astype(q.dtype)
 
 
 def varlen_verify_attention(
     q: jax.Array,
-    k_cache: jax.Array,
-    v_cache: jax.Array,
+    k_blocks: jax.Array,
+    v_blocks: jax.Array,
     positions: jax.Array,
     *,
+    block_tables: jax.Array,
     sm_scale: float | None = None,
-    block_tables: jax.Array | None = None,
     k_scale: jax.Array | None = None,
     v_scale: jax.Array | None = None,
 ) -> jax.Array:
@@ -179,26 +171,22 @@ def varlen_verify_attention(
     length vector (T=1 reduces to exactly
     ``varlen_decode_attention(..., lengths=positions + 1)``).
 
-    k_cache / v_cache: [S, H, Kb, D] bucket-sliced caches, or one
-    layer's paged block pool ([NB, BS, H*D], scales [NB, BS, H]) when
-    ``block_tables`` is given — same gather contract as the decode
-    path. Returns [S, T, H, D]; numerics mirror the decode path (f32
-    scores/softmax, probabilities cast to the value dtype, f32
-    accumulation) so a verify step's sampled tokens match what T
+    k_blocks / v_blocks: one layer's block pool ([NB, BS, H*D],
+    scales [NB, BS, H]) behind ``block_tables`` — same gather contract
+    as the decode path. Returns [S, T, H, D]; numerics mirror the
+    decode path (f32 scores/softmax, probabilities cast to the value
+    dtype, f32 accumulation) so a verify step's sampled tokens match what T
     single-token steps would have drawn — the property every
     token-identical golden with speculation on rests on.
     """
-    kv = "shkd"
-    if block_tables is not None:
-        k_cache, v_cache = gather_layer_kv(
-            k_cache, v_cache, block_tables, q.shape[-2], q.dtype,
-            k_scale=k_scale, v_scale=v_scale,
-        )
-        kv = "skhd"
+    k_cache, v_cache = gather_layer_kv(
+        k_blocks, v_blocks, block_tables, q.shape[-2], q.dtype,
+        k_scale=k_scale, v_scale=v_scale,
+    )
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     s = jnp.einsum(
-        f"sthd,{kv}->shtk", q, k_cache,
+        "sthd,skhd->shtk", q, k_cache,
         preferred_element_type=jnp.float32,
     ) * sm_scale
     col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 3)
@@ -207,7 +195,7 @@ def varlen_verify_attention(
     s = jnp.where(col <= limit, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(v_cache.dtype)
     return jnp.einsum(
-        f"shtk,{kv}->sthd", p, v_cache,
+        "shtk,skhd->sthd", p, v_cache,
         preferred_element_type=jnp.float32,
     ).astype(q.dtype)
 
@@ -337,155 +325,3 @@ def grouped_chunk_attention(
         by_group(k), by_group(v), by_group(k_ctx), by_group(v_ctx),
     ))  # [G, R, T, D]
     return jnp.moveaxis(out, 2, 0).reshape(t_n, h, d).astype(q.dtype)
-
-
-class KVCachePool:
-    """Preallocated per-request KV slots with host-side bookkeeping.
-
-    Device state: ``k``/``v`` [L, S, H, max_len, D], replaced wholesale
-    by the engine's jitted steps. Host state: a free-slot list and the
-    per-slot populated lengths (the numpy mirror the engine feeds back
-    into every decode step). Thread-safe: the batcher loop allocates
-    and frees while frontend threads read occupancy.
-    """
-
-    def __init__(
-        self,
-        *,
-        num_layers: int,
-        num_slots: int,
-        num_heads: int,
-        max_len: int,
-        head_dim: int,
-        dtype=jnp.float32,
-        registry=None,
-        sharding=None,
-    ):
-        if num_slots < 1:
-            raise ValueError(f"num_slots={num_slots} must be >= 1")
-        self.num_layers = num_layers
-        self.num_slots = num_slots
-        self.num_heads = num_heads
-        self.max_len = max_len
-        self.head_dim = head_dim
-        self.dtype = dtype
-        self._registry = registry
-        # Optional NamedSharding for the [L, S, H, max_len, D] device
-        # arrays (ISSUE 7): the engine derives it from its
-        # ShardingConfig — heads over `model` is the tensor-parallel
-        # layout — so the cache is born (and reallocated) in the same
-        # placement the compiled steps consume. None = single-device
-        # default placement, today's behavior.
-        self._sharding = sharding
-        self.k = self._zeros()
-        self.v = self._zeros()
-        self.lengths = np.zeros((num_slots,), np.int32)
-        self._free = list(range(num_slots - 1, -1, -1))  # pop() -> slot 0 first
-        self._lock = threading.Lock()
-        self._publish()
-
-    # ------------------------------------------------------------- slots
-
-    def _reg(self):
-        return (
-            self._registry
-            if self._registry is not None
-            else registry_mod.default_registry()
-        )
-
-    def _publish(self) -> None:
-        reg = self._reg()
-        active = self.num_slots - len(self._free)
-        # Dense pool: a claimed slot IS max_len of committed cache, so
-        # slot occupancy and capacity occupancy are the same number.
-        # The paged pool (paged_kv.py) splits them — kv_occupancy
-        # becomes used-block fraction there — and publishes both.
-        reg.gauge("serving/kv_occupancy").set(active / self.num_slots)
-        reg.gauge("serving/kv_slot_occupancy").set(active / self.num_slots)
-        reg.gauge("serving/kv_slots_active").set(active)
-        reg.gauge("serving/kv_tokens").set(int(self.lengths.sum()))
-
-    def alloc(self) -> int | None:
-        """Claim a free slot (None when the pool is full). The slot's
-        length starts at 0; the engine's prefill sets it."""
-        with self._lock:
-            if not self._free:
-                return None
-            slot = self._free.pop()
-            self.lengths[slot] = 0
-            self._publish()
-            return slot
-
-    def free(self, slot: int) -> None:
-        with self._lock:
-            if slot in self._free:  # double-free is a caller bug
-                raise ValueError(f"slot {slot} is already free")
-            self.lengths[slot] = 0
-            self._free.append(slot)
-            self._publish()
-
-    def _zeros(self):
-        shape = (self.num_layers, self.num_slots, self.num_heads,
-                 self.max_len, self.head_dim)
-        if self._sharding is None:
-            return jnp.zeros(shape, self.dtype)
-        # Born sharded: zeros are created per-shard in place — the full
-        # pool never materializes on one device (it may only fit split).
-        return jnp.zeros(shape, self.dtype, device=self._sharding)
-
-    def reallocate(self) -> None:
-        """Replace ``k``/``v`` with fresh zeroed device arrays (in the
-        pool's sharding). The engine calls this when a donated compiled
-        step fails at runtime: donation consumed the old buffers, so
-        without replacement every later step would hit 'Array has been
-        deleted'. Slot bookkeeping is untouched — the batcher fails and
-        frees the whole in-flight set (its KV is gone) right after."""
-        self.k = self._zeros()
-        self.v = self._zeros()
-
-    def reset(self) -> None:
-        """Release every slot and zero the length mirror (the device
-        arrays keep whatever garbage they hold — unpopulated rows are
-        never read). Used after engine warmup."""
-        with self._lock:
-            self.lengths[:] = 0
-            self._free = list(range(self.num_slots - 1, -1, -1))
-            self._publish()
-
-    @property
-    def active_slots(self) -> int:
-        with self._lock:
-            return self.num_slots - len(self._free)
-
-    @property
-    def occupancy(self) -> float:
-        return self.active_slots / self.num_slots
-
-    def max_active_length(self) -> int:
-        """Longest populated prefix over all slots (0 when idle) — the
-        engine picks the decode KV bucket from this."""
-        with self._lock:
-            return int(self.lengths.max(initial=0))
-
-    # -------------------------------------------------- byte accounting
-
-    @property
-    def kv_bits(self) -> int:
-        """Storage bits per cache element (uniform with the paged
-        pool's quantization-aware figure)."""
-        return jnp.dtype(self.dtype).itemsize * 8
-
-    def bytes_per_slot(self) -> int:
-        """K+V device bytes one claimed slot commits (the dense pool
-        commits the full ``max_len`` extent per slot, used or not —
-        the economics the paged pool exists to beat)."""
-        return int(
-            2 * self.num_layers * self.num_heads * self.max_len
-            * self.head_dim * jnp.dtype(self.dtype).itemsize
-        )
-
-    def used_bytes(self) -> int:
-        """Cache bytes committed to the currently active request set
-        (tier-1 asserts the paged pool's figure for a mixed-length set
-        is <= 1/2 of this one at equal concurrency)."""
-        return self.active_slots * self.bytes_per_slot()

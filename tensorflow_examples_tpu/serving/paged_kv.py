@@ -1,18 +1,19 @@
 """Block-paged KV cache pool with prefix reuse + int8 KV (ISSUE 8).
 
-The dense ``KVCachePool`` commits ``max_len`` rows per slot the moment
-the slot is claimed: a 12-token request holds as much cache as a
-1024-token one, and concurrency is capped by the worst case, not the
-workload. This module replaces the storage layer behind the same
-interface the engine/batcher already speak:
+A cache that commits ``max_len`` rows per slot the moment the slot is
+claimed lets a 12-token request hold as much as a 1024-token one, and
+caps concurrency by the worst case, not the workload. This module is
+the engine's KV pool: slots (one per in-flight request, with host-side
+alloc/free and per-slot populated lengths) over block-granular storage:
 
 * **Paged blocks** — the device arrays are, per layer, ``[NB, BS, H*D]``
   pools of ``NB`` physical blocks of ``BS`` (power-of-two) token rows
   each, a row being the token's ``H*D`` values (lane-dense).
   A slot holds a *block table* (logical block index -> physical block
   id); capacity scales with the tokens a request has actually used,
-  so a mixed short/long request set commits a fraction of the dense
-  pool's bytes (tier-1 asserts <= 1/2 via ``used_bytes()``).
+  so a mixed short/long request set commits a fraction of what
+  ``slots x max_len`` rows would (tier-1 asserts <= 1/2 via
+  ``used_bytes()``).
   Physical block 0 is reserved as the **null block**: pad entries of
   every table point at it, parked decode slots write their discarded
   rows into it, and length masking guarantees its garbage is never
@@ -42,7 +43,7 @@ interface the engine/batcher already speak:
   f32 scales kept blockwise (``[NB, BS, H]`` per layer,
   ``core/precision.quantize_int8_rows``): rows append one decode step
   at a time without requantizing the block. fp32/bf16 paged serving
-  stays token-identical to the dense reference; int8 is a measured
+  stays token-identical to the cacheless reference; int8 is a measured
   bounded-divergence mode (tests pin both).
 
 * **Layer kinds** (ISSUE 28) — a layer is ``full`` (every token of the
@@ -62,11 +63,11 @@ interface the engine/batcher already speak:
   requests (a hit would find released blocks): nothing is published or
   looked up, the prefix cache stays empty.
 
-Occupancy telemetry splits what the dense pool conflated (ISSUE 8
-satellite): ``serving/kv_occupancy`` is the **used-block fraction**
-(the capacity signal the router tier load-balances on), while
+Occupancy telemetry keeps two things apart (ISSUE 8 satellite):
+``serving/kv_occupancy`` is the **used-block fraction** (the capacity
+signal the router tier load-balances on), while
 ``serving/kv_slot_occupancy`` tracks claimed slots — a pool with every
-slot busy on short prompts no longer reads as full.
+slot busy on short prompts does not read as full.
 """
 
 from __future__ import annotations
@@ -174,9 +175,9 @@ class _WindowSpace:
 
 
 class PagedKVPool:
-    """Paged drop-in for ``kv_cache.KVCachePool``: same slot interface
-    (``alloc``/``free``/``reset``/``reallocate``/``lengths``/
-    ``max_active_length``/``occupancy``), block-granular storage.
+    """The KV pool: a slot interface (``alloc``/``free``/``reset``/
+    ``reallocate``/``lengths``/``max_active_length``/``occupancy``)
+    over block-granular storage.
 
     Host bookkeeping (all under one lock; the batcher loop is the only
     writer, frontend threads read occupancy):
@@ -231,10 +232,10 @@ class PagedKVPool:
         self.head_dim = head_dim
         self.block_size = block_size
         self.max_blocks_per_slot = max_len // block_size
-        # Default capacity matches the dense pool's worst case (every
-        # slot at max_len) so nothing that served before can fail now;
-        # operators shrink it (ServeConfig.kv_blocks) to bank the
-        # memory the paging exists to save. +1 for the null block.
+        # Default capacity is the worst case (every slot at max_len),
+        # so no admitted request can exhaust it; operators shrink it
+        # (ServeConfig.kv_blocks) to bank the memory the paging exists
+        # to save. +1 for the null block.
         self.num_blocks = (
             int(num_blocks) if num_blocks
             else num_slots * self.max_blocks_per_slot + 1
@@ -813,7 +814,7 @@ class PagedKVPool:
     def used_bytes(self) -> int:
         """Cache bytes committed to the active request set — blocks
         actually referenced, not slots claimed. The number the tier-1
-        memory-claim test compares against the dense pool's."""
+        memory-claim test compares against ``slots x max_len`` rows."""
         with self._lock:
             return int((self._refcount > 0).sum()) * self.bytes_per_block(0) \
                 + sum(

@@ -98,6 +98,7 @@ and banks the ``serve_router`` record ``bench_gate`` accepts.
 from __future__ import annotations
 
 import dataclasses
+import http.client
 import http.server
 import json
 import logging
@@ -181,9 +182,9 @@ def _get_json(url: str, timeout: float) -> tuple[int, dict]:
     except urllib.error.HTTPError as e:
         try:
             return _as_object(e.code, json.loads(e.read() or b"{}"))
-        except (ValueError, OSError):
+        except (ValueError, OSError, http.client.HTTPException):
             return e.code, {}
-    except (OSError, ValueError) as e:
+    except (OSError, ValueError, http.client.HTTPException) as e:
         return 0, {"error": f"{type(e).__name__}: {e}"}
 
 
@@ -203,12 +204,14 @@ def post_json(url: str, body: dict, timeout: float) -> tuple[int, dict]:
     except urllib.error.HTTPError as e:
         try:
             return _as_object(e.code, json.loads(e.read() or b"{}"))
-        except (ValueError, OSError):
+        except (ValueError, OSError, http.client.HTTPException):
             return e.code, {}
-    except (OSError, ValueError) as e:
+    except (OSError, ValueError, http.client.HTTPException) as e:
         # Transport failure: status 0 — the dispatcher treats it like a
         # 503 (retryable on another replica) and the probe loop will
-        # notice a dead replica on its own.
+        # notice a dead replica on its own. A peer that dies between
+        # the headers and the body is an ``IncompleteRead``, which is
+        # an HTTPException and no OSError.
         return 0, {"error": f"{type(e).__name__}: {e}"}
 
 
@@ -556,8 +559,8 @@ class Router:
                         setattr(r, field, int(v))
                 r.digest_truncated = bool(body.get("digest_truncated"))
                 # Cache-aware scheduling fields (ISSUE 12) — absent on
-                # dense-pool or pre-ISSUE-12 replicas, which simply
-                # never win an affinity preference.
+                # pre-ISSUE-12 replicas, which simply never win an
+                # affinity preference.
                 role = body.get("role")
                 if isinstance(role, str) and role in (
                     "mixed", "prefill", "decode"

@@ -155,9 +155,9 @@ def make_draft(cfg) -> DraftSource:
 
 
 def accept_drafts(drafts: list[int], sampled, *, limit: int) -> list[int]:
-    """The acceptance rule, shared by the dense and paged verify paths
-    (and test-pinned): commit ``sampled[0]`` (the token a plain decode
-    step would have produced — its context is fully committed), then
+    """The acceptance rule (test-pinned): commit ``sampled[0]`` (the
+    token a plain decode step would have produced — its context is
+    fully committed), then
     one more sampled token per leading draft that AGREES with the
     sampled stream, stopping at the first disagreement. ``limit`` caps
     committed tokens at the rows whose K/V actually landed in the cache

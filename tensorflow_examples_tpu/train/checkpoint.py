@@ -271,6 +271,8 @@ class CheckpointManager:
         except Exception as e:  # metadata is best-effort across versions
             log.debug("checkpoint metadata unavailable (%s); skipping", e)
             return
+        # Newer orbax hands back a TreeMetadata around the saved tree.
+        meta = getattr(meta, "tree", meta)
         if not isinstance(meta, dict):
             return
 

@@ -60,6 +60,21 @@ def pytest_runtest_call(item):
         signal.signal(signal.SIGALRM, old)
 
 
+def slot_pool(num_slots: int, max_len: int, registry):
+    """The pool of a FAKE engine (test_serving, test_chaos,
+    test_overload, test_router, test_slo): the real ``PagedKVPool`` at
+    one layer of one 2-wide head, which those engines use as a slot
+    bookkeeper (alloc/free, lengths, occupancy, the stats and digest
+    the batcher and ``/health`` read) and never step on a device."""
+    from tensorflow_examples_tpu.serving.paged_kv import PagedKVPool
+
+    return PagedKVPool(
+        num_layers=1, num_slots=num_slots, num_heads=1, max_len=max_len,
+        head_dim=2, block_size=min(16, max_len & -max_len),
+        registry=registry,
+    )
+
+
 @pytest.fixture
 def faults():
     """Arm a deterministic fault plan for the duration of one test.

@@ -60,7 +60,7 @@ def _span_lines(trace_dir):
     return out
 
 
-def _engine(*, paged=True, **serve_kw):
+def _engine(**serve_kw):
     import jax
     import jax.numpy as jnp
 
@@ -68,8 +68,6 @@ def _engine(*, paged=True, **serve_kw):
     params = transformer.Transformer(cfg).init(
         {"params": jax.random.PRNGKey(0)}, jnp.zeros((1, 8), jnp.int32)
     )["params"]
-    if paged:
-        serve_kw.setdefault("kv_block_size", 16)
     return InferenceEngine(
         cfg, params, registry=MetricsRegistry(),
         cfg=ServeConfig(max_slots=2, prefill_bucket_floor=16,
@@ -212,32 +210,37 @@ def test_histogram_samples_since_a_mark():
 
 # ------------------------------------------------------- program names
 
-PAGED_RUNGS = [
+RUNGS = [
     ("_prefill_fns", "paged_prefill_impl_L", "serve_prefill_L"),
     ("_decode_fns", "paged_decode_impl_K", "serve_decode_K"),
     ("_extend_fns", "extend_impl_T", "serve_extend_T"),
     ("_verify_fns", "paged_verify_impl_K", "serve_verify_K"),
 ]
-DENSE_RUNGS = [
-    ("_prefill_fns", "prefill_impl_L", "serve_prefill_L"),
-    ("_decode_fns", "decode_impl_K", "serve_decode_K"),
-    ("_verify_fns", "verify_impl_K", "serve_verify_K"),
-]
 
 
 @pytest.fixture(scope="module")
 def engines():
-    return {True: _engine(paged=True, spec_decode_k=2),
-            False: _engine(paged=False, spec_decode_k=2)}
+    """GPT-2's block with every family, and the two-kind block (the
+    serve-longdoc cell's), which has no verify family."""
+    from test_launch_block import _two_kinds
+
+    make = {"gpt2": lambda: _engine(spec_decode_k=2), "two_kinds": _two_kinds}
+    made = {}
+
+    def get(name):
+        if name not in made:
+            made[name] = make[name]()
+        return made[name]
+    return get
 
 
 @pytest.mark.parametrize(
-    "paged,attr,program,sentinel",
-    [(True, *r) for r in PAGED_RUNGS] + [(False, *r) for r in DENSE_RUNGS],
+    "block,attr,program,sentinel",
+    [("gpt2", *r) for r in RUNGS] + [("two_kinds", *r) for r in RUNGS[:3]],
 )
 def test_every_rung_is_named_after_its_step_function_and_rung(
-        engines, paged, attr, program, sentinel):
-    engine = engines[paged]
+        engines, block, attr, program, sentinel):
+    engine = engines(block)
     fns = getattr(engine, attr)
     assert fns, f"{attr} is empty"
     for bucket, fn in fns.items():
@@ -266,11 +269,11 @@ def test_jax_reports_the_names_in_its_compile_events():
 
     jax.monitoring.register_event_duration_secs_listener(listen)
     try:
-        engine = _engine(paged=True, spec_decode_k=2)
+        engine = _engine(spec_decode_k=2)
         engine.warmup()
     finally:
         monitoring_src.unregister_event_duration_listener(listen)
-    for attr, program, _ in PAGED_RUNGS:
+    for attr, program, _ in RUNGS:
         for bucket in getattr(engine, attr):
             assert f"jit({program}{bucket})" in seen, (program, bucket, seen)
     assert not [n for n in seen if "unknown" in n], seen
@@ -281,7 +284,7 @@ def test_a_rung_lowers_to_a_module_with_its_name():
     import jax
     import jax.numpy as jnp
 
-    engine = _engine(paged=True)
+    engine = _engine()
     bucket = engine.kv_ladder[0]
     s = engine.cfg.max_slots
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
@@ -303,7 +306,7 @@ def served_trace(tmp_path_factory):
     import jax
 
     trace_dir = str(tmp_path_factory.mktemp("serve_trace"))
-    engine = _engine(paged=True)
+    engine = _engine()
     engine.warmup()
     spans_mod.reset_default_tracer()
     batcher = ContinuousBatcher(engine).start()

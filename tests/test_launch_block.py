@@ -27,17 +27,16 @@ MODEL = dict(vocab_size=211, max_len=64, num_layers=2, num_heads=2,
              d_model=32, dropout=0.0, attention="xla")
 SERVE = dict(max_slots=3, prefill_bucket_floor=16, kv_bucket_floor=32,
              spec_decode_k=2)
-PAGED = dict(kv_block_size=16, prefill_chunk_tokens=16)
 
 
-def _gpt2(paged):
+def _gpt2(**serve_kw):
     cfg = transformer.TransformerConfig(**MODEL)
     params = transformer.Transformer(cfg).init(
         {"params": jax.random.PRNGKey(0)}, np.zeros((1, 8), np.int32)
     )["params"]
     return InferenceEngine(
         cfg, params, registry=MetricsRegistry(),
-        cfg=ServeConfig(**SERVE, **(PAGED if paged else {})),
+        cfg=ServeConfig(**SERVE, **serve_kw),
     )
 
 
@@ -63,8 +62,13 @@ def _two_kinds():
     )
 
 
-ENGINES = {"paged": lambda: _gpt2(True), "dense": lambda: _gpt2(False),
-           "two_kinds": _two_kinds}
+# "no_prefix": the prefix cache off, so no extend family (and no chunked
+# prefill): a second configuration of the one pool.
+ENGINES = {
+    "paged": lambda: _gpt2(kv_block_size=16, prefill_chunk_tokens=16),
+    "no_prefix": lambda: _gpt2(prefix_cache=False),
+    "two_kinds": _two_kinds,
+}
 
 
 @pytest.fixture(scope="module")
@@ -116,8 +120,8 @@ def _bits(x):
 
 @pytest.mark.parametrize("name,kind", [
     ("paged", "prefill"), ("paged", "decode"), ("paged", "extend"),
-    ("paged", "verify"), ("dense", "prefill"), ("dense", "decode"),
-    ("dense", "verify"), ("two_kinds", "prefill"), ("two_kinds", "decode"),
+    ("paged", "verify"), ("no_prefix", "prefill"), ("no_prefix", "decode"),
+    ("no_prefix", "verify"), ("two_kinds", "prefill"), ("two_kinds", "decode"),
     ("two_kinds", "extend"),
 ])
 def test_every_spec_round_trips_bit_exactly(engines, name, kind):
@@ -162,8 +166,35 @@ def test_the_spec_is_what_the_step_function_reads(engines):
     assert [f.name for f in decode] == [
         "tokens", "positions", "tables", "seeds", "temps", "top_ks"]
     assert [f.dtype for f in decode].count("float32") == 1
-    assert engines("dense")._specs["prefill", 16][0] == launch_block.Field(
-        "slot", ())
+    # Without the prefix cache there is no extend family to lay out; a
+    # prefill still names its blocks.
+    no_prefix = engines("no_prefix")._specs
+    assert "extend" not in {kind for kind, _ in no_prefix}
+    assert no_prefix["prefill", 16][0] == launch_block.Field(
+        "block_ids", [(1,)])
+
+
+@pytest.mark.parametrize("name", sorted(ENGINES))
+def test_the_engine_has_four_program_families(engines, name):
+    """Prefill, decode, extend and verify, over one pool: every program
+    of every engine here lowers as ``(params, kv, block)`` with the
+    pool's whole state, and nothing else, donated."""
+    engine = engines(name)
+    families = {"prefill": engine._prefill_fns, "decode": engine._decode_fns,
+                "extend": engine._extend_fns, "verify": engine._verify_fns}
+    assert {kind for kind, _ in engine._specs} == {
+        kind for kind, fns in families.items() if fns}
+    assert {(kind, rung) for kind, fns in families.items() for rung in fns} \
+        == set(engine._specs)
+    for (kind, rung), spec in engine._specs.items():
+        block = launch_block.pack(spec, launch_block.zeros(spec))
+        lowered = families[kind][rung].lower(
+            engine.params, engine.pool.kv_state(), block)
+        (params, kv, operands), _ = lowered.args_info
+        assert all(a.donated for a in jax.tree.leaves(kv)), (kind, rung)
+        assert not any(
+            a.donated for a in jax.tree.leaves((params, operands))
+        ), (kind, rung)
 
 
 def test_pack_hands_back_a_buffer_of_its_own():
@@ -207,7 +238,7 @@ def _guarded(engine, call):
     return out, tuple(reg.counter(n).value - b for n, b in zip(names, before))
 
 
-@pytest.mark.parametrize("name", ["paged", "dense"])
+@pytest.mark.parametrize("name", ["paged", "no_prefix"])
 def test_prefill_decode_and_verify_are_one_transfer_and_one_launch(warm, name):
     engine = warm(name)
     engine.pool.reset()

@@ -261,11 +261,23 @@ class TestMaterialize:
             np.float32
         )
         qw = P.QuantizedWeight(*P._quantize_rows_host(w, "int8"))
+        # The dequantized weight is the same in a trace as outside one,
+        # bit for bit ...
+        assert np.array_equal(
+            np.asarray(jax.jit(P.materialize)(qw)),
+            np.asarray(qw.dequantize()),
+        )
+        # ... and the dot that consumes it agrees with numpy's to the
+        # rounding of an 8-term float32 sum of O(1) summands: one
+        # output here is a cancelling sum (-0.0178) that the two
+        # summation orders round 2.4e-7 apart, which no relative
+        # tolerance on the RESULT covers.
         f = jax.jit(lambda t, x: jnp.dot(x, P.materialize(t)))
         x = jnp.ones((2, 8))
-        assert np.allclose(
+        np.testing.assert_allclose(
             np.asarray(f(qw, x)),
             np.asarray(x) @ np.asarray(qw.dequantize()),
+            rtol=1e-5, atol=1e-5,
         )
 
     def test_take_rows_gathers_then_dequantizes(self):

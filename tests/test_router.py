@@ -24,7 +24,7 @@ import urllib.request
 import numpy as np
 import pytest
 
-from tensorflow_examples_tpu.serving import kv_cache
+from conftest import slot_pool
 from tensorflow_examples_tpu.serving.batcher import (
     ContinuousBatcher,
     Request,
@@ -65,10 +65,7 @@ class _FakeEngine:
         base["max_len"] = max_len
         self.model_cfg = transformer.TransformerConfig(**base)
         self.registry = MetricsRegistry()
-        self.pool = kv_cache.KVCachePool(
-            num_layers=1, num_slots=max_slots, num_heads=1,
-            max_len=max_len, head_dim=2, registry=self.registry,
-        )
+        self.pool = slot_pool(max_slots, max_len, self.registry)
         self.step_delay = step_delay
         self.warmed = True
 
@@ -578,17 +575,27 @@ class TestProbeGarbage:
     """ISSUE 10 satellite: malformed /health bodies mark the replica
     unhealthy instead of risking the probe loop."""
 
+    # A peer that dies between the headers and the body: more bytes
+    # declared than sent (http.client.IncompleteRead, no OSError).
+    TORN = b'{"ok": tr'
+
     def _garbage_server(self, payload: bytes):
         import http.server
         import threading
+
+        declared = len(payload) + (230 if payload == self.TORN else 0)
 
         class H(http.server.BaseHTTPRequestHandler):
             def do_GET(self):
                 self.send_response(200)
                 self.send_header("Content-Type", "application/json")
-                self.send_header("Content-Length", str(len(payload)))
+                self.send_header("Content-Length", str(declared))
                 self.end_headers()
                 self.wfile.write(payload)
+
+            def do_POST(self):
+                self.rfile.read(int(self.headers["Content-Length"]))
+                self.do_GET()
 
             def log_message(self, *a):
                 pass
@@ -601,10 +608,12 @@ class TestProbeGarbage:
 
     @pytest.mark.timeout(120)
     @pytest.mark.parametrize(
-        "payload", [b"<<<not json", b"[1, 2, 3]", b'"just a string"'],
-        ids=["non-json", "json-array", "json-string"],
+        "payload", [b"<<<not json", b"[1, 2, 3]", b'"just a string"', TORN],
+        ids=["non-json", "json-array", "json-string", "torn-body"],
     )
     def test_garbage_health_body_marks_unhealthy(self, payload):
+        from tensorflow_examples_tpu.serving.router import post_json
+
         garbage = self._garbage_server(payload)
         replicas = [_replica()]
         urls = [
@@ -613,6 +622,9 @@ class TestProbeGarbage:
         ]
         router = Router(urls, cfg=RouterConfig())
         try:
+            # The one JSON-over-HTTP client keeps its contract against
+            # the same peer: status 0, never an exception.
+            assert post_json(urls[0] + "/generate", {}, 5.0)[0] == 0
             for _ in range(router.cfg.unhealthy_after):
                 router.probe_once()  # must never raise
             bad, good = router.replicas

@@ -575,9 +575,11 @@ class TestChunkedPrefillGolden:
         ) >= 1
         assert engine.pool.active_slots == 0
 
-    def test_chunk_requires_paged_pool(self):
-        with pytest.raises(ValueError, match="paged pool"):
-            _build_engine(kv_block_size=0, prefill_chunk_tokens=16)
+    def test_chunk_requires_prefix_cache(self):
+        """The chunk program IS the extend rung, which exists only with
+        the prefix cache on."""
+        with pytest.raises(ValueError, match="prefix_cache=True"):
+            _build_engine(prefix_cache=False, prefill_chunk_tokens=16)
 
     def test_chunk_must_be_block_multiple(self):
         with pytest.raises(ValueError, match="multiple of kv_block"):
@@ -751,15 +753,6 @@ class TestHandoffGolden:
         engine.pool.free(d_slot)
         engine.pool.free(i_slot)
         assert importer_stream == donor_stream
-
-    def test_handoff_requires_paged_pool(self):
-        engine = _build_engine(kv_block_size=0)
-        batcher = ContinuousBatcher(engine)
-        fut = batcher.submit(Request(
-            prompt=[1, 2, 3], kind="prefill",
-        ))
-        with pytest.raises(ValueError, match="paged KV pool"):
-            fut.result(timeout=5)
 
 
 # ------------------------------------------ streaming delta (ISSUE 15)
@@ -1133,10 +1126,3 @@ class TestSchemaV9:
                 assert any(
                     f"v9 serving key '{key}'" in p for p in problems
                 ), (version, key, problems)
-
-    def test_dense_line_carries_no_v9_keys(self):
-        engine = _build_engine(kv_block_size=0)
-        batcher = ContinuousBatcher(engine)
-        line = batcher.stats_line()
-        for key in schema.SERVING_KEYS_V9:
-            assert key not in line["serving"]

@@ -25,6 +25,7 @@ import urllib.request
 import numpy as np
 import pytest
 
+from conftest import slot_pool
 from tensorflow_examples_tpu.models import transformer
 from tensorflow_examples_tpu.serving import kv_cache, paged_kv
 from tensorflow_examples_tpu.serving.batcher import (
@@ -149,11 +150,14 @@ class TestBuckets:
             kv_cache.pick_bucket(ladder, 65)
 
 
-class TestKVCachePool:
+class TestPoolSlots:
+    """The pool's slot interface (what the batcher and the fake engines
+    of the other suites use of it), before any block is claimed."""
+
     def _pool(self, slots=3, registry=None):
-        return kv_cache.KVCachePool(
+        return PagedKVPool(
             num_layers=1, num_slots=slots, num_heads=2, max_len=8,
-            head_dim=4, registry=registry or MetricsRegistry(),
+            head_dim=4, block_size=4, registry=registry or MetricsRegistry(),
         )
 
     def test_alloc_free_cycle(self):
@@ -179,7 +183,8 @@ class TestKVCachePool:
         pool.lengths[s] = 5
         pool.free(s)  # publish happens on transition
         g = reg.gauge_values()
-        assert g["serving/kv_occupancy"] == 0.25
+        assert g["serving/kv_slot_occupancy"] == 0.25
+        assert g["serving/kv_occupancy"] == 0.0  # blocks, none claimed
         assert g["serving/kv_slots_active"] == 1
         assert g["serving/kv_tokens"] == 0  # free() zeroed slot s
 
@@ -205,12 +210,28 @@ class TestVarlenAttention:
         )
 
         rng = np.random.default_rng(0)
-        S, H, K, D = 3, 2, 16, 4
+        S, H, K, D, BS = 3, 2, 16, 4, 4
         q = jnp.asarray(rng.standard_normal((S, H, D)), jnp.float32)
         k = jnp.asarray(rng.standard_normal((S, H, K, D)), jnp.float32)
         v = jnp.asarray(rng.standard_normal((S, H, K, D)), jnp.float32)
         lengths = jnp.asarray([1, 7, 16], jnp.int32)
-        out = kv_cache.varlen_decode_attention(q, k, v, lengths)
+        # The same K/V as the pool holds them: [NB, BS, H*D] blocks,
+        # the slots' blocks interleaved behind block 0 (the null one).
+        nb = K // BS
+        tables = 1 + np.arange(S * nb, dtype=np.int32).reshape(nb, S).T
+
+        def blocks(x):  # [S, H, K, D] -> [1 + S*nb, BS, H*D]
+            rows = np.asarray(x).transpose(0, 2, 1, 3).reshape(
+                S, nb, BS, H * D
+            )
+            pool = np.zeros((1 + S * nb, BS, H * D), np.float32)
+            pool[tables] = rows
+            return jnp.asarray(pool)
+
+        out = kv_cache.varlen_decode_attention(
+            q, blocks(k), blocks(v), lengths,
+            block_tables=jnp.asarray(tables),
+        )
         for s in range(S):
             ref = decode_attention_reference(
                 q[s][None, :, None, :], k[s][None], v[s][None],
@@ -288,10 +309,47 @@ class TestEngine:
             warm_engine.prefill(0, [1] * 65)
 
     def test_rejects_unsupported_models(self):
-        with pytest.raises(NotImplementedError, match="dense"):
+        with pytest.raises(NotImplementedError, match="moe_experts"):
             InferenceEngine(tiny_cfg(moe_experts=4), {})
         with pytest.raises(ValueError, match="ring"):
             InferenceEngine(tiny_cfg(attention="ring"), {})
+
+    @pytest.mark.timeout(180)
+    def test_default_serve_config_serves_paged(self):
+        """``ServeConfig()`` as it comes: the block pool at 16 rows a
+        block, prefix cache on, and the goldens' first request served
+        token for token as the cacheless reference replays it."""
+        cfg = tiny_cfg()
+        eng = InferenceEngine(
+            cfg, _tiny_params(cfg), cfg=ServeConfig(),
+            registry=MetricsRegistry(),
+        )
+        assert isinstance(eng.pool, PagedKVPool)
+        assert eng.pool.block_size == 16 and eng.pool.prefix_cache_enabled
+        assert {kind for kind, _ in eng._specs} == {
+            "prefill", "decode", "extend"
+        }
+        req = _mixed_requests(20, cfg)[0]
+        slot = eng.pool.alloc()
+        tok, _ = eng.prefill(slot, req.prompt, seed=req.seed)
+        served = [tok]
+        for _ in range(req.max_new_tokens - 1):
+            served.append(eng.decode(
+                [(slot, served[-1], req.seed, req.temperature, req.top_k)]
+            )[slot])
+        eng.pool.free(slot)
+        assert served == eng.reference_generate(
+            req.prompt, max_new=req.max_new_tokens, seed=req.seed,
+            temperature=req.temperature, top_k=req.top_k,
+        )
+
+    @pytest.mark.parametrize("block", [0, -16])
+    def test_block_size_zero_is_refused(self, block):
+        """The value that selected the dense pool is refused by name
+        where it is written — no ``ServeConfig`` holds it — not read as
+        "some default"."""
+        with pytest.raises(ValueError, match="dense .* pool .* is gone"):
+            ServeConfig(kv_block_size=block)
 
     def test_top_logprobs_normalized_and_ordered(self):
         logits = np.asarray([0.1, 3.0, -1.0, 2.0], np.float32)
@@ -380,10 +438,7 @@ class _FakeEngine:
         )
         self.model_cfg = tiny_cfg(max_len=max_len)
         self.registry = MetricsRegistry()
-        self.pool = kv_cache.KVCachePool(
-            num_layers=1, num_slots=max_slots, num_heads=1, max_len=max_len,
-            head_dim=2, registry=self.registry,
-        )
+        self.pool = slot_pool(max_slots, max_len, self.registry)
         self.step_delay = step_delay
         self.gate = threading.Event()
         self.gate.set()
@@ -706,7 +761,18 @@ class TestFrontend:
             {"prompt": [1, 2, 3], "top_n": 4},
         )
         assert status == 200
-        assert reply["top"] == eng.reference_classify([1, 2, 3], top_n=4)
+        # The served logits are the L16 prefill program's, the
+        # reference's a max_len-wide forward's: the same float32 math
+        # summed in another order, so the log-probabilities agree to
+        # float32 rounding, not bit for bit.
+        ref = eng.reference_classify([1, 2, 3], top_n=4)
+        assert [t["token"] for t in reply["top"]] == [
+            t["token"] for t in ref
+        ]
+        np.testing.assert_allclose(
+            [t["logprob"] for t in reply["top"]],
+            [t["logprob"] for t in ref], rtol=0, atol=1e-5,
+        )
 
     @pytest.mark.timeout(120)
     def test_generate_logprobs_over_http(self, live_frontend):
@@ -1019,29 +1085,27 @@ class TestPagedPool:
 
     def test_memory_claim_mixed_lengths_half_of_dense(self):
         """Acceptance: a mixed short/long request set commits <= 1/2 of
-        the dense pool's bytes at equal concurrency, by the pools' own
-        byte accounting."""
+        what a cache of ``max_len`` rows a slot would at equal
+        concurrency: slots x max_len x the bytes one token's K and V
+        take in every layer."""
         lengths = [4, 8, 12, 4, 60, 8, 4, 8]
-        dense = kv_cache.KVCachePool(
-            num_layers=2, num_slots=8, num_heads=2, max_len=64,
-            head_dim=16, registry=MetricsRegistry(),
-        )
+        layers, heads, head_dim, max_len = 2, 2, 16, 64
         paged = PagedKVPool(
-            num_layers=2, num_slots=8, num_heads=2, max_len=64,
-            head_dim=16, block_size=8, registry=MetricsRegistry(),
+            num_layers=layers, num_slots=8, num_heads=heads,
+            max_len=max_len, head_dim=head_dim, block_size=8,
+            registry=MetricsRegistry(),
         )
         for ln in lengths:
-            ds = dense.alloc()
-            dense.lengths[ds] = ln
             ps = paged.alloc()
             paged.assign(ps, paged.alloc_blocks(-(-ln // 8)))
             paged.lengths[ps] = ln
-        assert dense.active_slots == paged.active_slots == 8
-        assert paged.used_bytes() <= dense.used_bytes() / 2, (
-            f"paged {paged.used_bytes()} vs dense {dense.used_bytes()}"
+        assert paged.active_slots == 8
+        bytes_per_token = 2 * layers * heads * head_dim * 4  # K and V, f32
+        dense_bytes = 8 * max_len * bytes_per_token
+        assert paged.used_bytes() <= dense_bytes / 2, (
+            f"paged {paged.used_bytes()} vs dense {dense_bytes}"
         )
         for s in range(8):
-            dense.free(s)
             paged.free(s)
 
 
@@ -1295,14 +1359,17 @@ class TestInt8KV:
             )
         assert eng.post_warmup_recompiles() == 0
 
-    def test_int8_requires_paged_pool(self):
+    def test_int8_needs_no_other_option(self):
+        """``kv_dtype`` alone selects the quantized pool: there is no
+        second pool it could be refused for."""
         cfg = tiny_cfg()
-        with pytest.raises(ValueError, match="paged"):
-            InferenceEngine(
-                cfg, _tiny_params(cfg),
-                cfg=ServeConfig(kv_dtype="int8"),
-                registry=MetricsRegistry(),
-            )
+        eng = InferenceEngine(
+            cfg, _tiny_params(cfg),
+            cfg=ServeConfig(kv_dtype="int8"),
+            registry=MetricsRegistry(),
+        )
+        assert eng.pool.quantized and eng.pool.kv_bits == 8
+        assert len(eng.pool.kv_state()) == 4  # k, v and their scales
 
 
 class TestFp8KV:

@@ -335,7 +335,6 @@ class TestThePoolsKinds:
 
 
 REFUSED = [
-    (dict(kv_block_size=0, kv_blocks=0, prefill_chunk_tokens=0), "dense (un-paged) KV cache"),
     (dict(spec_decode_k=2), "speculative verify"),
     (dict(role="prefill"), "KV page export/import"),
     (dict(role="decode"), "KV page export/import"),
@@ -352,6 +351,11 @@ class TestWhatIsRefused:
         with pytest.raises(NotImplementedError, match="cohere2_moe") as e:
             make_engine(model, **over)
         assert mechanism in str(e.value) and "GPT-2 only" in str(e.value)
+
+    def test_block_size_zero_is_refused_as_for_any_block(self, model):
+        """Not a mechanism that serves GPT-2 only: the dense pool serves nobody."""
+        with pytest.raises(ValueError, match="dense .* pool .* is gone"):
+            make_engine(model, kv_block_size=0, kv_blocks=0, prefill_chunk_tokens=0)
 
     def test_sharded_serving_is_refused(self, model):
         mcfg, params = model

@@ -570,11 +570,11 @@ class TestShardedServing:
         )
         return InferenceEngine(mcfg, params, cfg=serve, sharding=sc)
 
-    # Every placement test runs on both pools: the dense [L, S, H,
-    # max_len, D] array (heads on dim 2) and the paged pool's per-layer
-    # [NB, BS, H*D] arrays (whole heads on the last dim).
+    # Every placement test runs at the default block size and at 8:
+    # the pool's per-layer [NB, BS, H*D] arrays shard whole heads on
+    # the last dim whatever NB and BS are.
     POOLS = pytest.mark.parametrize(
-        "serve_kw", [{}, {"kv_block_size": 8}], ids=["dense", "paged"]
+        "serve_kw", [{}, {"kv_block_size": 8}], ids=["block16", "block8"]
     )
 
     @POOLS
@@ -590,21 +590,15 @@ class TestShardedServing:
 
         def specs():
             arrays = jax.tree.leaves((eng.pool.k, eng.pool.v))
-            assert len(arrays) == (
-                2 * eng.model_cfg.num_layers if eng.paged else 2
-            )
+            assert len(arrays) == 2 * eng.model_cfg.num_layers
             return [a.sharding.spec for a in arrays]
 
         old_specs = specs()
-        assert all("model" in str(sp) for sp in old_specs)
-        if eng.paged:
-            mcfg = eng.model_cfg
-            assert all(
-                tuple(sp) == (None, None, "model") for sp in old_specs
-            )
-            assert eng.pool.k[0].addressable_shards[0].data.shape[-1] == (
-                mcfg.num_heads // 2 * mcfg.head_dim
-            )
+        mcfg = eng.model_cfg
+        assert all(tuple(sp) == (None, None, "model") for sp in old_specs)
+        assert eng.pool.k[0].addressable_shards[0].data.shape[-1] == (
+            mcfg.num_heads // 2 * mcfg.head_dim
+        )
         assert eng.param_sharding_digest is not None
         # reallocate() preserves the pool placement.
         eng.pool.reallocate()

@@ -30,7 +30,7 @@ import time
 import numpy as np
 import pytest
 
-from tensorflow_examples_tpu.serving import kv_cache
+from conftest import slot_pool
 from tensorflow_examples_tpu.serving.batcher import ContinuousBatcher
 from tensorflow_examples_tpu.serving.engine import ServeConfig
 from tensorflow_examples_tpu.serving.frontend import ServingFrontend
@@ -76,10 +76,7 @@ class _FakeEngine:
         base["max_len"] = max_len
         self.model_cfg = transformer.TransformerConfig(**base)
         self.registry = MetricsRegistry()
-        self.pool = kv_cache.KVCachePool(
-            num_layers=1, num_slots=max_slots, num_heads=1,
-            max_len=max_len, head_dim=2, registry=self.registry,
-        )
+        self.pool = slot_pool(max_slots, max_len, self.registry)
         self.warmed = True
 
     def post_warmup_recompiles(self):

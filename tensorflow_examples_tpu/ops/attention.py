@@ -8,22 +8,38 @@ attention over KV *blocks* with an online softmax and can return the
 per-row logsumexp, so ring hops merge kernel outputs exactly.
 
 Design (TPU-first, not a CUDA translation):
-- The grid is (batch·head, q-block, kv-block) with the KV dimension
-  innermost: only ONE [block_kv, head_dim] K/V tile is VMEM-resident at
-  a time, so sequence length is bounded by HBM, not VMEM — 16k–32k+
-  tokens run with the same kernel. The online-softmax running
-  (max, sum, acc) live in VMEM scratch carried across the inner KV grid
-  steps; outputs are written on the last step.
-- All matmuls run on the MXU in f32 accumulation
-  (``preferred_element_type``), inputs may be bf16.
-- Causal masking skips whole KV blocks above the diagonal (``pl.when``
-  guards: no MXU work issued) and masks inside the diagonal block with
-  ``broadcasted_iota``.
-- Backward is the standard two-kernel split (dkv by KV block, dq by Q
-  block) using the saved logsumexp, so the [seq, seq] score matrix is
-  never materialized. When the forward exposed the logsumexp, its
-  cotangent is exact: d(lse_i)/d(s_ij) = p_ij folds into
-  ``ds = p · (dp − delta + dlse)``.
+- One grid step meets a GROUP of rows (queries in the forward and dq
+  kernels, keys in the dk/dv kernel) with a CHUNK of the other
+  sequence, each up to ``_SPAN`` rows and whole in VMEM (GPT-2's 1,024
+  keys of 64 are 128 KB): few, fat grid steps. Longer sequences walk
+  several chunks on the innermost grid axis with the running state in
+  VMEM scratch, so sequence length stays bounded by HBM, not VMEM.
+- Where the causal diagonal crosses such a rectangle is one of a few
+  offsets known when the kernel is traced, so each gets a static body:
+  every tile of ``block`` rows takes what it sees of the chunk in ONE
+  shot — the slices wholly visible as one wide product, the slices the
+  diagonal crosses under a constant mask, the hidden ones not at all.
+  No loop over small tiles, no mask but on the diagonal, and with one
+  chunk no running softmax state either. A rectangle above the diagonal
+  costs neither a fetch nor arithmetic.
+- Every product takes its operands in the dtype the caller passed and
+  accumulates in f32 (``preferred_element_type``): bf16 inputs feed the
+  MXU bf16, f32 inputs f32. Scores, the softmax statistics, ``exp`` and
+  the accumulators are f32 either way; ``p`` and ``ds`` are rounded to
+  the operand dtype before their products, as ``attention_reference``
+  rounds ``p``.
+- Backward is the standard two-kernel split (dkv by KV tile, dq by Q
+  tile) using the saved logsumexp, so the [seq, seq] score matrix is
+  never materialized. The dk/dv kernel works on transposed tiles
+  (``k·qᵀ``: keys on sublanes, queries on lanes), so its four products
+  need no transpose and the per-query vectors ``lse`` and ``delta`` are
+  lane-dense rows; the forward's ``lse`` output keeps its
+  ``[batch·heads, seq, 1]`` shape and is re-laid outside the kernels.
+  When the forward exposed the logsumexp, its cotangent is exact:
+  d(lse_i)/d(s_ij) = p_ij folds into ``ds = p · (dp − (delta − dlse))``,
+  one subtraction outside the kernels.
+- The tiles of each kernel are a pure function of the shapes and the
+  dtype (``flash_blocks``), from a sweep on the chip (PERF.md §6).
 
 On non-TPU backends the same kernels run in Pallas interpret mode (used
 by the CPU test suite) and an XLA reference implementation is provided
@@ -41,6 +57,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from tensorflow_examples_tpu.core.device import pallas_interpret
+from tensorflow_examples_tpu.telemetry.spans import span
 
 NEG_INF = -1e30
 
@@ -78,328 +95,549 @@ def attention_reference(
     ).astype(q.dtype)
 
 
+# ----------------------------------------------------------- tile algebra
+#
+# One grid step meets a GROUP of rows (queries in the forward and dq
+# kernels, keys in dk/dv) with a CHUNK of the other sequence, both up to
+# ``_SPAN`` long and whole in VMEM. Where the causal diagonal crosses
+# that rectangle is one of a few offsets known when the kernel is
+# traced (``_crossings``), so the body for each is static: every tile
+# of ``block`` rows takes the visible part of the chunk in one shot —
+# the slices wholly visible as one wide product, the slices the
+# diagonal crosses under a constant mask, the hidden ones not at all.
+
+_NN = (((1,), (0,)), ((), ()))  # a · b
+_NT = (((1,), (1,)), ((), ()))  # a · bᵀ
+
+_SPAN = 1024  # rows of a group, and of a chunk, at most
+_SCOPED_VMEM_DEFAULT = 16 << 20  # Mosaic's own limit on a v5e
+_TILE_TEMPS = 8  # f32 copies of a tile x chunk score block kept alive
+
+
+def _dot(a, b, dims=_NN):
+    """One MXU product: operands as they are, the sum in f32."""
+    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _span(seq: int, block: int) -> int:
+    """Rows of one group or chunk: the whole sequence up to ``_SPAN``,
+    else the most ``block``-row tiles under that which divide it."""
+    tiles = seq // block
+    fit = max(1, _SPAN // block)
+    return block * next(
+        t for t in range(min(tiles, fit), 0, -1) if tiles % t == 0
+    )
+
+
+def _crossings(groups, group, chunks, chunk, shift):
+    """Every place the diagonal can stand in a group x chunk rectangle
+    of the grid, as ``d`` = the last column of the chunk that the
+    group's first row sees (columns are keys for the forward and dq,
+    query rows — counted from the chunk's end — for dk/dv). All wholly
+    visible rectangles are the one case ``d = chunk - 1``; a rectangle
+    with ``d + group - 1 < 0`` is wholly hidden and not listed."""
+    found = {
+        min(i * group + shift - c * chunk, chunk - 1)
+        for i in range(groups) for c in range(chunks)
+    }
+    return tuple(sorted(d for d in found if d > -group))
+
+
+def _visible(d, row, block, step, chunk):
+    """For the tile of ``block`` rows from ``row`` on, in a rectangle
+    with the diagonal at ``d``: how many ``step``-column slices of the
+    chunk all its rows see, and how many any row sees.
+    Bottom-right-aligned causal diagonal: row i sees keys
+    <= i + (seq_kv - seq_q), matching attention_reference."""
+    clip = lambda x: min(max(x, 0), chunk)
+    return clip(d + row + 1) // step, clip(d + row + block - 1 + step) // step
+
+
+def _ahead(shape, columns_axis):
+    """Column index minus row index inside a tile of ``shape``."""
+    cols = lax.broadcasted_iota(jnp.int32, shape, columns_axis)
+    return cols - lax.broadcasted_iota(jnp.int32, shape, 1 - columns_axis)
+
+
+def _by_crossing(body, d, crossings, chunk):
+    """Run the one static ``body(d)`` this grid step's rectangle needs:
+    the listed place where its diagonal stands, nothing if it is wholly
+    hidden; not causal, everything is visible everywhere."""
+    if crossings is None:
+        return body(chunk - 1)
+    d = jnp.minimum(d, chunk - 1)
+    for at in crossings:
+        pl.when(d == at)(functools.partial(body, at))
+
+
 # --------------------------------------------------------------- forward
 
 
 def _fwd_kernel(
-    q_ref, k_ref, v_ref, *rest, sm_scale, causal, has_bias=False
+    q_ref, k_ref, v_ref, *rest,
+    sm_scale, offset, block_q, block_kv, crossings, has_bias=False,
 ):
-    if has_bias:
-        kb_ref, o_ref, lse_ref, m_s, l_s, acc_s = rest
-    else:
-        kb_ref = None
-        o_ref, lse_ref, m_s, l_s, acc_s = rest
-    block_q, head_dim = q_ref.shape[1], q_ref.shape[2]
-    block_kv = k_ref.shape[1]
-    qi, kj = pl.program_id(1), pl.program_id(2)
-    num_kv = pl.num_programs(2)
-    # Bottom-right-aligned causal diagonal: query i attends keys
-    # <= i + (seq_kv - seq_q), matching attention_reference.
-    offset = num_kv * block_kv - pl.num_programs(1) * block_q
-    q_offset = qi * block_q
-    kv_offset = kj * block_kv
+    kb_ref = rest[0] if has_bias else None
+    o_ref, lse_ref, *state = rest[has_bias:]
+    group, chunk = q_ref.shape[1], k_ref.shape[1]
+    gi, c = pl.program_id(1), pl.program_id(2)
 
-    @pl.when(kj == 0)
-    def _init():
-        m_s[...] = jnp.full_like(m_s, NEG_INF)
-        l_s[...] = jnp.zeros_like(l_s)
-        acc_s[...] = jnp.zeros_like(acc_s)
-
-    # Causal: KV blocks entirely above the diagonal contribute nothing —
-    # issue no MXU work for them.
-    def _attend():
-        q = q_ref[0].astype(jnp.float32) * sm_scale
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        s = lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [block_q, block_kv]
+    def scores(q, a, b):
+        # The f32 scores are scaled, not q: a bf16 x bf16 product is
+        # exact in f32, a pre-scaled bf16 q only for power-of-two scales.
+        s = _dot(q, k_ref[0, a:b, :], _NT) * sm_scale  # [block_q, b - a]
         if has_bias:
-            s = s + kb_ref[0]  # [1, block_kv] broadcasts over rows
-        if causal:
-            row = q_offset + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_kv), 0
+            s = s + kb_ref[0, 0, :, a:b]  # [1, b - a] broadcasts over rows
+        return s
+
+    def finish(rows, m, l, acc):
+        l = jnp.maximum(l, 1e-30)
+        o_ref[0, rows, :] = (acc / l).astype(o_ref.dtype)
+        lse_ref[0, rows, :] = m + jnp.log(l)
+
+    def attend(d):
+        for row in range(0, group, block_q):
+            rows = slice(row, row + block_q)
+            full, some = _visible(d, row, block_q, block_kv, chunk)
+            if not some:
+                continue
+            q = q_ref[0, rows, :]
+            split, end = full * block_kv, some * block_kv
+            parts = []
+            if split:
+                parts.append((0, split, scores(q, 0, split)))
+            if end > split:
+                seen = _ahead((block_q, end - split), 1) <= d + row - split
+                parts.append((
+                    split, end,
+                    jnp.where(seen, scores(q, split, end), NEG_INF),
+                ))
+            m = functools.reduce(
+                jnp.maximum,
+                [jnp.max(s, axis=1, keepdims=True) for _, _, s in parts],
             )
-            col = kv_offset + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_kv), 1
+            if state:  # merge with what earlier chunks left
+                m_s, l_s, acc_s = state
+                m_old, m = m_s[rows, :], jnp.maximum(m_s[rows, :], m)
+                alpha = jnp.exp(m_old - m)
+                l, acc = l_s[rows, :] * alpha, acc_s[rows, :] * alpha
+            else:
+                l = acc = 0.0
+            for a, b, s in parts:
+                p = jnp.exp(s - m)
+                l = l + jnp.sum(p, axis=1, keepdims=True)
+                v = v_ref[0, a:b, :]
+                acc = acc + _dot(p.astype(v.dtype), v)
+            if state:
+                m_s[rows, :], l_s[rows, :], acc_s[rows, :] = m, l, acc
+            else:
+                finish(rows, m, l, acc)
+
+    if state:
+        m_s, l_s, acc_s = state
+
+        @pl.when(c == 0)
+        def _init():
+            m_s[...] = jnp.full_like(m_s, NEG_INF)
+            l_s[...] = jnp.zeros_like(l_s)
+            acc_s[...] = jnp.zeros_like(acc_s)
+
+    _by_crossing(attend, gi * group + offset - c * chunk, crossings, chunk)
+
+    if state:
+        @pl.when(c == pl.num_programs(2) - 1)
+        def _finalize():
+            finish(slice(None), m_s[...], l_s[...], acc_s[...])
+
+
+def _compiler_params(rows: int, block_elems: int):
+    """Mosaic's scoped-VMEM limit from the tile: ``rows`` operand,
+    output and scratch rows, each a 128-lane f32 row at most and held
+    twice (the pipeline's two buffers), plus ``_TILE_TEMPS`` f32 copies
+    of the tile x chunk score block for what a kernel body keeps alive
+    (scores, p, dp, ds, the mask, the operand-dtype casts). Never under
+    the compiler's own default, which the measured tiles stay inside."""
+    need = 2 * rows * 128 * 4 + _TILE_TEMPS * 4 * block_elems
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=max(need, _SCOPED_VMEM_DEFAULT),
+    )
+
+
+def _rows(x, block):
+    """[n, seq] -> [n, seq // block, 1, block]: a per-position f32
+    vector (lse, delta, the key bias), lane-dense, ``block`` of it a
+    row. A block's last two dims are then the array's own, which Mosaic
+    always accepts (a rank-2 [batch, seq_kv] bias with a (1, block_kv)
+    block is unlowerable whenever batch > 1 — compiled-TPU-only,
+    interpret mode never enforces it)."""
+    return x.reshape(x.shape[0], x.shape[1] // block, 1, block)
+
+
+def _rectangles(rows, cols, row_block, col_block, shift, causal):
+    """The grid of group x chunk rectangles one kernel walks: (groups,
+    chunks), their sizes, the crossings to trace bodies for, and the
+    chunk a grid step fetches — a chunk wholly hidden from the group
+    re-names the last one that is not: same block index, so the
+    pipeline fetches nothing."""
+    group, chunk = _span(rows, row_block), _span(cols, col_block)
+    groups, chunks = rows // group, cols // chunk
+    if not causal:
+        return (groups, chunks), group, chunk, None, lambda i, c: c
+    crossings = _crossings(groups, group, chunks, chunk, shift)
+    visit = lambda i, c: jnp.minimum(
+        c, (i * group + group - 1 + shift) // chunk
+    )
+    return (groups, chunks), group, chunk, crossings, visit
+
+
+def _traced_once(call):
+    """Every layer of a model makes the same kernel call at the same
+    shapes, and tracing and lowering a kernel's body in Python is most
+    of what such a call costs at start-up (GPT-2 124M's train step:
+    4.1 s of it for 36 calls, 1.4 s for three, on the sandbox's CPU).
+    So ``call(*arrays, **static)`` — ``static`` hashable, an array may
+    be None — is traced once per operand types and ``static``, and its
+    jaxpr replayed for every other call; the lowering of equal
+    equations is shared the same way. The least recently used traces
+    go, so a swept ``sm_scale`` leaks nothing."""
+
+    @functools.lru_cache(maxsize=64)
+    def trace(types, static):
+        def placed(*given):
+            given = iter(given)
+            return call(
+                *(t if t is None else next(given) for t in types),
+                **dict(static),
             )
-            s = jnp.where(row + offset >= col, s, NEG_INF)
-        m = m_s[...]
-        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        m_s[...] = m_new
-        l_s[...] = l_s[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_s[...] = acc_s[...] * alpha + jnp.dot(
-            p, v, preferred_element_type=jnp.float32
+
+        return jax.make_jaxpr(placed, return_shape=True)(
+            *(t for t in types if t is not None)
         )
 
-    if causal:
-        pl.when(q_offset + block_q - 1 + offset >= kv_offset)(_attend)
-    else:
-        _attend()
+    @functools.wraps(call)
+    def replay(*arrays, **static):
+        types = tuple(a if a is None else jax.typeof(a) for a in arrays)
+        closed, shape = trace(types, tuple(sorted(static.items())))
+        out = jax.core.eval_jaxpr(
+            closed.jaxpr, closed.consts, *(a for a in arrays if a is not None)
+        )
+        return jax.tree.unflatten(jax.tree.structure(shape), out)
 
-    @pl.when(kj == num_kv - 1)
-    def _finalize():
-        l = jnp.maximum(l_s[...], 1e-30)
-        o_ref[0] = (acc_s[...] / l).astype(o_ref.dtype)
-        lse_ref[0] = (m_s[...] + jnp.log(l)).astype(jnp.float32)
+    return replay
 
 
+@_traced_once
 def _flash_fwd(
-    q, k, v, sm_scale, causal, block_q, block_kv, interpret, kb=None, heads=1
+    q, k, v, kb=None, *, sm_scale, causal, blocks, interpret, heads=1
 ):
     bh, seq_q, head_dim = q.shape
     seq_kv = k.shape[1]
-    grid = (bh, seq_q // block_q, seq_kv // block_kv)
-    kernel = functools.partial(
-        _fwd_kernel, sm_scale=sm_scale, causal=causal, has_bias=kb is not None
+    block_q, block_kv = blocks
+    offset = seq_kv - seq_q
+    grid, group, chunk, crossings, visit = _rectangles(
+        seq_q, seq_kv, block_q, block_kv, offset, causal
     )
-    in_specs = [
-        pl.BlockSpec((1, block_q, head_dim), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, block_kv, head_dim), lambda b, i, j: (b, j, 0)),
-        pl.BlockSpec((1, block_kv, head_dim), lambda b, i, j: (b, j, 0)),
-    ]
-    args = (q, k, v)
+    row_blk = pl.BlockSpec((1, group, head_dim), lambda b, i, c: (b, i, 0))
+    kv_blk = pl.BlockSpec(
+        (1, chunk, head_dim), lambda b, i, c: (b, visit(i, c), 0)
+    )
+    in_specs = [row_blk, kv_blk, kv_blk]
+    args = [q, k, v]
     if kb is not None:
-        # Carried as [batch, 1, seq_kv]: Mosaic constrains the LAST TWO
-        # dims of a block to (8k, 128k) or the full array dim, so a
-        # rank-2 [batch, seq_kv] bias with a (1, block_kv) block is
-        # unlowerable whenever batch > 1 (compiled-TPU-only failure;
-        # interpret mode never enforces it). Rank-3 puts batch outside
-        # the constrained dims. Grid dim 0 is batch·heads, so the batch
-        # row is program_id(0) // heads (static closure).
-        in_specs.append(
-            pl.BlockSpec((1, 1, block_kv), lambda b, i, j: (b // heads, 0, j))
-        )
-        args = args + (kb[:, None, :],)
-    o, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
+        # Grid dim 0 is batch·heads, so the bias row is
+        # program_id(0) // heads (static closure).
+        in_specs.append(pl.BlockSpec(
+            (1, 1, 1, chunk), lambda b, i, c: (b // heads, visit(i, c), 0, 0)
+        ))
+        args.append(_rows(kb, chunk))
+    # One chunk holds every key: nothing to merge, no running state.
+    state = [] if grid[1] == 1 else [
+        pltpu.VMEM((group, 1), jnp.float32),
+        pltpu.VMEM((group, 1), jnp.float32),
+        pltpu.VMEM((group, head_dim), jnp.float32),
+    ]
+    return pl.pallas_call(
+        functools.partial(
+            _fwd_kernel, sm_scale=sm_scale, offset=offset, block_q=block_q,
+            block_kv=block_kv, crossings=crossings, has_bias=kb is not None,
+        ),
+        grid=(bh, *grid),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, head_dim), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
+            row_blk,
+            pl.BlockSpec((1, group, 1), lambda b, i, c: (b, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, seq_q, head_dim), q.dtype),
             jax.ShapeDtypeStruct((bh, seq_q, 1), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, head_dim), jnp.float32),
-        ],
+        scratch_shapes=state,
+        compiler_params=_compiler_params(
+            6 * group + 2 * chunk, block_q * chunk
+        ),
         interpret=interpret,
     )(*args)
-    return o, lse
 
 
 # -------------------------------------------------------------- backward
 
 
-def _bwd_dkv_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dlse_ref, *rest,
-    sm_scale, causal, has_bias=False,
-):
-    if has_bias:
-        kb_ref, dk_ref, dv_ref, dk_s, dv_s = rest
-    else:
-        kb_ref = None
-        dk_ref, dv_ref, dk_s, dv_s = rest
-    block_kv, head_dim = k_ref.shape[1], k_ref.shape[2]
-    block_q = q_ref.shape[1]
-    ki, qj = pl.program_id(1), pl.program_id(2)
-    num_q = pl.num_programs(2)
-    offset = pl.num_programs(1) * block_kv - num_q * block_q
-    kv_offset = ki * block_kv
-    q_offset = qj * block_q
-
-    @pl.when(qj == 0)
-    def _init():
-        dk_s[...] = jnp.zeros_like(dk_s)
-        dv_s[...] = jnp.zeros_like(dv_s)
-
-    # Q blocks strictly above this KV block's diagonal see none of it.
-    def _accumulate():
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        q = q_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0]  # [block_q, 1]
-        delta = delta_ref[0]
-        dlse = dlse_ref[0]
-        s = lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * sm_scale  # [block_q, block_kv]
-        if has_bias:
-            s = s + kb_ref[0]
-        if causal:
-            row = q_offset + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_kv), 0
-            )
-            col = kv_offset + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_kv), 1
-            )
-            s = jnp.where(row + offset >= col, s, NEG_INF)
-        p = jnp.exp(s - lse)  # [block_q, block_kv]
-        # dv += p^T do
-        dv_s[...] += lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        # dp = do v^T ; ds = p * (dp - delta + dlse)
-        dp = lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta + dlse)
-        # dk += ds^T q * scale
-        dk_s[...] += sm_scale * lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-
-    if causal:
-        pl.when(q_offset + block_q - 1 + offset >= kv_offset)(_accumulate)
-    else:
-        _accumulate()
-
-    @pl.when(qj == num_q - 1)
-    def _finalize():
-        dk_ref[0] = dk_s[...].astype(dk_ref.dtype)
-        dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
-
-
 def _bwd_dq_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dlse_ref, *rest,
-    sm_scale, causal, has_bias=False,
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
+    sm_scale, offset, block_q, block_kv, crossings, has_bias=False,
 ):
-    if has_bias:
-        kb_ref, dq_ref, dq_s = rest
-    else:
-        kb_ref = None
-        dq_ref, dq_s = rest
-    block_q, head_dim = q_ref.shape[1], q_ref.shape[2]
-    block_kv = k_ref.shape[1]
-    qi, kj = pl.program_id(1), pl.program_id(2)
-    num_kv = pl.num_programs(2)
-    offset = num_kv * block_kv - pl.num_programs(1) * block_q
-    q_offset = qi * block_q
-    kv_offset = kj * block_kv
+    kb_ref = rest[0] if has_bias else None
+    dq_ref, *state = rest[has_bias:]
+    group, chunk = q_ref.shape[1], k_ref.shape[1]
+    gi, c = pl.program_id(1), pl.program_id(2)
 
-    @pl.when(kj == 0)
-    def _init():
-        dq_s[...] = jnp.zeros_like(dq_s)
+    def accumulate(d):
+        for row in range(0, group, block_q):
+            rows = slice(row, row + block_q)
+            full, some = _visible(d, row, block_q, block_kv, chunk)
+            if not some:
+                continue
+            q, do = q_ref[0, rows, :], do_ref[0, rows, :]
+            # The per-row vectors arrive as lane-dense rows; laid along
+            # the sublanes once a tile, to broadcast against its rows.
+            lse = lse_ref[0, 0, :, rows].T  # [block_q, 1]
+            delta = delta_ref[0, 0, :, rows].T
 
-    def _accumulate():
-        q = q_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0]
-        delta = delta_ref[0]
-        dlse = dlse_ref[0]
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        s = lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * sm_scale
-        if has_bias:
-            s = s + kb_ref[0]
-        if causal:
-            row = q_offset + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_kv), 0
-            )
-            col = kv_offset + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_kv), 1
-            )
-            s = jnp.where(row + offset >= col, s, NEG_INF)
-        p = jnp.exp(s - lse)
-        dp = lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta + dlse)
-        dq_s[...] += sm_scale * jnp.dot(
-            ds, k, preferred_element_type=jnp.float32
-        )
+            def part(a, b, seen=None):
+                k, v = k_ref[0, a:b, :], v_ref[0, a:b, :]
+                s = _dot(q, k, _NT) * sm_scale  # [block_q, b - a]
+                if has_bias:
+                    s = s + kb_ref[0, 0, :, a:b]
+                if seen is not None:
+                    s = jnp.where(seen, s, NEG_INF)
+                p = jnp.exp(s - lse)
+                # dp = do v^T; ds = p (dp - delta), delta less any dlse
+                ds = p * (_dot(do, v, _NT) - delta)
+                return _dot(ds.astype(k.dtype), k)
 
-    if causal:
-        pl.when(q_offset + block_q - 1 + offset >= kv_offset)(_accumulate)
-    else:
-        _accumulate()
+            split, end = full * block_kv, some * block_kv
+            dq = part(0, split) if split else 0.0
+            if end > split:
+                seen = _ahead((block_q, end - split), 1) <= d + row - split
+                dq = dq + part(split, end, seen)
+            if state:
+                state[0][rows, :] += dq
+            else:
+                dq_ref[0, rows, :] = (dq * sm_scale).astype(dq_ref.dtype)
 
-    @pl.when(kj == num_kv - 1)
-    def _finalize():
-        dq_ref[0] = dq_s[...].astype(dq_ref.dtype)
+    if state:
+        dq_s, = state
+
+        @pl.when(c == 0)
+        def _init():
+            dq_s[...] = jnp.zeros_like(dq_s)
+
+    _by_crossing(accumulate, gi * group + offset - c * chunk, crossings, chunk)
+
+    if state:
+        @pl.when(c == pl.num_programs(2) - 1)
+        def _finalize():
+            dq_ref[0] = (dq_s[...] * sm_scale).astype(dq_ref.dtype)
 
 
-def _flash_bwd(
-    sm_scale, causal, block_q, block_kv, interpret, residuals, do, dlse,
-    kb=None, heads=1,
+def _bwd_dkv_kernel(
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
+    sm_scale, block_q, block_kv, crossings, has_bias=False,
 ):
-    q, k, v, o, lse = residuals
+    """Transposed tiles: keys on sublanes, queries on lanes. ``k·qᵀ``,
+    ``v·doᵀ``, ``pᵀ·do`` and ``dsᵀ·q`` are then plain products, and
+    ``lse`` / ``delta`` broadcast as the lane-dense rows they arrive as.
+
+    Counted from the far corner — keys from the group's last, query
+    rows from the chunk's last — a group of keys against a chunk of rows
+    is the forward's rectangle with the diagonal top-left aligned: key
+    κ' is seen by rows ρ' <= κ' + d. So the same ``_visible`` says, for
+    each tile of keys, how many slices of rows (from the chunk's end)
+    all of it is seen by, and how many any of it."""
+    kb_ref = rest[0] if has_bias else None
+    dk_ref, dv_ref, *state = rest[has_bias:]
+    group, chunk = k_ref.shape[1], q_ref.shape[1]
+    gi, c = pl.program_id(1), pl.program_id(2)
+    far_group = pl.num_programs(1) - 1 - gi
+    far_chunk = pl.num_programs(2) - 1 - c
+
+    def accumulate(d):
+        for far in range(0, group, block_kv):
+            keys = slice(group - far - block_kv, group - far)
+            full, some = _visible(d, far, block_kv, block_q, chunk)
+            if not some:
+                continue
+            k, v = k_ref[0, keys, :], v_ref[0, keys, :]
+            if has_bias:
+                bias = kb_ref[0, 0, :, keys].T  # [block_kv, 1]
+
+            def part(a, b, seen=None):
+                q, do = q_ref[0, a:b, :], do_ref[0, a:b, :]
+                st = _dot(k, q, _NT) * sm_scale  # [block_kv, b - a]
+                if has_bias:
+                    st = st + bias
+                if seen is not None:
+                    st = jnp.where(seen, st, NEG_INF)
+                pt = jnp.exp(st - lse_ref[0, 0, :, a:b])  # [1, b - a] a key
+                dv = _dot(pt.astype(do.dtype), do)
+                dst = pt * (_dot(v, do, _NT) - delta_ref[0, 0, :, a:b])
+                return _dot(dst.astype(q.dtype), q), dv
+
+            start, split = chunk - some * block_q, chunk - full * block_q
+            dk = dv = 0.0
+            if split > start:
+                # key - row here, against the same bound in far terms
+                seen = _ahead((block_kv, split - start), 0) <= (
+                    d + far + block_kv - some * block_q
+                )
+                dk, dv = part(start, split, seen)
+            if full:
+                dk_p, dv_p = part(split, chunk)
+                dk, dv = dk + dk_p, dv + dv_p
+            if state:
+                state[0][keys, :] += dk
+                state[1][keys, :] += dv
+            else:
+                dk_ref[0, keys, :] = (dk * sm_scale).astype(dk_ref.dtype)
+                dv_ref[0, keys, :] = dv.astype(dv_ref.dtype)
+
+    if state:
+        dk_s, dv_s = state
+
+        @pl.when(c == 0)
+        def _init():
+            dk_s[...] = jnp.zeros_like(dk_s)
+            dv_s[...] = jnp.zeros_like(dv_s)
+
+    _by_crossing(
+        accumulate, far_group * group - far_chunk * chunk, crossings, chunk
+    )
+
+    if state:
+        @pl.when(c == pl.num_programs(2) - 1)
+        def _finalize():
+            dk_ref[0] = (dk_s[...] * sm_scale).astype(dk_ref.dtype)
+            dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
+
+
+@_traced_once
+def _flash_dq(
+    q, k, v, do, lse, delta, kb=None, *,
+    sm_scale, causal, blocks, interpret, heads=1,
+):
+    """dq by query tile; ``lse`` and ``delta`` are [bh, seq_q] f32."""
     bh, seq_q, head_dim = q.shape
     seq_kv = k.shape[1]
-    has_bias = kb is not None
-    # delta_i = rowsum(do_i * o_i) — cheap, let XLA fuse it.
-    delta = jnp.sum(
-        do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1, keepdims=True
+    block_q, block_kv = blocks
+    offset = seq_kv - seq_q
+    grid, group, chunk, crossings, visit = _rectangles(
+        seq_q, seq_kv, block_q, block_kv, offset, causal
     )
-    if dlse is None:
-        dlse = jnp.zeros_like(lse)
-    dlse = dlse.astype(jnp.float32).reshape(lse.shape)
-
-    q_blk = pl.BlockSpec((1, block_q, head_dim), lambda b, i, j: (b, j, 0))
-    kv_blk = pl.BlockSpec((1, block_kv, head_dim), lambda b, i, j: (b, i, 0))
-    vec_blk = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, j, 0))
-    # Bias rides as [batch, 1, seq_kv] — see _flash_fwd's spec note on
-    # Mosaic's last-two-dims block constraint. In the dkv grid the KV
-    # block index is grid dim 1 (i).
-    kb3 = kb[:, None, :] if has_bias else None
-    kb_blk = pl.BlockSpec((1, 1, block_kv), lambda b, i, j: (b // heads, 0, i))
-    in_specs = [q_blk, kv_blk, kv_blk, q_blk, vec_blk, vec_blk, vec_blk]
-    args = (q, k, v, do, lse, delta, dlse)
-    if has_bias:
-        in_specs.append(kb_blk)
-        args = args + (kb3,)
-
-    dk, dv = pl.pallas_call(
+    row_blk = pl.BlockSpec((1, group, head_dim), lambda b, i, c: (b, i, 0))
+    kv_blk = pl.BlockSpec(
+        (1, chunk, head_dim), lambda b, i, c: (b, visit(i, c), 0)
+    )
+    vec_blk = pl.BlockSpec((1, 1, 1, group), lambda b, i, c: (b, i, 0, 0))
+    in_specs = [row_blk, kv_blk, kv_blk, row_blk, vec_blk, vec_blk]
+    args = [q, k, v, do, _rows(lse, group), _rows(delta, group)]
+    if kb is not None:
+        in_specs.append(pl.BlockSpec(
+            (1, 1, 1, chunk), lambda b, i, c: (b // heads, visit(i, c), 0, 0)
+        ))
+        args.append(_rows(kb, chunk))
+    state = [] if grid[1] == 1 else [
+        pltpu.VMEM((group, head_dim), jnp.float32)
+    ]
+    return pl.pallas_call(
         functools.partial(
-            _bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
-            has_bias=has_bias,
+            _bwd_dq_kernel, sm_scale=sm_scale, offset=offset, block_q=block_q,
+            block_kv=block_kv, crossings=crossings, has_bias=kb is not None,
         ),
-        grid=(bh, seq_kv // block_kv, seq_q // block_q),
+        grid=(bh, *grid),
+        in_specs=in_specs,
+        out_specs=row_blk,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        scratch_shapes=state,
+        compiler_params=_compiler_params(
+            4 * group + 2 * chunk + group // 8, block_q * chunk
+        ),
+        interpret=interpret,
+    )(*args)
+
+
+@_traced_once
+def _flash_dkv(
+    q, k, v, do, lse, delta, kb=None, *,
+    sm_scale, causal, blocks, interpret, heads=1,
+):
+    """dk and dv by key tile; ``lse`` and ``delta`` are [bh, seq_q] f32."""
+    bh, seq_q, head_dim = q.shape
+    seq_kv = k.shape[1]
+    block_q, block_kv = blocks
+    # The far-corner view (see the kernel): the diagonal is top-left
+    # aligned there, so no shift, and the grid's indices count back.
+    grid, group, chunk, crossings, far_visit = _rectangles(
+        seq_kv, seq_q, block_kv, block_q, 0, causal
+    )
+    groups, chunks = grid
+    visit = lambda i, c: chunks - 1 - far_visit(groups - 1 - i, chunks - 1 - c)
+    kv_blk = pl.BlockSpec((1, group, head_dim), lambda b, i, c: (b, i, 0))
+    row_blk = pl.BlockSpec(
+        (1, chunk, head_dim), lambda b, i, c: (b, visit(i, c), 0)
+    )
+    vec_blk = pl.BlockSpec(
+        (1, 1, 1, chunk), lambda b, i, c: (b, visit(i, c), 0, 0)
+    )
+    in_specs = [row_blk, kv_blk, kv_blk, row_blk, vec_blk, vec_blk]
+    args = [q, k, v, do, _rows(lse, chunk), _rows(delta, chunk)]
+    if kb is not None:
+        in_specs.append(pl.BlockSpec(
+            (1, 1, 1, group), lambda b, i, c: (b // heads, i, 0, 0)
+        ))
+        args.append(_rows(kb, group))
+    state = [] if chunks == 1 else [
+        pltpu.VMEM((group, head_dim), jnp.float32),
+        pltpu.VMEM((group, head_dim), jnp.float32),
+    ]
+    return pl.pallas_call(
+        functools.partial(
+            _bwd_dkv_kernel, sm_scale=sm_scale, block_q=block_q,
+            block_kv=block_kv, crossings=crossings, has_bias=kb is not None,
+        ),
+        grid=(bh, *grid),
         in_specs=in_specs,
         out_specs=[kv_blk, kv_blk],
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_kv, head_dim), jnp.float32),
-            pltpu.VMEM((block_kv, head_dim), jnp.float32),
-        ],
-        interpret=interpret,
-    )(*args)
-
-    q_blk = pl.BlockSpec((1, block_q, head_dim), lambda b, i, j: (b, i, 0))
-    kv_blk = pl.BlockSpec((1, block_kv, head_dim), lambda b, i, j: (b, j, 0))
-    vec_blk = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
-    kb_blk = pl.BlockSpec((1, 1, block_kv), lambda b, i, j: (b // heads, 0, j))
-    in_specs = [q_blk, kv_blk, kv_blk, q_blk, vec_blk, vec_blk, vec_blk]
-    args = (q, k, v, do, lse, delta, dlse)
-    if has_bias:
-        in_specs.append(kb_blk)
-        args = args + (kb3,)
-
-    dq = pl.pallas_call(
-        functools.partial(
-            _bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
-            has_bias=has_bias,
+        scratch_shapes=state,
+        compiler_params=_compiler_params(
+            6 * group + 2 * chunk + chunk // 8, block_kv * chunk
         ),
-        grid=(bh, seq_q // block_q, seq_kv // block_kv),
-        in_specs=in_specs,
-        out_specs=q_blk,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, head_dim), jnp.float32)],
         interpret=interpret,
     )(*args)
+
+
+def _flash_bwd(
+    sm_scale, causal, blocks, interpret, residuals, do, dlse,
+    kb=None, heads=1,
+):
+    q, k, v, o, lse = residuals
+    # delta_i = rowsum(do_i * o_i) — cheap, let XLA fuse it; an lse
+    # cotangent only ever appears as (delta - dlse), so it is folded in
+    # here and the plain path makes no zeros to stand in for it.
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    if dlse is not None:
+        delta = delta - dlse.astype(jnp.float32).reshape(delta.shape)
+    lse = lse.reshape(delta.shape)  # the forward's [bh, seq, 1], lane-dense
+    static = dict(
+        sm_scale=sm_scale, causal=causal, interpret=interpret, heads=heads
+    )
+    grads = (q, k, v, do, lse, delta, kb)
+    dq = _flash_dq(*grads, blocks=blocks[1], **static)
+    dk, dv = _flash_dkv(*grads, blocks=blocks[2], **static)
     return dq, dk, dv
 
 
@@ -407,45 +645,50 @@ def _flash_bwd(
 
 
 @functools.lru_cache(maxsize=None)
-def _make_flash(causal, block_q, block_kv, interpret):
-    # sm_scale stays out of the cache key (a swept/per-layer scale must
-    # not leak a closure per value) — it rides through as a nondiff arg.
+def _make_flash(causal, blocks, interpret):
+    # sm_scale stays out of this cache's key (a swept/per-layer scale
+    # must not leak a closure per value) — it rides through as a
+    # nondiff arg, into _traced_once's bounded one.
+    forward = functools.partial(
+        _flash_fwd, causal=causal, blocks=blocks[0], interpret=interpret
+    )
+
     @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
     def flash(q, k, v, sm_scale):
-        o, _ = _flash_fwd(q, k, v, sm_scale, causal, block_q, block_kv, interpret)
+        o, _ = forward(q, k, v, sm_scale=sm_scale)
         return o
 
     def fwd(q, k, v, sm_scale):
-        o, lse = _flash_fwd(q, k, v, sm_scale, causal, block_q, block_kv, interpret)
+        o, lse = forward(q, k, v, sm_scale=sm_scale)
         return o, (q, k, v, o, lse)
 
     def bwd(sm_scale, residuals, g):
-        return _flash_bwd(
-            sm_scale, causal, block_q, block_kv, interpret, residuals, g, None
-        )
+        return _flash_bwd(sm_scale, causal, blocks, interpret, residuals, g, None)
 
     flash.defvjp(fwd, bwd)
     return flash
 
 
 @functools.lru_cache(maxsize=None)
-def _make_flash_lse(causal, block_q, block_kv, interpret):
+def _make_flash_lse(causal, blocks, interpret):
     """Variant returning (o, lse) with the exact lse cotangent in bwd —
     the building block ring attention merges across hops."""
+    forward = functools.partial(
+        _flash_fwd, causal=causal, blocks=blocks[0], interpret=interpret
+    )
 
     @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
     def flash(q, k, v, sm_scale):
-        o, lse = _flash_fwd(q, k, v, sm_scale, causal, block_q, block_kv, interpret)
-        return o, lse
+        return tuple(forward(q, k, v, sm_scale=sm_scale))
 
     def fwd(q, k, v, sm_scale):
-        o, lse = _flash_fwd(q, k, v, sm_scale, causal, block_q, block_kv, interpret)
+        o, lse = forward(q, k, v, sm_scale=sm_scale)
         return (o, lse), (q, k, v, o, lse)
 
     def bwd(sm_scale, residuals, g):
         do, dlse = g
         return _flash_bwd(
-            sm_scale, causal, block_q, block_kv, interpret, residuals, do, dlse
+            sm_scale, causal, blocks, interpret, residuals, do, dlse
         )
 
     flash.defvjp(fwd, bwd)
@@ -453,7 +696,7 @@ def _make_flash_lse(causal, block_q, block_kv, interpret):
 
 
 @functools.lru_cache(maxsize=None)
-def _make_flash_bias(causal, block_q, block_kv, interpret, heads):
+def _make_flash_bias(causal, blocks, interpret, heads):
     """Variant with a [batch, seq_kv] additive key bias (padding masks).
 
     The bias is treated as NON-differentiable data — it comes from an
@@ -461,27 +704,25 @@ def _make_flash_bias(causal, block_q, block_kv, interpret, heads):
     its cotangent is zeros; the bwd kernels still ADD it when
     recomputing the scores (p must match the forward's softmax).
     """
+    forward = functools.partial(
+        _flash_fwd, causal=causal, blocks=blocks[0], interpret=interpret,
+        heads=heads,
+    )
 
     @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
     def flash(q, k, v, kb, sm_scale):
-        o, _ = _flash_fwd(
-            q, k, v, sm_scale, causal, block_q, block_kv, interpret,
-            kb=kb, heads=heads,
-        )
+        o, _ = forward(q, k, v, kb, sm_scale=sm_scale)
         return o
 
     def fwd(q, k, v, kb, sm_scale):
-        o, lse = _flash_fwd(
-            q, k, v, sm_scale, causal, block_q, block_kv, interpret,
-            kb=kb, heads=heads,
-        )
+        o, lse = forward(q, k, v, kb, sm_scale=sm_scale)
         return o, (q, k, v, o, lse, kb)
 
     def bwd(sm_scale, residuals, g):
         *res, kb = residuals
         dq, dk, dv = _flash_bwd(
-            sm_scale, causal, block_q, block_kv, interpret, tuple(res), g,
-            None, kb=kb, heads=heads,
+            sm_scale, causal, blocks, interpret, tuple(res), g, None,
+            kb=kb, heads=heads,
         )
         return dq, dk, dv, jnp.zeros_like(kb)
 
@@ -489,7 +730,14 @@ def _make_flash_bias(causal, block_q, block_kv, interpret, heads):
     return flash
 
 
-_DEFAULT_BLOCK = 256  # one guess; the on-chip sweep is ROADMAP queue 1 item 9
+KERNELS = ("fwd", "dq", "dkv")
+
+# (block_q, block_kv) each kernel aims for: the fastest of {128, 256,
+# 512}² on a v5e at GPT-2 124M's training shape — batch·heads 192, seq
+# 1,024, head_dim 64, bf16, causal (PERF.md §6 has the table; f32
+# operands and BERT's non-causal 512 are within 2% of their own best
+# there, 4,096 causal prefers a 512-row forward tile by 9%).
+_BLOCK_TARGETS = {"fwd": (256, 256), "dq": (256, 256), "dkv": (128, 128)}
 
 
 def _fit_block(target: int, seq: int) -> int:
@@ -518,40 +766,21 @@ def _fit_block(target: int, seq: int) -> int:
     )
 
 
-@functools.lru_cache(maxsize=1)
-def _tuned_block_table() -> dict:
-    """Per-sequence block defaults from an on-chip sweep
-    (tools/flash_tune.py → tools/flash_table_from_sweep.py →
-    docs/tpu_sweeps/flash_block_table.json). Maps str(seq) →
-    {"block_q": B, "block_kv": B} from the fwd+bwd-optimal cell —
-    training is the default consumer. No sweep has been run on a v5e,
-    so the file does not exist and the table is empty (the 256
-    fallback); a file that exists but cannot be read is an error."""
-    import json
-    import os
-
-    path = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__)
-        ))),
-        "docs", "tpu_sweeps", "flash_block_table.json",
-    )
-    try:
-        with open(path) as f:
-            return json.load(f).get("by_seq", {})
-    except FileNotFoundError:
-        return {}
+def flash_blocks(
+    seq_q: int, seq_kv: int, head_dim: int, dtype, causal: bool, kernel: str
+) -> tuple[int, int]:
+    """The (block_q, block_kv) tile of one of ``KERNELS`` for a call of
+    these shapes: the swept targets, fitted down to hardware-legal
+    divisors of the two lengths (``_fit_block``). ``head_dim``,
+    ``dtype`` and ``causal`` are what a further sweep would be looked
+    up by; the one there is gave them no row of their own."""
+    block_q, block_kv = _BLOCK_TARGETS[kernel]
+    return _fit_block(block_q, seq_q), _fit_block(block_kv, seq_kv)
 
 
-def _resolve_block(block: int | None, seq: int, which: str = "block_q") -> int:
-    """Explicit block sizes are honored exactly (divisibility enforced,
-    never silently overridden); None selects the swept per-seq default
-    (falling back to the 256 target fit)."""
-    if block is None:
-        tuned = _tuned_block_table().get(str(seq))
-        if tuned and tuned.get(which):
-            return _fit_block(int(tuned[which]), seq)
-        return _fit_block(_DEFAULT_BLOCK, seq)
+def _resolve_block(block: int, seq: int) -> int:
+    """An explicit block size is honored exactly (divisibility enforced,
+    never silently overridden)."""
     b = min(block, seq)
     if seq % b:
         raise ValueError(
@@ -561,13 +790,22 @@ def _resolve_block(block: int | None, seq: int, which: str = "block_q") -> int:
     return b
 
 
+@functools.lru_cache(maxsize=None)
+def _record_plan(shape, seq_kv, dtype, causal, blocks):
+    """One ``span/flash_plan`` per traced shape, so a run's record says
+    which tiles it ran (at trace time, never inside a step)."""
+    with span(
+        "flash_plan", q=str(shape), seq_kv=seq_kv, dtype=dtype,
+        causal=causal, **{n: str(b) for n, b in zip(KERNELS, blocks)},
+    ):
+        pass
+
+
 def _prepare(q, k, v, causal, sm_scale, block_q, block_kv, interpret):
     if interpret is None:
         interpret = pallas_interpret("flash_attention")
     b, h, seq_q, head_dim = q.shape
     seq_kv = k.shape[2]
-    block_q = _resolve_block(block_q, seq_q, "block_q")
-    block_kv = _resolve_block(block_kv, seq_kv, "block_kv")
     if causal and seq_q > seq_kv:
         # Rows with zero visible keys are degenerate (the reference
         # softmaxes an all-masked row into uniform weights; the kernel
@@ -575,9 +813,18 @@ def _prepare(q, k, v, causal, sm_scale, block_q, block_kv, interpret):
         raise ValueError(
             f"causal attention requires seq_q ({seq_q}) <= seq_kv ({seq_kv})"
         )
+    plan = []
+    for kernel in KERNELS:
+        auto = flash_blocks(seq_q, seq_kv, head_dim, q.dtype, causal, kernel)
+        plan.append((
+            auto[0] if block_q is None else _resolve_block(block_q, seq_q),
+            auto[1] if block_kv is None else _resolve_block(block_kv, seq_kv),
+        ))
+    blocks = tuple(plan)
+    _record_plan(q.shape, seq_kv, jnp.dtype(q.dtype).name, bool(causal), blocks)
     if sm_scale is None:
         sm_scale = head_dim**-0.5
-    return float(sm_scale), block_q, block_kv, interpret
+    return float(sm_scale), blocks, interpret
 
 
 def flash_attention(
@@ -597,15 +844,16 @@ def flash_attention(
     Runs the Pallas kernel compiled by Mosaic on ``tpu`` and in
     interpret mode on ``cpu`` (the tests); any other platform needs an
     explicit ``interpret=`` (``core/device.pallas_interpret``).
-    block_q/block_kv None = auto: 256-targeted (not measured on a v5e —
-    PERF.md), fitted down to a hardware-legal divisor of
-    the sequence; explicit sizes are enforced exactly.
+    block_q/block_kv None = auto: each kernel's own tile
+    (``flash_blocks``: measured on a v5e — PERF.md §6), fitted down to a
+    hardware-legal divisor of the sequence; explicit sizes set every
+    kernel's tile and are enforced exactly.
 
     ``key_bias``: optional [batch, seq_kv] additive score bias (f32),
     broadcast over heads and query rows — the padding-mask shape BERT
     needs. Non-differentiable (zero cotangent; it is mask data).
     """
-    sm_scale, block_q, block_kv, interpret = _prepare(
+    sm_scale, blocks, interpret = _prepare(
         q, k, v, causal, sm_scale, block_q, block_kv, interpret
     )
     b, h, seq_q, head_dim = q.shape
@@ -616,13 +864,13 @@ def flash_attention(
                 f"key_bias shape {key_bias.shape} != (batch, seq_kv) "
                 f"({b}, {k.shape[2]})"
             )
-        flash = _make_flash_bias(bool(causal), block_q, block_kv, interpret, h)
+        flash = _make_flash_bias(bool(causal), blocks, interpret, h)
         out = flash(
             fold(q), fold(k), fold(v),
             key_bias.astype(jnp.float32), sm_scale,
         )
         return out.reshape(b, h, seq_q, head_dim)
-    flash = _make_flash(bool(causal), block_q, block_kv, interpret)
+    flash = _make_flash(bool(causal), blocks, interpret)
     out = flash(fold(q), fold(k), fold(v), sm_scale)
     return out.reshape(b, h, seq_q, head_dim)
 
@@ -642,11 +890,11 @@ def flash_attention_with_lse(
     [batch, heads, seq] (f32), differentiable in both outputs. Partial
     attention results merge exactly via their lse — the primitive ring
     attention builds on."""
-    sm_scale, block_q, block_kv, interpret = _prepare(
+    sm_scale, blocks, interpret = _prepare(
         q, k, v, causal, sm_scale, block_q, block_kv, interpret
     )
     b, h, seq_q, head_dim = q.shape
-    flash = _make_flash_lse(bool(causal), block_q, block_kv, interpret)
+    flash = _make_flash_lse(bool(causal), blocks, interpret)
     fold = lambda x: x.reshape(b * h, x.shape[2], head_dim)
     o, lse = flash(fold(q), fold(k), fold(v), sm_scale)
     return o.reshape(b, h, seq_q, head_dim), lse.reshape(b, h, seq_q)

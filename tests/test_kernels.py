@@ -544,69 +544,232 @@ def test_block_autofit_odd_lengths():
         np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
 
-def test_tuned_block_table_consulted(tmp_path, monkeypatch):
-    """_resolve_block prefers the committed swept table for a matching
-    seq and falls back to the 256 target otherwise."""
-    import json
+# ------------------------------------------- the plan and the three kernels
 
+
+def _reference_with_lse(q, k, v, *, causal, key_bias=None):
+    """attention_reference plus the row logsumexp it never returns."""
+    from tensorflow_examples_tpu.ops.attention import NEG_INF
+
+    s = jnp.einsum(
+        "bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32
+    ) * q.shape[-1] ** -0.5
+    if key_bias is not None:
+        s = s + key_bias[:, None, None, :]
+    if causal:
+        sq, sk = s.shape[-2:]
+        row = jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 1)
+        s = jnp.where(row + (sk - sq) >= col, s, NEG_INF)
+    o = attention_reference(q, k, v, causal=causal, key_bias=key_bias)
+    return o, jax.nn.logsumexp(s, axis=-1)
+
+
+# name -> (seq_q, seq_kv, causal, block_q, block_kv, key_bias?, lse?).
+# None blocks = the tiles flash_blocks plans, per kernel.
+_PLAN_CASES = {
+    "planned_1024_causal": (1024, 1024, True, None, None, False, False),
+    "planned_1024_full": (1024, 1024, False, None, None, False, False),
+    "wide_kv_tiles": (512, 512, True, 128, 256, False, False),
+    "tall_q_tiles": (512, 512, True, 256, 128, False, False),
+    "key_bias": (256, 256, False, 128, 64, True, False),
+    "key_bias_causal": (256, 256, True, 64, 128, True, False),
+    "lse_cotangent": (256, 256, True, 64, 128, False, True),
+    "short_queries": (128, 384, True, 64, 128, False, True),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("case", sorted(_PLAN_CASES))
+def test_flash_kernels_match_reference(case, dtype):
+    """Forward and all three gradients against attention_reference at
+    the planned tiles for (1,024, 64) and at tiles with block_q !=
+    block_kv, with a key bias, with an lse cotangent and with fewer
+    queries than keys — at the operands' own width (bf16 bands are
+    tests_tpu's: 2e-2, and 2e-2 x (1 + max|want|) for gradients) and in
+    f32 at the f32 tests' tolerances."""
+    from tensorflow_examples_tpu.ops.attention import (
+        NEG_INF,
+        flash_attention_with_lse,
+    )
+
+    seq_q, seq_kv, causal, block_q, block_kv, biased, with_lse = _PLAN_CASES[case]
+    ks = jax.random.split(jax.random.PRNGKey(11), 5)
+    q = jax.random.normal(ks[0], (2, 2, seq_q, 64), dtype)
+    k = jax.random.normal(ks[1], (2, 2, seq_kv, 64), dtype)
+    v = jax.random.normal(ks[2], (2, 2, seq_kv, 64), dtype)
+    g = jax.random.normal(ks[3], q.shape, jnp.float32)
+    h = jax.random.normal(ks[4], q.shape[:3], jnp.float32)
+    kb = None
+    if biased:  # batch row 0 pads its last 77 keys away
+        kb = jnp.zeros((2, seq_kv), jnp.float32).at[0, -77:].set(NEG_INF)
+    blocks = dict(block_q=block_q, block_kv=block_kv)
+
+    def flash(q, k, v):
+        if with_lse:
+            return flash_attention_with_lse(q, k, v, causal=causal, **blocks)
+        o = flash_attention(q, k, v, causal=causal, key_bias=kb, **blocks)
+        return o, jnp.zeros(q.shape[:3], jnp.float32)
+
+    def reference(q, k, v):
+        o, lse = _reference_with_lse(q, k, v, causal=causal, key_bias=kb)
+        return o, lse if with_lse else jnp.zeros_like(lse)
+
+    def loss(f):
+        def inner(q, k, v):
+            o, lse = f(q, k, v)
+            return jnp.sum(o.astype(jnp.float32) * g) + jnp.sum(lse * h)
+
+        return inner
+
+    bf16 = dtype == jnp.bfloat16
+    for got, want in zip(flash(q, k, v), reference(q, k, v)):
+        tol = 2e-2 if bf16 else 2e-5
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(want, np.float32),
+            atol=tol, rtol=tol,
+        )
+    got = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(reference), argnums=(0, 1, 2))(q, k, v)
+    for a, b, name in zip(got, want, "qkv"):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        if bf16:
+            assert np.max(np.abs(a - b)) < 2e-2 * (1 + np.max(np.abs(b))), name
+        else:
+            np.testing.assert_allclose(
+                a, b, atol=5e-4, rtol=5e-4, err_msg=f"d{name}"
+            )
+
+
+@pytest.mark.parametrize("span", [128, 256])
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_walks_several_chunks(monkeypatch, causal, span):
+    """Sequences longer than one group x chunk rectangle walk several on
+    the grid, the running state in scratch; shrink the span so 256
+    queries against 512 keys are 2 x 4 rectangles of one 128-row tile,
+    or 1 x 2 of two — crossed by the diagonal at offsets other than 0."""
     from tensorflow_examples_tpu.ops import attention
 
-    attention._tuned_block_table.cache_clear()
-    monkeypatch.setattr(  # monkeypatch restores the lru_cache'd original
-        attention, "_tuned_block_table",
-        lambda: {"1024": {"block_q": 512, "block_kv": 128}},
+    monkeypatch.setattr(attention, "_SPAN", span)
+    assert attention._span(512, 128) == span
+    q = jax.random.normal(jax.random.PRNGKey(0), (1, 2, 256, 64))
+    k = jax.random.normal(jax.random.PRNGKey(1), (1, 2, 512, 64))
+    v = jax.random.normal(jax.random.PRNGKey(2), (1, 2, 512, 64))
+    flash = lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, block_q=128, block_kv=128
     )
-    assert attention._resolve_block(None, 1024, "block_q") == 512
-    assert attention._resolve_block(None, 1024, "block_kv") == 128
-    assert attention._resolve_block(None, 2048, "block_q") == 256
-    # explicit sizes still win over the table
-    assert attention._resolve_block(128, 1024, "block_q") == 128
+    ref = lambda q, k, v: attention_reference(q, k, v, causal=causal)
+    np.testing.assert_allclose(flash(q, k, v), ref(q, k, v), atol=2e-5, rtol=2e-5)
+    loss = lambda f: lambda q, k, v: jnp.sum(f(q, k, v) ** 2)
+    got = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+    for a, b, name in zip(got, want, "qkv"):
+        np.testing.assert_allclose(a, b, atol=5e-4, rtol=5e-4, err_msg=f"d{name}")
 
 
-def test_tuned_block_table_loader_handles_absent_file():
-    from tensorflow_examples_tpu.ops import attention
+@pytest.mark.parametrize("seq", [128, 320, 384, 512, 1024, 4096])
+def test_flash_blocks_are_legal_tiles(seq):
+    """Every kernel's planned tile divides the length and is a multiple
+    of 128 where one divides it, else of 8 (the whole sequence when it
+    is shorter than the target)."""
+    from tensorflow_examples_tpu.ops.attention import KERNELS, flash_blocks
 
-    attention._tuned_block_table.cache_clear()
-    table = attention._tuned_block_table()
-    assert isinstance(table, dict)  # {} when no sweep is banked
-    attention._tuned_block_table.cache_clear()
+    for kernel in KERNELS:
+        for causal in (True, False):
+            blocks = flash_blocks(seq, seq, 64, jnp.bfloat16, causal, kernel)
+            for b in blocks:
+                assert seq % b == 0 and b % 8 == 0, (kernel, blocks)
+                if seq % 128 == 0:
+                    assert b % 128 == 0, (kernel, blocks)
 
 
-def test_flash_table_from_sweep_tool(tmp_path):
-    import json
-    import subprocess
-    import sys as _sys
+def test_flash_blocks_refuse_untileable_length():
+    from tensorflow_examples_tpu.ops.attention import flash_blocks
 
-    sweep = {
-        "complete": True,
-        "shapes": [
-            {"name": "s1024", "batch": 8, "heads": 12, "seq": 1024,
-             "head_dim": 64, "causal": True,
-             "best_fwd": {"block_q": 256, "block_kv": 256, "fwd_ms": 1.0},
-             "best_fwdbwd": {"block_q": 512, "block_kv": 256,
-                             "fwdbwd_ms": 3.0}},
-        ],
-    }
-    p = tmp_path / "sweep.json"
-    p.write_text(json.dumps(sweep))
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    r = subprocess.run(
-        [_sys.executable,
-         os.path.join(repo, "tools", "flash_table_from_sweep.py"), str(p)],
-        capture_output=True, text=True, timeout=120,
+    with pytest.raises(ValueError, match="multiple-of-8"):  # 1021 is prime
+        flash_blocks(1021, 1021, 64, jnp.bfloat16, True, "fwd")
+    with pytest.raises(KeyError):
+        flash_blocks(1024, 1024, 64, jnp.bfloat16, True, "dv")
+
+
+def _kernel_products(fn, *args):
+    """(operand dtypes, result dtype) of every dot_general inside every
+    pallas_call of ``fn``'s jaxpr, by kernel."""
+    found = []
+
+    def jaxprs_in(value):
+        if hasattr(value, "eqns"):
+            yield value
+        elif hasattr(value, "jaxpr"):
+            yield from jaxprs_in(value.jaxpr)
+        elif isinstance(value, (tuple, list)):
+            for item in value:
+                yield from jaxprs_in(item)
+
+    def walk(jaxpr, kernel):
+        for eqn in jaxpr.eqns:
+            inside = kernel
+            if eqn.primitive.name == "pallas_call":
+                found.append([])
+                inside = found[-1]
+            if eqn.primitive.name == "dot_general" and inside is not None:
+                inside.append((
+                    tuple(v.aval.dtype for v in eqn.invars),
+                    eqn.outvars[0].aval.dtype,
+                ))
+            for value in eqn.params.values():
+                for sub in jaxprs_in(value):
+                    walk(sub, inside)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr, None)
+    return found
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+def test_flash_products_take_the_operands_own_width(dtype):
+    """All nine products of the three kernels (two forward, three dq,
+    four dk/dv; traced once per static body) take their operands in the
+    caller's dtype and accumulate in f32: bf16 inputs feed the MXU bf16,
+    f32 inputs f32 as before."""
+    q, k, v = _qkv(jax.random.PRNGKey(0), (1, 2, 256, 64), dtype)
+    kernels = _kernel_products(
+        jax.grad(
+            lambda q, k, v: jnp.sum(
+                flash_attention(q, k, v, causal=True).astype(jnp.float32)
+            ),
+            argnums=(0, 1, 2),
+        ),
+        q, k, v,
     )
-    assert r.returncode == 0, r.stderr
-    table = json.loads((tmp_path / "flash_block_table.json").read_text())
-    assert table["by_seq"]["1024"]["block_q"] == 512
-    # partial sweep refused
-    sweep["complete"] = False
-    p.write_text(json.dumps(sweep))
-    r = subprocess.run(
-        [_sys.executable,
-         os.path.join(repo, "tools", "flash_table_from_sweep.py"), str(p)],
-        capture_output=True, text=True, timeout=120,
-    )
-    assert r.returncode == 1
+    # forward, dq, dk/dv in the order they are called: two, three and
+    # four products a traced body (masked and plain bodies both count).
+    assert len(kernels) == 3
+    for products, each in zip(kernels, (2, 3, 4)):
+        assert products and len(products) % each == 0, (each, products)
+        for operands, result in products:
+            assert operands == (dtype, dtype), products
+            assert result == jnp.float32, products
+
+
+def test_flash_plan_recorded_once_per_traced_shape():
+    """span/flash_plan carries the shapes, the dtype and the three tile
+    pairs, once per shape and never per call."""
+    from tensorflow_examples_tpu.ops.attention import KERNELS, flash_blocks
+    from tensorflow_examples_tpu.telemetry import spans
+
+    plans = lambda: [
+        e for e in spans._default.events() if e["name"] == "flash_plan"
+    ]
+    before = len(plans())
+    q, k, v = _qkv(jax.random.PRNGKey(0), (1, 1, 136, 8), jnp.float32)
+    for _ in range(2):
+        flash_attention(q, k, v, causal=True)
+    (event,) = plans()[before:]
+    assert event["args"]["q"] == "(1, 1, 136, 8)"
+    assert event["args"]["dtype"] == "float32"
+    for kernel in KERNELS:
+        want = flash_blocks(136, 136, 8, jnp.float32, True, kernel)
+        assert event["args"][kernel] == str(want)
 
 
 # ------------------------------------------------ chip-free TPU lowering
@@ -640,6 +803,7 @@ def _lowering_cases():
 
     bf16, f32 = jnp.bfloat16, jnp.float32
     qkv = (_S((2, 12, 1024, 64), bf16),) * 3
+    cell = (_S((16, 12, 1024, 64), bf16),) * 3
     flash = functools.partial(flash_attention, causal=True, interpret=False)
     total = lambda f: lambda *a: jnp.sum(f(*a).astype(f32))
     logits = _S((16 * 1023, 50257), bf16)
@@ -662,6 +826,19 @@ def _lowering_cases():
                 q, k, v, causal=False, key_bias=kb, interpret=False
             ),
             (_S((2, 12, 512, 64), bf16),) * 3 + (_S((2, 512), f32),),
+        ),
+        # The training cell's own call (batch 16 x 12 heads, bf16) ...
+        "flash_cell_fwd": (flash, cell),
+        "flash_cell_grad": (jax.grad(total(flash), argnums=(0, 1, 2)), cell),
+        # ... and BERT's: non-causal, a padding bias, seq 128.
+        "flash_key_bias_grad": (
+            jax.grad(
+                lambda q, k, v, kb: jnp.sum(flash_attention(
+                    q, k, v, causal=False, key_bias=kb, interpret=False
+                ).astype(f32)),
+                argnums=(0, 1, 2),
+            ),
+            (_S((8, 12, 128, 64), bf16),) * 3 + (_S((8, 128), f32),),
         ),
         "decode_step": (
             decode,

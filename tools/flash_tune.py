@@ -1,12 +1,12 @@
 #!/usr/bin/env python
-"""On-chip flash-attention block-size autotune.
+"""On-chip flash-attention block-size sweep.
 
-The Pallas flash kernel's auto block sizing targets 256x256 on the
-strength of ONE end-to-end measurement (ops/attention.py:_prepare,
-~1.3% over 128 on GPT-2 124M b8 s1024, round 2). This tool sweeps
-block_q x block_kv over the benched shapes, forward AND
-forward+backward, on the real chip — so the default can be set from a
-measured table instead of a single point.
+The Pallas flash kernels pick their tiles from constants in
+ops/attention.py (``flash_blocks``), set from a per-kernel sweep on a
+v5e at GPT-2 124M's training shape (PERF.md §6 has the table). This
+tool is the end-to-end check of such a choice: it sweeps explicit
+block_q x block_kv (which set all three kernels' tile) over the benched
+shapes, forward AND forward+backward, on the real chip.
 
 TPU only (interpret-mode cells would time Python, not the chip): off
 the chip it exits non-zero before timing anything. Emits ONE JSON line

@@ -27,17 +27,6 @@ def kernel_source_hash(repo_root: "str | None" = None) -> str:
         os.path.dirname(os.path.abspath(__file__))
     )
     h = hashlib.sha256()
-    # The flash block table is kernel configuration living outside the
-    # package (docs/): swapping it changes every compiled flash kernel,
-    # so it changes the hash exactly like a source edit.
-    table = os.path.join(
-        root, "docs", "tpu_sweeps", "flash_block_table.json"
-    )
-    if os.path.exists(table):
-        h.update(b"flash_block_table.json\0")
-        with open(table, "rb") as f:
-            h.update(f.read())
-        h.update(b"\0")
     for sub in (
         "tests_tpu",
         os.path.join("tensorflow_examples_tpu", "ops"),

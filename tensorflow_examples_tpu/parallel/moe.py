@@ -67,11 +67,21 @@ def _router(
     jitter: float,
     select: str = "softmax",
     precision=None,
+    select_bias: jax.Array | None = None,  # [E] f32
+    scale: float = 1.0,
 ):
     """Top-k router, shared by every dispatch formulation. Returns
     (gates, experts, mean_onehot0 [E], mean_probs [E]). ``select`` is
     the score every expert gets before the top-k: ``softmax`` over the
-    experts (Switch/GShard) or an independent ``sigmoid`` each."""
+    experts (Switch/GShard) or an independent ``sigmoid`` each.
+
+    ``select_bias`` is added to the scores for the CHOICE alone: the
+    top-k are those of ``score + bias``, their gates the scores
+    without it (the load-balancing bias of auxiliary-loss-free
+    routing). ``scale`` multiplies the gates after their
+    normalisation. ``select="sigmoid"`` with both is DeepSeek-V3's
+    ``noaux_tc`` with one group. The defaults leave every gate and
+    choice bit-identical to a router that knows neither."""
     e = gate_w.shape[-1]
     logits = jnp.dot(
         tokens.astype(jnp.float32), gate_w.astype(jnp.float32),
@@ -91,12 +101,23 @@ def _router(
     # Sequential top-k: argmax, mask, repeat (k is tiny and static).
     masked = probs
     experts, gates = [], []
-    for _ in range(top_k):
-        ej = jnp.argmax(masked, axis=-1)  # [n]
-        pj = jnp.take_along_axis(masked, ej[:, None], axis=-1)[:, 0]
-        experts.append(ej)
-        gates.append(pj)
-        masked = masked * (1.0 - jax.nn.one_hot(ej, e, dtype=jnp.float32))
+    if select_bias is None:
+        for _ in range(top_k):
+            ej = jnp.argmax(masked, axis=-1)  # [n]
+            pj = jnp.take_along_axis(masked, ej[:, None], axis=-1)[:, 0]
+            experts.append(ej)
+            gates.append(pj)
+            masked = masked * (1.0 - jax.nn.one_hot(ej, e, dtype=jnp.float32))
+    else:
+        # A biased score may be negative: taken experts drop to -inf.
+        masked = probs + select_bias.astype(jnp.float32)
+        for _ in range(top_k):
+            ej = jnp.argmax(masked, axis=-1)
+            experts.append(ej)
+            gates.append(jnp.take_along_axis(probs, ej[:, None], axis=-1)[:, 0])
+            masked = jnp.where(
+                jax.nn.one_hot(ej, e, dtype=jnp.bool_), -jnp.inf, masked
+            )
     # top-1: keep the raw router probability as the gate (Switch) — it
     # is how the router gets task-loss gradient. Renormalizing would
     # make the gate identically 1.0 and silently detach the router.
@@ -105,6 +126,8 @@ def _router(
     if top_k > 1:
         denom = jnp.maximum(sum(gates), 1e-9)
         gates = [g / denom for g in gates]
+    if scale != 1.0:
+        gates = [g * scale for g in gates]
 
     mean_onehot0 = jnp.mean(
         jax.nn.one_hot(experts[0], e, dtype=jnp.float32), axis=0
@@ -475,13 +498,17 @@ def moe_ffn_held(
     held: tuple,
     top_k: int,
     valid: jax.Array | None = None,  # [n] bool: rows that are real tokens
+    select_bias: jax.Array | None = None,  # [E]: moves the choice, not the weights
+    scale: float = 1.0,                    # on the weights, after normalisation
 ) -> tuple[jax.Array, jax.Array]:
     """The expert layer of a chip that is TOLD WHAT IT HOLDS: ``held``
     are the ids, among the router's ``E`` experts, of the SwiGLU experts
     whose weights are here (``w_*[i]`` is expert ``held[i]``).
 
     The router scores all ``E`` experts (sigmoid, top ``top_k``,
-    weights normalised over the chosen), and the chip computes the
+    weights normalised over the chosen; with ``select_bias`` the top
+    are those of score + bias, and ``scale`` multiplies the normalised
+    weights: ``_router``), and the chip computes the
     grouped formulation's product for the (token, expert) pairs routed
     to its own experts, dropless, combined with the router's weights.
     Pairs routed to absent experts contribute nothing: the result is
@@ -497,6 +524,7 @@ def moe_ffn_held(
     gates, experts, _, _ = _router(
         tokens, router_w, top_k=min(top_k, e), rng=None, jitter=0.0,
         select="sigmoid", precision=lax.Precision.HIGHEST,
+        select_bias=select_bias, scale=scale,
     )
     # Global expert id -> this chip's group; every absent expert is
     # the one group past the held, which the product never visits.

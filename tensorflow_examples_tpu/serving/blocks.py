@@ -7,10 +7,12 @@ and GPT-2's verify) are each ONE loop
     for layer: x, stats = model.block(params, x, layer, positions, attend, valid)
     logits = model.head(params, x)
 
-and differ only in where ``attend(q, k, v)`` finds K and V (the fresh
-prompt, the cached context plus the tail, the slots' block tables). A
-model is one of the classes below, chosen by the type of its config
-(:func:`block_for`), never by a ``ServeConfig`` field:
+and differ only in where ``attend(q, *row)`` finds the context (the
+fresh prompt, the cached context plus the tail, the slots' block
+tables); ``row`` is what the block caches of a token, and the forward
+writes exactly that. A model is one of the classes below, chosen by the
+type of its config (:func:`block_for`), never by a ``ServeConfig``
+field:
 
 * :class:`Gpt2Block` — ``models/transformer.py``'s param tree: learned
   positions, LayerNorm with bias, ``H`` equal heads, a dense GELU MLP.
@@ -18,10 +20,23 @@ model is one of the classes below, chosen by the type of its config
   block, grouped-query heads, window layers with rotary positions and
   full layers with none, an expert layer that holds some of the
   experts (``parallel/moe.moe_ffn_held``) beside shared experts.
+* :class:`Glm4MoeLiteBlock` — ``models/glm4_moe_lite.py``'s: a
+  sequential pre-norm block with RMS norms, latent attention (one
+  latent row a token in the cache, no heads, no V), a dense first
+  layer, then expert layers whose router chooses by a biased score, a
+  shared expert, an untied head.
 
 What a model tells the engine beside its three functions: ``num_layers``,
-``num_heads`` / ``num_kv_heads`` / ``head_dim`` (a cache row is
-``num_kv_heads * head_dim`` values), ``layer_windows`` (one entry a
+``num_heads`` / ``num_kv_heads`` / ``head_dim``, **``cache_rows``** (the
+arrays a layer keeps of a token, ``(heads, width)`` each: K and V of
+``num_kv_heads x head_dim`` for the first two, one ``1 x (dc + dr)``
+latent row for the third; the pool allocates by it and the forwards
+scatter what ``block`` hands ``attend`` after ``q``),
+**``own_attention``** (false: the engine's generic ``softmax(q k) v``
+over gathered heads serves it; true: attention over the cache is the
+block's own mathematics, ``chunk_attention`` for a prompt chunk and
+``decode_attention`` for a decode step), ``refused`` (any block but GPT-2's: why each mechanism
+the engine refuses it cannot serve it), ``layer_windows`` (one entry a
 layer: ``None`` = full, ``W`` = window; the paged pool keeps one
 block-id space per kind), ``stats_len`` (the int32 counts a block adds
 to a step's fetched output, 0 for none; a model that has any books them
@@ -37,6 +52,8 @@ flax defaults (eps 1e-5, gelu approximate).
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -44,8 +61,12 @@ from tensorflow_examples_tpu.core import precision as precision_mod
 from tensorflow_examples_tpu.core.precision import materialize as _w
 from tensorflow_examples_tpu.core.precision import take_rows as _rows
 from tensorflow_examples_tpu.models.cohere2_moe import Cohere2MoeConfig
+from tensorflow_examples_tpu.models.glm4_moe_lite import Glm4MoeLiteConfig
 from tensorflow_examples_tpu.models.transformer import TransformerConfig
 from tensorflow_examples_tpu.parallel.moe import moe_ffn_held
+from tensorflow_examples_tpu.serving import kv_cache
+from tensorflow_examples_tpu.telemetry import schema
+from tensorflow_examples_tpu.telemetry.spans import span
 
 
 def _normalise(x, eps):
@@ -84,12 +105,14 @@ class Gpt2Block:
 
     name = "gpt2"
     stats_len = 0
+    own_attention = False
 
     def __init__(self, cfg: TransformerConfig):
         self.cfg = cfg
         self.num_layers = cfg.num_layers
         self.num_heads = self.num_kv_heads = cfg.num_heads
         self.head_dim = cfg.head_dim
+        self.cache_rows = ((cfg.num_heads, cfg.head_dim),) * 2  # K, V
         self.max_len = cfg.max_len
         self.layer_windows = (None,) * cfg.num_layers
 
@@ -127,6 +150,25 @@ class Gpt2Block:
         )
 
 
+def _count_expert_stats(registry, held, stats, decode: bool) -> None:
+    """The expert counts a block with held experts puts first in its
+    stats — the pairs each held expert computed, the pairs routed to
+    all experts, the held experts hit — into the registry's counters."""
+    n = len(held)
+    pairs_held = int(stats[:n].sum())
+    registry.counter("serving/moe_pairs_held").inc(pairs_held)
+    registry.counter("serving/moe_pairs_routed").inc(int(stats[n]))
+    for expert, pairs in zip(held, stats[:n]):
+        registry.counter(
+            f"serving/moe_pairs_expert_{expert}"
+        ).inc(int(pairs))
+    if decode:
+        registry.counter("serving/moe_decode_pairs_held").inc(pairs_held)
+        registry.counter("serving/moe_decode_experts_hit").inc(
+            int(stats[n + 1])
+        )
+
+
 def _scale_norm(x, scale, eps):
     """Mean-subtracting LayerNorm with a scale and no bias, float32."""
     return _normalise(x.astype(jnp.float32), eps) * scale.astype(jnp.float32)
@@ -160,6 +202,18 @@ class Cohere2MoeBlock:
     the forward)."""
 
     name = "cohere2_moe"
+    own_attention = False
+    # Why each mechanism the engine refuses this block cannot serve it.
+    refused = {
+        "verify": "its verify forward is GPT-2's",
+        "pages": "a page payload has one block-id space and equal heads",
+        "kv_dtype": "the grouped-query gather does not dequantize",
+        "weights": "the block reads its weights as stored",
+        "paged_flash": "the kernel reads rows of equal heads through one "
+                       "table",
+        "flash": "no grouped-query or window mask",
+        "sharding": "the placement rules are GPT-2's",
+    }
 
     def __init__(self, cfg: Cohere2MoeConfig):
         self.cfg = cfg
@@ -167,6 +221,7 @@ class Cohere2MoeBlock:
         self.num_heads = cfg.num_heads
         self.num_kv_heads = cfg.num_kv_heads
         self.head_dim = cfg.head_dim
+        self.cache_rows = ((cfg.num_kv_heads, cfg.head_dim),) * 2  # K, V
         self.max_len = cfg.max_len
         self.layer_windows = tuple(cfg.layer_windows)
         self.stats_len = len(cfg.held_experts) + 2
@@ -174,20 +229,7 @@ class Cohere2MoeBlock:
     def count_stats(self, registry, stats, *, decode: bool) -> None:
         """Book one step's fetched ``stats`` (``block``'s, summed over
         the layers) into the registry's expert counters."""
-        held = self.cfg.held_experts
-        n = len(held)
-        pairs_held = int(stats[:n].sum())
-        registry.counter("serving/moe_pairs_held").inc(pairs_held)
-        registry.counter("serving/moe_pairs_routed").inc(int(stats[n]))
-        for expert, pairs in zip(held, stats[:n]):
-            registry.counter(
-                f"serving/moe_pairs_expert_{expert}"
-            ).inc(int(pairs))
-        if decode:
-            registry.counter("serving/moe_decode_pairs_held").inc(pairs_held)
-            registry.counter("serving/moe_decode_experts_hit").inc(
-                int(stats[n + 1])
-            )
+        _count_expert_stats(registry, self.cfg.held_experts, stats, decode)
 
     def param_dtype(self, params):
         return params["wte"]["embedding"].dtype
@@ -256,13 +298,236 @@ class Cohere2MoeBlock:
         )
 
 
+def _rms_norm(x, scale, eps):
+    """``x / sqrt(mean(x^2) + eps) * scale``, float32."""
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(
+        jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps
+    ) * scale.astype(jnp.float32)
+
+
+def _swiglu(x, p):
+    """One SwiGLU FFN on ``x`` [n, d] in the weights' dtype; float32 out."""
+    f32 = dict(preferred_element_type=jnp.float32)
+    g = jnp.dot(x, p["w_gate"], **f32)
+    u = jnp.dot(x, p["w_up"], **f32)
+    return jnp.dot(
+        (jax.nn.silu(g) * u).astype(p["w_down"].dtype), p["w_down"], **f32
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def _record_mla_plan(family, queries, context, dtype, head_group):
+    """One ``span/mla_plan`` per traced shape, so a run's record says
+    which attention form each program family ran and how the heads were
+    grouped (at trace time, never inside a step)."""
+    with span(
+        schema.MLA_PLAN_SPAN, family=family, queries=queries, context=context,
+        dtype=dtype, form="absorbed", head_group=head_group,
+    ):
+        pass
+
+
+class Glm4MoeLiteBlock:
+    """GLM-4.7-Flash's block over ``models/glm4_moe_lite.py``'s param
+    tree. The residual stream, the RMS norms and the router are
+    float32; the projections, the attention products and the experts
+    run in the parameters' dtype with float32 accumulation.
+
+    **The cache row is the block's**: ``[c_kv | k_pe]`` after the norm
+    and the rotation, ``kv_lora_rank + qk_rope_head_dim`` values a
+    token a layer, no heads, no V (``cache_rows``). ``block`` hands it
+    to ``attend`` behind the queries, the forward writes it, and the
+    attention over rows is this class's: ``chunk_attention`` (a prompt
+    chunk over itself and the cached context) and ``decode_attention``
+    (one query a slot through a block table), both absorbed
+    (``kv_cache``: every head reads the row as it lies).
+
+    ``block`` also returns the step's counts, ``stats_len`` int32
+    values summed over the layers by the forward: the expert counts of
+    :class:`Cohere2MoeBlock` (the dense layers add none), then the query
+    tokens that went through the latent attention."""
+
+    name = "glm4_moe_lite"
+    own_attention = True
+    # Why each mechanism the engine refuses this block cannot serve it.
+    refused = {
+        "verify": "its verify forward is GPT-2's: K and V rows of equal "
+                  "heads, not a latent row",
+        "pages": "a page payload carries K and V with heads, not a latent "
+                 "row",
+        "kv_dtype": "a latent row has no heads to keep a scale for",
+        "weights": "the block reads its weights as stored",
+        "paged_flash": "the kernel reads K and V rows of equal heads, not a "
+                       "latent row shared by every head",
+        "flash": "the kernel takes K and V with heads; a latent row is "
+                 "attended by the block itself",
+        "sharding": "the placement rules are GPT-2's, and a latent row has "
+                    "no heads axis to shard",
+    }
+    def __init__(self, cfg: Glm4MoeLiteConfig):
+        self.cfg = cfg
+        self.num_layers = cfg.num_layers
+        self.num_heads = cfg.num_heads
+        self.num_kv_heads = 1  # one latent row, shared by every head
+        self.head_dim = cfg.qk_head_dim
+        self.cache_rows = ((1, cfg.latent_dim),)
+        self.max_len = cfg.max_len
+        self.layer_windows = (None,) * cfg.num_layers
+        self.stats_len = len(cfg.held_experts) + 3
+        self.sm_scale = cfg.qk_head_dim ** -0.5
+
+    def count_stats(self, registry, stats, *, decode: bool) -> None:
+        """Book one step's fetched ``stats`` into the registry: the
+        expert counters, and the query tokens through the latent
+        attention (every layer counted them: one layer's share is
+        booked)."""
+        held = self.cfg.held_experts
+        _count_expert_stats(registry, held, stats, decode)
+        registry.counter(schema.LATENT_ATTENTION_TOKENS).inc(
+            int(stats[len(held) + 2]) // self.num_layers
+        )
+
+    def param_dtype(self, params):
+        return params["wte"]["embedding"].dtype
+
+    def embed(self, params, tokens, positions):
+        del positions  # rotary: q_pe and k_pe turn inside the block
+        return params["wte"]["embedding"][tokens].astype(jnp.float32)
+
+    # ------------------------------------------------ attention over rows
+
+    def _up(self, params, layer):
+        """``W_kvb`` of one layer as ``(W_uk [dc, H, dn], W_uv [dc, H,
+        dv])``."""
+        kv_b = params[f"h_{layer}"]["attn"]["kv_b"]
+        dn = self.cfg.qk_nope_head_dim
+        return kv_b[..., :dn], kv_b[..., dn:]
+
+    def _split(self, q):
+        dn = self.cfg.qk_nope_head_dim
+        return q[..., :dn], q[..., dn:]
+
+    def chunk_attention(self, params, layer, q, row, ctx_rows=None,
+                        ctx_len=0):
+        """A prompt chunk: q [T, H, dn + dr], its own rows ``row`` [T,
+        1, dc + dr], the cached context ``ctx_rows`` [C, 1, dc + dr]
+        (first ``ctx_len`` populated) or None. Returns [T, H, dv]."""
+        t_n = q.shape[0]
+        cols = t_n + (0 if ctx_rows is None else ctx_rows.shape[0])
+        _record_mla_plan(
+            "extend" if ctx_rows is not None else "prefill", t_n, cols,
+            str(q.dtype),
+            kv_cache.latent_head_group(self.num_heads, t_n, cols),
+        )
+        with jax.named_scope("attn_latent_absorbed"):
+            return kv_cache.latent_chunk_attention(
+                *self._split(q), row[:, 0], *self._up(params, layer),
+                None if ctx_rows is None else ctx_rows[:, 0],
+                ctx_len=ctx_len, sm_scale=self.sm_scale,
+            )
+
+    def decode_attention(self, params, layer, q, blocks, positions, table):
+        """A decode step: q [S, H, dn + dr] at ``positions`` over one
+        layer's pool ``blocks`` [NB, BS, dc + dr] through ``table`` [S,
+        nb]. Returns [S, H, dv]."""
+        _record_mla_plan(
+            "decode", q.shape[0], table.shape[1] * blocks.shape[1],
+            str(q.dtype), self.num_heads,
+        )
+        with jax.named_scope("attn_latent_absorbed"):
+            return kv_cache.latent_decode_attention(
+                *self._split(q), blocks, positions, table,
+                *self._up(params, layer), sm_scale=self.sm_scale,
+            )
+
+    # -------------------------------------------------------------- block
+
+    def block(self, params, x, layer, positions, attend, valid=None):
+        cfg, p = self.cfg, params[f"h_{layer}"]
+        a, eps, dc = p["attn"], cfg.rms_norm_eps, cfg.kv_lora_rank
+        dtype = a["q_a"].dtype
+        f32 = dict(preferred_element_type=jnp.float32)
+        hb = _rms_norm(x, p["ln_1"]["scale"], eps).astype(dtype)
+        c_q = _rms_norm(
+            jnp.dot(hb, a["q_a"], **f32), a["q_ln"]["scale"], eps
+        ).astype(dtype)
+        q_nope, q_pe = self._split(
+            jnp.einsum("...r,rhc->...hc", c_q, a["q_b"], **f32)
+        )
+        q = jnp.concatenate(
+            [q_nope, rope_interleaved(q_pe, positions, cfg.rope_theta)],
+            axis=-1,
+        ).astype(dtype)
+        kv = jnp.dot(hb, a["kv_a"], **f32)
+        c_kv = _rms_norm(kv[..., :dc], a["kv_ln"]["scale"], eps)
+        k_pe = rope_interleaved(
+            kv[..., None, dc:], positions, cfg.rope_theta
+        )
+        # What the token leaves in the cache, and nothing else.
+        row = jnp.concatenate(
+            [c_kv[..., None, :], k_pe], axis=-1
+        ).astype(dtype)
+        att = attend(q, row)
+        x = x + jnp.einsum(
+            "...hc,hcd->...d", att.astype(dtype), a["o"], **f32
+        )
+
+        h = _rms_norm(x, p["ln_2"]["scale"], eps)
+        flat = h.reshape(-1, h.shape[-1])
+        rows = None if valid is None else jnp.broadcast_to(
+            valid, x.shape[:-1]
+        ).reshape(-1)
+        n_real = flat.shape[0] if rows is None else jnp.sum(rows)
+        n_held = len(cfg.held_experts)
+        if "mlp" in p:
+            with jax.named_scope("ffn_dense"):
+                y = _swiglu(flat.astype(dtype), p["mlp"])
+            pairs = jnp.zeros((n_held,), jnp.int32)
+            routed = 0
+        else:
+            moe = p["moe"]
+            part, pairs = moe_ffn_held(
+                moe["router"], moe["w_gate"], moe["w_up"], moe["w_down"],
+                flat, held=tuple(cfg.held_experts), top_k=cfg.top_k,
+                valid=rows, select_bias=moe["bias"],
+                scale=cfg.routed_scale,
+            )
+            with jax.named_scope("moe_shared"):
+                y = part + _swiglu(flat.astype(dtype), p["shared"])
+            routed = n_real * cfg.top_k
+        stats = jnp.concatenate([
+            pairs,
+            jnp.stack([routed, jnp.sum(pairs > 0), n_real]).astype(jnp.int32),
+        ])
+        return x + y.reshape(x.shape), stats
+
+    def head(self, params, x):
+        kernel = params["lm_head"]["kernel"]
+        x = _rms_norm(x, params["ln_f"]["scale"], self.cfg.rms_norm_eps)
+        return jnp.dot(
+            x.astype(kernel.dtype), kernel,
+            preferred_element_type=jnp.float32,
+        )
+
+    def last_logits(self, params, x, index):
+        """Logits of row ``index`` of ``x`` [T, d]: only that row meets
+        the vocabulary."""
+        return self.head(
+            params, jax.lax.dynamic_index_in_dim(x, index, keepdims=False)
+        )
+
+
 def block_for(model_cfg):
     """The block that serves ``model_cfg``, by the config's type."""
+    if isinstance(model_cfg, Glm4MoeLiteConfig):
+        return Glm4MoeLiteBlock(model_cfg)
     if isinstance(model_cfg, Cohere2MoeConfig):
         return Cohere2MoeBlock(model_cfg)
     if isinstance(model_cfg, TransformerConfig):
         return Gpt2Block(model_cfg)
     raise TypeError(
         f"no serving block for a {type(model_cfg).__name__}: the engine "
-        "serves TransformerConfig (GPT-2) and Cohere2MoeConfig"
+        "serves TransformerConfig (GPT-2), Cohere2MoeConfig and "
+        "Glm4MoeLiteConfig"
     )

@@ -11,9 +11,10 @@ engine runs comes from a FINITE, warmed-up ladder:
   ``max_len``) and runs batch-1: one compiled program per rung.
   Causal masking makes the pad rows inert — the true prompt length
   rides in as a traced scalar that only picks the logits row. The
-  prompt's K/V are scattered into the slot's blocks of the ONE KV pool
-  (``paged_kv.PagedKVPool``: per layer ``[NB, BS, H*D]`` blocks behind
-  per-slot block tables).
+  prompt's cache rows — K and V, or whatever the block says it caches
+  of a token (``blocks.py`` ``cache_rows``) — are scattered into the
+  slot's blocks of the ONE KV pool (``paged_kv.PagedKVPool``: per layer
+  ``[NB, BS, row]`` blocks behind per-slot block tables).
 * **Extend** runs only a prompt's TAIL over context already in the
   pool — a prefix-cache hit, or one chunk of a chunked prefill — one
   program per tail bucket of the same ladder.
@@ -183,8 +184,12 @@ class ServeConfig:
 # Every forward below is ONE loop over a block interface
 # (serving/blocks.py): ``model.embed``, ``model.block(params, x, layer,
 # positions, attend, valid)`` per layer, ``model.head``. They differ only
-# in where ``attend(q, k, v)`` finds K and V — the fresh prompt, the
-# pool through a block table — and in what they write. The verify
+# in where ``attend(q, *row)`` finds the context — the fresh prompt, the
+# pool through a block table — and in where they write ``row``: what the
+# block caches of a token (K and V with heads; one latent row), scattered
+# as the block handed it over. A block with ``own_attention`` attends
+# from its rows itself (``chunk_attention`` / ``decode_attention``); the
+# others get the generic attention over gathered heads. The verify
 # forward serves GPT-2 alone (the engine refuses it for any other block
 # at construction); prefill, decode and extend serve every block.
 
@@ -252,9 +257,11 @@ def forward_full(cfg: TransformerConfig, params, tokens):
 
 # ------------------------------------------------------- the pool's forwards
 #
-# K/V land in per-layer [NB, BS, Hkv*D] block pools addressed through
+# Cache rows land in per-layer [NB, BS, row] block pools addressed through
 # per-slot block tables (ISSUE 8; paged_kv.py). ``kv`` is the pool's
-# device state — (k, v) or, quantized, (k, v, k_scale, v_scale), each
+# device state — one entry per array of the block's ``cache_rows``: (k,
+# v) with rows of Hkv*D, (latent,) for a latent row — or, quantized, (k,
+# v, k_scale, v_scale), each
 # a tuple of one array per layer, with per-row scales stored blockwise
 # ([NB, BS, H]; core/precision.quantize_rows). No
 # program reads or writes more of a layer's array than the blocks its
@@ -265,10 +272,12 @@ def forward_full(cfg: TransformerConfig, params, tokens):
 # one table per kind where a one-kind pool hands it one.
 
 
-def _paged_write_rows(kv, layer, index, k, v):
-    """Write K/V ``[..., H, hd]`` into layer ``layer``'s arrays at
-    ``index`` (a tuple indexing their leading axes, block then row):
-    values flattened to the pool's ``H*hd`` rows, quantized with their
+def _paged_write_rows(kv, layer, index, *row):
+    """Write a token's cache ``row`` — one ``[..., heads, width]`` array
+    per array the pool keeps: K and V, or a latent row of one "head" —
+    into layer ``layer``'s arrays at ``index`` (a tuple indexing their
+    leading axes, block then row): values flattened to the pool's
+    ``heads*width`` rows, K and V quantized with their
     per-row scales when the pool is (the store dtype, int8 or fp8,
     rides on the pool arrays themselves — one write path serves both).
     A decode or verify step writes ``(write_blocks, offsets)``, one row
@@ -281,11 +290,11 @@ def _paged_write_rows(kv, layer, index, k, v):
 
     if len(kv) == 4:
         (qk, sk), (qv, sv) = (
-            quantize_rows(x, kv[0][layer].dtype) for x in (k, v)
+            quantize_rows(x, kv[0][layer].dtype) for x in row
         )
         new = (rows(qk), rows(qv), sk, sv)
     else:
-        new = (rows(k), rows(v))
+        new = tuple(rows(x) for x in row)
     return tuple(
         (*arrs[:layer],
          arrs[layer].at[index].set(x.astype(arrs[layer].dtype)),
@@ -294,18 +303,17 @@ def _paged_write_rows(kv, layer, index, k, v):
     )
 
 
-def _paged_write_prompt(kv, ks, vs, block_ids, layer_kind, *, block_size):
-    """Scatter a prefill's freshly computed K/V (per layer ``[bucket,
-    H, hd]``) into the blocks named by ``block_ids`` (one ``[bucket //
+def _paged_write_prompt(kv, written, block_ids, layer_kind, *, block_size):
+    """Scatter a prefill's freshly computed cache rows (``written``: per
+    layer, the row's arrays, ``[bucket, heads, width]`` each) into the
+    blocks named by ``block_ids`` (one ``[bucket //
     BS]`` array per kind; pad entries point at the null block; their
     garbage is never read). ``[bucket, H, hd] -> [nb, BS, H, hd]`` is a
     pure reshape."""
     ids = _per_kind(block_ids)
-    for layer, (k, v) in enumerate(zip(ks, vs)):
-        k, v = (
-            x.reshape(-1, block_size, *x.shape[1:]) for x in (k, v)
-        )
-        kv = _paged_write_rows(kv, layer, (ids[layer_kind[layer]],), k, v)
+    for layer, row in enumerate(written):
+        row = (x.reshape(-1, block_size, *x.shape[1:]) for x in row)
+        kv = _paged_write_rows(kv, layer, (ids[layer_kind[layer]],), *row)
     return kv
 
 
@@ -320,15 +328,19 @@ def _layer_scales(kv, layer) -> dict:
 def _forward_prefill(model, params, kv, block_ids, tokens, length,
                      layer_kind, *, block_size: int, impl: str):
     """A whole prompt, ``tokens`` [1, bucket] right-padded: causal
-    self-attention over the fresh K/V, which are then scattered into
+    self-attention over the fresh rows, which are then scattered into
     the slot's blocks. Returns the pool state, the final hidden state
     [1, bucket, d] and the blocks' stats."""
-    ks, vs = [], []
+    written = []
 
     def attend_for(layer):
-        def attend(q, k, v):  # [1, bucket, H, hd]
-            ks.append(k[0])
-            vs.append(v[0])
+        def attend(q, *row):  # each [1, bucket, heads, width]
+            written.append(tuple(x[0] for x in row))
+            if model.own_attention:
+                return model.chunk_attention(
+                    params, layer, q[0], row[0][0]
+                )[None]
+            k, v = row
             if _plain(model, layer):
                 return _prefill_attend(q, k, v, impl=impl)
             return kv_mod.grouped_chunk_attention(
@@ -342,7 +354,7 @@ def _forward_prefill(model, params, kv, block_ids, tokens, length,
         attend_for, positions < length,
     )
     kv = _paged_write_prompt(
-        kv, ks, vs, block_ids, layer_kind, block_size=block_size
+        kv, written, block_ids, layer_kind, block_size=block_size
     )
     return kv, x, stats
 
@@ -388,10 +400,14 @@ def _forward_decode(model, params, kv, tokens, positions, tables,
     def attend_for(layer):
         kind = layer_kind[layer]
 
-        def attend(q, k, v):  # [S, H, hd]
+        def attend(q, *row):  # each [S, heads, width]
             state[0] = kv_ = _paged_write_rows(
-                state[0], layer, (write_blocks[kind], offsets), k, v
+                state[0], layer, (write_blocks[kind], offsets), *row
             )
+            if model.own_attention:
+                return model.decode_attention(
+                    params, layer, q, kv_[0][layer], positions, tabs[kind]
+                )
             if _plain(model, layer):
                 return plain_attend(
                     q, kv_[0][layer], kv_[1][layer], lengths,
@@ -509,15 +525,23 @@ def _forward_extend(model, params, kv, ctx_table, tail_ids, tokens,
     sm_scale = model.head_dim ** -0.5
     ctx_tables = _per_kind(ctx_table)
     positions = ctx_len + jnp.arange(tb, dtype=jnp.int32)
-    ks, vs = [], []
+    written = []
 
     def attend_for(layer):
         kind = layer_kind[layer]
         window = model.layer_windows[layer]
 
-        def attend(q, k, v):  # [1, tb, H, hd]
-            ks.append(k[0])
-            vs.append(v[0])
+        def attend(q, *row):  # each [1, tb, heads, width]
+            written.append(tuple(x[0] for x in row))
+            if model.own_attention:
+                # The cached rows as [ctx_cols, heads, width], as they lie.
+                ctx = kv_mod.gather_block_kv(
+                    kv[0][layer], ctx_tables[kind], row[0].shape[-2]
+                )
+                return model.chunk_attention(
+                    params, layer, q[0], row[0][0], ctx, ctx_len
+                )[None]
+            k, v = row
             # The cached context as [ctx_cols, Hkv, hd], from this
             # layer's blocks of the table alone.
             kc, vc = (
@@ -547,7 +571,7 @@ def _forward_extend(model, params, kv, ctx_table, tail_ids, tokens,
         jnp.arange(tb) < tail_len,
     )
     kv = _paged_write_prompt(
-        kv, ks, vs, tail_ids, layer_kind, block_size=block_size
+        kv, written, tail_ids, layer_kind, block_size=block_size
     )
     return kv, x, stats
 
@@ -709,7 +733,7 @@ class InferenceEngine:
         precision=None,
     ):
         # The block this engine runs, chosen by the config's type
-        # (serving/blocks.py): GPT-2's or Cohere2-MoE's.
+        # (serving/blocks.py): GPT-2's, Cohere2-MoE's or GLM-4.7-Flash's.
         self.model = block_for(model_cfg)
         self.gpt2 = isinstance(self.model, Gpt2Block)
         if self.gpt2 and model_cfg.moe_experts:
@@ -893,8 +917,9 @@ class InferenceEngine:
                     "(every compiled bucket is a whole number of "
                     "blocks)"
                 )
-        # A cache row holds the KEY/VALUE heads (fewer than the query
-        # heads under grouped-query attention); kv_blocks counts the
+        # A cache row is what the block says it is (``cache_rows``: K
+        # and V of the KEY/VALUE heads, fewer than the query heads under
+        # grouped-query attention; one latent row); kv_blocks counts the
         # full kind's blocks, a window kind's follow from the slots, its
         # W, the chunk and the block size.
         self.pool = paged_kv.PagedKVPool(
@@ -912,6 +937,7 @@ class InferenceEngine:
             sharding=self._kv_sharding(),
             layer_windows=self.model.layer_windows,
             window_span=self.cfg.prefill_chunk_tokens,
+            rows=tuple(h * w for h, w in self.model.cache_rows),
         )
         self._layer_kind = self.pool.layer_kind
         self._kinds = len(self.pool.kinds)
@@ -919,9 +945,13 @@ class InferenceEngine:
         # longer than one chunk (a longer prompt is split, prefill_open):
         # the rungs above the chunk's are unreachable, and for a model
         # whose pool has a window kind they must not exist — the kind's
-        # block space holds W plus ONE chunk a slot.
+        # block space holds W plus ONE chunk a slot — nor for one that
+        # attends from its own rows, whose chunk attention is sized for
+        # a chunk of queries (a 32k-token rung's scores are not).
         longest = model_cfg.max_len
-        if self.cfg.prefill_chunk_tokens and self._kinds > 1:
+        if self.cfg.prefill_chunk_tokens and (
+            self._kinds > 1 or self.model.own_attention
+        ):
             longest = min(longest, self.cfg.prefill_chunk_tokens)
         self.prefill_ladder = kv_mod.bucket_ladder(
             min(self.cfg.prefill_bucket_floor, longest), longest
@@ -1012,36 +1042,33 @@ class InferenceEngine:
 
     def _refuse_for_block(self, sharding, precision) -> None:
         """A block other than GPT-2's runs on the pool's XLA path —
-        prefill, extend (chunked prefill) and decode — and on nothing
-        else. Every other mechanism keeps serving GPT-2 as it
+        prefill, extend (chunked prefill, prefix hits) and decode — and
+        on nothing else. Every other mechanism keeps serving GPT-2 as it
         is and REFUSES this block here, by name, at construction: no
-        silent fallback."""
+        silent fallback. The reason is the block's (``refused``): what
+        its cache row is decides what cannot read it."""
         cfg, name = self.cfg, self.model.name
         refused = [
-            ("speculative verify (spec_decode_k)", cfg.spec_decode_k > 0,
-             "its verify forward is GPT-2's"),
-            ("KV page export/import (role='prefill'/'decode')",
-             cfg.role != "mixed", "a page payload has one block-id space "
-             "and equal heads"),
-            ("quantized KV (kv_dtype int8/fp8)",
-             bool(cfg.kv_dtype or (precision and precision.kv_dtype)),
-             "the grouped-query gather does not dequantize"),
-            ("weight quantization (weight_dtype / precision=)",
-             bool(cfg.weight_dtype or precision),
-             "the block reads its weights as stored"),
-            ("the fused paged_flash decode kernel (attention='paged_flash')",
-             cfg.attention == "paged_flash", "the kernel reads rows of "
-             "equal heads through one table"),
-            ("the Pallas flash prefill (attention='flash')",
-             cfg.attention == "flash", "no grouped-query or window mask"),
-            ("sharded serving (sharding=)", sharding is not None,
-             "the placement rules are GPT-2's"),
+            ("verify", "speculative verify (spec_decode_k)",
+             cfg.spec_decode_k > 0),
+            ("pages", "KV page export/import (role='prefill'/'decode')",
+             cfg.role != "mixed"),
+            ("kv_dtype", "quantized KV (kv_dtype int8/fp8)",
+             bool(cfg.kv_dtype or (precision and precision.kv_dtype))),
+            ("weights", "weight quantization (weight_dtype / precision=)",
+             bool(cfg.weight_dtype or precision)),
+            ("paged_flash",
+             "the fused paged_flash decode kernel (attention='paged_flash')",
+             cfg.attention == "paged_flash"),
+            ("flash", "the Pallas flash prefill (attention='flash')",
+             cfg.attention == "flash"),
+            ("sharding", "sharded serving (sharding=)", sharding is not None),
         ]
-        for mechanism, on, why in refused:
+        for key, mechanism, on in refused:
             if on:
                 raise NotImplementedError(
                     f"{mechanism} does not serve the {name} block "
-                    f"({why}); it serves GPT-2 only"
+                    f"({self.model.refused[key]}); it serves GPT-2 only"
                 )
 
     def _kv_sharding(self):
@@ -1582,8 +1609,8 @@ class InferenceEngine:
         if not self.gpt2:
             raise NotImplementedError(
                 f"KV page {what} does not serve the {self.model.name} "
-                "block (a page payload has one block-id space and equal "
-                "heads); it serves GPT-2 only"
+                "block (a page payload has one block-id space and K and "
+                "V rows of equal heads); it serves GPT-2 only"
             )
 
     def export_kv_pages(self, slot: int, prompt: Sequence[int], *,
@@ -1832,9 +1859,11 @@ class InferenceEngine:
             tables = self._kind_tables(
                 positions, bucket // bs, live=slots
             )
-            if self._kinds > 1:
-                # What the pool holds for what is resident, and what
-                # of it this step's tables reach, sampled once a step
+            if not self.gpt2:
+                # What the pool holds for what is resident (a block
+                # several slots share counted once), and what of it
+                # this step's tables reach (every slot's own reach: a
+                # shared block once per reader), sampled once a step
                 # (kv_bytes_per_resident_token; the decode roofline's
                 # cache bytes).
                 reg, pool = self.registry, self.pool

@@ -26,6 +26,11 @@ module holds what the compiled steps do with it:
   the Pallas kernel directly, see ``engine._prefill_attend``).
   ``varlen_verify_attention`` is its T-rows-a-slot form, and the
   ``grouped_*`` attentions serve grouped-query and window layers.
+* ``latent_chunk_attention`` / ``latent_decode_attention`` — attention
+  over LATENT rows (``[c_kv | k_pe]``, one a token, no heads, no V):
+  a prompt chunk over itself and the cached context, a decode step
+  through the block tables, both ABSORBED (the queries moved into the
+  latent space, every head reading the same rows).
 
 Everything here is functionally pure: the pool's arrays are replaced
 wholesale by the jitted steps that update them, so the engine composes
@@ -325,3 +330,184 @@ def grouped_chunk_attention(
         by_group(k), by_group(v), by_group(k_ctx), by_group(v_ctx),
     ))  # [G, R, T, D]
     return jnp.moveaxis(out, 2, 0).reshape(t_n, h, d).astype(q.dtype)
+
+
+# ------------------------------------------------------ latent attention
+#
+# A latent layer caches ONE row a token, ``[c_kv | k_pe]``: the
+# compressed key/value vector (``dc`` values) and the rotary key all
+# heads share (``dr`` values). Head ``h``'s key is ``[c_kv W_uk[h] |
+# k_pe]`` and its value ``c_kv W_uv[h]``. Both functions below attend
+# ABSORBED: ``W_uk`` moves to the query (``q_lat = q_nope W_uk^T``) and
+# ``W_uv`` behind the softmax, so the scores are ``dc + dr`` wide
+# straight against the rows and the values ``dc`` wide, every head
+# reading the same rows once (multi-query attention over the latent).
+# The EXPANDED form (K and V of every head made from the rows, then
+# ordinary attention: ``2 * dc * (dn + dv)`` more operations a cached
+# row a head) is the model's mathematics as published and what the
+# plain reference computes; on the chip it was slower at every shape
+# the engine runs (PERF.md §6, PR 32; ``tools/mla_forms_bench.py``
+# still times it).
+
+# The f32 scores that may exist at once, in elements: a chunk attends
+# in groups of heads no larger than this allows.
+LATENT_SCORE_ELEMENTS = 1 << 26
+# The bytes of cached rows a decode step gathers at once. Measured on
+# the chip (PERF.md §6, PR 32): 32 slots' rows of one layer gathered
+# whole (1.2 GB at 32k rows a slot) are read at 118 GB/s, groups of up
+# to ~75 MB at 175-200 GB/s.
+LATENT_GATHER_BYTES = 80 << 20
+
+
+def _largest_divisor(n: int, limit: int) -> int:
+    """The largest divisor of ``n`` no larger than ``limit`` (at least 1)."""
+    group = max(1, min(n, limit))
+    while n % group:
+        group -= 1
+    return group
+
+
+def latent_head_group(heads: int, rows: int, cols: int) -> int:
+    """Heads a chunk's attention takes at once: the largest divisor of
+    ``heads`` whose ``[group, rows, cols]`` float32 scores stay within
+    ``LATENT_SCORE_ELEMENTS`` (at least one head)."""
+    return _largest_divisor(heads, LATENT_SCORE_ELEMENTS // max(rows * cols, 1))
+
+
+def latent_chunk_attention(
+    q_nope: jax.Array,
+    q_pe: jax.Array,
+    rows: jax.Array,
+    w_uk: jax.Array,
+    w_uv: jax.Array,
+    ctx_rows: jax.Array | None = None,
+    *,
+    ctx_len=0,
+    sm_scale: float,
+) -> jax.Array:
+    """Attention of one prompt chunk over latent rows, absorbed: a
+    prefill (no context) or one extend step.
+
+    q_nope: [T, H, dn], q_pe: [T, H, dr] (rotated), the chunk's queries
+    at positions ``ctx_len + t``; rows: [T, dc + dr], the chunk's own
+    latent rows (seen causally); ctx_rows: [C, dc + dr], the cached
+    context as gathered, of which only the first ``ctx_len`` rows are
+    populated. w_uk: [dc, H, dn], w_uv: [dc, H, dv].
+
+    :func:`latent_head_group` heads at a time (``lax.map``), so the
+    scores that exist at once are ``[group, T, C + T]`` float32, never
+    all heads'. Numerics as the other cache attentions: f32 scores and
+    softmax, probabilities in the rows' dtype, f32 accumulation.
+    Returns [T, H, dv]."""
+    t_n, h, _ = q_nope.shape
+    dc = w_uk.shape[0]
+    dtype = q_nope.dtype
+    q_pos = ctx_len + jnp.arange(t_n)
+    pieces = [(rows, q_pos[:, None] >= q_pos[None, :])]
+    if ctx_rows is not None:
+        seen = jnp.arange(ctx_rows.shape[0]) < ctx_len
+        pieces.insert(0, (ctx_rows, jnp.broadcast_to(
+            seen[None, :], (t_n, ctx_rows.shape[0])
+        )))
+    cols = sum(r.shape[0] for r, _ in pieces)
+    g = latent_head_group(h, t_n, cols)
+    f32 = dict(preferred_element_type=jnp.float32)
+
+    def one_group(args):
+        qn, qp, uk, uv = args  # [G,T,dn] [G,T,dr] [G,dc,dn] [G,dc,dv]
+        q_lat = jnp.einsum("gtn,gcn->gtc", qn, uk, **f32).astype(dtype)
+        q_all = jnp.concatenate([q_lat, qp], axis=-1)  # [G,T,dc+dr]
+        prob = jax.nn.softmax(jnp.concatenate([
+            jnp.where(
+                ok[None],
+                jnp.einsum("gtr,kr->gtk", q_all, kx, **f32) * sm_scale,
+                NEG_INF,
+            ) for kx, ok in pieces
+        ], axis=-1), axis=-1)
+        out, col = None, 0
+        for kx, _ in pieces:
+            n = kx.shape[0]
+            part = jnp.einsum(
+                "gtk,kc->gtc", prob[..., col:col + n].astype(kx.dtype),
+                kx[:, :dc], **f32,
+            )
+            out = part if out is None else out + part
+            col += n
+        return jnp.einsum(  # [G,T,dv] f32
+            "gtc,gcv->gtv", out.astype(dtype), uv, **f32
+        )
+
+    by_group = lambda x, axis: jnp.moveaxis(x, axis, 0).reshape(  # noqa: E731
+        h // g, g, *x.shape[:axis], *x.shape[axis + 1:]
+    )
+    out = jax.lax.map(one_group, (
+        by_group(q_nope, 1), by_group(q_pe, 1),
+        by_group(w_uk, 1), by_group(w_uv, 1),
+    ))  # [H/G, G, T, dv]
+    return jnp.moveaxis(out.reshape(h, t_n, -1), 0, 1).astype(dtype)
+
+
+def latent_slot_group(slots: int, cols: int, row_bytes: int) -> int:
+    """Slots a decode step's attention gathers at once: the largest
+    divisor of ``slots`` whose gathered rows ``[group, cols, row]`` stay
+    within ``LATENT_GATHER_BYTES`` (at least one slot)."""
+    return _largest_divisor(
+        slots, LATENT_GATHER_BYTES // max(cols * row_bytes, 1)
+    )
+
+
+def latent_decode_attention(
+    q_nope: jax.Array,
+    q_pe: jax.Array,
+    blocks: jax.Array,
+    positions: jax.Array,
+    block_tables: jax.Array,
+    w_uk: jax.Array,
+    w_uv: jax.Array,
+    *,
+    sm_scale: float,
+) -> jax.Array:
+    """One decode step's attention over a paged latent layer, ABSORBED:
+    every head reads the slot's gathered rows once.
+
+    q_nope: [S, H, dn], q_pe: [S, H, dr] (rotated): slot s's query at
+    ``positions[s]`` (its own row already written). blocks: one layer's
+    pool ``[NB, BS, dc + dr]``; block_tables: [S, nb], the slot's
+    logical blocks from 0. Slot s sees rows at positions ``<=
+    positions[s]``. :func:`latent_slot_group` slots at a time, so the
+    rows gathered at once are
+    ``[group, nb * BS, dc + dr]``, never all slots' (1.2 GB a layer at
+    32 slots of 32k rows). The values are the rows' first ``dc``
+    columns: the product runs over whole rows and the rotary columns of
+    the result are dropped, which costs an eighth more operations and
+    saves a copy of every gathered row. f32 scores and softmax,
+    probabilities in the rows' dtype, f32 accumulation. Returns [S, H,
+    dv]."""
+    s_n, nb = block_tables.shape
+    dc = w_uk.shape[0]
+    dtype = q_nope.dtype
+    f32 = dict(preferred_element_type=jnp.float32)
+    q_lat = jnp.einsum("shn,chn->shc", q_nope, w_uk, **f32).astype(dtype)
+    q_all = jnp.concatenate([q_lat, q_pe], axis=-1)        # [S,H,dc+dr]
+    g = latent_slot_group(
+        s_n, nb * blocks.shape[1], blocks.shape[2] * blocks.dtype.itemsize
+    )
+
+    def one_group(args):
+        q, table, pos = args  # [G,H,R] [G,nb] [G]
+        rows = gather_block_kv(blocks, table, 1)[..., 0, :]    # [G,K,dc+dr]
+        s = jnp.einsum("ghr,gkr->ghk", q, rows, **f32) * sm_scale
+        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        s = jnp.where(col <= pos[:, None, None], s, NEG_INF)
+        p = jax.nn.softmax(s, axis=-1).astype(rows.dtype)
+        return jnp.einsum("ghk,gkr->ghr", p, rows, **f32)[..., :dc]
+
+    # Unrolled, not ``lax.map``: a loop that reads the pool's array makes
+    # XLA copy the whole array into the loop's state, a layer's pool a layer.
+    o_lat = jnp.concatenate([
+        one_group((q_all[i:i + g], block_tables[i:i + g], positions[i:i + g]))
+        for i in range(0, s_n, g)
+    ])
+    return jnp.einsum(
+        "shc,chv->shv", o_lat.astype(dtype), w_uv, **f32
+    ).astype(dtype)

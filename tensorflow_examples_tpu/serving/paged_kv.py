@@ -6,9 +6,12 @@ caps concurrency by the worst case, not the workload. This module is
 the engine's KV pool: slots (one per in-flight request, with host-side
 alloc/free and per-slot populated lengths) over block-granular storage:
 
-* **Paged blocks** — the device arrays are, per layer, ``[NB, BS, H*D]``
+* **Paged blocks** — the device arrays are, per layer, ``[NB, BS, row]``
   pools of ``NB`` physical blocks of ``BS`` (power-of-two) token rows
-  each, a row being the token's ``H*D`` values (lane-dense).
+  each. What a row holds is the serving block's to say (``rows``, one
+  width per array a layer keeps): K and V of ``H*D`` values each for
+  attention with heads (the default, lane-dense), ONE latent row and
+  no V for latent attention (``serving/blocks.py``).
   A slot holds a *block table* (logical block index -> physical block
   id); capacity scales with the tokens a request has actually used,
   so a mixed short/long request set commits a fraction of what
@@ -208,9 +211,13 @@ class PagedKVPool:
         sharding=None,
         layer_windows=None,
         window_span: int = 0,
+        rows: tuple | None = None,
     ):
         """``num_heads`` is the heads a cache row holds (the key/value
-        heads of a grouped-query model). ``layer_windows``: one entry a
+        heads of a grouped-query model). ``rows``: the width of each
+        array a layer keeps per token, as the serving block describes
+        its cache row; omitted, K and V of ``num_heads * head_dim``
+        each. ``layer_windows``: one entry a
         layer, ``None`` for a full layer or the window ``W``; omitted,
         every layer is full. ``window_span``: the most positions one
         step writes into a slot (the prefill chunk; 0 = ``max_len``),
@@ -264,6 +271,13 @@ class PagedKVPool:
                     "use kv_dtype='int8'"
                 )
         self.quantized = self.kv_dtype in ("int8", "fp8")
+        self.rows = tuple(int(r) for r in rows) if rows is not None \
+            else (num_heads * head_dim,) * 2
+        if self.quantized and self.rows != (num_heads * head_dim,) * 2:
+            raise ValueError(
+                f"kv_dtype={kv_dtype!r} keeps one scale a head of K and "
+                f"of V; a cache row of widths {self.rows} has no such heads"
+            )
         # Kinds: index 0 is the full kind (this object's own tables,
         # free list and refcounts below), then one _WindowSpace per
         # distinct window, ascending.
@@ -305,10 +319,12 @@ class PagedKVPool:
         self._free_blocks = list(range(self.num_blocks - 1, 0, -1))  # guard: self._lock
         self._refcount = np.zeros((self.num_blocks,), np.int32)  # guard: self._lock
         # Prefix cache: (parent physical id | -1, tokens tuple) -> id;
-        # reverse map for eviction; LRU order over refcount-0 cached
+        # reverse map for eviction; per block, how many published
+        # blocks are chained under it; LRU order over refcount-0 cached
         # blocks ("evictable": published but unreferenced).
         self._cache: dict[tuple, int] = {}  # guard: self._lock
         self._cache_key: dict[int, tuple] = {}  # guard: self._lock
+        self._children: dict[int, int] = {}  # guard: self._lock
         # Content chain digests (ISSUE 12): per published block, the
         # replica- and restart-stable scheduler.chain_key of its whole
         # token prefix (+ its chain depth). The /health prefix digest
@@ -332,13 +348,13 @@ class PagedKVPool:
     # ------------------------------------------------------ device state
 
     def _alloc_arrays(self) -> None:
-        # One array per layer, a token's row its H*D values: the minor
+        # One array per layer and per entry of ``rows``, a token's row
+        # its values (H*D of K, of V; or one latent row): the minor
         # dimension is lane-dense (768 = 6 x 128 for GPT-2), so the
         # TPU's default layout is the one the row scatters and block
         # gathers want. A trailing [..., BS, D=64] made XLA put NB
         # minor-most and convert the WHOLE pool on the way in and out
         # of every program (PERF.md, PR 26).
-        row = self.num_heads * self.head_dim
         if self.kv_dtype == "fp8":
             from tensorflow_examples_tpu.core import precision
 
@@ -357,13 +373,24 @@ class PagedKVPool:
                 for kind in self.layer_kind
             )
 
-        self.k = per_layer(jnp.zeros, row, store)
-        self.v = per_layer(jnp.zeros, row, store)
+        self._payload = tuple(
+            per_layer(jnp.zeros, row, store) for row in self.rows
+        )
         if self.quantized:
             self.k_scale = per_layer(jnp.ones, self.num_heads, jnp.float32)
             self.v_scale = per_layer(jnp.ones, self.num_heads, jnp.float32)
         else:
             self.k_scale = self.v_scale = None
+
+    @property
+    def k(self) -> tuple:
+        """The first array a layer keeps (K; the latent rows)."""
+        return self._payload[0]
+
+    @property
+    def v(self) -> tuple:
+        """The second (V); a pool of one array a layer has none."""
+        return self._payload[1]
 
     def kind_blocks(self, kind: int) -> int:
         """Physical blocks (the null block included) of one kind."""
@@ -378,19 +405,20 @@ class PagedKVPool:
 
     def kv_state(self) -> tuple:
         """The device state the engine's compiled steps donate and
-        return (``set_kv_state`` reassigns from the outputs): ``(k, v)``
-        or, quantized, ``(k, v, k_scale, v_scale)``, each a tuple of
-        ``num_layers`` arrays — ``[NB, BS, H*D]`` payloads, ``[NB, BS,
+        return (``set_kv_state`` reassigns from the outputs): one entry
+        per array of ``rows`` — ``(k, v)``, or ``(latent,)`` — and,
+        quantized, ``(k, v, k_scale, v_scale)``, each a tuple of
+        ``num_layers`` arrays — ``[NB, BS, row]`` payloads, ``[NB, BS,
         H]`` scales, ``NB`` that of the layer's kind."""
         if self.quantized:
-            return (self.k, self.v, self.k_scale, self.v_scale)
-        return (self.k, self.v)
+            return (*self._payload, self.k_scale, self.v_scale)
+        return self._payload
 
     def set_kv_state(self, state: tuple) -> None:
+        n = len(self.rows)
+        self._payload = tuple(state[:n])
         if self.quantized:
-            self.k, self.v, self.k_scale, self.v_scale = state
-        else:
-            self.k, self.v = state
+            self.k_scale, self.v_scale = state[n:]
 
     def reallocate(self) -> None:
         """Fresh zeroed device arrays after a failed donated step (the
@@ -410,6 +438,7 @@ class PagedKVPool:
         self._evictable.clear()
         self._cache.clear()
         self._cache_key.clear()
+        self._children.clear()
         self._chain_hash.clear()
         self._chain_depth.clear()
         self._digest_gen += 1
@@ -460,7 +489,9 @@ class PagedKVPool:
         with self._lock:
             if slot in self._free_slots:  # double-free is a caller bug
                 raise ValueError(f"slot {slot} is already free")
-            for i in range(int(self._slot_blocks[slot])):
+            # Last block first: a chain parks its leaves before its
+            # head, the order eviction takes them in.
+            for i in reversed(range(int(self._slot_blocks[slot]))):
                 self._release_block_locked(int(self.block_tables[slot, i]))
             self.block_tables[slot, :] = NULL_BLOCK
             self._slot_blocks[slot] = 0
@@ -519,15 +550,23 @@ class PagedKVPool:
         if self._free_blocks:
             return self._free_blocks.pop()
         if self._evictable:
-            # Reclaim the least-recently-published unreferenced prefix
-            # block: cache reuse is an optimization, never a reason to
-            # refuse admission.
-            bid, _ = self._evictable.popitem(last=False)
-            key = self._cache_key.pop(bid)
-            del self._cache[key]
-            self._chain_hash.pop(bid, None)
-            self._chain_depth.pop(bid, None)
-            self._digest_gen += 1
+            # Reclaim an unreferenced prefix block — cache reuse is an
+            # optimization, never a reason to refuse admission: the
+            # least recently released that no published block is
+            # chained under. A chain goes from its LEAVES, so what is
+            # left of it still hits, and no child stays keyed by a
+            # parent id that can be published again under other tokens.
+            # Slots release last block first, so the oldest parked
+            # block is such a leaf — unless a racing twin published its
+            # tail under a chain it never held (``insert_prefix``) and
+            # still holds it: then the oldest goes with what hangs
+            # under it.
+            bid = next(
+                (b for b in self._evictable if not self._children.get(b)),
+                next(iter(self._evictable)),
+            )
+            self._unpublish_locked(bid)
+            del self._evictable[bid]
             return bid
         self._reg().counter("serving/kv_exhausted_total").inc()
         log.warning(
@@ -540,6 +579,29 @@ class PagedKVPool:
             f"({self.block_size} tokens each) all referenced by active "
             "requests — admission must shed load"
         )
+
+    def _unpublish_locked(self, bid: int) -> None:
+        """Take ``bid`` out of the prefix cache, with every block
+        published under it (a parked one goes back to the free list, a
+        referenced one when its slots release it)."""
+        doomed = [bid]
+        if self._children.get(bid):
+            under: dict[int, list[int]] = {}
+            for (parent, _), child in self._cache.items():
+                under.setdefault(parent, []).append(child)
+            for b in doomed:  # grows as it is walked
+                doomed.extend(under.get(b, ()))
+        parent = self._cache_key[bid][0]
+        if parent in self._children:
+            self._children[parent] -= 1
+        for b in doomed:
+            del self._cache[self._cache_key.pop(b)]
+            self._children.pop(b, None)
+            self._chain_hash.pop(b, None)
+            self._chain_depth.pop(b, None)
+            if b != bid and self._evictable.pop(b, 0) is None:
+                self._free_blocks.append(b)
+        self._digest_gen += 1
 
     def _release_block_locked(self, bid: int) -> None:
         if bid == NULL_BLOCK:
@@ -683,7 +745,7 @@ class PagedKVPool:
         """Undo a ``prefix_lookup``'s refcounts (the admission that
         followed it failed before ``assign``)."""
         with self._lock:
-            for bid in blocks:
+            for bid in reversed(blocks):
                 self._release_block_locked(bid)
             self._publish_locked()
 
@@ -733,6 +795,8 @@ class PagedKVPool:
                     break
                 self._cache[key] = bid
                 self._cache_key[bid] = key
+                if parent != -1:
+                    self._children[parent] = self._children.get(parent, 0) + 1
                 self._chain_hash[bid] = parent_hash
                 self._chain_depth[bid] = i + 1
                 self._digest_gen += 1
@@ -799,17 +863,17 @@ class PagedKVPool:
     # -------------------------------------------------- byte accounting
 
     def bytes_per_block(self, kind: int | None = None) -> int:
-        """K+V device bytes one physical block commits (int8 payload +
-        its blockwise f32 row scales when quantized) over the layers of
-        its ``kind`` — over every layer when the pool has one kind."""
-        row = self.num_heads * self.head_dim
+        """Device bytes one physical block commits — every array of
+        the row (K and V; or the one latent row), int8 payload + its
+        blockwise f32 row scales when quantized — over the layers of
+        its ``kind``: over every layer when the pool has one kind."""
         if self.quantized:
-            per = self.block_size * row * 1 + self.block_size * self.num_heads * 4
+            per = sum(self.rows) * 1 + len(self.rows) * self.num_heads * 4
         else:
-            per = self.block_size * row * jnp.dtype(self.dtype).itemsize
+            per = sum(self.rows) * jnp.dtype(self.dtype).itemsize
         layers = self.num_layers if kind is None \
             else self.layer_kind.count(kind)
-        return int(2 * layers * per)
+        return int(layers * self.block_size * per)
 
     def used_bytes(self) -> int:
         """Cache bytes committed to the active request set — blocks

@@ -352,6 +352,18 @@ MOE_KV_KIND_INSTRUMENTS = (
 )
 MOE_EXPERT_COUNTER_PREFIX = "serving/moe_pairs_expert_"
 
+# The instrument of a block that caches a latent row (ISSUE 32; writer:
+# serving/blocks.py `Glm4MoeLiteBlock.count_stats`; catalog:
+# docs/observability.md): the query tokens that went through its
+# (absorbed) latent attention, counted in the program and fetched with
+# the tokens. Such a block books the MOE_KV_KIND_INSTRUMENTS expert and
+# `kv_sampled_*` counters too, the latter right for its row and for
+# blocks shared between slots. `span/mla_plan` (args: family, queries,
+# context, dtype, form, head_group) is recorded once per traced shape.
+LATENT_ATTENTION_TOKENS = "serving/latent_attn_absorbed_tokens"
+MLA_PLAN_SPAN = "mla_plan"
+MLA_PLAN_ARGS = ("family", "queries", "context", "dtype", "form", "head_group")
+
 # The per-host entry of a fleet line's "hosts" list: "host" is a
 # required int, and each of these is required numeric-or-null (the
 # writer side, fleet.VECTOR_KEYS, aliases FLEET_VECTOR_KEYS below — the

@@ -30,8 +30,15 @@ What a model tells the engine beside its three functions: ``num_layers``,
 ``num_heads`` / ``num_kv_heads`` / ``head_dim``, **``cache_rows``** (the
 arrays a layer keeps of a token, ``(heads, width)`` each: K and V of
 ``num_kv_heads x head_dim`` for the first two, one ``1 x (dc + dr)``
-latent row for the third; the pool allocates by it and the forwards
-scatter what ``block`` hands ``attend`` after ``q``),
+latent row for the third, zero-padded to a whole number of 128-lane
+tiles — ``kv_cache.lane_dense``: 640 columns for 576 values; a toy
+row under one tile stays as it is — because
+the TPU re-lays a whole pool array of any other width around every
+write; the pool allocates by it and the forwards scatter what ``block``
+hands ``attend`` after ``q``), **``row_values``** (any block but
+GPT-2's, which books no ``kv_sampled_*``: the values of a token's row
+in the model's mathematics, pad columns left out — what a step must
+read of it, ``serving/kv_sampled_reach_bytes``),
 **``own_attention``** (false: the engine's generic ``softmax(q k) v``
 over gathered heads serves it; true: attention over the cache is the
 block's own mathematics, ``chunk_attention`` for a prompt chunk and
@@ -222,6 +229,7 @@ class Cohere2MoeBlock:
         self.num_kv_heads = cfg.num_kv_heads
         self.head_dim = cfg.head_dim
         self.cache_rows = ((cfg.num_kv_heads, cfg.head_dim),) * 2  # K, V
+        self.row_values = 2 * cfg.num_kv_heads * cfg.head_dim
         self.max_len = cfg.max_len
         self.layer_windows = tuple(cfg.layer_windows)
         self.stats_len = len(cfg.held_experts) + 2
@@ -317,13 +325,15 @@ def _swiglu(x, p):
 
 
 @functools.lru_cache(maxsize=None)
-def _record_mla_plan(family, queries, context, dtype, head_group):
+def _record_mla_plan(family, queries, context, dtype, head_group, rows):
     """One ``span/mla_plan`` per traced shape, so a run's record says
-    which attention form each program family ran and how the heads were
-    grouped (at trace time, never inside a step)."""
+    which attention form each program family ran, how the heads were
+    grouped and how wide the row's arrays are as stored (at trace time,
+    never inside a step)."""
     with span(
         schema.MLA_PLAN_SPAN, family=family, queries=queries, context=context,
         dtype=dtype, form="absorbed", head_group=head_group,
+        rows=list(rows),
     ):
         pass
 
@@ -336,8 +346,10 @@ class Glm4MoeLiteBlock:
 
     **The cache row is the block's**: ``[c_kv | k_pe]`` after the norm
     and the rotation, ``kv_lora_rank + qk_rope_head_dim`` values a
-    token a layer, no heads, no V (``cache_rows``). ``block`` hands it
-    to ``attend`` behind the queries, the forward writes it, and the
+    token a layer (``row_values``), no heads, no V, stored in ONE array
+    zero-padded to whole 128-lane tiles (``cache_rows``: 576 values in
+    640 columns; ``kv_cache`` says why). ``block`` hands it to
+    ``attend`` behind the queries, the forward writes it, and the
     attention over rows is this class's: ``chunk_attention`` (a prompt
     chunk over itself and the cached context) and ``decode_attention``
     (one query a slot through a block table), both absorbed
@@ -371,7 +383,8 @@ class Glm4MoeLiteBlock:
         self.num_heads = cfg.num_heads
         self.num_kv_heads = 1  # one latent row, shared by every head
         self.head_dim = cfg.qk_head_dim
-        self.cache_rows = ((1, cfg.latent_dim),)
+        self.row_values = cfg.latent_dim
+        self.cache_rows = ((1, kv_cache.lane_dense(cfg.latent_dim)),)
         self.max_len = cfg.max_len
         self.layer_windows = (None,) * cfg.num_layers
         self.stats_len = len(cfg.held_experts) + 3
@@ -411,7 +424,7 @@ class Glm4MoeLiteBlock:
     def chunk_attention(self, params, layer, q, row, ctx_rows=None,
                         ctx_len=0):
         """A prompt chunk: q [T, H, dn + dr], its own rows ``row`` [T,
-        1, dc + dr], the cached context ``ctx_rows`` [C, 1, dc + dr]
+        1, W] as stored, the cached context ``ctx_rows`` [C, 1, W]
         (first ``ctx_len`` populated) or None. Returns [T, H, dv]."""
         t_n = q.shape[0]
         cols = t_n + (0 if ctx_rows is None else ctx_rows.shape[0])
@@ -419,6 +432,7 @@ class Glm4MoeLiteBlock:
             "extend" if ctx_rows is not None else "prefill", t_n, cols,
             str(q.dtype),
             kv_cache.latent_head_group(self.num_heads, t_n, cols),
+            row.shape[-1:],
         )
         with jax.named_scope("attn_latent_absorbed"):
             return kv_cache.latent_chunk_attention(
@@ -429,11 +443,11 @@ class Glm4MoeLiteBlock:
 
     def decode_attention(self, params, layer, q, blocks, positions, table):
         """A decode step: q [S, H, dn + dr] at ``positions`` over one
-        layer's pool ``blocks`` [NB, BS, dc + dr] through ``table`` [S,
-        nb]. Returns [S, H, dv]."""
+        layer's pool ``blocks`` [NB, BS, W] through ``table`` [S, nb].
+        Returns [S, H, dv]."""
         _record_mla_plan(
             "decode", q.shape[0], table.shape[1] * blocks.shape[1],
-            str(q.dtype), self.num_heads,
+            str(q.dtype), self.num_heads, blocks.shape[-1:],
         )
         with jax.named_scope("attn_latent_absorbed"):
             return kv_cache.latent_decode_attention(
@@ -464,9 +478,11 @@ class Glm4MoeLiteBlock:
         k_pe = rope_interleaved(
             kv[..., None, dc:], positions, cfg.rope_theta
         )
-        # What the token leaves in the cache, and nothing else.
-        row = jnp.concatenate(
-            [c_kv[..., None, :], k_pe], axis=-1
+        # What the token leaves in the cache, and nothing else: the pad
+        # columns are written as zeros.
+        row = kv_cache.pad_columns(
+            jnp.concatenate([c_kv[..., None, :], k_pe], axis=-1),
+            self.cache_rows[0][1],
         ).astype(dtype)
         att = attend(q, row)
         x = x + jnp.einsum(

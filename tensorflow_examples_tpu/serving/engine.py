@@ -917,11 +917,37 @@ class InferenceEngine:
                     "(every compiled bucket is a whole number of "
                     "blocks)"
                 )
+        # The TPU keeps a pool array whose rows are not a whole number
+        # of 128-lane tiles with NB minor-most and re-lays ALL of it
+        # around every row write, in every program (PERF.md §6, PRs 26
+        # and 33). A block that makes its own row pads it
+        # (``kv_cache.lane_dense``) or is refused; K and V with heads
+        # are as wide as the model is, so that is said and served.
+        # Rows under one tile are toy sizes and pass.
+        for heads, width in self.model.cache_rows:
+            values = heads * width
+            if values % kv_mod.LANES == 0 or values < kv_mod.LANES:
+                continue
+            if self.model.own_attention:
+                raise ValueError(
+                    f"{self.model.name}: a cache row array of {values} "
+                    f"values is not a whole number of {kv_mod.LANES}-lane "
+                    "tiles; pad it in cache_rows (kv_cache.lane_dense) — "
+                    "the TPU would re-lay the whole pool around every "
+                    "write"
+                )
+            log.warning(
+                "%s: cache rows of %d values (%d heads x %d) are not a "
+                "whole number of %d-lane tiles: on a TPU every paged "
+                "program re-lays each layer's whole pool",
+                self.model.name, values, heads, width, kv_mod.LANES,
+            )
         # A cache row is what the block says it is (``cache_rows``: K
         # and V of the KEY/VALUE heads, fewer than the query heads under
-        # grouped-query attention; one latent row); kv_blocks counts the
-        # full kind's blocks, a window kind's follow from the slots, its
-        # W, the chunk and the block size.
+        # grouped-query attention; one latent row, padded to whole
+        # tiles); kv_blocks counts the full kind's blocks, a window
+        # kind's follow from the slots, its W, the chunk and the block
+        # size.
         self.pool = paged_kv.PagedKVPool(
             num_layers=self.model.num_layers,
             num_slots=self.cfg.max_slots,
@@ -1865,7 +1891,8 @@ class InferenceEngine:
                 # this step's tables reach (every slot's own reach: a
                 # shared block once per reader), sampled once a step
                 # (kv_bytes_per_resident_token; the decode roofline's
-                # cache bytes).
+                # cache bytes: the row's VALUES, what the mathematics
+                # reads, whatever pad columns the pool stores).
                 reg, pool = self.registry, self.pool
                 ctx = positions[slots].astype(np.int64) + 1
                 reg.counter("serving/kv_sampled_bytes").inc(
@@ -1874,8 +1901,11 @@ class InferenceEngine:
                 reg.counter("serving/kv_sampled_tokens").inc(
                     int(ctx.sum())
                 )
+                row_bytes = (
+                    self.model.row_values * jnp.dtype(pool.dtype).itemsize
+                )
                 reg.counter("serving/kv_sampled_reach_bytes").inc(sum(
-                    pool.bytes_per_block(kind) // bs * int(
+                    pool.layer_kind.count(kind) * row_bytes * int(
                         (ctx if w is None else np.minimum(ctx, w)).sum()
                     )
                     for kind, w in enumerate(pool.kinds)

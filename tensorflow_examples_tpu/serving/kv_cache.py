@@ -348,6 +348,21 @@ def grouped_chunk_attention(
 # plain reference computes; on the chip it was slower at every shape
 # the engine runs (PERF.md §6, PR 32; ``tools/mla_forms_bench.py``
 # still times it).
+#
+# The row is STORED lane-dense (:func:`lane_dense`: 576 values in 640
+# columns, the pad zero). XLA:TPU keeps an array whose minor dimension
+# is not a whole number of 128-lane tiles with ``NB`` minor-most and
+# re-lays the WHOLE pool around every row scatter (two pool-sized
+# copies a layer in every program: 10 ms of a 61 ms decode step,
+# PERF.md §6, PR 33). Both functions take rows of the stored width and
+# pad the absorbed query with zeros to meet them, so a pad column adds
+# nothing to a score; the values are the first ``dc`` columns as
+# before. Of the three forms timed (this; ``c_kv`` and ``k_pe`` as two
+# arrays, ``k_pe`` padded to 128 or left at 64) this was the fastest
+# by 4-5 ms a decode step: a second array is a second gather and a
+# second score product (``tools/mla_forms_bench.py --only pool``).
+
+LANES = 128
 
 # The f32 scores that may exist at once, in elements: a chunk attends
 # in groups of heads no larger than this allows.
@@ -357,6 +372,19 @@ LATENT_SCORE_ELEMENTS = 1 << 26
 # whole (1.2 GB at 32k rows a slot) are read at 118 GB/s, groups of up
 # to ~75 MB at 175-200 GB/s.
 LATENT_GATHER_BYTES = 80 << 20
+
+
+def lane_dense(width: int) -> int:
+    """``width`` rounded up to a whole number of 128-lane tiles: the
+    stored width of a cache row array the TPU does not re-lay. A width
+    under one tile is left as it is: a toy size, whose pool is small
+    whatever its layout, and which padding would multiply."""
+    return width if width < LANES else -(-width // LANES) * LANES
+
+
+def pad_columns(x: jax.Array, width: int) -> jax.Array:
+    """``x`` with zero columns appended up to ``width``."""
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - x.shape[-1])])
 
 
 def _largest_divisor(n: int, limit: int) -> int:
@@ -389,10 +417,11 @@ def latent_chunk_attention(
     prefill (no context) or one extend step.
 
     q_nope: [T, H, dn], q_pe: [T, H, dr] (rotated), the chunk's queries
-    at positions ``ctx_len + t``; rows: [T, dc + dr], the chunk's own
-    latent rows (seen causally); ctx_rows: [C, dc + dr], the cached
-    context as gathered, of which only the first ``ctx_len`` rows are
-    populated. w_uk: [dc, H, dn], w_uv: [dc, H, dv].
+    at positions ``ctx_len + t``; rows: [T, W], the chunk's own latent
+    rows as stored (``[c_kv | k_pe | 0]``, ``W >= dc + dr``; seen
+    causally); ctx_rows: [C, W], the cached context as gathered, of
+    which only the first ``ctx_len`` rows are populated. w_uk: [dc, H,
+    dn], w_uv: [dc, H, dv].
 
     :func:`latent_head_group` heads at a time (``lax.map``), so the
     scores that exist at once are ``[group, T, C + T]`` float32, never
@@ -416,7 +445,9 @@ def latent_chunk_attention(
     def one_group(args):
         qn, qp, uk, uv = args  # [G,T,dn] [G,T,dr] [G,dc,dn] [G,dc,dv]
         q_lat = jnp.einsum("gtn,gcn->gtc", qn, uk, **f32).astype(dtype)
-        q_all = jnp.concatenate([q_lat, qp], axis=-1)  # [G,T,dc+dr]
+        q_all = pad_columns(  # [G,T,W]
+            jnp.concatenate([q_lat, qp], axis=-1), rows.shape[-1]
+        )
         prob = jax.nn.softmax(jnp.concatenate([
             jnp.where(
                 ok[None],
@@ -472,30 +503,32 @@ def latent_decode_attention(
 
     q_nope: [S, H, dn], q_pe: [S, H, dr] (rotated): slot s's query at
     ``positions[s]`` (its own row already written). blocks: one layer's
-    pool ``[NB, BS, dc + dr]``; block_tables: [S, nb], the slot's
-    logical blocks from 0. Slot s sees rows at positions ``<=
-    positions[s]``. :func:`latent_slot_group` slots at a time, so the
-    rows gathered at once are
-    ``[group, nb * BS, dc + dr]``, never all slots' (1.2 GB a layer at
-    32 slots of 32k rows). The values are the rows' first ``dc``
-    columns: the product runs over whole rows and the rotary columns of
-    the result are dropped, which costs an eighth more operations and
-    saves a copy of every gathered row. f32 scores and softmax,
-    probabilities in the rows' dtype, f32 accumulation. Returns [S, H,
-    dv]."""
+    pool ``[NB, BS, W]``, rows as stored (``[c_kv | k_pe | 0]``, ``W >=
+    dc + dr``); block_tables: [S, nb], the slot's logical blocks from
+    0. Slot s sees rows at positions ``<= positions[s]``.
+    :func:`latent_slot_group` slots at a time, so the rows gathered at
+    once are ``[group, nb * BS, W]``, never all slots' (1.3 GB a layer
+    at 32 slots of 32k rows). The values are the rows' first ``dc``
+    columns: the product runs over whole rows and the rotary and pad
+    columns of the result are dropped, which costs a quarter more
+    operations and saves a copy of every gathered row. f32 scores and
+    softmax, probabilities in the rows' dtype, f32 accumulation.
+    Returns [S, H, dv]."""
     s_n, nb = block_tables.shape
     dc = w_uk.shape[0]
     dtype = q_nope.dtype
     f32 = dict(preferred_element_type=jnp.float32)
     q_lat = jnp.einsum("shn,chn->shc", q_nope, w_uk, **f32).astype(dtype)
-    q_all = jnp.concatenate([q_lat, q_pe], axis=-1)        # [S,H,dc+dr]
+    q_all = pad_columns(                                   # [S,H,W]
+        jnp.concatenate([q_lat, q_pe], axis=-1), blocks.shape[2]
+    )
     g = latent_slot_group(
         s_n, nb * blocks.shape[1], blocks.shape[2] * blocks.dtype.itemsize
     )
 
     def one_group(args):
         q, table, pos = args  # [G,H,R] [G,nb] [G]
-        rows = gather_block_kv(blocks, table, 1)[..., 0, :]    # [G,K,dc+dr]
+        rows = gather_block_kv(blocks, table, 1)[..., 0, :]    # [G,K,W]
         s = jnp.einsum("ghr,gkr->ghk", q, rows, **f32) * sm_scale
         col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
         s = jnp.where(col <= pos[:, None, None], s, NEG_INF)

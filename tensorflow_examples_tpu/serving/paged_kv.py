@@ -10,8 +10,11 @@ alloc/free and per-slot populated lengths) over block-granular storage:
   pools of ``NB`` physical blocks of ``BS`` (power-of-two) token rows
   each. What a row holds is the serving block's to say (``rows``, one
   width per array a layer keeps): K and V of ``H*D`` values each for
-  attention with heads (the default, lane-dense), ONE latent row and
-  no V for latent attention (``serving/blocks.py``).
+  attention with heads (the default, lane-dense), ONE latent row,
+  zero-padded to whole 128-lane tiles (576 values in 640 columns), and
+  no V for latent attention (``serving/blocks.py``). The pool stores
+  and counts the widths it is given (``bytes_per_block``: the pad is
+  paid for).
   A slot holds a *block table* (logical block index -> physical block
   id); capacity scales with the tokens a request has actually used,
   so a mixed short/long request set commits a fraction of what
@@ -349,12 +352,14 @@ class PagedKVPool:
 
     def _alloc_arrays(self) -> None:
         # One array per layer and per entry of ``rows``, a token's row
-        # its values (H*D of K, of V; or one latent row): the minor
-        # dimension is lane-dense (768 = 6 x 128 for GPT-2), so the
-        # TPU's default layout is the one the row scatters and block
-        # gathers want. A trailing [..., BS, D=64] made XLA put NB
-        # minor-most and convert the WHOLE pool on the way in and out
-        # of every program (PERF.md, PR 26).
+        # its values (H*D of K, of V; or one latent row, padded): the
+        # minor dimension is lane-dense (768 = 6 x 128 for GPT-2, 640
+        # for a latent row of 576), so the TPU's default layout is the
+        # one the row scatters and block gathers want. A trailing [...,
+        # BS, D=64] made XLA put NB minor-most and convert the WHOLE
+        # pool on the way in and out of every program (PERF.md, PR
+        # 26), and so did a row of 576 (PR 33): the engine refuses or
+        # names what is not (``InferenceEngine.__init__``).
         if self.kv_dtype == "fp8":
             from tensorflow_examples_tpu.core import precision
 
@@ -864,7 +869,8 @@ class PagedKVPool:
 
     def bytes_per_block(self, kind: int | None = None) -> int:
         """Device bytes one physical block commits — every array of
-        the row (K and V; or the one latent row), int8 payload + its
+        the row as stored (K and V; or the one latent row with its pad
+        columns), int8 payload + its
         blockwise f32 row scales when quantized — over the layers of
         its ``kind``: over every layer when the pool has one kind."""
         if self.quantized:

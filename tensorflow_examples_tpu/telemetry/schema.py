@@ -359,10 +359,13 @@ MOE_EXPERT_COUNTER_PREFIX = "serving/moe_pairs_expert_"
 # the tokens. Such a block books the MOE_KV_KIND_INSTRUMENTS expert and
 # `kv_sampled_*` counters too, the latter right for its row and for
 # blocks shared between slots. `span/mla_plan` (args: family, queries,
-# context, dtype, form, head_group) is recorded once per traced shape.
+# context, dtype, form, head_group, rows — the widths of the row's
+# arrays as stored) is recorded once per traced shape.
 LATENT_ATTENTION_TOKENS = "serving/latent_attn_absorbed_tokens"
 MLA_PLAN_SPAN = "mla_plan"
-MLA_PLAN_ARGS = ("family", "queries", "context", "dtype", "form", "head_group")
+MLA_PLAN_ARGS = (
+    "family", "queries", "context", "dtype", "form", "head_group", "rows",
+)
 
 # The per-host entry of a fleet line's "hosts" list: "host" is a
 # required int, and each of these is required numeric-or-null (the

@@ -78,10 +78,10 @@ def _engine(kv_dtype):
     )
 
 
-def _pool_sized_results(hlo: str, sizes: set[int]) -> list[str]:
-    """Instructions of the optimised HLO whose result has a pool's or a
-    layer's element count and is neither free nor an in-place scatter
-    (a ``scatter``, or a fusion whose computation holds one)."""
+def _results(hlo: str):
+    """``(op, dtype, dims, name)`` of every instruction of the optimised
+    HLO that is neither free nor an in-place scatter (a ``scatter``, or
+    a fusion whose computation holds one)."""
     bodies: dict[str, list[str]] = {}
     current = None
     for line in hlo.splitlines():
@@ -94,19 +94,43 @@ def _pool_sized_results(hlo: str, sizes: set[int]) -> list[str]:
         name for name, body in bodies.items()
         if any(" scatter(" in line for line in body)
     }
-    found = []
     for line in hlo.splitlines():
         m = _INSTR.match(line)
         if not m or m["op"] in FREE_OPS or m["op"] == "scatter":
             continue
-        count = int(np.prod([int(d) for d in m["dims"].split(",") if d]))
-        if count not in sizes:
-            continue
         called = re.search(r"calls=%?([\w.\-]+)", m["rest"])
         if m["op"] == "fusion" and called and called[1] in scatters:
             continue
-        found.append(f"{m['op']} {m['dtype']}[{m['dims']}] {m['name']}")
-    return found
+        dims = [int(d) for d in m["dims"].split(",") if d]
+        yield m["op"], m["dtype"], dims, m["name"]
+
+
+def _pool_sized_results(hlo: str, sizes: set[int]) -> list[str]:
+    """Those whose result has a pool's or a layer's element count."""
+    return [
+        f"{op} {dtype}{dims} {name}" for op, dtype, dims, name in _results(hlo)
+        if int(np.prod(dims)) in sizes
+    ]
+
+
+def _results_of_bytes(hlo: str, at_least: float) -> list[str]:
+    """Those whose result holds ``at_least`` bytes or more."""
+    width = lambda dtype: int(re.search(r"\d+", dtype)[0]) // 8 or 1  # noqa: E731
+    return [
+        f"{op} {dtype}{dims} {name}" for op, dtype, dims, name in _results(hlo)
+        if dtype != "token" and dtype != "pred"
+        and int(np.prod(dims)) * width(dtype) >= at_least
+    ]
+
+
+def _packed(engine, family, args, chip):
+    """``args`` of ``sizing*.engine_programs`` in the form the engine
+    launches: params, the pool and the ONE operand block."""
+    ladder = engine.kv_ladder if family == "decode" else engine.prefill_ladder
+    spec = engine._specs[family, ladder[-1]]
+    return (*args[:2], jax.ShapeDtypeStruct(
+        (launch_block.size(spec),), np.int32, sharding=chip
+    ))
 
 
 @pytest.mark.parametrize("kv_dtype", ["", "int8"], ids=["f32", "int8"])
@@ -132,12 +156,7 @@ def test_no_program_touches_the_whole_pool(described_chip, form, family,
     pool_bytes = sum(a.nbytes for a in jax.tree.leaves(state))
     fn, args = sizing.engine_programs(engine, described_chip)[family]
     if form == "packed":
-        ladder = engine.kv_ladder if family == "decode" \
-            else engine.prefill_ladder
-        spec = engine._specs[family, ladder[-1]]
-        args = (*args[:2], jax.ShapeDtypeStruct(
-            (launch_block.size(spec),), np.int32, sharding=described_chip
-        ))
+        args = _packed(engine, family, args, described_chip)
     compiled = fn.lower(*args).compile()
 
     sizes = {layer.size, layer.size * MODEL["num_layers"]}
@@ -149,6 +168,118 @@ def test_no_program_touches_the_whole_pool(described_chip, form, family,
     # [L, NB, H, BS, D] pool's programs held 2.5 times the pool).
     assert mem.temp_size_in_bytes < pool_bytes / 4, (
         mem.temp_size_in_bytes, pool_bytes)
+
+
+# ------------------------------------------------- every block's cache rows
+#
+# The same guard for the rows the other blocks declare (ISSUE 33): GPT-2's
+# 768 = 6 x 128 lanes was the only width the guard above ever saw, and a
+# latent row of 512 + 64 = 576 values, stored as one array, came back
+# with PR 26's pathology — the TPU kept ``bf16[NB, 16, 576]`` with ``NB``
+# minor-most and wrapped each layer's row scatter in two pool-sized
+# copies, in every program. Stated in BYTES, so that a row kept as
+# several arrays is judged by what its programs move: no result, other
+# than the in-place row scatters, that holds a quarter or more of ONE
+# layer's pool bytes, and temporaries under a quarter of the pool.
+
+
+def _glm_engine():
+    """GLM-4.7-Flash's block at the published latent widths (512 + 64),
+    everything else tiny, bf16 as served: one dense and one expert
+    layer, a pool (2 layers x 2,048 blocks) far larger than the weights
+    or any gathered view (4 slots x 256 rows)."""
+    from tensorflow_examples_tpu.workloads import glm4_moe_lite as workload
+
+    pcfg = workload.Glm4MoeLiteServeConfig(
+        hidden_size=128, num_attention_heads=2, q_lora_rank=32,
+        qk_nope_head_dim=16, v_head_dim=16, intermediate_size=128,
+        moe_intermediate_size=128, n_routed_experts=4, num_experts_per_tok=2,
+        vocab_size=256, num_hidden_layers=2, seq_len=256,
+    )
+    assert (pcfg.kv_lora_rank, pcfg.qk_rope_head_dim) == (512, 64)
+    return _kinds_engine(workload, pcfg)
+
+
+def _cohere_engine():
+    """Cohere2-MoE's block: grouped-query rows of 2 x 64 = 128 lanes,
+    window and full layers (two block-id spaces), held experts."""
+    from tensorflow_examples_tpu.workloads import cohere2_moe as workload
+
+    pcfg = workload.Cohere2MoeServeConfig(
+        hidden_size=128, num_attention_heads=4, num_key_value_heads=2,
+        head_dim=64, intermediate_size=128, num_experts_per_tok=2,
+        num_shared_experts=1, sliding_window=64,
+        layer_types=("sliding_attention", "full_attention"),
+        num_hidden_layers=2, held_experts=(0, 1), router_experts=4,
+        vocab_size=256, seq_len=256,
+    )
+    return _kinds_engine(workload, pcfg)
+
+
+def _kinds_engine(workload, pcfg):
+    params = jax.jit(workload.make_task(pcfg).init_fn)(
+        jax.random.PRNGKey(0)
+    )["params"]
+    return InferenceEngine(
+        workload.model_config(pcfg), params,
+        cfg=ServeConfig(max_slots=4, kv_block_size=16, kv_blocks=2048,
+                        prefill_chunk_tokens=32, prefill_bucket_floor=32,
+                        kv_bucket_floor=256),
+    )
+
+
+@pytest.mark.parametrize("family", ["decode", "prefill", "extend"])
+@pytest.mark.parametrize(
+    "make_engine", [_glm_engine, _cohere_engine], ids=["glm4_moe_lite", "cohere2_moe"]
+)
+def test_no_program_of_any_block_touches_the_whole_pool(described_chip,
+                                                         make_engine, family):
+    sys.path.insert(0, REPO)
+    try:
+        from benchmark import sizing_kinds
+    finally:
+        sys.path.remove(REPO)
+
+    engine = make_engine()
+    pool = engine.pool
+    state = pool.kv_state()
+    pool_bytes = sum(a.nbytes for a in jax.tree.leaves(state))
+    # One layer's arrays, of the kind with the most blocks.
+    layer_bytes = max(
+        sum(arrs[layer].nbytes for arrs in state)
+        for layer in range(pool.num_layers)
+    )
+    # No weight, nor all of them, could be taken for a quarter of it.
+    assert layer_bytes / 4 > 2 * sum(
+        a.nbytes for a in jax.tree.leaves(engine.params)
+    )
+    fn, args = sizing_kinds.engine_programs(engine, described_chip)[family]
+    compiled = fn.lower(*_packed(engine, family, args, described_chip)).compile()
+
+    assert _results_of_bytes(compiled.as_text(), layer_bytes / 4) == []
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes / 4, (
+        mem.temp_size_in_bytes, pool_bytes)
+
+
+@pytest.mark.parametrize("heads,named", [(3, True), (2, False), (1, False)])
+def test_rows_with_heads_that_are_not_lane_dense_are_named(caplog, heads, named):
+    """K and V rows are as wide as the model is: 3 x 64 = 192 values
+    (GPT-2 XL's 25 x 64 = 1,600 likewise) are served, and said to be
+    re-laid; whole tiles (128) and toy widths under one tile (64) say
+    nothing."""
+    mcfg = transformer.TransformerConfig(**dict(
+        MODEL, num_heads=heads, d_model=64 * heads, vocab_size=64, max_len=32))
+    params = transformer.Transformer(mcfg).init(
+        {"params": jax.random.PRNGKey(0)}, np.zeros((1, 8), np.int32)
+    )["params"]
+    with caplog.at_level("WARNING", logger="tensorflow_examples_tpu.serving.engine"):
+        InferenceEngine(mcfg, params, cfg=ServeConfig(
+            max_slots=2, prefill_bucket_floor=16, kv_bucket_floor=32, kv_block_size=16))
+    said = [r.getMessage() for r in caplog.records if "128-lane tiles" in r.getMessage()]
+    assert len(said) == (2 if named else 0)  # K's array and V's
+    assert all("gpt2" in m and f"{64 * heads} values" in m for m in said)
 
 
 # ------------------------------------------------- tokens on the new layout

@@ -9,6 +9,12 @@ everything the engine refuses for such a block, by name.
 Sizes: hidden 64, 4 heads, q rank 24, latent 16 + 8 rotary, nope 12,
 v 16, block 4, 1 dense + 2 expert layers, 8 experts top 2 with a
 non-zero bias, scale 1.8, 1 shared, a 128-row vocabulary; float32.
+
+The row's STORED form (ISSUE 33): a row of a tile or more lies in whole
+128-lane tiles — the published 576 values in 640 columns; here a latent
+of 136 + 8 = 144 in 256 — the pad written as zeros and read by nothing
+(``TestTheStoredForm``); the 24-value toy row, under one tile, is
+stored as it is.
 """
 
 import os
@@ -46,6 +52,9 @@ TINY = dict(
     rope_theta=1e6, rms_norm_eps=1e-5, vocab_size=128, num_hidden_layers=3,
 )
 LATENT = 16 + 8
+STORED = 128  # a whole tile: the width the 24-value rows are padded to where a test pads them
+WIDE = dict(kv_lora_rank=136)  # a row of 136 + 8 = 144 values: over one tile, stored in two
+WIDE_LATENT, WIDE_STORED = 136 + 8, 256
 SERVE = dict(max_slots=3, kv_block_size=4, kv_blocks=49, prefill_bucket_floor=8,
              kv_bucket_floor=16, prefill_chunk_tokens=8)
 
@@ -66,6 +75,12 @@ def init(pcfg, seed=0):
 @pytest.fixture(scope="module")
 def model():
     pcfg = program_config()
+    return workload.model_config(pcfg), init(pcfg)
+
+
+@pytest.fixture(scope="module")
+def wide_model():
+    pcfg = program_config(**WIDE)
     return workload.model_config(pcfg), init(pcfg)
 
 
@@ -265,6 +280,12 @@ class TestTheTwoForms:
         return dict(q_nope=f(t, h, dn), q_pe=f(t, h, dr), rows=f(t, dc + dr),
                     w_uk=f(dc, h, dn) * 0.3, w_uv=f(dc, h, dv) * 0.3, ctx_rows=f(c, dc + dr))
 
+    @staticmethod
+    def stored(rows, width):
+        """``rows`` as the pool keeps them; NaN in the pad columns where
+        zeros would do shows that nothing reads them."""
+        return None if rows is None else kv_cache.pad_columns(rows, width)
+
     @pytest.mark.parametrize("ctx_len", [None, 24, 13])
     @pytest.mark.parametrize("score_elements", [256, 512, 1 << 26])
     def test_absorbed_equals_expanded_on_the_same_rows(self, rows, ctx_len, score_elements,
@@ -282,6 +303,20 @@ class TestTheTwoForms:
         assert got.shape == (8, 4, 16)
         np.testing.assert_allclose(got, expanded_attention(**kw), atol=1e-4)
 
+    @pytest.mark.parametrize("ctx_len", [None, 13])
+    def test_the_stored_width_changes_no_number_of_a_chunk(self, rows, ctx_len):
+        """The same rows at the stored width (24 values in 128 columns,
+        the pad zero) give the very numbers of the rows at 24."""
+        kw = dict(rows, sm_scale=20 ** -0.5)
+        if ctx_len is None:
+            kw.pop("ctx_rows")
+        else:
+            kw["ctx_len"] = ctx_len
+        want = kv_cache.latent_chunk_attention(**kw)
+        kw.update(rows=self.stored(rows["rows"], STORED),
+                  ctx_rows=self.stored(kw.get("ctx_rows"), STORED))
+        np.testing.assert_array_equal(kv_cache.latent_chunk_attention(**kw), want)
+
     def test_a_decode_step_is_the_last_row_of_a_chunk(self, rows):
         """Absorbed through a block table = the published form's last
         query over the same rows laid out in blocks."""
@@ -293,6 +328,11 @@ class TestTheTwoForms:
             rows["w_uk"], rows["w_uv"], sm_scale=20 ** -0.5)
         want = expanded_attention(**rows, ctx_len=24, sm_scale=20 ** -0.5)[-1:]
         np.testing.assert_allclose(got, want, atol=1e-4)
+        # ... and the same blocks at the stored width, pad columns zero: the same numbers
+        wide = kv_cache.latent_decode_attention(
+            rows["q_nope"][-1:], rows["q_pe"][-1:], self.stored(blocks_, STORED),
+            jnp.asarray([31]), table, rows["w_uk"], rows["w_uv"], sm_scale=20 ** -0.5)
+        np.testing.assert_array_equal(wide, got)
 
     def test_the_head_group_bounds_the_scores(self):
         assert kv_cache.latent_head_group(20, 512, 32768 + 512) == 2
@@ -322,11 +362,115 @@ class TestTheTwoForms:
         assert [p["context"] for p in by_family["decode"]] == [16, 32, 64]
         assert all(p["form"] == "absorbed" for p in by_family["decode"])
         assert by_family["extend"][0]["head_group"] == 4
+        assert all(p["rows"] == [LATENT] for p in plans()[before:])  # the widths as stored
         assert all(set(p) == set(schema.MLA_PLAN_ARGS) for p in plans()[before:])
         n = len(plans())
         eng2, _ = make_engine(model)
         eng2.warmup()  # the same shapes traced again: no new plan
         assert len(plans()) == n
+
+
+def log_softmax(row):
+    row = np.asarray(row, np.float64) - np.max(row)
+    return row - np.log(np.exp(row).sum())
+
+
+class TestTheStoredForm:
+    """A row of a tile or more is stored in whole 128-lane tiles (ISSUE
+    33; here 144 values in 256 columns): that changes no number, the
+    pad is written as zeros, and nothing reads it — a pool whose pad
+    columns hold NaN serves what a clean one serves."""
+
+    SIZES = dict(TINY, **WIDE)
+
+    DOC, Q1, Q2, SHORT = 32, 5, 7, 5
+
+    @staticmethod
+    def poison(pool):
+        """NaN into every pad column of every block, before anything is written."""
+        pool.set_kv_state(tuple(
+            tuple(a.at[..., WIDE_LATENT:].set(jnp.nan) for a in arrs) for arrs in pool.kv_state()))
+
+    def served(self, model, poisoned):
+        """One engine through the three program families: a short
+        prompt (the prefill rung), a document cold, then the same
+        document under another question (a prefix hit: the extend rung
+        over cached rows) and four decode steps of it. Returns the
+        last-row logits of the prefill and of the hit, the decode
+        program's own log-probabilities, the tokens, and the engine."""
+        eng, reg = make_engine(model)
+        if poisoned:
+            self.poison(eng.pool)
+        short = prompt_of(self.SHORT, seed=21)
+        doc, q1, q2 = (prompt_of(n, seed=s) for n, s in ((self.DOC, 22), (self.Q1, 23), (self.Q2, 24)))
+        slot = eng.pool.alloc()
+        _, prefill = serve(eng, slot, short, 1)
+        eng.pool.free(slot)
+        slot = eng.pool.alloc()
+        serve(eng, slot, doc + q1, 1)
+        eng.pool.free(slot)
+        reused = reg.counter("serving/prefix_reused_tokens").value
+        slot = eng.pool.alloc()
+        toks, hit = serve(eng, slot, doc + q2, 1)
+        assert reg.counter("serving/prefix_reused_tokens").value - reused == self.DOC
+        logprobs = []
+        reach = reg.counter("serving/kv_sampled_reach_bytes").value
+        for _ in range(4):
+            toks.append(eng.decode([(slot, toks[-1], 0, 0.0, 0)])[slot])
+            logprobs.append(float(eng.last_logprobs[slot]))
+        # what a step MUST read: the row's 144 values a layer, not the 256 stored
+        n = self.DOC + self.Q2
+        assert reg.counter("serving/kv_sampled_reach_bytes").value - reach == sum(
+            (n + k) * 3 * WIDE_LATENT * 4 for k in range(1, 5))
+        return dict(prefill=np.asarray(prefill), extend=np.asarray(hit),
+                    decode=np.asarray(logprobs), tokens=toks, prompts=(short, doc + q2),
+                    engine=eng, slot=slot)
+
+    @pytest.fixture(scope="class")
+    def clean(self, wide_model):
+        return self.served(wide_model, poisoned=False)
+
+    @pytest.fixture(scope="class")
+    def poisoned(self, wide_model):
+        return self.served(wide_model, poisoned=True)
+
+    @pytest.mark.parametrize("kind", ["prefill", "extend", "decode"])
+    def test_served_log_probabilities_are_the_references(self, wide_model, clean, kind):
+        _, params = wide_model
+        short, long_ = clean["prompts"]
+        if kind == "prefill":
+            logits, _ = REF.forward(params, short, self.SIZES, rows=[len(short) - 1], q_block=8)
+            got, want = log_softmax(clean["prefill"]), log_softmax(logits[0])
+        elif kind == "extend":
+            logits, _ = REF.forward(params, long_, self.SIZES, rows=[len(long_) - 1], q_block=8)
+            got, want = log_softmax(clean["extend"]), log_softmax(logits[0])
+        else:
+            seq = long_ + clean["tokens"]
+            logits, _ = REF.forward(params, seq, self.SIZES, rows=range(len(long_), len(seq) - 1),
+                                    q_block=8)
+            got = clean["decode"]
+            want = [log_softmax(row)[tok] for row, tok in zip(logits, clean["tokens"][1:])]
+        np.testing.assert_allclose(got, want, atol=1e-4)
+
+    @pytest.mark.parametrize("kind", ["prefill", "extend", "decode"])
+    def test_nan_in_the_pad_columns_reaches_no_score_and_no_value(self, clean, poisoned, kind):
+        assert np.isfinite(poisoned[kind]).all()
+        np.testing.assert_array_equal(poisoned[kind], clean[kind])
+        assert poisoned["tokens"] == clean["tokens"]
+
+    def test_the_pad_is_written_as_zeros_and_nothing_else_is_touched(self, poisoned):
+        eng, slot = poisoned["engine"], poisoned["slot"]
+        pool = eng.pool
+        n = int(pool.lengths[slot])
+        assert n == self.DOC + self.Q2 + 4
+        blocks_ = pool.block_tables[slot, : -(-n // 4)]
+        for layer in pool.k:
+            rows = np.asarray(layer)[blocks_].reshape(-1, WIDE_STORED)[:n]
+            assert np.isfinite(rows).all() and (rows[:, WIDE_LATENT:] == 0).all()
+            assert np.abs(rows[:, :WIDE_LATENT]).min(axis=1).max() > 0  # real values before the pad
+            # a block no request ever held keeps what it had: no program re-wrote the pool
+            never = np.asarray(layer)[pool._free_blocks[0]]
+            assert np.isnan(never[:, WIDE_LATENT:]).all() and (never[:, :WIDE_LATENT] == 0).all()
 
 
 ROUTER_CASES = [
@@ -388,6 +532,7 @@ class TestTheLatentPool:
     def test_one_latent_row_a_token_and_no_v_array(self, engine):
         eng, _ = engine
         pool = eng.pool
+        assert eng.model.row_values == LATENT and eng.model.cache_rows == ((1, LATENT),)
         assert len(pool.kv_state()) == 1 and pool.rows == (LATENT,)
         assert [a.shape for a in pool.k] == [(49, 4, LATENT)] * 3
         with pytest.raises(IndexError):
@@ -395,6 +540,22 @@ class TestTheLatentPool:
         assert pool.kinds == (None,) and pool.prefix_cache_enabled
         # a block: 3 layers x 4 rows x 24 values x 4 bytes, once (no V)
         assert pool.bytes_per_block() == pool.bytes_per_block(0) == 3 * 4 * LATENT * 4
+
+    @pytest.mark.parametrize("values,stored", [(576, 640), (144, 256), (128, 128), (129, 256),
+                                               (24, 24), (127, 127)])
+    def test_a_row_of_a_tile_or_more_is_stored_in_whole_tiles(self, values, stored):
+        assert kv_cache.lane_dense(values) == stored
+
+    def test_the_pool_counts_what_it_stores(self, wide_model):
+        """The published geometry in small: 144 values in 256 columns,
+        and ``bytes_per_block`` is the arrays' real bytes, pad included."""
+        eng, _ = make_engine(wide_model)
+        pool = eng.pool
+        assert eng.model.row_values == WIDE_LATENT
+        assert eng.model.cache_rows == ((1, WIDE_STORED),) and pool.rows == (WIDE_STORED,)
+        assert [a.shape for a in pool.k] == [(49, 4, WIDE_STORED)] * 3
+        assert pool.bytes_per_block() == 3 * 4 * WIDE_STORED * 4
+        assert pool.bytes_per_block() * pool.num_blocks == sum(a.nbytes for a in pool.k)
 
     def test_shared_blocks_are_refcounted_and_counted_once(self):
         pool = self.make()
@@ -575,7 +736,7 @@ class TestTheLatentPool:
         # 4 shared document blocks + 1 private block a slot (19 + 1 tokens each)
         assert got["bytes"] == 6 * block == eng.pool.used_bytes()
         assert got["tokens"] == 2 * 20
-        assert got["reach_bytes"] == 2 * 20 * (block // 4)
+        assert got["reach_bytes"] == 2 * 20 * 3 * LATENT * 4 == 2 * 20 * (block // 4)
         for s in slots:
             eng.pool.free(s)
 
@@ -593,6 +754,14 @@ REFUSED = [
 
 
 class TestWhatIsRefused:
+    def test_an_own_row_that_is_not_lane_dense_is_refused_by_name(self, wide_model, monkeypatch):
+        """A block that makes its own row pads it to whole 128-lane
+        tiles or is not served: the TPU would re-lay the whole pool
+        around every write (ISSUE 33)."""
+        monkeypatch.setattr(kv_cache, "lane_dense", lambda width: width)
+        with pytest.raises(ValueError, match=r"glm4_moe_lite.*144 values.*128-lane tiles"):
+            make_engine(wide_model)
+
     @pytest.mark.parametrize("over,mechanism", REFUSED,
                              ids=[f"{m}-{list(o.values())[0]}" for o, m in REFUSED])
     def test_refused_by_name_at_construction(self, model, over, mechanism):

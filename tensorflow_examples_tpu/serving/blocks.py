@@ -25,30 +25,44 @@ field:
   latent row a token in the cache, no heads, no V), a dense first
   layer, then expert layers whose router chooses by a biased score, a
   shared expert, an untied head.
+* :class:`MimoV2Block` — ``models/mimo_v2.py``'s: a sequential pre-norm
+  block whose full and window layers differ in their KV heads (4 and
+  8), keys of 192 beside values of 128, rotary on the first 64
+  dimensions with a base a kind, a learned sink logit in the window
+  layers' softmax, a dense first layer, then expert layers that hold
+  some of the experts under a biased choice, no shared expert.
 
-What a model tells the engine beside its three functions: ``num_layers``,
-``num_heads`` / ``num_kv_heads`` / ``head_dim``, **``cache_rows``** (the
-arrays a layer keeps of a token, ``(heads, width)`` each: K and V of
-``num_kv_heads x head_dim`` for the first two, one ``1 x (dc + dr)``
-latent row for the third, zero-padded to a whole number of 128-lane
-tiles — ``kv_cache.lane_dense``: 640 columns for 576 values; a toy
-row under one tile stays as it is — because
-the TPU re-lays a whole pool array of any other width around every
-write; the pool allocates by it and the forwards scatter what ``block``
-hands ``attend`` after ``q``), **``row_values``** (any block but
-GPT-2's, which books no ``kv_sampled_*``: the values of a token's row
-in the model's mathematics, pad columns left out — what a step must
-read of it, ``serving/kv_sampled_reach_bytes``),
-**``own_attention``** (false: the engine's generic ``softmax(q k) v``
-over gathered heads serves it; true: attention over the cache is the
-block's own mathematics, ``chunk_attention`` for a prompt chunk and
-``decode_attention`` for a decode step), ``refused`` (any block but GPT-2's: why each mechanism
-the engine refuses it cannot serve it), ``layer_windows`` (one entry a
-layer: ``None`` = full, ``W`` = window; the paged pool keeps one
-block-id space per kind), ``stats_len`` (the int32 counts a block adds
-to a step's fetched output, 0 for none; a model that has any books them
-itself, ``count_stats(registry, stats, decode=)`` — the engine only
-forwards what it fetched) and ``name`` (what a refusal says).
+What a model tells the engine beside its three functions, **per layer**
+wherever layers may differ (one tuple entry a layer): ``num_layers``;
+**``cache_rows``** (per layer, the arrays the layer keeps of a token,
+``(heads, width)`` each: K and V of the layer's own KV heads and its
+key and value widths for the first two and the fourth — 4 x 192 and
+4 x 128 in MiMo's full layers, 8 x 192 and 8 x 128 in its window layers
+— one ``1 x (dc + dr)`` latent row for the third, zero-padded to a
+whole number of 128-lane tiles — ``kv_cache.lane_dense``: 640 columns
+for 576 values; a toy row under one tile stays as it is — because the
+TPU re-lays a whole pool array of any other width around every write;
+the pool allocates each layer's arrays by it, the layers of one kind
+alike, and the forwards scatter what ``block`` hands ``attend`` after
+``q``); **``row_values``** (per layer; any block but GPT-2's, which
+books no ``kv_sampled_*``: the values of a token's row in the model's
+mathematics, pad columns left out — what a step must read of it,
+``serving/kv_sampled_reach_bytes``); **``own_attention``** (true:
+attention over the cache is the block's own mathematics,
+``chunk_attention`` for a prompt chunk and ``decode_attention`` for a
+decode step; false: the engine's generic ``softmax(q k) v`` over
+gathered heads serves it, and the block says what that needs of each
+layer in **``layer_attention``**, one :class:`LayerAttention` a layer:
+query and KV heads, key and value widths, the softmax scale, and
+whether the layer has a sink — then ``sinks(params, layer)`` hands the
+``[H]`` float32 logits); ``refused`` (any block but GPT-2's: why each
+mechanism the engine refuses it cannot serve it); ``layer_windows``
+(one entry a layer: ``None`` = full, ``W`` = window; the paged pool
+keeps one block-id space per kind, a kind being a window and a row
+shape); ``stats_len`` (the int32 counts a block adds to a step's
+fetched output, 0 for none; a model that has any books them itself,
+``count_stats(registry, stats, decode=)`` — the engine only forwards
+what it fetched) and ``name`` (what a refusal says).
 
 Every matmul weight of GPT-2 is read through ``core/precision.
 materialize`` (``_w``) and its embedding tables through ``take_rows``
@@ -60,6 +74,7 @@ flax defaults (eps 1e-5, gelu approximate).
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -69,11 +84,29 @@ from tensorflow_examples_tpu.core.precision import materialize as _w
 from tensorflow_examples_tpu.core.precision import take_rows as _rows
 from tensorflow_examples_tpu.models.cohere2_moe import Cohere2MoeConfig
 from tensorflow_examples_tpu.models.glm4_moe_lite import Glm4MoeLiteConfig
+from tensorflow_examples_tpu.models.mimo_v2 import MimoV2Config
 from tensorflow_examples_tpu.models.transformer import TransformerConfig
 from tensorflow_examples_tpu.parallel.moe import moe_ffn_held
 from tensorflow_examples_tpu.serving import kv_cache
 from tensorflow_examples_tpu.telemetry import schema
 from tensorflow_examples_tpu.telemetry.spans import span
+
+
+class LayerAttention(NamedTuple):
+    """What the engine's generic attention needs of ONE layer of a
+    block without ``own_attention``."""
+
+    heads: int        # query heads
+    kv_heads: int     # key/value heads: query head i reads i // (heads / kv_heads)
+    key_dim: int      # a query's and a key's head width
+    value_dim: int    # a value's head width (the attention's output)
+    sm_scale: float   # on q . k
+    sink: bool = False  # model.sinks(params, layer): [heads] f32 logits
+
+    @property
+    def rows(self) -> tuple:
+        """The layer's cache row: K and V as ``(heads, width)``."""
+        return (self.kv_heads, self.key_dim), (self.kv_heads, self.value_dim)
 
 
 def _normalise(x, eps):
@@ -117,9 +150,12 @@ class Gpt2Block:
     def __init__(self, cfg: TransformerConfig):
         self.cfg = cfg
         self.num_layers = cfg.num_layers
-        self.num_heads = self.num_kv_heads = cfg.num_heads
-        self.head_dim = cfg.head_dim
-        self.cache_rows = ((cfg.num_heads, cfg.head_dim),) * 2  # K, V
+        attn = LayerAttention(
+            cfg.num_heads, cfg.num_heads, cfg.head_dim, cfg.head_dim,
+            cfg.head_dim ** -0.5,
+        )
+        self.layer_attention = (attn,) * cfg.num_layers
+        self.cache_rows = (attn.rows,) * cfg.num_layers  # K, V
         self.max_len = cfg.max_len
         self.layer_windows = (None,) * cfg.num_layers
 
@@ -225,11 +261,13 @@ class Cohere2MoeBlock:
     def __init__(self, cfg: Cohere2MoeConfig):
         self.cfg = cfg
         self.num_layers = cfg.num_layers
-        self.num_heads = cfg.num_heads
-        self.num_kv_heads = cfg.num_kv_heads
-        self.head_dim = cfg.head_dim
-        self.cache_rows = ((cfg.num_kv_heads, cfg.head_dim),) * 2  # K, V
-        self.row_values = 2 * cfg.num_kv_heads * cfg.head_dim
+        attn = LayerAttention(
+            cfg.num_heads, cfg.num_kv_heads, cfg.head_dim, cfg.head_dim,
+            cfg.head_dim ** -0.5,
+        )
+        self.layer_attention = (attn,) * cfg.num_layers
+        self.cache_rows = (attn.rows,) * cfg.num_layers  # K, V
+        self.row_values = (2 * cfg.num_kv_heads * cfg.head_dim,) * cfg.num_layers
         self.max_len = cfg.max_len
         self.layer_windows = tuple(cfg.layer_windows)
         self.stats_len = len(cfg.held_experts) + 2
@@ -314,6 +352,15 @@ def _rms_norm(x, scale, eps):
     ) * scale.astype(jnp.float32)
 
 
+def _untied_head(params, x, eps):
+    """The final RMS norm, then the untied head ``[d, vocab]``."""
+    kernel = params["lm_head"]["kernel"]
+    x = _rms_norm(x, params["ln_f"]["scale"], eps)
+    return jnp.dot(
+        x.astype(kernel.dtype), kernel, preferred_element_type=jnp.float32
+    )
+
+
 def _swiglu(x, p):
     """One SwiGLU FFN on ``x`` [n, d] in the weights' dtype; float32 out."""
     f32 = dict(preferred_element_type=jnp.float32)
@@ -381,10 +428,10 @@ class Glm4MoeLiteBlock:
         self.cfg = cfg
         self.num_layers = cfg.num_layers
         self.num_heads = cfg.num_heads
-        self.num_kv_heads = 1  # one latent row, shared by every head
-        self.head_dim = cfg.qk_head_dim
-        self.row_values = cfg.latent_dim
-        self.cache_rows = ((1, kv_cache.lane_dense(cfg.latent_dim)),)
+        # One latent row a token, shared by every head, in every layer.
+        self.row_values = (cfg.latent_dim,) * cfg.num_layers
+        self.stored_width = kv_cache.lane_dense(cfg.latent_dim)
+        self.cache_rows = (((1, self.stored_width),),) * cfg.num_layers
         self.max_len = cfg.max_len
         self.layer_windows = (None,) * cfg.num_layers
         self.stats_len = len(cfg.held_experts) + 3
@@ -482,7 +529,7 @@ class Glm4MoeLiteBlock:
         # columns are written as zeros.
         row = kv_cache.pad_columns(
             jnp.concatenate([c_kv[..., None, :], k_pe], axis=-1),
-            self.cache_rows[0][1],
+            self.stored_width,
         ).astype(dtype)
         att = attend(q, row)
         x = x + jnp.einsum(
@@ -519,12 +566,154 @@ class Glm4MoeLiteBlock:
         return x + y.reshape(x.shape), stats
 
     def head(self, params, x):
-        kernel = params["lm_head"]["kernel"]
-        x = _rms_norm(x, params["ln_f"]["scale"], self.cfg.rms_norm_eps)
-        return jnp.dot(
-            x.astype(kernel.dtype), kernel,
-            preferred_element_type=jnp.float32,
+        return _untied_head(params, x, self.cfg.rms_norm_eps)
+
+    def last_logits(self, params, x, index):
+        """Logits of row ``index`` of ``x`` [T, d]: only that row meets
+        the vocabulary."""
+        return self.head(
+            params, jax.lax.dynamic_index_in_dim(x, index, keepdims=False)
         )
+
+
+def rope_half(x, positions, theta: float, rotary_dim: int):
+    """Rotate the FIRST ``rotary_dim`` dimensions of ``x`` [..., heads,
+    D] in rotate-half pairs: ``(x[i], x[i + rotary_dim / 2])`` turns by
+    ``positions * theta ** (-2i / rotary_dim)``; the other dimensions
+    carry no position. ``positions`` carries x's leading axes (or
+    broadcasts against them from the right). Float32 out."""
+    half = rotary_dim // 2
+    inv_freq = theta ** (
+        -jnp.arange(0, rotary_dim, 2, dtype=jnp.float32) / rotary_dim
+    )
+    ang = positions.astype(jnp.float32)[..., None, None] * inv_freq
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x = x.astype(jnp.float32)
+    x0, x1 = x[..., :half], x[..., half:rotary_dim]
+    return jnp.concatenate(
+        [x0 * cos - x1 * sin, x1 * cos + x0 * sin, x[..., rotary_dim:]],
+        axis=-1,
+    )
+
+
+class MimoV2Block:
+    """MiMo-V2.5's block over ``models/mimo_v2.py``'s param tree. The
+    residual stream, the RMS norms, the rotation and the router are
+    float32; the projections and the experts run in the parameters'
+    dtype with float32 accumulation.
+
+    A full and a window layer keep rows of different shapes (K and V of
+    the kind's own KV heads, a key head wider than a value head:
+    ``cache_rows`` / ``layer_attention`` per layer), rotate the first
+    ``rotary_dim`` dimensions of q and k with the kind's base, and the
+    window layers hand the engine a sink logit a head (``sinks``). The
+    values are scaled before they are cached: what a row holds is what
+    attention reads.
+
+    ``block`` also returns the step's expert counts, as
+    :class:`Cohere2MoeBlock` does (a dense layer adds none)."""
+
+    name = "mimo_v2"
+    own_attention = False
+    # Why each mechanism the engine refuses this block cannot serve it.
+    refused = {
+        "verify": "its verify forward is GPT-2's",
+        "pages": "a page payload has one block-id space, one row shape "
+                 "and equal heads",
+        "kv_dtype": "the grouped-query gather does not dequantize, and a "
+                    "scale a head has no one head count to go by",
+        "weights": "the block reads its weights as stored",
+        "paged_flash": "the kernel reads K and V rows of equal heads and "
+                       "one width through one table, and knows no sink",
+        "flash": "no grouped-query or window mask, no sink, one head "
+                 "width for keys and values",
+        "sharding": "the placement rules are GPT-2's",
+    }
+
+    def __init__(self, cfg: MimoV2Config):
+        self.cfg = cfg
+        self.num_layers = cfg.num_layers
+        self.max_len = cfg.max_len
+        self.layer_windows = tuple(cfg.layer_windows)
+        self.layer_attention = tuple(
+            LayerAttention(
+                a.num_heads, a.num_kv_heads, a.head_dim, a.v_head_dim,
+                a.head_dim ** -0.5, a.sink,
+            ) for a in map(cfg.attention, range(cfg.num_layers))
+        )
+        self.cache_rows = tuple(a.rows for a in self.layer_attention)
+        self.row_values = tuple(
+            a.kv_heads * (a.key_dim + a.value_dim)
+            for a in self.layer_attention
+        )
+        self.stats_len = len(cfg.held_experts) + 2
+
+    def count_stats(self, registry, stats, *, decode: bool) -> None:
+        """Book one step's fetched ``stats`` (``block``'s, summed over
+        the layers) into the registry's expert counters."""
+        _count_expert_stats(registry, self.cfg.held_experts, stats, decode)
+
+    def param_dtype(self, params):
+        return params["wte"]["embedding"].dtype
+
+    def embed(self, params, tokens, positions):
+        del positions  # rotary: q and k turn inside the block
+        return params["wte"]["embedding"][tokens].astype(jnp.float32)
+
+    def sinks(self, params, layer):
+        """The sink logits [H] float32 of a layer whose
+        ``layer_attention`` says it has them."""
+        return params[f"h_{layer}"]["attn"]["sinks"]
+
+    def block(self, params, x, layer, positions, attend, valid=None):
+        cfg, p = self.cfg, params[f"h_{layer}"]
+        a, kind, eps = p["attn"], cfg.attention(layer), cfg.rms_norm_eps
+        dtype = a["q"].dtype
+        f32 = dict(preferred_element_type=jnp.float32)
+        hb = _rms_norm(x, p["ln_1"]["scale"], eps).astype(dtype)
+        q, k, v = (
+            jnp.einsum("...d,dhc->...hc", hb, a[n], **f32) for n in "qkv"
+        )
+        q, k = (
+            rope_half(t, positions, kind.rope_theta, cfg.rotary_dim)
+            .astype(dtype) for t in (q, k)
+        )
+        v = (cfg.value_scale * v).astype(dtype)
+        window = self.layer_windows[layer]
+        with jax.named_scope("attn_full" if window is None else "attn_window"):
+            att = attend(q, k, v)
+        x = x + jnp.einsum(
+            "...hc,hcd->...d", att.astype(dtype), a["o"], **f32
+        )
+
+        h = _rms_norm(x, p["ln_2"]["scale"], eps)
+        flat = h.reshape(-1, h.shape[-1])
+        n_held = len(cfg.held_experts)
+        if "mlp" in p:
+            with jax.named_scope("ffn_dense"):
+                y = _swiglu(flat.astype(dtype), p["mlp"])
+            pairs = jnp.zeros((n_held,), jnp.int32)
+            routed = 0
+        else:
+            rows = None if valid is None else jnp.broadcast_to(
+                valid, x.shape[:-1]
+            ).reshape(-1)
+            moe = p["moe"]
+            y, pairs = moe_ffn_held(
+                moe["router"], moe["w_gate"], moe["w_up"], moe["w_down"],
+                flat, held=tuple(cfg.held_experts), top_k=cfg.top_k,
+                valid=rows, select_bias=moe["bias"],
+            )
+            n_real = flat.shape[0] if rows is None else jnp.sum(rows)
+            routed = n_real * cfg.top_k
+        stats = jnp.concatenate([
+            pairs,
+            jnp.stack([routed, jnp.sum(pairs > 0)]).astype(jnp.int32),
+        ])
+        return x + y.reshape(x.shape), stats
+
+    def head(self, params, x):
+        return _untied_head(params, x, self.cfg.rms_norm_eps)
 
     def last_logits(self, params, x, index):
         """Logits of row ``index`` of ``x`` [T, d]: only that row meets
@@ -536,6 +725,8 @@ class Glm4MoeLiteBlock:
 
 def block_for(model_cfg):
     """The block that serves ``model_cfg``, by the config's type."""
+    if isinstance(model_cfg, MimoV2Config):
+        return MimoV2Block(model_cfg)
     if isinstance(model_cfg, Glm4MoeLiteConfig):
         return Glm4MoeLiteBlock(model_cfg)
     if isinstance(model_cfg, Cohere2MoeConfig):
@@ -544,6 +735,6 @@ def block_for(model_cfg):
         return Gpt2Block(model_cfg)
     raise TypeError(
         f"no serving block for a {type(model_cfg).__name__}: the engine "
-        "serves TransformerConfig (GPT-2), Cohere2MoeConfig and "
-        "Glm4MoeLiteConfig"
+        "serves TransformerConfig (GPT-2), Cohere2MoeConfig, "
+        "Glm4MoeLiteConfig and MimoV2Config"
     )

@@ -74,6 +74,7 @@ from tensorflow_examples_tpu.serving import launch_block
 from tensorflow_examples_tpu.serving import paged_kv
 from tensorflow_examples_tpu.serving.blocks import Gpt2Block, block_for
 from tensorflow_examples_tpu.telemetry import registry as registry_mod
+from tensorflow_examples_tpu.telemetry import schema
 from tensorflow_examples_tpu.telemetry.compilation import CompilationSentinel
 from tensorflow_examples_tpu.telemetry.spans import span as host_span
 from tensorflow_examples_tpu.utils import faults as faults_mod
@@ -228,11 +229,37 @@ def _run_blocks(model, params, x, positions, attend_for, valid=None):
 
 def _plain(model, layer) -> bool:
     """A layer the GPT-2 attention paths serve as they are: as many
-    key/value heads as query heads, no window."""
+    key/value heads as query heads, values as wide as keys, no window,
+    no sink."""
+    a = model.layer_attention[layer]
     return (
-        model.num_kv_heads == model.num_heads
+        a.kv_heads == a.heads and a.value_dim == a.key_dim and not a.sink
         and model.layer_windows[layer] is None
     )
+
+
+def _grouped(model, params, layer) -> dict:
+    """What the generic grouped attentions take of one layer beside its
+    tensors (``layer_attention``, ``layer_windows``, ``sinks``)."""
+    a = model.layer_attention[layer]
+    return dict(
+        window=model.layer_windows[layer], sm_scale=a.sm_scale,
+        sinks=model.sinks(params, layer) if a.sink else None,
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def _record_kind_plan(family, rung, kinds):
+    """One ``span/kind_plan`` per traced program of a pool with several
+    kinds (at trace time, never inside a step): ``kinds`` is, per kind,
+    ``schema.KIND_PLAN_KIND_KEYS`` — what the kind's layers keep of a
+    token and attend with, the kind's physical blocks, and the columns
+    of its table in this program."""
+    with host_span(
+        schema.KIND_PLAN_SPAN, family=family, rung=rung,
+        kinds=[dict(zip(schema.KIND_PLAN_KIND_KEYS, k)) for k in kinds],
+    ):
+        pass
 
 
 def _per_kind(tables) -> tuple:
@@ -344,7 +371,7 @@ def _forward_prefill(model, params, kv, block_ids, tokens, length,
             if _plain(model, layer):
                 return _prefill_attend(q, k, v, impl=impl)
             return kv_mod.grouped_chunk_attention(
-                q[0], k[0], v[0], window=model.layer_windows[layer]
+                q[0], k[0], v[0], **_grouped(model, params, layer)
             )[None]
         return attend
 
@@ -415,8 +442,8 @@ def _forward_decode(model, params, kv, tokens, positions, tables,
                 )
             return kv_mod.grouped_decode_attention(
                 q, kv_[0][layer], kv_[1][layer], positions, tabs[kind],
-                num_kv_heads=model.num_kv_heads,
-                window=model.layer_windows[layer],
+                num_kv_heads=model.layer_attention[layer].kv_heads,
+                **_grouped(model, params, layer),
             )
         return attend
 
@@ -522,7 +549,6 @@ def _forward_extend(model, params, kv, ctx_table, tail_ids, tokens,
     reads (``kv_cache.window_base(ctx_len, ...)``), at most ``W / BS +
     1``."""
     tb = tokens.shape[1]
-    sm_scale = model.head_dim ** -0.5
     ctx_tables = _per_kind(ctx_table)
     positions = ctx_len + jnp.arange(tb, dtype=jnp.int32)
     written = []
@@ -542,22 +568,23 @@ def _forward_extend(model, params, kv, ctx_table, tail_ids, tokens,
                     params, layer, q[0], row[0][0], ctx, ctx_len
                 )[None]
             k, v = row
-            # The cached context as [ctx_cols, Hkv, hd], from this
+            attn = model.layer_attention[layer]
+            # The cached context as [ctx_cols, Hkv, width], from this
             # layer's blocks of the table alone.
             kc, vc = (
                 x.astype(q.dtype) for x in kv_mod.gather_layer_kv(
                     kv[0][layer], kv[1][layer], ctx_tables[kind],
-                    model.num_kv_heads, q.dtype, **_layer_scales(kv, layer),
+                    attn.kv_heads, q.dtype, **_layer_scales(kv, layer),
                 )
             )
             if _plain(model, layer):
                 return _plain_extend_attention(
-                    q, k, v, kc, vc, ctx_len, sm_scale
+                    q, k, v, kc, vc, ctx_len, attn.sm_scale
                 )
             return kv_mod.grouped_chunk_attention(
                 q[0], k[0], v[0], kc, vc, ctx_len=ctx_len,
                 ctx_base=kv_mod.window_base(ctx_len, window, block_size),
-                window=window,
+                **_grouped(model, params, layer),
             )[None]
         return attend
 
@@ -924,7 +951,10 @@ class InferenceEngine:
         # (``kv_cache.lane_dense``) or is refused; K and V with heads
         # are as wide as the model is, so that is said and served.
         # Rows under one tile are toy sizes and pass.
-        for heads, width in self.model.cache_rows:
+        for heads, width in (  # each row shape's arrays, once
+            array for row in dict.fromkeys(self.model.cache_rows)
+            for array in row
+        ):
             values = heads * width
             if values % kv_mod.LANES == 0 or values < kv_mod.LANES:
                 continue
@@ -942,18 +972,20 @@ class InferenceEngine:
                 "program re-lays each layer's whole pool",
                 self.model.name, values, heads, width, kv_mod.LANES,
             )
-        # A cache row is what the block says it is (``cache_rows``: K
-        # and V of the KEY/VALUE heads, fewer than the query heads under
-        # grouped-query attention; one latent row, padded to whole
-        # tiles); kv_blocks counts the full kind's blocks, a window
-        # kind's follow from the slots, its W, the chunk and the block
-        # size.
+        # A cache row is what the block says it is, layer by layer
+        # (``cache_rows``: K and V of the layer's KEY/VALUE heads, fewer
+        # than the query heads under grouped-query attention, a value
+        # head as wide as the model makes it; one latent row, padded to
+        # whole tiles); kv_blocks counts the full kind's blocks, a
+        # window kind's follow from the slots, its W, the chunk and the
+        # block size (``num_heads``/``head_dim`` are the first array's:
+        # what a quantized pool keeps scales by).
         self.pool = paged_kv.PagedKVPool(
             num_layers=self.model.num_layers,
             num_slots=self.cfg.max_slots,
-            num_heads=self.model.num_kv_heads,
+            num_heads=self.model.cache_rows[0][0][0],
             max_len=model_cfg.max_len,
-            head_dim=self.model.head_dim,
+            head_dim=self.model.cache_rows[0][0][1],
             block_size=bs,
             num_blocks=self.cfg.kv_blocks,
             dtype=cache_dtype,
@@ -963,7 +995,9 @@ class InferenceEngine:
             sharding=self._kv_sharding(),
             layer_windows=self.model.layer_windows,
             window_span=self.cfg.prefill_chunk_tokens,
-            rows=tuple(h * w for h, w in self.model.cache_rows),
+            rows=tuple(
+                tuple(h * w for h, w in row) for row in self.model.cache_rows
+            ),
         )
         self._layer_kind = self.pool.layer_kind
         self._kinds = len(self.pool.kinds)
@@ -1115,7 +1149,7 @@ class InferenceEngine:
         m = int(self.mesh.shape[AxisNames.MODEL])
         heads = (
             AxisNames.MODEL
-            if m > 1 and self.model.num_kv_heads % m == 0
+            if m > 1 and self.model.cache_rows[0][0][0] % m == 0
             else None
         )
         return NamedSharding(self.mesh, P(None, None, heads))
@@ -1198,6 +1232,25 @@ class InferenceEngine:
             return tokens
         return jnp.concatenate([jnp.reshape(tokens, (-1,)), stats])
 
+    def _kind_plan(self, family: str, rung: int, table_blocks) -> None:
+        """``span/kind_plan`` of one program of a pool with several
+        kinds, as it is traced: what each kind's layers keep of a token
+        and attend with, its physical blocks, and the columns of its
+        table here (``table_blocks``)."""
+        if self._kinds < 2:
+            return
+        pool, model = self.pool, self.model
+        kinds = []
+        for kind in sorted(set(pool.layer_kind)):  # the kinds that have layers
+            layer = pool.layer_kind.index(kind)
+            k_row, v_row = pool.kind_rows[kind]
+            kinds.append((
+                pool.kinds[kind], model.cache_rows[layer][0][0], k_row, v_row,
+                model.layer_attention[layer].sink,
+                pool.kind_blocks(kind), int(table_blocks[kind]),
+            ))
+        _record_kind_plan(family, rung, tuple(kinds))
+
     def _paged_prefill_impl(self, bucket, params, kv, *operands):
         """tokens [1, bucket] (right-padded), length = true prompt len.
         Scatters the prompt's K/V into the slot's blocks (pad rows land
@@ -1206,6 +1259,10 @@ class InferenceEngine:
         at row length-1."""
         block_ids, tokens, length, key, temp, top_k = self._operands(
             "prefill", bucket, operands
+        )
+        self._kind_plan(
+            "prefill", bucket,
+            [bucket // self.cfg.kv_block_size] * self._kinds,
         )
         kv, x, stats = _forward_prefill(
             self.model, params, kv, block_ids, tokens, length,
@@ -1219,6 +1276,10 @@ class InferenceEngine:
     def _paged_decode_impl(self, bucket, params, kv, *operands):
         tokens, positions, tables, seeds, temps, top_ks = self._operands(
             "decode", bucket, operands
+        )
+        self._kind_plan(
+            "decode", bucket,
+            self._kind_blocks(bucket // self.cfg.kv_block_size),
         )
         kv, logits, stats = _forward_decode(
             self.model, params, kv, tokens, positions, tables,
@@ -1252,6 +1313,10 @@ class InferenceEngine:
         samples the first token from the tail's last true row."""
         (ctx_table, tail_ids, tokens, ctx_len, tail_len, key, temp,
          top_k) = self._operands("extend", tail_bucket, operands)
+        self._kind_plan(
+            "extend", tail_bucket,
+            self._kind_blocks(self.pool.max_blocks_per_slot),
+        )
         kv, x, stats = _forward_extend(
             self.model, params, kv, ctx_table, tail_ids, tokens,
             ctx_len, tail_len, self._layer_kind,
@@ -1893,22 +1958,33 @@ class InferenceEngine:
                 # (kv_bytes_per_resident_token; the decode roofline's
                 # cache bytes: the row's VALUES, what the mathematics
                 # reads, whatever pad columns the pool stores).
+                # A pool of several kinds also books its bytes kind by
+                # kind, and every step the token rows its full-kind
+                # gather touches: the rung, for every live slot.
                 reg, pool = self.registry, self.pool
                 ctx = positions[slots].astype(np.int64) + 1
-                reg.counter("serving/kv_sampled_bytes").inc(
-                    pool.used_bytes()
-                )
+                by_kind = pool.used_bytes_by_kind()
+                reg.counter("serving/kv_sampled_bytes").inc(sum(by_kind))
+                if len(by_kind) > 1:
+                    for kind, used in enumerate(by_kind):
+                        reg.counter(
+                            schema.KV_KIND_BYTES_COUNTER_PREFIX
+                            + pool.kind_name(kind)
+                        ).inc(used)
                 reg.counter("serving/kv_sampled_tokens").inc(
                     int(ctx.sum())
                 )
-                row_bytes = (
-                    self.model.row_values * jnp.dtype(pool.dtype).itemsize
+                reg.counter(schema.DECODE_GATHERED_TOKENS).inc(
+                    bucket * len(slots)
                 )
+                itemsize = jnp.dtype(pool.dtype).itemsize
+                reach = [
+                    int((ctx if w is None else np.minimum(ctx, w)).sum())
+                    for w in pool.kinds
+                ]
                 reg.counter("serving/kv_sampled_reach_bytes").inc(sum(
-                    pool.layer_kind.count(kind) * row_bytes * int(
-                        (ctx if w is None else np.minimum(ctx, w)).sum()
-                    )
-                    for kind, w in enumerate(pool.kinds)
+                    values * itemsize * reach[kind] for values, kind in
+                    zip(self.model.row_values, pool.layer_kind)
                 ))
         with host_span("engine_decode_upload"):
             block = self._put(self._specs["decode", bucket], (

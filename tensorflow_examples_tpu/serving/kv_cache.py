@@ -214,6 +214,18 @@ def _window_ok(query_pos, key_pos, window):
     return ok
 
 
+def _softmax_with_sink(scores, sink):
+    """``softmax(scores)`` over the last axis, with ``sink`` (broadcast
+    against ``scores[..., :1]``, or None) as one more logit of the
+    denominator that has no column of its own: ``exp(s_j) / (exp(sink)
+    + sum_j' exp(s_j'))`` — the sink takes mass and gives no value."""
+    if sink is None:
+        return jax.nn.softmax(scores, axis=-1)
+    top = jnp.maximum(jnp.max(scores, axis=-1, keepdims=True), sink)
+    e = jnp.exp(scores - top)
+    return e / (jnp.sum(e, axis=-1, keepdims=True) + jnp.exp(sink - top))
+
+
 def window_base(position, window, block_size: int):
     """Absolute position of column 0 of a window kind's gathered view:
     the start of the oldest logical block a query at ``position`` reads
@@ -234,20 +246,24 @@ def grouped_decode_attention(
     num_kv_heads: int,
     window: int | None = None,
     sm_scale: float | None = None,
+    sinks: jax.Array | None = None,
 ) -> jax.Array:
     """:func:`varlen_decode_attention` over a paged layer for
-    grouped-query heads and, optionally, a window.
+    grouped-query heads and, optionally, a window and a sink.
 
     q: [S, H, D], slot s's query at ``positions[s]`` (its own K/V
     already written). k_blocks / v_blocks: one layer's pool ``[NB, BS,
-    G*D]`` with ``G = num_kv_heads``; query head ``i`` reads KV head
-    ``i // (H / G)``. block_tables: [S, nb] — for a full layer the
+    G*D]`` and ``[NB, BS, G*Dv]`` with ``G = num_kv_heads`` (a value
+    head may be narrower than a key head); query head ``i`` reads KV
+    head ``i // (H / G)``. ``sinks``: [H] float32, one logit a head in
+    the softmax's denominator (:func:`_softmax_with_sink`), or None.
+    block_tables: [S, nb] — for a full layer the
     slot's logical blocks from 0, for a window layer those from the
     oldest block the query reads (:func:`window_base`), ``nb <= W / BS
     + 1`` whatever the context. Slot s sees key positions ``j`` with
     ``0 <= positions[s] - j`` (``< W`` under a window). Numerics as the
     plain path: f32 scores and softmax, probabilities in the value
-    dtype, f32 accumulation. Returns [S, H, D]."""
+    dtype, f32 accumulation. Returns [S, H, Dv]."""
     s_n, h, d = q.shape
     g = num_kv_heads
     k, v = gather_layer_kv(k_blocks, v_blocks, block_tables, g, q.dtype)
@@ -261,10 +277,11 @@ def grouped_decode_attention(
     key_pos = jnp.reshape(base, (-1, 1)) + jnp.arange(k.shape[1])[None, :]
     ok = _window_ok(positions[:, None], key_pos, window)
     scores = jnp.where(ok[:, None, None, :], scores, NEG_INF)
-    p = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+    sink = None if sinks is None else sinks.reshape(1, g, h // g, 1)
+    p = _softmax_with_sink(scores, sink).astype(v.dtype)
     return jnp.einsum(
         "sgrk,skgd->sgrd", p, v, preferred_element_type=jnp.float32
-    ).astype(q.dtype).reshape(s_n, h, d)
+    ).astype(q.dtype).reshape(s_n, h, v.shape[-1])
 
 
 def grouped_chunk_attention(
@@ -278,17 +295,22 @@ def grouped_chunk_attention(
     ctx_base=0,
     window: int | None = None,
     sm_scale: float | None = None,
+    sinks: jax.Array | None = None,
 ) -> jax.Array:
     """Attention of one prompt chunk for grouped-query heads and,
-    optionally, a window: a prefill (no context) or one extend step.
+    optionally, a window and a sink: a prefill (no context) or one
+    extend step.
 
-    q: [T, H, D], the chunk's queries at positions ``ctx_len + t``; k,
-    v: [T, G, D], the chunk's own keys and values (seen causally).
-    k_ctx, v_ctx: [C, G, D] — the cached context as gathered, column c
-    at position ``ctx_base + c``, of which only positions below
-    ``ctx_len`` are populated. One KV group at a time (``lax.map``), so
-    the scores that exist at once are ``[H / G, T, C + T]`` f32, not
-    all H heads'. Returns [T, H, D]."""
+    q: [T, H, D], the chunk's queries at positions ``ctx_len + t``; k:
+    [T, G, D], v: [T, G, Dv], the chunk's own keys and values (seen
+    causally; a value head may be narrower than a key head). k_ctx:
+    [C, G, D], v_ctx: [C, G, Dv] — the cached context as gathered,
+    column c at position ``ctx_base + c``, of which only positions
+    below ``ctx_len`` are populated. ``sinks``: [H] float32, one logit
+    a head in the softmax's denominator (:func:`_softmax_with_sink`),
+    or None. One KV group at a time (``lax.map``), so the scores that
+    exist at once are ``[H / G, T, C + T]`` f32, not all H heads'.
+    Returns [T, H, Dv]."""
     t_n, h, d = q.shape
     g = k.shape[1]
     if sm_scale is None:
@@ -302,17 +324,18 @@ def grouped_chunk_attention(
         )[None, :]
 
     def one_group(args):
-        qg, kg, vg, kcg, vcg = args  # [R,T,D] [T,D] [T,D] [C,D] [C,D]
+        # [R,T,D] [T,D] [T,Dv] [C,D] [C,Dv] [R]
+        qg, kg, vg, kcg, vcg, sink = args
         # The context's columns (where there is one) before the chunk's.
         pieces = [(kg, vg, ok_tail)]
         if kcg is not None:
             pieces.insert(0, (kcg, vcg, ok_ctx))
-        prob = jax.nn.softmax(jnp.concatenate([
+        prob = _softmax_with_sink(jnp.concatenate([
             jnp.where(ok[None], jnp.einsum(
                 "rtd,kd->rtk", qg, kx, preferred_element_type=jnp.float32
             ) * sm_scale, NEG_INF)
             for kx, _, ok in pieces
-        ], axis=-1), axis=-1)
+        ], axis=-1), None if sink is None else sink[:, None, None])
         out, col = None, 0
         for _, vx, _ in pieces:
             part = jnp.einsum(
@@ -328,8 +351,11 @@ def grouped_chunk_attention(
     out = jax.lax.map(one_group, (
         jnp.moveaxis(q.reshape(t_n, g, h // g, d), (1, 2), (0, 1)),
         by_group(k), by_group(v), by_group(k_ctx), by_group(v_ctx),
-    ))  # [G, R, T, D]
-    return jnp.moveaxis(out, 2, 0).reshape(t_n, h, d).astype(q.dtype)
+        None if sinks is None else sinks.reshape(g, h // g),
+    ))  # [G, R, T, Dv]
+    return jnp.moveaxis(out, 2, 0).reshape(
+        t_n, h, v.shape[-1]
+    ).astype(q.dtype)
 
 
 # ------------------------------------------------------ latent attention

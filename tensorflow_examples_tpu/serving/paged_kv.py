@@ -65,6 +65,12 @@ alloc/free and per-slot populated lengths) over block-granular storage:
   ``num_blocks`` counts the full kind's blocks; a window kind's ``NB``
   follows from the slots, ``W``, ``span`` and ``BS``. A model whose
   layers are all ``full`` (GPT-2) has one kind and nothing changes.
+  A kind is a window AND a row shape (ISSUE 34): ``rows`` may be given
+  per layer, the layers of one kind keep rows of one shape (full layers
+  K and V of 4 heads, window layers of 8; keys of 192 beside values of
+  128), each layer's arrays are as wide as its own row, and the bytes
+  (``bytes_per_block``, ``used_bytes``, ``used_bytes_by_kind``) count
+  each layer by it.
   With a window kind present, prompt blocks are NOT shared across
   requests (a hit would find released blocks): nothing is published or
   looked up, the prefix cache stays empty.
@@ -219,8 +225,9 @@ class PagedKVPool:
         """``num_heads`` is the heads a cache row holds (the key/value
         heads of a grouped-query model). ``rows``: the width of each
         array a layer keeps per token, as the serving block describes
-        its cache row; omitted, K and V of ``num_heads * head_dim``
-        each. ``layer_windows``: one entry a
+        its cache row — one tuple of widths for every layer, or one such
+        tuple a layer (the layers of one kind alike); omitted, K and V
+        of ``num_heads * head_dim`` each. ``layer_windows``: one entry a
         layer, ``None`` for a full layer or the window ``W``; omitted,
         every layer is full. ``window_span``: the most positions one
         step writes into a slot (the prefill chunk; 0 = ``max_len``),
@@ -274,12 +281,29 @@ class PagedKVPool:
                     "use kv_dtype='int8'"
                 )
         self.quantized = self.kv_dtype in ("int8", "fp8")
-        self.rows = tuple(int(r) for r in rows) if rows is not None \
-            else (num_heads * head_dim,) * 2
+        if rows is None:
+            rows = (num_heads * head_dim,) * 2
+        if not isinstance(rows[0], (tuple, list)):
+            rows = (rows,) * num_layers
+        # Per layer, the widths of the arrays it keeps of a token.
+        self.layer_rows = tuple(tuple(int(r) for r in row) for row in rows)
+        if len(self.layer_rows) != num_layers or len(
+            {len(row) for row in self.layer_rows}
+        ) != 1:
+            raise ValueError(
+                f"rows names {len(self.layer_rows)} layers' arrays "
+                f"({self.layer_rows}) for {num_layers} layers of as many "
+                "arrays each"
+            )
+        # The one row shape of a pool whose layers all keep the same
+        # (every model but one with row shapes by kind); None otherwise.
+        self.rows = self.layer_rows[0] \
+            if len(set(self.layer_rows)) == 1 else None
         if self.quantized and self.rows != (num_heads * head_dim,) * 2:
             raise ValueError(
                 f"kv_dtype={kv_dtype!r} keeps one scale a head of K and "
-                f"of V; a cache row of widths {self.rows} has no such heads"
+                f"of V; cache rows of widths {self.layer_rows} have no "
+                "such heads"
             )
         # Kinds: index 0 is the full kind (this object's own tables,
         # free list and refcounts below), then one _WindowSpace per
@@ -293,6 +317,19 @@ class PagedKVPool:
             )
         self.kinds = (None, *sorted({w for w in windows if w is not None}))
         self.layer_kind = tuple(self.kinds.index(w) for w in windows)
+        # A kind's row shape: its layers' own, all alike (None for a
+        # full kind without layers).
+        shapes = [
+            {r for r, k in zip(self.layer_rows, self.layer_kind) if k == kind}
+            for kind in range(len(self.kinds))
+        ]
+        if any(len(of_kind) > 1 for of_kind in shapes):
+            raise ValueError(
+                f"cache rows {self.layer_rows} differ between layers of one "
+                f"kind (layer_windows {windows}): a kind is a window and "
+                "ONE row shape"
+            )
+        self.kind_rows = tuple(next(iter(s), None) for s in shapes)
         # (The list never changes; each space's tables and free list
         # are read and written under self._lock, like the full kind's.)
         self._windows = [
@@ -370,20 +407,23 @@ class PagedKVPool:
             store = self.dtype
         kw = {} if self._sharding is None else {"device": self._sharding}
 
-        def per_layer(make, minor, dtype):
-            # Each layer's array has the NB of the layer's kind.
+        def per_layer(make, minors, dtype):
+            # Each layer's array has the NB of the layer's kind and the
+            # width of the layer's own row.
             return tuple(
                 make((self.kind_blocks(kind), self.block_size, minor),
                      dtype, **kw)
-                for kind in self.layer_kind
+                for kind, minor in zip(self.layer_kind, minors)
             )
 
         self._payload = tuple(
-            per_layer(jnp.zeros, row, store) for row in self.rows
+            per_layer(jnp.zeros, widths, store)
+            for widths in zip(*self.layer_rows)
         )
         if self.quantized:
-            self.k_scale = per_layer(jnp.ones, self.num_heads, jnp.float32)
-            self.v_scale = per_layer(jnp.ones, self.num_heads, jnp.float32)
+            heads = (self.num_heads,) * self.num_layers
+            self.k_scale = per_layer(jnp.ones, heads, jnp.float32)
+            self.v_scale = per_layer(jnp.ones, heads, jnp.float32)
         else:
             self.k_scale = self.v_scale = None
 
@@ -420,7 +460,7 @@ class PagedKVPool:
         return self._payload
 
     def set_kv_state(self, state: tuple) -> None:
-        n = len(self.rows)
+        n = len(self.layer_rows[0])
         self._payload = tuple(state[:n])
         if self.quantized:
             self.k_scale, self.v_scale = state[n:]
@@ -873,24 +913,34 @@ class PagedKVPool:
         columns), int8 payload + its
         blockwise f32 row scales when quantized — over the layers of
         its ``kind``: over every layer when the pool has one kind."""
-        if self.quantized:
-            per = sum(self.rows) * 1 + len(self.rows) * self.num_heads * 4
-        else:
-            per = sum(self.rows) * jnp.dtype(self.dtype).itemsize
-        layers = self.num_layers if kind is None \
-            else self.layer_kind.count(kind)
-        return int(layers * self.block_size * per)
+        itemsize = 1 if self.quantized else jnp.dtype(self.dtype).itemsize
+        per = sum(
+            sum(row) * itemsize
+            + (len(row) * self.num_heads * 4 if self.quantized else 0)
+            for row, k in zip(self.layer_rows, self.layer_kind)
+            if kind is None or k == kind
+        )
+        return int(self.block_size * per)
 
     def used_bytes(self) -> int:
         """Cache bytes committed to the active request set — blocks
         actually referenced, not slots claimed. The number the tier-1
         memory-claim test compares against ``slots x max_len`` rows."""
+        return sum(self.used_bytes_by_kind())
+
+    def used_bytes_by_kind(self) -> list[int]:
+        """:meth:`used_bytes` kind by kind (the full kind first): each
+        kind's blocks in use times the bytes its layers' rows make of a
+        block."""
         with self._lock:
-            return int((self._refcount > 0).sum()) * self.bytes_per_block(0) \
-                + sum(
-                    w.used * self.bytes_per_block(kind)
-                    for kind, w in enumerate(self._windows, 1)
-                )
+            used = [int((self._refcount > 0).sum()),
+                    *(w.used for w in self._windows)]
+        return [n * self.bytes_per_block(kind) for kind, n in enumerate(used)]
+
+    def kind_name(self, kind: int) -> str:
+        """``full`` or ``window<W>``: a kind in a counter's name."""
+        w = self.kinds[kind]
+        return "full" if w is None else f"window{w}"
 
     # ------------------------------------------------------------- stats
 

@@ -367,6 +367,27 @@ MLA_PLAN_ARGS = (
     "family", "queries", "context", "dtype", "form", "head_group", "rows",
 )
 
+# Instruments of a pool whose kinds keep rows of their own shape (ISSUE
+# 34; writer: serving/engine.py `decode`; catalog: docs/observability.md),
+# sampled at every decode step beside `kv_sampled_bytes` /
+# `kv_sampled_tokens`: `serving/kv_sampled_bytes_kind_<kind>` — the
+# pool's bytes in use by kind (`full`, `window<W>`:
+# `PagedKVPool.kind_name`; a pool of several kinds books them, and they
+# add up to `kv_sampled_bytes`) — and `serving/decode_gathered_tokens`
+# (the step's rung K x its live slots: the token rows the full kind's
+# gather touches, against the `kv_sampled_tokens` resident). `span/kind_plan`
+# (args: family, rung, kinds — per kind its window, KV heads, K and V
+# row widths as stored, sink or none, physical blocks and the columns
+# of its table in the program) is recorded once per traced program of
+# such a pool.
+KV_KIND_BYTES_COUNTER_PREFIX = "serving/kv_sampled_bytes_kind_"
+DECODE_GATHERED_TOKENS = "serving/decode_gathered_tokens"
+KIND_PLAN_SPAN = "kind_plan"
+KIND_PLAN_ARGS = ("family", "rung", "kinds")
+KIND_PLAN_KIND_KEYS = (
+    "window", "kv_heads", "k_row", "v_row", "sink", "blocks", "table_blocks",
+)
+
 # The per-host entry of a fleet line's "hosts" list: "host" is a
 # required int, and each of these is required numeric-or-null (the
 # writer side, fleet.VECTOR_KEYS, aliases FLEET_VECTOR_KEYS below — the

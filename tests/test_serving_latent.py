@@ -532,7 +532,8 @@ class TestTheLatentPool:
     def test_one_latent_row_a_token_and_no_v_array(self, engine):
         eng, _ = engine
         pool = eng.pool
-        assert eng.model.row_values == LATENT and eng.model.cache_rows == ((1, LATENT),)
+        # one entry a layer (ISSUE 34): every layer keeps the same latent row
+        assert eng.model.row_values == (LATENT,) * 3 and eng.model.cache_rows == (((1, LATENT),),) * 3
         assert len(pool.kv_state()) == 1 and pool.rows == (LATENT,)
         assert [a.shape for a in pool.k] == [(49, 4, LATENT)] * 3
         with pytest.raises(IndexError):
@@ -551,8 +552,8 @@ class TestTheLatentPool:
         and ``bytes_per_block`` is the arrays' real bytes, pad included."""
         eng, _ = make_engine(wide_model)
         pool = eng.pool
-        assert eng.model.row_values == WIDE_LATENT
-        assert eng.model.cache_rows == ((1, WIDE_STORED),) and pool.rows == (WIDE_STORED,)
+        assert eng.model.row_values == (WIDE_LATENT,) * 3
+        assert eng.model.cache_rows == (((1, WIDE_STORED),),) * 3 and pool.rows == (WIDE_STORED,)
         assert [a.shape for a in pool.k] == [(49, 4, WIDE_STORED)] * 3
         assert pool.bytes_per_block() == 3 * 4 * WIDE_STORED * 4
         assert pool.bytes_per_block() * pool.num_blocks == sum(a.nbytes for a in pool.k)

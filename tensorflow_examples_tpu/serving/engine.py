@@ -544,7 +544,9 @@ def _forward_extend(model, params, kv, ctx_table, tail_ids, tokens,
     scattered into ``tail_ids`` [tb // BS].
 
     ``ctx_table`` and ``tail_ids``: per kind. The full kind's context
-    table is the slot's whole table [max_blocks]; a window kind's holds
+    table is the first blocks of the slot's table, as many as the
+    program's context rung holds (the whole table on the top rung);
+    a window kind's holds
     the logical blocks from the oldest one the chunk's first query
     reads (``kv_cache.window_base(ctx_len, ...)``), at most ``W / BS +
     1``."""
@@ -721,16 +723,17 @@ class ChunkedPrefill:
         self.pending = []
 
 
-def _named(step, tag: str):
+def _named(step, rung: str):
     """Give ``step`` — a ``functools.partial`` of an ``*_impl`` step
-    function over its rung — the step function's name and the rung as
-    its ``__name__`` (``paged_decode_impl_K512``). JAX names the
+    function over its rung — the step function's name and the rung's
+    (``K512``, ``T512_C2048``) as its ``__name__``
+    (``paged_decode_impl_K512``). JAX names the
     compiled program after it in the lowering, the compile events and
     the profiler's trace; a nameless partial is ``jit__unknown``
     everywhere, and no decode execution can be told from a prefill.
     The sentinel's names (``serve_decode_K512``) are the operator's and
     stay."""
-    step.__name__ = f"{step.func.__name__.lstrip('_')}_{tag}{step.args[0]}"
+    step.__name__ = f"{step.func.__name__.lstrip('_')}_{rung}"
     return step
 
 
@@ -1029,7 +1032,7 @@ class InferenceEngine:
             lb: self.sentinel.wrap(
                 jax.jit(
                     _named(functools.partial(
-                        self._paged_prefill_impl, lb), "L"),
+                        self._paged_prefill_impl, lb), f"L{lb}"),
                     donate_argnums=(1,),
                 ),
                 f"serve_prefill_L{lb}",
@@ -1040,33 +1043,54 @@ class InferenceEngine:
             kb: self.sentinel.wrap(
                 jax.jit(
                     _named(functools.partial(
-                        self._paged_decode_impl, kb), "K"),
+                        self._paged_decode_impl, kb), f"K{kb}"),
                     donate_argnums=(1,),
                 ),
                 f"serve_decode_K{kb}",
             )
             for kb in self.kv_ladder
         }
-        # One extend program per TAIL bucket; the cached context always
-        # rides in as the slot's full block table (masked to the true
-        # context length) — |prefill ladder| programs, not a ladder
-        # product.
+        # The extend family: a program per TAIL bucket over the slot's
+        # WHOLE table (``_extend_fns[tb]``, ``extend_impl_T<tb>``, as it
+        # always was: the cached context masked to its true length) and,
+        # for the LONGEST tail bucket — the one that writes a prompt
+        # chunk by chunk, a launch every ``prefill_ladder[-1]`` prompt
+        # tokens where a shorter tail is one launch a request — a
+        # program per CONTEXT rung under the whole table
+        # (``_extend_fns[tb, cb]``, ``extend_impl_T<tb>_C<cb>``): the
+        # context rides in as the table's first ``cb / BS`` blocks, and
+        # a launch takes the smallest rung that holds its context as a
+        # decode step takes its K (``_extend_launch``). The context
+        # rungs are the kv ladder's from the longest tail up: a program
+        # scores T x (C + T) columns, so a rung under the longest tail
+        # saves less than the program it costs (each costs ~2 s of
+        # every start-up) — and a model whose tails run to ``max_len``
+        # (GPT-2: no chunk cap on its prefill ladder) has no rung under
+        # the whole table.
+        self.extend_ladder = [
+            cb for cb in self.kv_ladder if cb >= self.prefill_ladder[-1]
+        ]
         self._extend_fns = {
-            tb: self.sentinel.wrap(
+            rung: self.sentinel.wrap(
                 jax.jit(
                     _named(functools.partial(
-                        self._extend_impl, tb), "T"),
+                        self._extend_impl, rung), name),
                     donate_argnums=(1,),
                 ),
-                f"serve_extend_T{tb}",
+                f"serve_extend_{name}",
             )
-            for tb in self.prefill_ladder
+            for rung, name in (
+                *((tb, f"T{tb}") for tb in self.prefill_ladder),
+                *(((self.prefill_ladder[-1], cb),
+                   f"T{self.prefill_ladder[-1]}_C{cb}")
+                  for cb in self.extend_ladder[:-1]),
+            )
         } if self.cfg.prefix_cache else {}
         self._verify_fns = {
             kb: self.sentinel.wrap(
                 jax.jit(
                     _named(functools.partial(
-                        self._paged_verify_impl, kb), "K"),
+                        self._paged_verify_impl, kb), f"K{kb}"),
                     donate_argnums=(1,),
                 ),
                 f"serve_verify_K{kb}",
@@ -1177,11 +1201,13 @@ class InferenceEngine:
                 Field("top_ks", (s,)),
             )
         if kind == "extend":
+            tb, nb_full = self._extend_shape(rung)
             where = (
-                Field("ctx_table", [(nb,) for nb in self._kind_blocks(
-                    self.pool.max_blocks_per_slot)]),
-                Field("tail_ids", [(rung // bs,)] * self._kinds),
-                Field("tokens", (1, rung)),
+                Field("ctx_table", [
+                    (nb,) for nb in self._kind_blocks(nb_full)
+                ]),
+                Field("tail_ids", [(tb // bs,)] * self._kinds),
+                Field("tokens", (1, tb)),
                 Field("ctx_len", ()), Field("tail_len", ()),
             )
         else:
@@ -1307,16 +1333,15 @@ class InferenceEngine:
         )
         return kv, _sample_verify(seeds, positions, logits, temps, top_ks)
 
-    def _extend_impl(self, tail_bucket, params, kv, *operands):
+    def _extend_impl(self, rung, params, kv, *operands):
         """Prefix-cache hit path and chunked prefill: prefill only the
         prompt tail over the cached context (see ``_forward_extend``);
-        samples the first token from the tail's last true row."""
+        samples the first token from the tail's last true row. ``rung``
+        is the program's key in ``_extend_fns``."""
         (ctx_table, tail_ids, tokens, ctx_len, tail_len, key, temp,
-         top_k) = self._operands("extend", tail_bucket, operands)
-        self._kind_plan(
-            "extend", tail_bucket,
-            self._kind_blocks(self.pool.max_blocks_per_slot),
-        )
+         top_k) = self._operands("extend", rung, operands)
+        tail_bucket, nb_full = self._extend_shape(rung)
+        self._kind_plan("extend", tail_bucket, self._kind_blocks(nb_full))
         kv, x, stats = _forward_extend(
             self.model, params, kv, ctx_table, tail_ids, tokens,
             ctx_len, tail_len, self._layer_kind,
@@ -1337,6 +1362,32 @@ class InferenceEngine:
             nb_full if w is None else min(nb_full, w // bs + 1)
             for w in self.pool.kinds
         ]
+
+    def _extend_shape(self, rung) -> tuple[int, int]:
+        """(tail bucket, columns of the full kind's context table) of
+        the extend program ``rung``: ``tb`` takes the slot's whole
+        table, ``(tb, cb)`` its first ``cb / BS`` blocks."""
+        if isinstance(rung, tuple):
+            return rung[0], rung[1] // self.cfg.kv_block_size
+        return rung, self.pool.max_blocks_per_slot
+
+    def _extend_launch(self, slot: int, ctx: int, tail: int):
+        """The extend program for ``tail`` new tokens over ``ctx``
+        cached ones — the smallest tail bucket that holds the tail and,
+        where the family has them for that bucket, the smallest context
+        rung that holds the context — as (its key in ``_extend_fns``,
+        its tail bucket, the slot's context tables for it); books what
+        the launch gathers beside what it reads."""
+        tb = kv_mod.pick_bucket(self.prefill_ladder, tail)
+        cb = kv_mod.pick_bucket(self.extend_ladder, max(ctx, 1))
+        rung = (tb, cb) if (tb, cb) in self._extend_fns else tb
+        nb_full = self._extend_shape(rung)[1]
+        reg = self.registry
+        reg.counter(schema.EXTEND_GATHERED_TOKENS).inc(
+            nb_full * self.cfg.kv_block_size
+        )
+        reg.counter(schema.EXTEND_CONTEXT_TOKENS).inc(ctx)
+        return rung, tb, self._kind_tables(ctx, nb_full, slot)
 
     def _kind_tables(self, position, nb_full: int, slot=None,
                      live=None) -> list:
@@ -1565,17 +1616,14 @@ class InferenceEngine:
                 )
             else:
                 tail = n - ctx
-                bucket = kv_mod.pick_bucket(self.prefill_ladder, tail)
+                bucket, tb, ctx_tables = self._extend_launch(slot, ctx, tail)
                 tail_ids = self._span_ids(
-                    slot, ctx // bs, total_blocks, bucket // bs
+                    slot, ctx // bs, total_blocks, tb // bs
                 )
-                tokens = np.zeros((1, bucket), np.int32)
+                tokens = np.zeros((1, tb), np.int32)
                 tokens[0, :tail] = prompt[ctx:]
                 kind, fns, host = "extend", self._extend_fns, (
-                    self._kind_tables(
-                        ctx, self.pool.max_blocks_per_slot, slot
-                    ),
-                    tail_ids, tokens, ctx, tail,
+                    ctx_tables, tail_ids, tokens, ctx, tail,
                 )
         # The first token is drawn with the key of (seed, n), made in
         # the program.
@@ -1662,25 +1710,22 @@ class InferenceEngine:
         with host_span("engine_prefill_build"):
             start, end = state.spans[state.idx]
             tail = end - start
-            tb = kv_mod.pick_bucket(self.prefill_ladder, tail)
             # Window kinds claim this chunk's blocks and let go of what
             # no query from ``start`` on can read.
             self.pool.ensure_span(slot, start, end)
+            rung, tb, ctx_tables = self._extend_launch(slot, start, tail)
             tail_ids = self._span_ids(
                 slot, start // bs, -(-end // bs), tb // bs
-            )
-            ctx_tables = self._kind_tables(
-                start, self.pool.max_blocks_per_slot, slot
             )
             tokens = np.zeros((1, tb), np.int32)
             tokens[0, :tail] = prompt[start:end]
         with host_span("engine_prefill_upload"):
-            block = self._put(self._specs["extend", tb], (
+            block = self._put(self._specs["extend", rung], (
                 ctx_tables, tail_ids, tokens, start, tail,
                 state.seed, end, state.temperature, state.top_k,
             ))
         tok, last = self._run_compiled(
-            "prefill", self._extend_fns[tb], block
+            "prefill", self._extend_fns[rung], block
         )
         state.idx += 1
         self.registry.counter("serving/prefill_chunks").inc()

@@ -388,6 +388,16 @@ KIND_PLAN_KIND_KEYS = (
     "window", "kv_heads", "k_row", "v_row", "sink", "blocks", "table_blocks",
 )
 
+# Instruments of the extend family's context ladder (ISSUE 35; writer:
+# serving/engine.py `_extend_launch`; catalog: docs/observability.md),
+# booked at every extend launch, a chunk of a chunked prefill and a
+# prefix hit's tail alike: `serving/extend_gathered_tokens` — the
+# launch's context rung, the cached token rows the full kind's gather
+# touches — beside `serving/extend_context_tokens`, the cached tokens
+# the launch reads (its `ctx`).
+EXTEND_GATHERED_TOKENS = "serving/extend_gathered_tokens"
+EXTEND_CONTEXT_TOKENS = "serving/extend_context_tokens"
+
 # The per-host entry of a fleet line's "hosts" list: "host" is a
 # required int, and each of these is required numeric-or-null (the
 # writer side, fleet.VECTOR_KEYS, aliases FLEET_VECTOR_KEYS below — the

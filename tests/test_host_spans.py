@@ -218,6 +218,12 @@ RUNGS = [
 ]
 
 
+def _rung(bucket) -> str:
+    """A rung as its program's name ends: the bucket, or for an extend
+    program under the whole table ``(tb, cb)`` as ``<tb>_C<cb>``."""
+    return "{}_C{}".format(*bucket) if isinstance(bucket, tuple) else str(bucket)
+
+
 @pytest.fixture(scope="module")
 def engines():
     """GPT-2's block with every family, and the two-kind block (the
@@ -244,6 +250,7 @@ def test_every_rung_is_named_after_its_step_function_and_rung(
     fns = getattr(engine, attr)
     assert fns, f"{attr} is empty"
     for bucket, fn in fns.items():
+        bucket = _rung(bucket)
         assert fn.__name__ == f"{program}{bucket}"
         # benchmark/runners/serve.py fails a run in whose window a
         # program with "_impl" in its name compiles; the roofline
@@ -252,6 +259,50 @@ def test_every_rung_is_named_after_its_step_function_and_rung(
         assert ("decode_impl" in fn.__name__) == (attr == "_decode_fns")
         # the sentinel's names are the operator's, and stay
         assert f"{sentinel}{bucket}" in engine.sentinel.compile_counts()
+
+
+def test_the_two_kind_block_has_an_extend_program_a_context_rung(engines):
+    """ISSUE 35: chunks of 8 under kv rungs 16, 32 and 64 — the whole
+    table under the name it had, the rungs under it ``_C<cb>``."""
+    assert [fn.__name__ for fn in engines("two_kinds")._extend_fns.values()] == [
+        "extend_impl_T8", "extend_impl_T8_C16", "extend_impl_T8_C32"]
+
+
+@pytest.mark.parametrize("serve_kw", [{}, dict(kv_block_size=16, prefill_chunk_tokens=64)],
+                         ids=["whole_prompts", "chunked"])
+def test_gpt2_keeps_its_nineteen_programs_and_their_names(serve_kw):
+    """GPT-2's tails run to ``max_len`` (no chunk cap on its prefill
+    ladder), so the only context rung at or above the longest tail is
+    the whole table: at the serving cell's ladders (1,024 positions, the
+    default floors) the engine has the 19 programs it had before the
+    extend family got a context ladder (ISSUE 35), under their names."""
+    import jax
+    import jax.numpy as jnp
+
+    cfg = transformer.TransformerConfig(**{**MODEL, "max_len": 1024})
+    params = transformer.Transformer(cfg).init(
+        {"params": jax.random.PRNGKey(0)}, jnp.zeros((1, 8), jnp.int32)
+    )["params"]
+    engine = InferenceEngine(cfg, params, registry=MetricsRegistry(),
+                             cfg=ServeConfig(max_slots=2, **serve_kw))
+    tails, contexts = [16, 32, 64, 128, 256, 512, 1024], [64, 128, 256, 512, 1024]
+    assert engine.extend_ladder == [1024]
+    names = [
+        fn.__name__
+        for fns in (engine._prefill_fns, engine._decode_fns, engine._extend_fns)
+        for fn in fns.values()
+    ]
+    assert names == (
+        [f"paged_prefill_impl_L{lb}" for lb in tails]
+        + [f"paged_decode_impl_K{kb}" for kb in contexts]
+        + [f"extend_impl_T{tb}" for tb in tails]
+    )
+    assert len(names) == engine.expected_compiles() == 19
+    assert sorted(engine.sentinel.compile_counts()) == sorted(
+        [f"serve_prefill_L{lb}" for lb in tails]
+        + [f"serve_decode_K{kb}" for kb in contexts]
+        + [f"serve_extend_T{tb}" for tb in tails]
+    )
 
 
 def test_jax_reports_the_names_in_its_compile_events():
@@ -274,7 +325,7 @@ def test_jax_reports_the_names_in_its_compile_events():
     finally:
         monitoring_src.unregister_event_duration_listener(listen)
     for attr, program, _ in RUNGS:
-        for bucket in getattr(engine, attr):
+        for bucket in map(_rung, getattr(engine, attr)):
             assert f"jit({program}{bucket})" in seen, (program, bucket, seen)
     assert not [n for n in seen if "unknown" in n], seen
     assert engine.post_warmup_recompiles() == 0

@@ -20,6 +20,7 @@ import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+import extend_rungs  # noqa: E402
 from benchmark import spec  # noqa: E402
 from tensorflow_examples_tpu.serving import blocks, kv_cache, paged_kv  # noqa: E402
 from tensorflow_examples_tpu.serving import engine as engine_mod  # noqa: E402
@@ -145,6 +146,20 @@ class TestAgainstTheReference:
         for k, (tok, lp) in enumerate(zip(got.tokens, got.logprobs)):
             row = logits[k] - logits[k].max()
             assert abs(lp - (row[tok] - np.log(np.exp(row).sum()))) < TOL, k
+
+    @pytest.mark.parametrize("ctx,rung", extend_rungs.CASES)
+    def test_a_chunk_through_a_lower_context_rung_is_the_whole_tables(self, model, ctx,
+                                                                      rung):
+        """ISSUE 35: the chunk that starts at ``ctx`` takes the smallest
+        context rung that holds it and gives the tokens and logits of the
+        same chunk through the whole-table program — rows by kind, the
+        sink in the window layers. (A window kind is present, so the
+        prefix cache is off: chunks are this block's only extend
+        launches.)"""
+        prompt = prompt_of(ctx + 7, seed=ctx)
+        extend_rungs.assert_lower_rung_is_whole_tables(
+            lambda: make_engine(model)[0],
+            lambda eng: extend_rungs.last_chunk(eng, prompt, ctx), rung, atol=TOL)
 
     def test_two_requests_decode_together_as_they_do_alone(self, engine):
         eng, _ = engine
@@ -598,7 +613,13 @@ class TestWhatTheBlockTellsTheEngine:
         plans = [e["args"] for e in spans._default.events()
                  if e["name"] == schema.KIND_PLAN_SPAN][before:]
         assert sorted((p["family"], p["rung"]) for p in plans) == [
-            ("decode", 16), ("decode", 32), ("decode", 64), ("extend", 8), ("prefill", 8)]
+            ("decode", 16), ("decode", 32), ("decode", 64),
+            ("extend", 8), ("extend", 8), ("extend", 8), ("prefill", 8)]
+        # one extend program a context rung, told apart by the columns
+        # of the full kind's table; the window kind's stay W / BS + 1
+        assert sorted(
+            [k["table_blocks"] for k in p["kinds"]] for p in plans if p["family"] == "extend"
+        ) == [[4, 3], [8, 3], [16, 3]]
         assert all(set(p) == set(schema.KIND_PLAN_ARGS) for p in plans)
         nb_window = 2 * ((8 + 8) // 4 + 1) + 1
         decode = next(p for p in plans if (p["family"], p["rung"]) == ("decode", 64))

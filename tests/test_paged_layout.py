@@ -123,11 +123,12 @@ def _results_of_bytes(hlo: str, at_least: float) -> list[str]:
     ]
 
 
-def _packed(engine, family, args, chip):
+def _packed(engine, family, args, chip, rung=None):
     """``args`` of ``sizing*.engine_programs`` in the form the engine
-    launches: params, the pool and the ONE operand block."""
+    launches: params, the pool and the ONE operand block (of the
+    family's largest rung, or of ``rung``)."""
     ladder = engine.kv_ladder if family == "decode" else engine.prefill_ladder
-    spec = engine._specs[family, ladder[-1]]
+    spec = engine._specs[family, rung or ladder[-1]]
     return (*args[:2], jax.ShapeDtypeStruct(
         (launch_block.size(spec),), np.int32, sharding=chip
     ))
@@ -224,11 +225,13 @@ def _kinds_engine(workload, pcfg):
         workload.model_config(pcfg), params,
         cfg=ServeConfig(max_slots=4, kv_block_size=16, kv_blocks=2048,
                         prefill_chunk_tokens=32, prefill_bucket_floor=32,
-                        kv_bucket_floor=256),
+                        kv_bucket_floor=128),
     )
 
 
-@pytest.mark.parametrize("family", ["decode", "prefill", "extend"])
+# "extend_lowest": the extend family's LOWEST context rung (ISSUE 35),
+# the one the cells' chunks run most; the others are the largest rungs.
+@pytest.mark.parametrize("family", ["decode", "prefill", "extend", "extend_lowest"])
 @pytest.mark.parametrize(
     "make_engine", [_glm_engine, _cohere_engine], ids=["glm4_moe_lite", "cohere2_moe"]
 )
@@ -253,8 +256,14 @@ def test_no_program_of_any_block_touches_the_whole_pool(described_chip,
     assert layer_bytes / 4 > 2 * sum(
         a.nbytes for a in jax.tree.leaves(engine.params)
     )
+    rung = None
+    if family == "extend_lowest":
+        family, rung = "extend", (engine.prefill_ladder[-1], engine.extend_ladder[0])
+        assert rung == (32, 128) and rung in engine._extend_fns
     fn, args = sizing_kinds.engine_programs(engine, described_chip)[family]
-    compiled = fn.lower(*_packed(engine, family, args, described_chip)).compile()
+    if rung:
+        fn = engine._extend_fns[rung]
+    compiled = fn.lower(*_packed(engine, family, args, described_chip, rung)).compile()
 
     assert _results_of_bytes(compiled.as_text(), layer_bytes / 4) == []
     mem = compiled.memory_analysis()

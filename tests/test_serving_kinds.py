@@ -19,6 +19,7 @@ import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+import extend_rungs  # noqa: E402
 from benchmark import spec  # noqa: E402
 from tensorflow_examples_tpu.serving import paged_kv  # noqa: E402
 from tensorflow_examples_tpu.serving.batcher import (  # noqa: E402
@@ -29,6 +30,7 @@ from tensorflow_examples_tpu.serving.engine import (  # noqa: E402
     InferenceEngine,
     ServeConfig,
 )
+from tensorflow_examples_tpu.telemetry import schema  # noqa: E402
 from tensorflow_examples_tpu.telemetry.registry import MetricsRegistry  # noqa: E402
 from tensorflow_examples_tpu.workloads import cohere2_moe as workload  # noqa: E402
 
@@ -235,6 +237,64 @@ class TestAgainstTheReference:
         logits, _ = REF.forward(params, prompt, dict(TINY, held_experts=[4, 5, 6, 7]),
                                 rows=[len(prompt) - 1], q_block=8)
         np.testing.assert_allclose(last, logits[0], atol=2e-5)
+
+
+class TestTheExtendFamilysContextRungs:
+    """ISSUE 35: an extend program per (tail bucket, context rung); a
+    launch takes the smallest context rung that holds its context. The
+    prefix cache is off in a pool with a window kind, so this block's
+    only extend launches are the chunks of a chunked prefill."""
+
+    @pytest.fixture(scope="class")
+    def wide(self):
+        """A window of 8 blocks: wider than the two lower rungs' tables."""
+        pcfg = program_config(sliding_window=32)
+        params = jax.jit(workload.make_task(pcfg).init_fn)(jax.random.PRNGKey(0))["params"]
+        return workload.model_config(pcfg), params
+
+    @pytest.mark.parametrize("window", [8, 32])
+    @pytest.mark.parametrize("ctx,rung", extend_rungs.CASES)
+    def test_a_chunk_through_a_lower_rung_is_the_whole_tables(self, model, wide, ctx, rung,
+                                                              window):
+        prompt = prompt_of(ctx + 7, seed=ctx)
+        extend_rungs.assert_lower_rung_is_whole_tables(
+            lambda: make_engine(model if window == 8 else wide)[0],
+            lambda eng: extend_rungs.last_chunk(eng, prompt, ctx), rung, atol=2e-5)
+
+    @pytest.mark.parametrize("ctx,rung", [(0, 16), (1, 16), (16, 16), (17, 32), (32, 32),
+                                          (33, 64), (56, 64)])
+    def test_the_smallest_rung_that_holds_the_context_and_its_tables(self, wide, ctx, rung):
+        """The full kind's table has the rung's blocks, a window kind's
+        no more than the ``W / BS + 1`` one query's window touches."""
+        eng, _ = make_engine(wide)
+        assert eng.extend_ladder == [16, 32, 64] and eng.prefill_ladder == [8]
+        key, tb, tables = eng._extend_launch(0, ctx, 5)
+        assert (key, tb) == ((8, rung) if rung < 64 else 8, 8)
+        assert [t.shape for t in tables] == [(rung // 4,), (min(rung // 4, 32 // 4 + 1),)]
+        spec = {f.name: f.shape for f in eng._specs["extend", key]}
+        assert spec["ctx_table"] == [t.shape for t in tables]
+
+    def test_every_rung_is_compiled_before_traffic_and_none_after(self, model):
+        eng, _ = make_engine(model)
+        counts = eng.warmup()
+        assert {n for n in counts if "extend" in n} == {
+            "serve_extend_T8", "serve_extend_T8_C16", "serve_extend_T8_C32"}
+        assert sum(counts.values()) == eng.expected_compiles() == 1 + 3 + 3
+        slot = eng.pool.alloc()
+        serve(eng, slot, prompt_of(60, seed=3), 2)      # chunks over contexts 0, 8 ... 56
+        eng.pool.free(slot)
+        assert eng.sentinel.compile_counts() == counts
+        assert eng.post_warmup_recompiles() == 0
+
+    def test_the_counters_add_up_to_the_rungs_and_the_contexts(self, model):
+        eng, reg = make_engine(model)
+        slot = eng.pool.alloc()
+        serve(eng, slot, prompt_of(30, seed=4), 1)      # chunks from 0, 8, 16 and 24
+        serve(eng, eng.pool.alloc(), prompt_of(45, seed=5), 1)   # ... and 32, 40
+        assert reg.counter(schema.EXTEND_GATHERED_TOKENS).value == (
+            16 + 16 + 16 + 32) + (16 + 16 + 16 + 32 + 32 + 64)
+        assert reg.counter(schema.EXTEND_CONTEXT_TOKENS).value == 48 + 120
+        assert reg.counter("serving/prefill_chunks").value == 4 + 6
 
 
 class TestThePoolsKinds:

@@ -27,6 +27,7 @@ import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+import extend_rungs  # noqa: E402
 from benchmark import spec  # noqa: E402
 from tensorflow_examples_tpu.parallel import moe  # noqa: E402
 from tensorflow_examples_tpu.serving import blocks, kv_cache, paged_kv  # noqa: E402
@@ -167,6 +168,46 @@ class TestAgainstTheReference:
         seq = doc + q2
         logits, _ = REF.forward(params, seq, TINY, rows=[len(seq) - 1], q_block=8)
         np.testing.assert_allclose(hit, logits[0], atol=1e-4)
+
+    @pytest.mark.parametrize("kind,ctx,rung", [
+        *(("chunk", *case) for case in extend_rungs.CASES),
+        *(("hit", *case) for case in extend_rungs.CASES[1:]),
+    ])
+    def test_a_launch_through_a_lower_context_rung_is_the_whole_tables(
+            self, model, kind, ctx, rung):
+        """ISSUE 35: a chunk of a chunked prefill, and the tail of a
+        prefix hit, over ``ctx`` cached latent rows take the smallest
+        context rung that holds them and give the tokens and logits of
+        the same launch through the whole-table program."""
+        prompt = prompt_of(ctx + 7, seed=ctx)
+
+        def launch(eng):
+            if kind == "chunk":
+                return extend_rungs.last_chunk(eng, prompt, ctx)
+            slot = eng.pool.alloc()
+            serve(eng, slot, prompt[:ctx] + prompt_of(3, seed=99), 1)  # the document, cached
+            eng.pool.free(slot)
+            out = extend_rungs.hit_tail(eng, prompt)
+            assert eng.registry.counter("serving/prefix_reused_tokens").value == ctx
+            return out
+
+        extend_rungs.assert_lower_rung_is_whole_tables(
+            lambda: make_engine(model)[0], launch, rung, atol=1e-4)
+
+    def test_only_the_chunk_bucket_has_context_rungs(self, model):
+        """A tail shorter than a chunk is one launch a request and keeps
+        the whole-table program alone; the bucket that writes prompts
+        chunk by chunk has a program a context rung (ISSUE 35)."""
+        eng, reg = make_engine(model, prefill_bucket_floor=4)
+        assert eng.prefill_ladder == [4, 8] and eng.extend_ladder == [16, 32, 64]
+        assert list(eng._extend_fns) == [4, 8, (8, 16), (8, 32)]
+        assert eng.expected_compiles() == 2 + 3 + 4
+        key, tb, tables = eng._extend_launch(0, 16, 3)
+        assert (key, tb) == (4, 4) and tables[0].shape == (16,)
+        key, tb, tables = eng._extend_launch(0, 16, 7)
+        assert (key, tb) == ((8, 16), 8) and tables[0].shape == (4,)
+        assert reg.counter(schema.EXTEND_GATHERED_TOKENS).value == 64 + 16
+        assert reg.counter(schema.EXTEND_CONTEXT_TOKENS).value == 16 + 16
 
     def test_two_requests_decode_together_as_they_do_alone(self, engine):
         eng, _ = engine
@@ -358,10 +399,13 @@ class TestTheTwoForms:
         by_family = {}
         for p in plans()[before:]:
             by_family.setdefault(p["family"], []).append(p)
-        assert len(by_family["prefill"]) == 1 and len(by_family["extend"]) == 1
+        assert len(by_family["prefill"]) == 1
         assert [p["context"] for p in by_family["decode"]] == [16, 32, 64]
         assert all(p["form"] == "absorbed" for p in by_family["decode"])
-        assert by_family["extend"][0]["head_group"] == 4
+        # one extend program a context rung: the chunk's own 8 columns
+        # behind the rung's, the whole table (64) first as it is keyed
+        assert [p["context"] for p in by_family["extend"]] == [72, 24, 40]
+        assert all(p["head_group"] == 4 for p in by_family["extend"])
         assert all(p["rows"] == [LATENT] for p in plans()[before:])  # the widths as stored
         assert all(set(p) == set(schema.MLA_PLAN_ARGS) for p in plans()[before:])
         n = len(plans())

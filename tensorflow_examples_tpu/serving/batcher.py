@@ -531,9 +531,10 @@ class ContinuousBatcher:
         decode_steps = 0
         while not self._stop.is_set():
             # One busy iteration on the profiler's clock (ISSUE 25):
-            # serve_admission -> [serve_prefill ...] -> serve_decode_step
-            # {engine_decode_build/upload/dispatch/fetch} -> serve_commit,
-            # with nothing between the spans but this loop's own tests.
+            # serve_admission -> [serve_admit ...] -> [serve_prefill_chunk]
+            # -> serve_decode_step {engine_decode_build/upload/dispatch/
+            # fetch} -> serve_commit, with nothing between the spans but
+            # this loop's own tests.
             with span("serve_admission"):
                 # Brownout tick (ISSUE 13): one controller evaluation
                 # per loop iteration — queue depth + KV occupancy here,
@@ -554,7 +555,11 @@ class ContinuousBatcher:
                 self._wd("serve_prefill")
                 for item in staged:
                     try:
-                        self._admit(item)
+                        # The request's whole admission (ISSUE 36): its
+                        # claim, its prefill or extend, its bookkeeping.
+                        with span(schema.SERVE_ADMIT_SPAN,
+                                  tokens=len(item.req.prompt)):
+                            self._admit(item)
                     except Exception as e:  # noqa: BLE001 — one bad
                         # request must not take the serve loop down
                         log.exception("prefill failed; failing request")
@@ -577,7 +582,9 @@ class ContinuousBatcher:
                 # first): the decode step below runs between chunks,
                 # which is the whole TTFT-vs-TPOT admission bargain.
                 self._wd("serve_prefill")
-                self._chunk_step()
+                # The whole turn, the final chunk's _finish_prefill too.
+                with span("serve_prefill_chunk"):
+                    self._chunk_step()
             if not self._active:
                 continue
             self._wd("serve_decode")
@@ -920,8 +927,7 @@ class ContinuousBatcher:
             # handed-off KV pages in and continue the stream from the
             # prefill replica's first token.
             t_import = time.monotonic()
-            with span("serve_resume", tokens=len(req.prompt)):
-                self.engine.import_kv_pages(slot, req.pages, req.prompt)
+            self.engine.import_kv_pages(slot, req.pages, req.prompt)
             item.t_first = time.monotonic()
             if item.spans is not None:
                 item.spans.append(close_span(
@@ -1108,8 +1114,7 @@ class ContinuousBatcher:
             return
         t_chunk = time.monotonic()
         try:
-            with span("serve_prefill_chunk"):
-                done, first, last_logits = self.engine.prefill_step(state)
+            done, first, last_logits = self.engine.prefill_step(state)
         except Exception as e:  # noqa: BLE001 — one bad chunk must not
             # take the serve loop down
             log.exception("prefill chunk failed; failing request")

@@ -1604,34 +1604,26 @@ class InferenceEngine:
         # returns ctx=0 exactly when there is no rung to run a tail on.
         with host_span("engine_prefill_build"):
             ctx, _ = self.pool.claim_prompt_blocks(slot, prompt)
-            self.pool.ensure_span(slot, ctx, n)
-            total_blocks = -(-n // bs)
             if ctx == 0:
+                self.pool.ensure_span(slot, 0, n)
                 bucket = kv_mod.pick_bucket(self.prefill_ladder, n)
-                ids = self._span_ids(slot, 0, total_blocks, bucket // bs)
+                ids = self._span_ids(slot, 0, -(-n // bs), bucket // bs)
                 tokens = np.zeros((1, bucket), np.int32)
                 tokens[0, :n] = prompt
                 kind, fns, host = "prefill", self._prefill_fns, (
                     ids, tokens, n,
                 )
-            else:
-                tail = n - ctx
-                bucket, tb, ctx_tables = self._extend_launch(slot, ctx, tail)
-                tail_ids = self._span_ids(
-                    slot, ctx // bs, total_blocks, tb // bs
-                )
-                tokens = np.zeros((1, tb), np.int32)
-                tokens[0, :tail] = prompt[ctx:]
-                kind, fns, host = "extend", self._extend_fns, (
-                    ctx_tables, tail_ids, tokens, ctx, tail,
-                )
+        if ctx:
+            # A prefix hit: the tail runs as an extend launch, named so.
+            bucket, host = self._extend_build(slot, prompt, ctx, n)
+            kind, fns = "extend", self._extend_fns
         # The first token is drawn with the key of (seed, n), made in
         # the program.
-        with host_span("engine_prefill_upload"):
+        with host_span(f"engine_{kind}_upload"):
             block = self._put(self._specs[kind, bucket], (
                 *host, seed, n, temperature, top_k,
             ))
-        tok, last = self._run_compiled("prefill", fns[bucket], block)
+        tok, last = self._run_compiled(kind, fns[bucket], block)
         if ctx:
             self.registry.counter(
                 "serving/prefix_reused_tokens"
@@ -1639,15 +1631,38 @@ class InferenceEngine:
         self.pool.insert_prefix(slot, prompt)
         self.pool.lengths[slot] = n
         self.registry.counter("serving/prefill_tokens").inc(n)
-        return self._fetch_prefill(tok, last)
+        return self._fetch_prefill(kind, tok, last)
 
-    def _fetch_prefill(self, tok, last, pending=()):
+    def _extend_build(self, slot: int, prompt, start: int, end: int):
+        """The operands of an extend launch that writes positions
+        ``[start, end)`` of ``prompt`` over the ``start`` cached ones, as
+        (the program's key in ``_extend_fns``, host operands), under
+        ``span/engine_extend_build`` (``T`` the tail bucket, ``rung``
+        the context columns gathered — a context rung or the whole
+        table —, ``ctx`` the cached tokens)."""
+        bs = self.cfg.kv_block_size
+        tail = end - start
+        with host_span("engine_extend_build", ctx=start) as late:
+            # Window kinds claim the tail's blocks and let go of what no
+            # query from ``start`` on can read.
+            self.pool.ensure_span(slot, start, end)
+            rung, tb, ctx_tables = self._extend_launch(slot, start, tail)
+            late.update(T=tb, rung=self._extend_shape(rung)[1] * bs)
+            tail_ids = self._span_ids(
+                slot, start // bs, -(-end // bs), tb // bs
+            )
+            tokens = np.zeros((1, tb), np.int32)
+            tokens[0, :tail] = prompt[start:end]
+        return rung, (ctx_tables, tail_ids, tokens, start, tail)
+
+    def _fetch_prefill(self, kind: str, tok, last, pending=()):
         """The prefill's device->host sync: first token and last-row
-        logits, under ``span/engine_prefill_fetch``. Where the model's
+        logits, under ``span/engine_<kind>_fetch`` (``prefill`` or
+        ``extend``: the program that ran). Where the model's
         blocks report stats they ride behind the token, and in the
         ``pending`` outputs of a chunked prefill's earlier chunks
         (finished long since: reading them waits for nothing)."""
-        with host_span("engine_prefill_fetch"):
+        with host_span(f"engine_{kind}_fetch"):
             tok, last = np.asarray(tok), np.asarray(last)
             if self.model.stats_len:
                 for out in (*map(np.asarray, pending), tok):
@@ -1705,27 +1720,15 @@ class InferenceEngine:
         ``request_key(seed, n)``, exactly the unchunked prefill's, so
         the chunked stream is token-identical to the single-shot one
         (test-pinned)."""
-        bs = self.cfg.kv_block_size
         slot, prompt = state.slot, state.prompt
-        with host_span("engine_prefill_build"):
-            start, end = state.spans[state.idx]
-            tail = end - start
-            # Window kinds claim this chunk's blocks and let go of what
-            # no query from ``start`` on can read.
-            self.pool.ensure_span(slot, start, end)
-            rung, tb, ctx_tables = self._extend_launch(slot, start, tail)
-            tail_ids = self._span_ids(
-                slot, start // bs, -(-end // bs), tb // bs
-            )
-            tokens = np.zeros((1, tb), np.int32)
-            tokens[0, :tail] = prompt[start:end]
-        with host_span("engine_prefill_upload"):
+        start, end = state.spans[state.idx]
+        rung, host = self._extend_build(slot, prompt, start, end)
+        with host_span("engine_extend_upload"):
             block = self._put(self._specs["extend", rung], (
-                ctx_tables, tail_ids, tokens, start, tail,
-                state.seed, end, state.temperature, state.top_k,
+                *host, state.seed, end, state.temperature, state.top_k,
             ))
         tok, last = self._run_compiled(
-            "prefill", self._extend_fns[rung], block
+            "extend", self._extend_fns[rung], block
         )
         state.idx += 1
         self.registry.counter("serving/prefill_chunks").inc()
@@ -1737,7 +1740,8 @@ class InferenceEngine:
         self.pool.lengths[slot] = n
         self.pool.insert_prefix(slot, prompt)
         self.registry.counter("serving/prefill_tokens").inc(n)
-        return (True, *self._fetch_prefill(tok, last, state.pending))
+        return (True, *self._fetch_prefill("extend", tok, last,
+                                           state.pending))
 
     # ----------------------------------- KV page handoff (ISSUE 12 (c))
 

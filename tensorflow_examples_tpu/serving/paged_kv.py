@@ -93,6 +93,8 @@ import numpy as np
 
 from tensorflow_examples_tpu.serving import scheduler
 from tensorflow_examples_tpu.telemetry import registry as registry_mod
+from tensorflow_examples_tpu.telemetry import schema
+from tensorflow_examples_tpu.telemetry.spans import span as host_span
 
 log = logging.getLogger(__name__)
 
@@ -802,26 +804,35 @@ class PagedKVPool:
         Returns ``(ctx, fresh)``: the cached token count and the fresh
         block ids (the table rows from ``ctx // block_size`` on). The
         ONE home of the claim discipline — the prefill, chunked-prefill,
-        and page-import paths all route through it."""
+        and page-import paths all route through it, so every admission's
+        claim is ``span/kv_claim`` (``tokens``; ``reused``, the cached
+        tokens, at its end)."""
         total = -(-len(prompt) // self.block_size)
-        reused, ctx = self.prefix_lookup(prompt)
-        try:
-            fresh = self.alloc_blocks(total - len(reused))
-        except BlockExhausted:
-            self.release_prefix(reused)
-            raise
-        self.assign(slot, reused + fresh)
+        with host_span(schema.KV_CLAIM_SPAN, tokens=len(prompt)) as late:
+            reused, ctx = self.prefix_lookup(prompt)
+            late["reused"] = ctx
+            try:
+                fresh = self.alloc_blocks(total - len(reused))
+            except BlockExhausted:
+                self.release_prefix(reused)
+                raise
+            self.assign(slot, reused + fresh)
         return ctx, fresh
 
     def insert_prefix(self, slot: int, prompt) -> None:
         """Publish the slot's FULL prompt blocks for reuse. Idempotent
         per chain link; a block already published under a different
         physical id (a racing identical prompt) is left alone — first
-        writer wins, both copies serve."""
+        writer wins, both copies serve. Under ``span/kv_publish``
+        (``blocks``: the prompt's full blocks; ``new``, those published
+        for the first time, at its end): the walk hashes every block,
+        the cached ones too."""
         if not self.prefix_cache_enabled:
             return
         bs = self.block_size
-        with self._lock:
+        with host_span(schema.KV_PUBLISH_SPAN, blocks=len(prompt) // bs) \
+                as late, self._lock:
+            late["new"] = 0
             parent = -1
             parent_hash = ""
             for i in range(len(prompt) // bs):
@@ -845,6 +856,7 @@ class PagedKVPool:
                 self._chain_hash[bid] = parent_hash
                 self._chain_depth[bid] = i + 1
                 self._digest_gen += 1
+                late["new"] += 1
                 parent = bid
             self._publish_locked()
 

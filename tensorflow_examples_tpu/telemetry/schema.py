@@ -398,6 +398,25 @@ KIND_PLAN_KIND_KEYS = (
 EXTEND_GATHERED_TOKENS = "serving/extend_gathered_tokens"
 EXTEND_CONTEXT_TOKENS = "serving/extend_context_tokens"
 
+# The serve thread's turn-over, named where it happens (ISSUE 36;
+# writers: serving/batcher.py, serving/paged_kv.py, serving/engine.py;
+# catalog: docs/observability.md; readers: benchmark/layer_metrics/
+# idle_in_admission.serve, idle_in_kv_pool.serve, idle_in_extend.serve).
+# `span/serve_admit` (args: tokens) brackets one request's whole
+# admission at the top level of the serve loop; `span/kv_claim` (tokens,
+# reused: the cached tokens, set at the span's end) the pool's prefix
+# lookup, allocation and table install, and `span/kv_publish` (blocks:
+# the prompt's full blocks; new: those published for the first time)
+# its prefix index's publishing walk, whoever calls them; an extend
+# launch — a prefix hit's tail or a prompt chunk — is
+# `span/engine_extend_{build,upload,dispatch,fetch}` (build's args: T the
+# tail bucket, rung the context columns gathered — a rung or the whole
+# table —, ctx the cached tokens), where a prefill is `engine_prefill_*`.
+SERVE_ADMIT_SPAN = "serve_admit"
+KV_CLAIM_SPAN, KV_CLAIM_ARGS = "kv_claim", ("tokens", "reused")
+KV_PUBLISH_SPAN, KV_PUBLISH_ARGS = "kv_publish", ("blocks", "new")
+EXTEND_BUILD_ARGS = ("T", "rung", "ctx")
+
 # The per-host entry of a fleet line's "hosts" list: "host" is a
 # required int, and each of these is required numeric-or-null (the
 # writer side, fleet.VECTOR_KEYS, aliases FLEET_VECTOR_KEYS below — the

@@ -79,18 +79,25 @@ class Tracer:
 
     @contextlib.contextmanager
     def span(self, name: str, **args):
+        """Yields a dict: what the body puts there joins ``args`` when
+        the span closes (a number known only at the end, such as the
+        blocks a claim reused)."""
         tid = threading.get_ident()
         label = f"span/{name}"
+        late: dict = {}
         # The annotation brackets the bookkeeping below too, so back-to-back
         # spans leave no hole between them on the profiler's timeline.
-        with _trace_annotation()(label, **args):
+        with _trace_annotation()(label, **args) as annotation:
             t0 = self._now_ns()
             with self._lock:
                 self._open.setdefault(tid, []).append(name)
             try:
-                yield
+                yield late
             finally:
                 t1 = self._now_ns()
+                if late:
+                    annotation.set_metadata(**late)
+                    args = {**args, **late}
                 with self._lock:
                     stack = self._open.get(tid)
                     if stack and stack[-1] == name:

@@ -24,6 +24,7 @@ import pytest
 from tensorflow_examples_tpu.models import transformer
 from tensorflow_examples_tpu.serving.batcher import ContinuousBatcher, Request
 from tensorflow_examples_tpu.serving.engine import InferenceEngine, ServeConfig
+from tensorflow_examples_tpu.telemetry import schema
 from tensorflow_examples_tpu.telemetry import spans as spans_mod
 from tensorflow_examples_tpu.telemetry.registry import (
     MetricsRegistry,
@@ -391,23 +392,27 @@ def _children(events, parent):
             if e is not parent and parent[0] <= e[0] and e[1] <= parent[1]]
 
 
+def _top(events):
+    return [e for e in events
+            if not any(o is not e and o[0] <= e[0] and e[1] <= o[1]
+                       for o in events)]
+
+
 def test_the_serve_thread_runs_the_span_sequence_without_holes(served_trace):
     events, _ = served_trace
-    top = [e for e in events
-           if not any(o is not e and o[0] <= e[0] and e[1] <= o[1]
-                      for o in events)]
+    top = _top(events)
     names = [e[2] for e in top]
-    allowed = {"span/serve_admission", "span/serve_prefill",
+    allowed = {"span/serve_admission", "span/serve_admit",
                "span/serve_decode_step", "span/serve_commit"}
     assert set(names) <= allowed, set(names) - allowed
     steps = [i for i, n in enumerate(names) if n == "span/serve_decode_step"]
     assert len(steps) >= 5
     for i in steps:
         # commit right behind every decode step; admission in front of
-        # it, with only this iteration's prefills between
+        # it, with only this iteration's admits between
         assert names[i + 1] == "span/serve_commit"
         j = i - 1
-        while j >= 0 and names[j] == "span/serve_prefill":
+        while j >= 0 and names[j] == "span/serve_admit":
             j -= 1
         # (the first iteration's admission was waiting for a request
         # when the trace began: a span opened before the session is
@@ -415,7 +420,11 @@ def test_the_serve_thread_runs_the_span_sequence_without_holes(served_trace):
         assert names[j] == "span/serve_admission" if j >= 0 else i == steps[0]
         assert j <= 0 or names[j - 1] in (
             "span/serve_commit", "span/serve_admission")
-    assert "span/serve_prefill" in names
+    admits = [e for e in top if e[2] == "span/serve_admit"]
+    assert len(admits) == 2
+    for admit in admits:
+        # the whole admission: GPT-2's unchunked prompts prefill in it
+        assert [e[2] for e in _children(events, admit)][0] == "span/serve_prefill"
 
 
 def test_build_upload_dispatch_fetch_sit_inside_the_decode_step(served_trace):
@@ -429,9 +438,14 @@ def test_build_upload_dispatch_fetch_sit_inside_the_decode_step(served_trace):
         build = _children(events, step)[0]
         assert build[3]["K"] in (32, 64)
     for prefill in (e for e in events if e[2] == "span/serve_prefill"):
-        assert [e[2] for e in _children(events, prefill)] == [
-            "span/engine_prefill_build", "span/engine_prefill_upload",
-            "span/engine_prefill_dispatch", "span/engine_prefill_fetch"]
+        # (the pool's claim inside the build, its publish behind the
+        # dispatch: unique prompts, nothing reused, nothing extended)
+        inner = _children(events, prefill)
+        assert [e[2] for e in inner] == [
+            "span/engine_prefill_build", "span/kv_claim",
+            "span/engine_prefill_upload", "span/engine_prefill_dispatch",
+            "span/kv_publish", "span/engine_prefill_fetch"]
+        assert inner[1][3]["reused"] == 0
 
 
 def test_the_busy_thread_is_inside_a_span_nearly_all_the_time(served_trace):
@@ -441,7 +455,7 @@ def test_the_busy_thread_is_inside_a_span_nearly_all_the_time(served_trace):
     first = min(e[0] for e in events if e[2] == "span/serve_decode_step")
     last = max(e[1] for e in events if e[2] == "span/serve_commit")
     top = [e for e in events if first <= e[0] and e[1] <= last
-           and e[2] in ("span/serve_admission", "span/serve_prefill",
+           and e[2] in ("span/serve_admission", "span/serve_admit",
                         "span/serve_decode_step", "span/serve_commit")]
     covered = sum(e[1] - e[0] for e in top)
     assert covered / (last - first) > 0.9
@@ -456,3 +470,149 @@ def test_the_same_spans_reach_the_chrome_buffer_on_the_batcher_thread(
     counts = lambda name: sum(1 for e in events if e[2] == "span/" + name)
     assert counts("serve_commit") == counts("serve_decode_step") \
         == counts("engine_decode_fetch")
+
+
+# --------------------------- the prefix-hit and chunked paths (ISSUE 36)
+
+DOC = list(range(1, 41))       # five blocks of 8: published before the trace
+TOP = ("span/serve_admission", f"span/{schema.SERVE_ADMIT_SPAN}", "span/serve_prefill_chunk",
+       "span/serve_decode_step", "span/serve_commit")
+
+
+@pytest.fixture(scope="module")
+def prefix_trace(tmp_path_factory):
+    """The toy engine with the prefix cache on and chunks of 16 (blocks
+    of 8): a document goes through its three chunks before the session;
+    in it, two questions on the document (a prefix hit: a tail of 3
+    after 40 cached tokens, run inline through an extend launch) and one
+    cold prompt of 40 (the chunked path). The serve thread's ``span/``
+    events."""
+    import jax
+
+    trace_dir = str(tmp_path_factory.mktemp("prefix_trace"))
+    engine = _engine(kv_block_size=8, prefill_chunk_tokens=16)
+    engine.warmup()
+    batcher = ContinuousBatcher(engine).start()
+    try:
+        batcher.submit(Request(prompt=DOC, max_new_tokens=2)).result(
+            timeout=120)
+        jax.profiler.start_trace(trace_dir)
+        try:
+            futures = [
+                batcher.submit(Request(prompt=prompt, max_new_tokens=6))
+                for prompt in (DOC + [50, 51, 52], DOC + [60, 61, 62],
+                               list(range(100, 140)))
+            ]
+            results = [f.result(timeout=120) for f in futures]
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        batcher.close(drain=True, timeout=60.0)
+    assert all(len(r.tokens) == 6 for r in results)
+    (events,) = [evs for evs in _span_lines(trace_dir).values()
+                 if any(e[2] == "span/serve_decode_step" for e in evs)]
+    return events
+
+
+def _parent(events, e):
+    """The innermost span around ``e``."""
+    around = [o for o in events
+              if o is not e and o[0] <= e[0] and e[1] <= o[1]]
+    return min(around, key=lambda o: o[1] - o[0]) if around else None
+
+
+def test_prefix_and_chunked_admissions_leave_no_unnamed_stretch(prefix_trace):
+    """Every stretch of work is inside one of the loop's top-level spans,
+    in the loop's order, and those cover the busy thread."""
+    events = prefix_trace
+    top = _top(events)
+    names = [e[2] for e in top]
+    assert set(names) <= set(TOP), set(names) - set(TOP)
+    assert names.count("span/serve_admit") == 3
+    for i, name in enumerate(names[1:], 1):
+        # an admit follows the iteration's admission or another admit;
+        # a commit follows its decode step (the first iteration's
+        # admission opened before the session: it is not in the trace)
+        if name == "span/serve_admit":
+            assert names[i - 1] in ("span/serve_admission", "span/serve_admit")
+        if name == "span/serve_commit":
+            assert names[i - 1] == "span/serve_decode_step"
+        if name == "span/serve_decode_step":
+            assert names[i - 1] in ("span/serve_admission", "span/serve_admit",
+                                    "span/serve_prefill_chunk")
+    first = min(e[0] for e in top if e[2] == "span/serve_decode_step")
+    last = max(e[1] for e in top if e[2] == "span/serve_commit")
+    covered = sum(e[1] - e[0] for e in top if first <= e[0] and e[1] <= last)
+    assert covered / (last - first) > 0.9
+
+
+def test_a_prefix_hit_claims_extends_and_publishes_inside_its_admit(prefix_trace):
+    events = prefix_trace
+    hits = [e for e in events if e[2] == "span/serve_admit"
+            and e[3]["tokens"] == len(DOC) + 3]
+    assert len(hits) == 2
+    for admit in hits:
+        inner = _children(events, admit)
+        assert [e[2] for e in inner] == [
+            "span/kv_claim", "span/serve_prefill",
+            "span/engine_extend_build", "span/engine_extend_upload",
+            "span/engine_extend_dispatch", "span/kv_publish",
+            "span/engine_extend_fetch"]
+        claim, _, build, *_, publish, _ = inner
+        assert claim[3] == dict(zip(schema.KV_CLAIM_ARGS, (len(DOC) + 3, len(DOC))))
+        assert _parent(events, claim) is admit
+        assert set(build[3]) == set(schema.EXTEND_BUILD_ARGS)
+        assert build[3]["ctx"] == len(DOC) and build[3]["T"] == 16
+        assert build[3]["rung"] >= len(DOC)
+        # the walk hashes the document's five blocks again, publishes none
+        assert publish[3] == dict(zip(schema.KV_PUBLISH_ARGS, (5, 0)))
+
+
+def test_a_chunked_prompt_claims_in_its_admit_and_extends_in_its_chunks(prefix_trace):
+    events = prefix_trace
+    (admit,) = [e for e in events if e[2] == "span/serve_admit"
+                and e[3]["tokens"] == 40]
+    assert [e[2] for e in _children(events, admit)] == ["span/kv_claim"]
+    assert _children(events, admit)[0][3] == {"tokens": 40, "reused": 0}
+    chunks = [e for e in events if e[2] == "span/serve_prefill_chunk"]
+    assert len(chunks) == 3
+    for k, chunk in enumerate(chunks):
+        inner = [e[2] for e in _children(events, chunk)]
+        launch = ["span/engine_extend_build", "span/engine_extend_upload",
+                  "span/engine_extend_dispatch"]
+        # the last chunk publishes the prompt and fetches its first token
+        # inside the chunk's turn
+        assert inner == launch + (
+            ["span/kv_publish", "span/engine_extend_fetch"] if k == 2 else [])
+        build = _children(events, chunk)[0]
+        assert build[3]["ctx"] == 16 * k and build[3]["T"] == 16
+        assert build[3]["rung"] >= 16 * k
+    publish = next(e for e in _children(events, chunks[-1])
+                   if e[2] == "span/kv_publish")
+    assert publish[3] == {"blocks": 5, "new": 5}
+    assert not [e for e in events if e[2].startswith("span/engine_prefill")]
+
+
+def test_a_plain_prefix_hit_claims_in_the_prefill_build_and_extends():
+    """Unchunked, a prefix hit's claim stays inside ``engine_prefill_build``
+    (the kind is known only after it) and the tail runs as an extend
+    launch under its own names; a cold prompt stays a prefill. The
+    spans' Chrome events, no profiler session."""
+    engine = _engine(kv_block_size=8)
+    tracer = spans_mod.reset_default_tracer()
+    engine.prefill(0, DOC)
+    # (no warm-up: a first launch compiles inside its dispatch)
+    cold = [e["name"] for e in tracer.events() if e["name"] != "compile"]
+    tracer = spans_mod.reset_default_tracer()
+    engine.prefill(1, DOC + [50, 51, 52])
+    events = [e for e in tracer.events() if e["name"] != "compile"]
+    names = [e["name"] for e in events]       # in the order they closed
+    assert cold == ["kv_claim", "engine_prefill_build", "engine_prefill_upload",
+                    "engine_prefill_dispatch", "kv_publish", "engine_prefill_fetch"]
+    assert names == ["kv_claim", "engine_prefill_build", "engine_extend_build",
+                     "engine_extend_upload", "engine_extend_dispatch", "kv_publish",
+                     "engine_extend_fetch"]
+    claim, _, build = events[:3]
+    assert claim["args"] == {"tokens": len(DOC) + 3, "reused": len(DOC)}
+    assert build["args"]["ctx"] == len(DOC) and build["args"]["T"] == 16
+    assert build["args"]["rung"] == engine.pool.max_blocks_per_slot * 8   # the whole table
